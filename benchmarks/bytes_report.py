@@ -1,8 +1,8 @@
 """Bytes-per-step report: A/B the remat policies on the headline ResNet-50
 training step via XLA's own cost model.
 
-The round-4 roofline analysis (BENCH_NOTES.md) pinned the full train step
-at 95% of the v5e HBM-bandwidth floor: 81.49 GB accessed / 5.689 TFLOP per
+The 2026-07-31 roofline analysis (BENCH_LAST_TPU.json) pinned the full
+train step at 95% of the v5e HBM-bandwidth floor: 81.49 GB accessed / 5.689 TFLOP per
 step at batch 256 bf16. Further headline gains therefore require MOVING
 FEWER BYTES, not faster kernels. The candidate lever is the "io" remat
 policy (parallel/trainer.py): keep the MXU outputs (conv/matmul, tagged
@@ -15,12 +15,11 @@ bytes-accessed counts plus the implied bandwidth-floor step time. A mode
 is `<remat>[+fused]`: the remat policy (none/full/io) crossed with the
 Pallas fused BN/ReLU/residual epilogue (MXNET_FUSED_BN_EPILOGUE=1,
 ops/pallas_fused.py) — the four decision modes of the bytes ledger are
-none / io / fused / io+fused (BENCH_NOTES.md avenue 3).
+none / io / fused / io+fused.
 
 Run on TPU for the authoritative numbers (fusion decisions are
-backend-specific; XLA:CPU CSEs remat differently) — benchmarks/
-tpu_session.sh runs it there (step 2b/2c). A CPU run (BYTES_SMALL=1
-recommended) still shows the program-level delta: saved-residual bytes
+backend-specific; XLA:CPU CSEs remat differently). A CPU run
+(BYTES_SMALL=1 recommended) still shows the program-level delta: saved-residual bytes
 move out of the forward/backward boundary. Two disclosures on every CPU
 line: the numbers are DIRECTIONAL (backend-specific fusion), and in
 fused modes the kernels run under the Pallas interpreter, whose lowered
@@ -86,8 +85,6 @@ def analyze(step, x, y):
             jnp.float32(0.0))  # chaos grad-poison seam: 0.0 = disarmed
     compiled = step._step_fn.lower(*args).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     return {
         "flops": cost.get("flops"),

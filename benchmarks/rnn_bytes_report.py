@@ -1,7 +1,7 @@
 """RNN carry-traffic bytes A/B: lax.scan vs the persistent fused kernel.
 
-The round-5 word-LM analysis (BENCH_NOTES.md) pins the LSTM train step to
-the sequential scan's per-iteration cost. Structurally, every XLA
+The 2026-07-31 word-LM profile pins the LSTM train step to the
+sequential scan's per-iteration cost. Structurally, every XLA
 while-loop iteration of the scan path moves per step:
 
 - the h/c carry round trip: ~4·N·H·itemsize (2 reads + 2 writes);
@@ -41,8 +41,8 @@ RNN_BYTES_HIDDEN (256 — the Mosaic-tile-eligible sweep width),
 BENCH_DTYPE (float32).
 
 Output: one JSON line per (mode, T) + the slope ledger on stderr.
-Committed artifact: BENCH_BYTES_RNN_CPU.txt (CPU run); tpu_session.sh
-step 2e re-runs it on-chip.
+Committed artifact: BENCH_BYTES_RNN_CPU.txt (CPU run); it has not run
+on the chip.
 """
 import json
 import os
@@ -81,8 +81,6 @@ def build_layer_grad(fused, T, N, C, H, dtype):
 
 def cost_of(jitted, args):
     cost = jitted.lower(*args).compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return (float(cost.get("flops", 0) or 0),
             float(cost.get("bytes accessed", 0) or 0))
 

@@ -45,8 +45,8 @@ SERVING_BYTES_BATCH (4), SERVING_BYTES_EXEC=1 (also time 20 real decode
 steps per leg), SERVING_BYTES_TP (comma list, default 1,2,4 — legs that
 don't fit the device/head count are skipped with a note). Output: one
 JSON line per (path, T) and per tp leg + a summary table on stderr.
-tpu_session.sh steps 2d/2g run it on TPU; the committed CPU run is
-BENCH_BYTES_SERVING_CPU.txt.
+The committed CPU run is BENCH_BYTES_SERVING_CPU.txt; it has not run on
+the chip.
 """
 import json
 import os
@@ -111,8 +111,6 @@ def analyze(eng, model, padded_T, width, true_lens):
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     info = {
         "path": "paged" if eng.paged else "gather",
         "tp": eng.tp,
@@ -280,8 +278,6 @@ def main():
         t0 = time.perf_counter()
         cost = model_q._decode_paged_q_jit.lower(*args).compile() \
             .cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0] if cost else {}
         fl4, by4 = paged_call_cost(batch, 1, cfg_heads, cfg_dh,
                                    w_paged, block_size)
         fl8, by8 = paged_call_cost(batch, 1, cfg_heads, cfg_dh,

@@ -38,7 +38,7 @@ const char* MXTPUPredGetLastError(void);
 /* Create a predictor from an artifact and a PJRT plugin.
  * opt_specs: num_opts strings in the CLI --opt grammar
  * ("name=int:N" | "name=str:S"), passed to PJRT_Client_Create as
- * NamedValues (tunneled TPU plugins require several; NULL/0 for none). */
+ * NamedValues (some plugins require several; NULL/0 for none). */
 int MXTPUPredCreate(const char* artifact_path,
                     const char* plugin_so,
                     const char* const* opt_specs,

@@ -8,8 +8,8 @@
 //
 // --echo-input-check asserts output 0 byte-equals input 0 (used by the
 // mock-plugin test, whose Execute is an echo).
-// --opt passes a NamedValue to PJRT_Client_Create — some plugins
-// (tunneled TPU clients) require plugin-specific create options.
+// --opt passes a NamedValue to PJRT_Client_Create — some plugins require
+// plugin-specific create options.
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>

@@ -51,7 +51,7 @@ from ..base import MXNetError
 
 #: entry format version — bumped on any layout change (old entries then
 #: fail the meta check and are recompiled, never misread)
-FORMAT = 1
+FORMAT = 2
 
 #: entry file suffix (one zip per executable)
 SUFFIX = ".mxaot"
@@ -143,40 +143,32 @@ def key_for(site, sig, lowered_text, variant=None, placement=(),
 
 
 # ---------------------------------------------------------------------------
-# executable (de)serialization — the version-portable seam
+# executable (de)serialization
 # ---------------------------------------------------------------------------
 
 
-def _serializers():
-    """(serialize, deserialize_and_load) or None when this jax build
-    can't round-trip executables — caching then silently disables (the
-    flag switches persistence, never behavior)."""
-    try:
-        from jax.experimental.serialize_executable import (
-            serialize, deserialize_and_load)
-        return serialize, deserialize_and_load
-    except Exception:                                    # pragma: no cover
-        return None
-
-
 def serialize_executable_blob(compiled):
-    """(payload bytes, pickled (in_tree, out_tree)) for a compiled
-    executable, or None when serialization is unavailable/unsupported
-    for this executable."""
-    sz = _serializers()
-    if sz is None:                                       # pragma: no cover
-        return None
-    payload, in_tree, out_tree = sz[0](compiled)
-    return bytes(payload), pickle.dumps((in_tree, out_tree))
+    """(payload bytes, pickled (in_tree, out_tree), device ids) for a
+    compiled executable. The device ids are the executable's own, in
+    assignment order: `load_executable` must be handed them back."""
+    from jax.experimental.serialize_executable import serialize
+    payload, in_tree, out_tree = serialize(compiled)
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return bytes(payload), pickle.dumps((in_tree, out_tree)), ids
 
 
-def load_executable(payload, in_tree, out_tree):
+def load_executable(payload, in_tree, out_tree, device_ids):
     """Rehydrate a serialized executable into a callable taking the
-    original dynamic arguments — zero XLA compilation."""
-    sz = _serializers()
-    if sz is None:                                       # pragma: no cover
-        raise CorruptEntry("executable serialization unavailable")
-    return sz[1](payload, in_tree, out_tree)
+    original dynamic arguments — zero XLA compilation. It is loaded onto
+    the devices it was compiled for (`device_ids`): left to its default,
+    jax loads onto every device of the backend and a one-device
+    executable then expects one shard per device."""
+    import jax
+    from jax.experimental.serialize_executable import deserialize_and_load
+    by_id = {d.id: d for d in jax.devices()}
+    return deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 # ---------------------------------------------------------------------------
@@ -348,9 +340,9 @@ def cache_dir():
 
 def cache():
     """The process-wide AOTCache, or None when caching is off (no dir
-    configured, or this jax can't serialize executables)."""
+    configured)."""
     d = cache_dir()
-    if not d or _serializers() is None:
+    if not d:
         return None
     with _cache_lock:
         c = _caches.get(d)

@@ -10,11 +10,37 @@ import ctypes
 import os
 import numpy as np
 
+import jax
 import jax.numpy as jnp
+
+#: the one jax this code is written and tested against (the installed
+#: one); there are no shims for any other, so another version is refused
+#: at import instead of failing somewhere inside a trace
+SUPPORTED_JAX = "0.9.0"
+if jax.__version__ != SUPPORTED_JAX:
+    raise ImportError("mxnet_tpu supports jax %s, found jax %s"
+                      % (SUPPORTED_JAX, jax.__version__))
 
 
 class MXNetError(RuntimeError):
     """Error raised by the framework (parity: reference MXNetError)."""
+
+
+def enable_compile_cache():
+    """Point jax's persistent compilation cache at a directory that can be
+    placed from outside, and return it. Entry points (chip_smoke.py,
+    bench.py, tools/serve.py, tests/conftest.py) call this before their
+    first compile. Where `JAX_COMPILATION_CACHE_DIR` is set jax has
+    already read it and nothing is touched; otherwise the cache lives at
+    `<checkout>/.jax_cache` — a fixed path derived from this file, because
+    the path is part of what a later process must find again.
+    `jax_persistent_cache_min_compile_time_secs` stays at jax's default,
+    so only compiles worth keeping are written."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------

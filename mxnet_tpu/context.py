@@ -41,18 +41,6 @@ class Context:
     def is_accelerator(self):
         return self.device_type in ("gpu", "tpu")
 
-    def jax_device(self):
-        """Resolve to a concrete jax.Device (accelerator if requested & present)."""
-        if self.is_accelerator:
-            accels = [d for d in jax.devices() if d.platform != "cpu"]
-            if accels:
-                return accels[self.device_id % len(accels)]
-            # graceful fallback (e.g. CPU-only test mesh)
-            return jax.devices()[self.device_id % len(jax.devices())]
-        cpus = jax.devices("cpu") if any(
-            d.platform == "cpu" for d in jax.local_devices()) else jax.devices()
-        return cpus[self.device_id % len(cpus)]
-
     def __hash__(self):
         return hash((self.device_typeid, self.device_id))
 

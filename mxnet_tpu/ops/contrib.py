@@ -786,7 +786,7 @@ def PagedAttention(query, k_pool, v_pool, block_table, q_start,
     """Ragged paged attention over a block-pooled KV-cache — the serving
     decode/prefill read (ops/pallas_paged.py) as a public operator.
 
-    query [B, Tq, H, Dh]; k_pool/v_pool [num_blocks, block_size, H, Dh]
+    query [B, Tq, H, Dh]; k_pool/v_pool [num_blocks, H, block_size, Dh]
     (ONE layer of serving.PagedKVCache's contiguous-per-layer pools);
     block_table [B, w] int32; q_start [B] int32 true position of each
     row's first query token. Keys past position q_start+i are masked per
@@ -807,14 +807,17 @@ def PagedAttention(query, k_pool, v_pool, block_table, q_start,
     if scale is None:
         scale = 1.0 / _math.sqrt(Dh)
     interpret = default_interpret()
-    if _pp.paged_enabled() and _pp.paged_eligible(Dh, block_size, Tq,
-                                                 interpret):
+    if _pp.paged_enabled() and _pp.paged_fallback_reason(
+            Dh, block_size, interpret, k_pool.dtype) is None:
         return _pp.paged_attention(query, k_pool, v_pool, block_table,
                                    q_start, block_size, scale=scale,
                                    interpret=interpret)
     w = block_table.shape[1]
-    ks = k_pool[block_table].reshape(B, w * block_size, H, Dh)
-    vs = v_pool[block_table].reshape(B, w * block_size, H, Dh)
+    # (B, w, H, bs, Dh) -> position-ordered (B, w*bs, H, Dh)
+    ks = k_pool[block_table].transpose(0, 1, 3, 2, 4).reshape(
+        B, w * block_size, H, Dh)
+    vs = v_pool[block_table].transpose(0, 1, 3, 2, 4).reshape(
+        B, w * block_size, H, Dh)
     s = jnp.einsum("bqhd,bthd->bhqt", query.astype(jnp.float32),
                    ks.astype(jnp.float32)) * scale
     kp = jnp.arange(w * block_size)[None, None, None, :]

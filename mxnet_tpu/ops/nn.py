@@ -479,8 +479,8 @@ def BatchNorm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
     if training:
         # MXNET_FUSED_BN_EPILOGUE=1: hand-fused Pallas kernels (one-pass
         # stats + normalize in two HBM sweeps, custom VJP) — the bytes/step
-        # lever for the bandwidth-bound train step (BENCH_NOTES.md avenue
-        # 3). Ineligible shapes/layouts keep the XLA path below.
+        # lever for the bandwidth-bound train step. Ineligible
+        # shapes/layouts keep the XLA path below.
         from . import pallas_fused as _pf
         if _pf.fuse_enabled() and _pf.fuse_eligible(data, axis):
             out, mean, var = _pf.fused_bn_act(data, g, beta, eps=eps)

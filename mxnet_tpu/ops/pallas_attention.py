@@ -249,11 +249,10 @@ def _make_flash(scale, causal, block_q, block_k, interpret):
 
 
 def default_interpret():
-    """Interpreter mode off only on real TPU backends."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    """Interpreter mode off only on real TPU backends. A backend that
+    fails to initialise raises here: it must not turn every kernel into
+    the interpreter."""
+    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,

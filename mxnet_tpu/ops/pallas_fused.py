@@ -1,9 +1,9 @@
 """Pallas fused BatchNorm/ReLU/residual epilogue kernels.
 
-Why a hand kernel: the round-4 roofline analysis (BENCH_NOTES.md) pinned
-the ResNet-50 train step at 95% of the v5e HBM-bandwidth floor — 81.49 GB
-accessed per step at ~70 flops/byte — and the per-HLO profile names the
-remaining elementwise headroom: 9 ms-class loop fusions on
+Why a hand kernel: the 2026-07-31 roofline analysis pinned the ResNet-50
+train step at 95% of the v5e HBM-bandwidth floor — 81.49 GB accessed per
+step at ~70 flops/byte — and the per-HLO profile named the remaining
+elementwise headroom: 9 ms-class loop fusions on
 [256,256,56,56] BatchNorm/residual chains. XLA's automatic fusion has
 already done what it can there; the next step is the TVM-style cross-op
 fusion (Chen et al., arXiv:1802.04799) written by hand: one kernel per
@@ -35,9 +35,9 @@ Selection: `MXNET_FUSED_BN_EPILOGUE=1` (read at trace time) routes the
 kernels for training-mode batch-stats BN; everything else (eval BN,
 channels-last layouts, exotic dtypes) keeps the XLA path. On CPU the
 kernels run in Pallas interpreter mode — the equality tests in
-tests/test_pallas.py prove forward + VJP against the XLA path there, so
-the TPU run is a pure measurement question (benchmarks/bytes_report.py,
-tpu_session.sh step 2c).
+tests/test_pallas.py prove forward + VJP against the XLA path there;
+`chip_smoke.py` compiles them with Mosaic and compares with the same
+XLA path on the chip.
 """
 from __future__ import annotations
 
@@ -56,35 +56,66 @@ def fuse_enabled():
     return os.environ.get("MXNET_FUSED_BN_EPILOGUE", "0") == "1"
 
 
-#: per-grid-step VMEM budget for one input block (the kernels hold at most
-#: three such blocks live: x, residual/dy, out)
-_BLOCK_BYTES = 1 << 21
-#: grid-size cap: beyond this the interpreter-mode python loop (CPU tests)
-#: dominates and the XLA fallback is the better path
+#: per-grid-step VMEM budget for one input block. The backward reduce
+#: holds four such blocks (dy, y, x in; dz out), each double-buffered by
+#: the pipeline, plus their f32 working copies: 0.5 MiB keeps all of it
+#: well inside v5e's 16 MiB default scoped VMEM.
+_BLOCK_BYTES = 1 << 19
+#: grid-size cap for the interpreter, whose grid is a python loop (CPU
+#: tests): beyond this the XLA path is the better one. The chip has no
+#: such limit.
 _MAX_GRID = 4096
 
 
 @functools.lru_cache(maxsize=None)
-def _largest_divisor(n, cap):
-    """Largest divisor of n that is <= cap (blocks must tile exactly —
-    Pallas pads out-of-bounds reads with undefined values, which would
-    corrupt the statistics reductions)."""
+def _largest_divisor(n, cap, multiple_of=1):
+    """Largest divisor of n that is <= cap and a multiple of
+    `multiple_of`, or None (blocks must tile exactly — Pallas pads
+    out-of-bounds reads with undefined values, which would corrupt the
+    statistics reductions)."""
     for d in range(max(1, min(n, cap)), 0, -1):
-        if n % d == 0:
+        if n % d == 0 and d % multiple_of == 0:
             return d
-    return 1
+    return None
 
 
 def _blocks_for(shape3, dtype):
-    """(bc, bs) channel/spatial block sizes for an [N, C, S] view. bc
-    targets the sublane tile (16 for bf16, 8 for f32); bs fills the lane
-    dimension up to the VMEM block budget."""
+    """(bn, bc, bs) batch/channel/spatial block sizes for an [N, C, S]
+    view, or None when no legal decomposition exists. Mosaic wants the
+    two minor block dims whole tiles or the array's whole extent, so bc
+    is the sublane tile (16 for bf16, 8 for f32) or all of C, and bs is
+    all of S when a [1, bc, S] slab fits the block budget (ResNet's
+    56x56, 28x28, 14x14 and 7x7 planes are no multiple of 128) and the
+    largest 128-multiple divisor of S otherwise. The batch dim is free:
+    bn fills what is left of the budget."""
     N, C, S = shape3
     itemsize = jnp.dtype(dtype).itemsize
     sub = 16 if jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16) else 8
-    bc = _largest_divisor(C, sub)
-    bs = _largest_divisor(S, max(1, _BLOCK_BYTES // max(1, N * bc * itemsize)))
-    return bc, bs
+    bc = sub if C % sub == 0 else C
+    cap = _BLOCK_BYTES // (bc * itemsize)
+    bs = S if S <= cap else _largest_divisor(S, cap, 128)
+    if not bs:
+        return None
+    # VMEM rounds the lane extent up to whole 128-lane tiles
+    lanes = -(-bs // 128) * 128
+    return _largest_divisor(N, max(1, cap // lanes)), bc, bs
+
+
+def _grid_for(shape3, blocks):
+    """(channel, batch, spatial) grid: the two reduction axes innermost,
+    so a channel block's statistics accumulate in scratch across them."""
+    (N, C, S), (bn, bc, bs) = shape3, blocks
+    return C // bc, N // bn, S // bs
+
+
+def _big_spec(blocks):
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec(blocks, lambda i, j, k: (j, i, k))
+
+
+def _per_channel_spec(bc):
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec((bc, 1), lambda i, j, k: (i, 0))
 
 
 def _flat_spatial(shape):
@@ -94,36 +125,39 @@ def _flat_spatial(shape):
     return s
 
 
-def fuse_eligible(x, axis=1):
+def fuse_eligible(x, axis=1, interpret=None):
     """Gate for the fused kernels; callers fall back to the XLA path when
-    False. Requires channel axis 1, f32/bf16 data, and a block
-    decomposition whose grid stays small enough for interpreter mode."""
+    False. Requires channel axis 1, f32/bf16 data and a legal block
+    decomposition (`_blocks_for`); under the interpreter also a grid
+    small enough for its python loop."""
     if x.ndim < 2 or axis % x.ndim != 1:
         return False
     if jnp.dtype(x.dtype) not in (jnp.dtype(jnp.float32),
                                   jnp.dtype(jnp.bfloat16)):
         return False
-    N, C = x.shape[0], x.shape[1]
-    S = _flat_spatial(x.shape)
-    if N * C * S == 0:
+    shape3 = (x.shape[0], x.shape[1], _flat_spatial(x.shape))
+    if 0 in shape3:
         return False
-    bc, bs = _blocks_for((N, C, S), x.dtype)
-    return (C // bc) * (S // bs) <= _MAX_GRID
+    blocks = _blocks_for(shape3, x.dtype)
+    if blocks is None:
+        return False
+    if interpret is None:
+        interpret = default_interpret()
+    if not interpret:
+        return True
+    nc, nn, ns = _grid_for(shape3, blocks)
+    return nc * nn * ns <= _MAX_GRID
 
 
 def _cost(flops, bytes_accessed, transcendentals=0):
-    """cost_estimate kwarg for pallas_call when this jax version supports
-    it — on TPU the kernel is an opaque custom call, and without a declared
-    cost the XLA cost model (bytes_report.py's A/B instrument) would count
-    it as zero bytes. Shared with pallas_rnn.py."""
-    try:
-        from jax.experimental import pallas as pl
-        est = pl.CostEstimate(flops=int(flops),
-                              bytes_accessed=int(bytes_accessed),
-                              transcendentals=int(transcendentals))
-        return {"cost_estimate": est}
-    except Exception:
-        return {}
+    """cost_estimate kwarg for pallas_call — on TPU the kernel is an
+    opaque custom call, and without a declared cost the XLA cost model
+    (bytes_report.py's A/B instrument) would count it as zero bytes.
+    Shared with pallas_rnn.py and pallas_attention.py."""
+    from jax.experimental import pallas as pl
+    return {"cost_estimate": pl.CostEstimate(
+        flops=int(flops), bytes_accessed=int(bytes_accessed),
+        transcendentals=int(transcendentals))}
 
 
 # ---------------------------------------------------------------------------
@@ -131,24 +165,41 @@ def _cost(flops, bytes_accessed, transcendentals=0):
 # ---------------------------------------------------------------------------
 
 
-def _stats_kernel(x_ref, mean_ref, var_ref, s_scr, q_scr, *, ns, inv_m):
-    """One-pass E[x]/E[x^2] per channel, f32 accumulation. Grid (nc, ns),
-    spatial innermost; scratch carries the partial sums across spatial
-    steps (same accumulator pattern as the flash-attention kernel)."""
+def _first_last_step():
+    """(is first, is last) step of a channel block's reduction sweep —
+    grid axes 1 (batch) and 2 (spatial) of `_grid_for`."""
+    from jax.experimental import pallas as pl
+    j, k = pl.program_id(1), pl.program_id(2)
+    return ((j == 0) & (k == 0),
+            (j == pl.num_programs(1) - 1) & (k == pl.num_programs(2) - 1))
+
+
+def _channel_sum(xb):
+    """[bn, bc, bs] -> [bc, 1]: the batch dim folds with plain vector
+    adds, then one lane reduction that keeps its dim (a rank-1 [bc]
+    intermediate is a shape Mosaic lays out poorly)."""
+    return jnp.sum(jnp.sum(xb, axis=0), axis=1, keepdims=True)
+
+
+def _stats_kernel(x_ref, mean_ref, var_ref, s_scr, q_scr, *, inv_m):
+    """One-pass E[x]/E[x^2] per channel, f32 accumulation. Grid
+    (nc, nn, ns), channel outermost; scratch carries the partial sums
+    across a channel block's batch and spatial steps (same accumulator
+    pattern as the flash-attention kernel)."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)
+    first, last = _first_last_step()
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
         q_scr[...] = jnp.zeros_like(q_scr)
 
-    xb = x_ref[...].astype(jnp.float32)            # [N, bc, bs]
-    s_scr[...] = s_scr[...] + jnp.sum(xb, axis=(0, 2))[:, None]
-    q_scr[...] = q_scr[...] + jnp.sum(xb * xb, axis=(0, 2))[:, None]
+    xb = x_ref[...].astype(jnp.float32)            # [bn, bc, bs]
+    s_scr[...] = s_scr[...] + _channel_sum(xb)
+    q_scr[...] = q_scr[...] + _channel_sum(xb * xb)
 
-    @pl.when(j == ns - 1)
+    @pl.when(last)
     def _emit():
         m = s_scr[...] * inv_m
         mean_ref[...] = m
@@ -160,17 +211,17 @@ def _bn_stats(x3, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     N, C, S = x3.shape
-    bc, bs = _blocks_for(x3.shape, x3.dtype)
-    ns = S // bs
-    kern = functools.partial(_stats_kernel, ns=ns, inv_m=1.0 / (N * S))
+    blocks = _blocks_for(x3.shape, x3.dtype)
+    bc = blocks[1]
+    per_c = _per_channel_spec(bc)
+    kern = functools.partial(_stats_kernel, inv_m=1.0 / (N * S))
     mean, var = pl.pallas_call(
         kern,
         out_shape=[jax.ShapeDtypeStruct((C, 1), jnp.float32),
                    jax.ShapeDtypeStruct((C, 1), jnp.float32)],
-        grid=(C // bc, ns),
-        in_specs=[pl.BlockSpec((N, bc, bs), lambda i, j: (0, i, j))],
-        out_specs=[pl.BlockSpec((bc, 1), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bc, 1), lambda i, j: (i, 0))],
+        grid=_grid_for(x3.shape, blocks),
+        in_specs=[_big_spec(blocks)],
+        out_specs=[per_c, per_c],
         scratch_shapes=[pltpu.VMEM((bc, 1), jnp.float32),
                         pltpu.VMEM((bc, 1), jnp.float32)],
         interpret=interpret,
@@ -200,10 +251,10 @@ def _bn_apply(x3, scale, offset, res3, relu, interpret):
     from jax.experimental import pallas as pl
 
     N, C, S = x3.shape
-    bc, bs = _blocks_for(x3.shape, x3.dtype)
+    blocks = _blocks_for(x3.shape, x3.dtype)
     itemsize = jnp.dtype(x3.dtype).itemsize
-    big = pl.BlockSpec((N, bc, bs), lambda i, j: (0, i, j))
-    per_c = pl.BlockSpec((bc, 1), lambda i, j: (i, 0))
+    big = _big_spec(blocks)
+    per_c = _per_channel_spec(blocks[1])
     kern = functools.partial(_apply_kernel, relu=relu,
                              has_res=res3 is not None)
     in_specs = [big, per_c, per_c]
@@ -216,7 +267,7 @@ def _bn_apply(x3, scale, offset, res3, relu, interpret):
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((N, C, S), x3.dtype),
-        grid=(C // bc, S // bs),
+        grid=_grid_for(x3.shape, blocks),
         in_specs=in_specs,
         out_specs=big,
         interpret=interpret,
@@ -230,7 +281,7 @@ def _bn_apply(x3, scale, offset, res3, relu, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_reduce_kernel(*refs, ns, relu):
+def _bwd_reduce_kernel(*refs, relu):
     """Backward pass 1: apply the relu mask to dy (one read of dy + y) and
     reduce sum(dz), sum(dz * xhat) per channel in the same sweep — the
     one-pass statistic-gradient read. dz is stored once; it IS the
@@ -243,9 +294,9 @@ def _bwd_reduce_kernel(*refs, ns, relu):
     else:
         (dy_ref, x_ref, mean_ref, inv_ref,
          sdz_ref, sdx_ref, a_scr, b_scr) = refs
-    j = pl.program_id(1)
+    first, last = _first_last_step()
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         a_scr[...] = jnp.zeros_like(a_scr)
         b_scr[...] = jnp.zeros_like(b_scr)
@@ -255,17 +306,19 @@ def _bwd_reduce_kernel(*refs, ns, relu):
         # mask from the saved/recomputed output sign; store dz rounded to
         # the activation dtype and reduce the SAME rounded values so the
         # sums seen by pass 2 are consistent with the dz it re-reads
-        dz_store = jnp.where(y_ref[...] > 0, dy, 0.0).astype(dz_ref.dtype)
+        # (compared in f32: the v5e vector unit has no bf16 compare)
+        dz_store = jnp.where(y_ref[...].astype(jnp.float32) > 0, dy,
+                             0.0).astype(dz_ref.dtype)
         dz_ref[...] = dz_store
         dzf = dz_store.astype(jnp.float32)
     else:
         dzf = dy
     xh = (x_ref[...].astype(jnp.float32) - mean_ref[...][None]) \
         * inv_ref[...][None]
-    a_scr[...] = a_scr[...] + jnp.sum(dzf, axis=(0, 2))[:, None]
-    b_scr[...] = b_scr[...] + jnp.sum(dzf * xh, axis=(0, 2))[:, None]
+    a_scr[...] = a_scr[...] + _channel_sum(dzf)
+    b_scr[...] = b_scr[...] + _channel_sum(dzf * xh)
 
-    @pl.when(j == ns - 1)
+    @pl.when(last)
     def _emit():
         sdz_ref[...] = a_scr[...]
         sdx_ref[...] = b_scr[...]
@@ -278,12 +331,12 @@ def _bwd_reduce(dy3, y3, x3, mean, inv, relu, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     N, C, S = x3.shape
-    bc, bs = _blocks_for(x3.shape, x3.dtype)
-    ns = S // bs
+    blocks = _blocks_for(x3.shape, x3.dtype)
+    bc = blocks[1]
     itemsize = jnp.dtype(x3.dtype).itemsize
-    big = pl.BlockSpec((N, bc, bs), lambda i, j: (0, i, j))
-    per_c = pl.BlockSpec((bc, 1), lambda i, j: (i, 0))
-    kern = functools.partial(_bwd_reduce_kernel, ns=ns, relu=relu)
+    big = _big_spec(blocks)
+    per_c = _per_channel_spec(bc)
+    kern = functools.partial(_bwd_reduce_kernel, relu=relu)
     sums_shape = jax.ShapeDtypeStruct((C, 1), jnp.float32)
     if relu:
         out_shape = [jax.ShapeDtypeStruct((N, C, S), dy3.dtype),
@@ -301,7 +354,7 @@ def _bwd_reduce(dy3, y3, x3, mean, inv, relu, interpret):
     outs = pl.pallas_call(
         kern,
         out_shape=out_shape,
-        grid=(C // bc, ns),
+        grid=_grid_for(x3.shape, blocks),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((bc, 1), jnp.float32),
@@ -331,14 +384,14 @@ def _bwd_dx(dz3, x3, c1, c2, c3, interpret):
     from jax.experimental import pallas as pl
 
     N, C, S = x3.shape
-    bc, bs = _blocks_for(x3.shape, x3.dtype)
+    blocks = _blocks_for(x3.shape, x3.dtype)
     itemsize = jnp.dtype(x3.dtype).itemsize
-    big = pl.BlockSpec((N, bc, bs), lambda i, j: (0, i, j))
-    per_c = pl.BlockSpec((bc, 1), lambda i, j: (i, 0))
+    big = _big_spec(blocks)
+    per_c = _per_channel_spec(blocks[1])
     return pl.pallas_call(
         _bwd_dx_kernel,
         out_shape=jax.ShapeDtypeStruct((N, C, S), x3.dtype),
-        grid=(C // bc, S // bs),
+        grid=_grid_for(x3.shape, blocks),
         in_specs=[big, big, per_c, per_c, per_c],
         out_specs=big,
         interpret=interpret,

@@ -10,10 +10,11 @@ Attention" (arxiv 2604.15464, PAPERS.md) the decode read should instead
 be ONE kernel that walks the block table in place: the grid iterates
 (batch row, head, table slot), a scalar-prefetched block table drives the
 BlockSpec index map so each grid step DMAs exactly one (block_size, Dh)
-pool block into VMEM, and an online-softmax accumulator (running max +
-denominator in VMEM scratch, the flash-attention formulation of
-ops/pallas_attention.py) folds the block in — no dense gather is ever
-materialized and scores never leave the chip.
+slab of the (num_blocks, H, block_size, Dh) pool into VMEM, and an
+online-softmax accumulator (running max + denominator in VMEM scratch,
+the flash-attention formulation of ops/pallas_attention.py) folds the
+block in — no dense gather is ever materialized and scores never leave
+the chip.
 
 Raggedness: every sequence carries its TRUE last position (`q_start`).
 Table slots past a row's live blocks are dead — the kernel skips their
@@ -44,7 +45,8 @@ moving zero bytes.
 
 On CPU the kernel runs in Pallas interpreter mode; the parity tests
 (tests/test_pallas_paged.py) prove it equal to the dense gather path
-there, so the TPU run is a pure measurement question (tpu_session.sh).
+there. `chip_smoke.py` compiles it with Mosaic (f32, bf16 and int8 pools)
+and compares it with the same reference on the chip.
 """
 from __future__ import annotations
 
@@ -87,33 +89,45 @@ def paged_call_cost(B, Tq, H, Dh, w, block_size, kv_itemsize=4,
     return flops, bytes_
 
 
-def paged_eligible(head_dim, block_size, n_queries, interpret,
-                   quant=False):
+#: rows of one VMEM tile by pool itemsize (f32 8, bf16 16, int8 32); the
+#: lane extent is always 128
+_SUBLANES = {4: 8, 2: 16, 1: 32}
+
+
+def paged_fallback_reason(head_dim, block_size, interpret, kv_dtype):
     """Gate for the compiled (Mosaic) kernel; interpreter mode takes any
-    shape. On real hardware stay off the (8, 128) VMEM tiling grid's bad
-    cases: the lane dim (head_dim) must be a multiple of 128 and the
-    sublane dims (block_size, and the query block for prefill chunks)
-    multiples of 8 — callers fall back to the XLA gather path otherwise.
-    An int8 pool (`quant`) tiles (32, 128), so its block_size must be a
-    multiple of 32 — ineligible quant configs fall back to the f32 pool
-    (the precision contract's oracle), not to a different kernel.
-    """
+    shape. A grid step moves one (block_size, head_dim) slab of the pool,
+    so on the chip the slab must be whole tiles of the pool's dtype: the
+    lane dim (head_dim) a multiple of 128 and block_size a multiple of
+    the tile's rows (8 for f32, 16 for bf16, 32 for int8). The query
+    block needs no gate: the wrapper pads it to whole f32 tiles. Callers
+    fall back to the XLA gather path otherwise and record why
+    (`Engine.paged_fallback`); an ineligible int8 config falls back to
+    the unquantized pool (the precision contract's oracle), not to a
+    different kernel. Returns None when eligible, else the reason."""
     if interpret:
-        return True
-    if head_dim % 128 != 0 or (n_queries != 1 and n_queries % 8 != 0):
-        return False
-    return block_size % (32 if quant else 8) == 0
+        return None
+    if head_dim % 128 != 0:
+        return ("head_dim %d is not a multiple of the 128-lane tile"
+                % head_dim)
+    rows = _SUBLANES.get(jnp.dtype(kv_dtype).itemsize)
+    if rows is None or block_size % rows != 0:
+        return ("block_size %d is not a multiple of the %s-row tile of a "
+                "%s pool" % (block_size, rows, jnp.dtype(kv_dtype).name))
+    return None
 
 
-def _kernel(tab_ref, qs_ref, *rest, scale, block_size, nw, tq,
+def _kernel(tab_ref, qs_ref, *rest, scale, block_size, nw, tq, n_heads,
             quant=False):
-    """One (batch row b, head h, table slot j) grid step: fold pool block
-    `tab[b, j]` into row b's online softmax. Scratch carries the
-    accumulator across the innermost (j) dimension. With `quant` the
-    pool refs hold int8 and two extra scalar-prefetched (num_blocks, H)
-    f32 refs carry the per-block-per-head scales: the block is
-    dequantized HERE, in VMEM, after the 1-byte-per-element DMA — the
-    HBM read stays int8-sized."""
+    """One (batch row b, head h, table slot j) grid step: fold pool slab
+    `(tab[b, j], h)` into row b's online softmax. Scratch carries the
+    accumulator across the innermost (j) dimension. `tq` is the TRUE
+    query count; the q/out blocks hold it padded to whole tiles and the
+    padded rows are dropped by the caller. With `quant` the pool refs
+    hold int8 and two extra scalar-prefetched flat (num_blocks * H,) f32
+    refs carry the per-block-per-head scales: the block is dequantized
+    HERE, in VMEM, after the 1-byte-per-element DMA — the HBM read stays
+    int8-sized."""
     from jax.experimental import pallas as pl
 
     if quant:
@@ -126,6 +140,7 @@ def _kernel(tab_ref, qs_ref, *rest, scale, block_size, nw, tq,
     b = pl.program_id(0)
     h = pl.program_id(1)
     j = pl.program_id(2)
+    rows = q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -140,23 +155,23 @@ def _kernel(tab_ref, qs_ref, *rest, scale, block_size, nw, tq,
 
     @pl.when(live)
     def _accumulate():
-        q = q_ref[0, :, 0].astype(jnp.float32)            # [tq, Dh]
-        k = k_ref[0, :, 0].astype(jnp.float32)            # [bs, Dh]
+        q = q_ref[0, 0]                                   # [rows, Dh] f32
+        k = k_ref[0, 0].astype(jnp.float32)               # [bs, Dh]
         if quant:
             # live implies j <= last, so tab[b, j] is this very block
-            k = k * ksc_ref[tab_ref[b, j], h]
+            k = k * ksc_ref[tab_ref[b, j] * n_heads + h]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         # ragged mask: key at table position j*bs+t is live for query i
         # iff it is at or before that query's true position qs+i (for
         # prefill chunks this IS the causal mask within the chunk)
         kp = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, block_size), 1)
+            jnp.int32, (rows, block_size), 1)
         qp = qs_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, block_size), 0)
+            jnp.int32, (rows, block_size), 0)
         s = jnp.where(kp <= qp, s, -jnp.inf)
 
-        m_prev = m_scr[...]                               # [tq, 1]
+        m_prev = m_scr[...]                               # [rows, 1]
         l_prev = l_scr[...]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -164,9 +179,9 @@ def _kernel(tab_ref, qs_ref, *rest, scale, block_size, nw, tq,
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe), 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe),
                           0.0)
-        v = v_ref[0, :, 0].astype(jnp.float32)            # [bs, Dh]
+        v = v_ref[0, 0].astype(jnp.float32)               # [bs, Dh]
         if quant:
-            v = v * vsc_ref[tab_ref[b, j], h]
+            v = v * vsc_ref[tab_ref[b, j] * n_heads + h]
         acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -175,8 +190,7 @@ def _kernel(tab_ref, qs_ref, *rest, scale, block_size, nw, tq,
 
     @pl.when(j == nw - 1)
     def _emit():
-        o_ref[0, :, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)) \
-            .astype(o_ref.dtype)
+        o_ref[0, 0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)
 
 
 @functools.lru_cache(maxsize=None)
@@ -193,34 +207,43 @@ def _make_paged(scale, block_size, interpret, quant=False):
         B, Tq, H, Dh = q.shape
         w = tables.shape[1]
         itemsize = jnp.dtype(k_pool.dtype).itemsize
-        # index maps see every scalar-prefetch operand as a trailing ref
-        n_pref = 4 if quant else 2
+        # the query block rides head-major like the pool, in f32 and
+        # padded to whole (8, 128) tiles: decode's single row would
+        # otherwise be a block Mosaic cannot tile. Rows are independent
+        # in attention, so the padded ones cost MXU lanes that were idle
+        # anyway and are dropped below.
+        rows = -(-Tq // 8) * 8
+        qt = jnp.swapaxes(q, 1, 2).astype(jnp.float32)
+        if rows != Tq:
+            qt = jnp.pad(qt, ((0, 0), (0, 0), (0, rows - Tq), (0, 0)))
 
         def kv_idx(b, h, j, tab_ref, qs_ref, *_scales):
             # dead slots re-read the row's last live block: Pallas skips
             # the DMA when consecutive grid steps map to the same block
             last = jnp.maximum(qs_ref[b] + Tq - 1, 0) // block_size
-            return (tab_ref[b, jnp.minimum(j, last)], 0, h, 0)
+            return (tab_ref[b, jnp.minimum(j, last)], h, 0, 0)
 
         def q_idx(b, h, j, *_pref):
-            return (b, 0, h, 0)
+            return (b, h, 0, 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_pref,
+            # index maps see every scalar-prefetch operand as a trailing
+            # ref
+            num_scalar_prefetch=4 if quant else 2,
             grid=(B, H, w),
             in_specs=[
-                pl.BlockSpec((1, Tq, 1, Dh), q_idx),
-                pl.BlockSpec((1, block_size, 1, Dh), kv_idx),
-                pl.BlockSpec((1, block_size, 1, Dh), kv_idx),
+                pl.BlockSpec((1, 1, rows, Dh), q_idx),
+                pl.BlockSpec((1, 1, block_size, Dh), kv_idx),
+                pl.BlockSpec((1, 1, block_size, Dh), kv_idx),
             ],
-            out_specs=pl.BlockSpec((1, Tq, 1, Dh), q_idx),
-            scratch_shapes=[pltpu.VMEM((Tq, 1), jnp.float32),
-                            pltpu.VMEM((Tq, 1), jnp.float32),
-                            pltpu.VMEM((Tq, Dh), jnp.float32)],
+            out_specs=pl.BlockSpec((1, 1, rows, Dh), q_idx),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, Dh), jnp.float32)],
         )
         kern = functools.partial(_kernel, scale=scale,
                                  block_size=block_size, nw=w, tq=Tq,
-                                 quant=quant)
+                                 n_heads=H, quant=quant)
         # 2 MACs/flop-pair per element for each of the QK and PV
         # matmuls; bytes = K+V blocks walked + q/out + the tables
         # (paged_call_cost — shared with the bytes-report instrument)
@@ -228,15 +251,19 @@ def _make_paged(scale, block_size, interpret, quant=False):
             B, Tq, H, Dh, w, block_size, kv_itemsize=itemsize,
             q_itemsize=jnp.dtype(q.dtype).itemsize,
             scale_blocks=k_pool.shape[0] if quant else 0)
-        operands = ((tables, q_start, k_scale, v_scale) if quant
+        # the scale sidecars ride SMEM flat: a 2-D (num_blocks, H) ref
+        # would pad its minor dimension out to a full lane row per block
+        operands = ((tables, q_start, k_scale.reshape(-1),
+                     v_scale.reshape(-1)) if quant
                     else (tables, q_start))
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kern,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
             interpret=interpret,
             **_cost(flops, bytes_),
-        )(*operands, q, k_pool, v_pool)
+        )(*operands, qt, k_pool, v_pool)
+        return jnp.swapaxes(out[:, :, :Tq], 1, 2).astype(q.dtype)
 
     return call
 
@@ -248,7 +275,8 @@ def paged_attention(q, k_pool, v_pool, tables, q_start, block_size,
 
     q:       (B, Tq, H, Dh) query block — Tq=1 for decode, Tq=chunk for
              chunked prefill (whose K/V are already written to the pool).
-    k_pool:  (num_blocks, block_size, H, Dh) one layer's key pool.
+    k_pool:  (num_blocks, H, block_size, Dh) one layer's key pool
+             (serving/kv_cache.py keeps the heads ahead of the block).
     v_pool:  same shape, values.
     tables:  (B, w) int32 block table, width w bucketed by the caller to
              the longest live sequence (null-padded past each row's

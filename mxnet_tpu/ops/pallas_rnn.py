@@ -1,8 +1,8 @@
 """Persistent Pallas fused-RNN scan kernels.
 
-Why a hand kernel: the word-LM LSTM trains at MFU 0.0023 (BENCH_LAST_TPU
-r4: 36.9k tok/s) and the round-5 latency-floor analysis (BENCH_NOTES.md)
-pins the cause: after the cuDNN-style input-projection hoist (ops/nn.py
+Why a hand kernel: the word-LM LSTM trained at MFU 0.0023 on 2026-07-31
+(BENCH_LAST_TPU.json: 36.9k tok/s) and the latency-floor analysis of that
+run pins the cause: after the cuDNN-style input-projection hoist (ops/nn.py
 `_scan_layer`), the `lax.scan` body still launches one tiny `h @ wh.T`
 matmul per timestep — T=35 times per layer-direction per step — with the
 h/c carry round-tripping HBM between XLA while-loop iterations. Each
@@ -50,8 +50,8 @@ kernels; everything else — gru, non-Mosaic-tileable hidden sizes
 the `lax.scan` path, which is preserved verbatim as the fallback and
 parity oracle. On CPU the kernels run in Pallas interpreter mode; the
 equality tests in tests/test_pallas_rnn.py prove forward + VJP against
-the scan path there, so the TPU run is a pure measurement question
-(bench.py `lstm_sweep`, tpu_session.sh step 2e).
+the scan path there; `chip_smoke.py` compiles the LSTM kernels with
+Mosaic and compares them with the scan path on the chip.
 
 Every pallas_call declares a `CostEstimate` (house pattern from
 `pallas_fused.py`/`pallas_paged.py`): on TPU the kernel is an opaque
@@ -82,8 +82,9 @@ def use_fused(fused):
 
 
 _GATES = {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4}
-#: grid cap: beyond this the interpreter-mode python loop (CPU tests)
-#: dominates and the scan fallback is the better path
+#: grid cap for the interpreter, whose grid is a python loop (CPU tests):
+#: beyond this the scan fallback is the better path. The chip has no such
+#: limit.
 _MAX_GRID = 4096
 #: VMEM budget for resident weights + streamed blocks + scratch; the
 #: physical VMEM is ~16 MB but the pipeline double-buffers streamed blocks
@@ -141,7 +142,7 @@ def fused_eligible(mode, T, N, H, *dtypes, interpret=None):
                      _sublane(dtypes[0], interpret))
     if bn is None:
         return False
-    return (N // bn) * T <= _MAX_GRID
+    return not interpret or (N // bn) * T <= _MAX_GRID
 
 
 def fwd_declared_cost(mode, T, N, H, dtype):
@@ -375,8 +376,9 @@ def _bwd_kernel(*refs, mode, T, nb):
         def _emit_dc0():
             dc0_ref[...] = dc_prev.astype(dc0_ref.dtype)
     elif mode == "rnn_relu":
-        # relu'(pre) == [y > 0] — no recompute matmul needed
-        dpre = jnp.where(ys_ref[0] > 0, dh, 0.0)
+        # relu'(pre) == [y > 0] — no recompute matmul needed (compared
+        # in f32: the v5e vector unit has no bf16 compare)
+        dpre = jnp.where(ys_ref[0].astype(jnp.float32) > 0, dh, 0.0)
     else:  # rnn_tanh: tanh'(pre) = 1 - y^2
         y = ys_ref[0].astype(jnp.float32)
         dpre = dh * (1.0 - y * y)

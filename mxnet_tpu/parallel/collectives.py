@@ -13,29 +13,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable shard_map: jax >= 0.5 exports `jax.shard_map`
-    (replication check kwarg `check_vma`); 0.4.x has
-    `jax.experimental.shard_map` (same check named `check_rep`). One
-    seam so library code and tests never pin a jax version."""
-    try:
-        from jax import shard_map as _sm
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name):
-    """Version-portable mesh-axis size inside shard_map: `lax.axis_size`
-    only exists in newer jax; `psum(1, axis)` is the classic idiom (a
-    static int — XLA folds it)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def allreduce(x, axis_name):
     """Sum-allreduce over a mesh axis (inside shard_map/pjit)."""
     return lax.psum(x, axis_name)
@@ -56,7 +33,7 @@ def all_gather(x, axis_name, gather_dim=0):
 
 def ring_permute(x, axis_name, shift=1):
     """Send each shard to the next device on the ring (ppermute)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

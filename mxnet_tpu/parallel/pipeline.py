@@ -26,8 +26,7 @@ def _gpipe_local(stage_fn, params_local, x_mb, axis_name):
     Returns (M, mb, ...) outputs of the final stage (replicated).
     """
     params = jax.tree_util.tree_map(lambda a: a[0], params_local)
-    from .collectives import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     M = x_mb.shape[0]
     T = M + n - 1  # pipeline ticks: fill + drain
@@ -62,15 +61,13 @@ def gpipe_apply(stage_fn, stacked_params, x, n_microbatches, mesh,
     x: (B, ...) batch; split into n_microbatches along axis 0.
     Returns (B, ...) outputs of the last stage.
     """
-    from .collectives import shard_map
-
     B = x.shape[0]
     assert B % n_microbatches == 0, "batch must divide into microbatches"
     x_mb = x.reshape((n_microbatches, B // n_microbatches) + x.shape[1:])
 
     param_specs = jax.tree_util.tree_map(
         lambda a: P(axis_name), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_gpipe_local, stage_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -45,8 +45,7 @@ def _block_attn(q, k, v, m_prev, l_prev, o_prev, scale, mask=None):
 def _ring_body(axis_name, causal, scale, q, k0, v0, q_index):
     """Scan over ring steps; each step attends to the current K/V block then
     rotates it to the neighbour."""
-    from .collectives import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, H, T, D = q.shape
     m0 = jnp.full((B, H, T), -jnp.inf, dtype=jnp.float32)
     l0 = jnp.zeros((B, H, T), dtype=jnp.float32)
@@ -90,11 +89,9 @@ def ring_attention_sharded(mesh, q, k, v, axis_name="sp", causal=False,
                            scale=None):
     """Convenience wrapper: shard the sequence axis over `axis_name` of
     `mesh` and run ring attention. q/k/v: [B, H, T, D] global arrays."""
-    from .collectives import shard_map
-
     spec = P(None, None, axis_name, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name, causal=causal,
                           scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

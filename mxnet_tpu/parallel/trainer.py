@@ -52,7 +52,7 @@ from .. import optimizer_rules as _rules
 #: tiny BN batch statistics, and RECOMPUTE the cheap elementwise chains
 #: (BN normalize, relu, residual adds) in backward instead of writing them
 #: out in forward and re-reading them — the bandwidth-roofline lever for a
-#: step measured at 95% of the HBM floor (BENCH_NOTES roofline analysis).
+#: step measured at 95% of the HBM floor (2026-07-31, BENCH_LAST_TPU.json).
 #: Composes with MXNET_FUSED_BN_EPILOGUE=1 (ops/pallas_fused.py): the
 #: fused op's custom-VJP residuals are exactly this save set (conv_out +
 #: bn_stats), so under "io" its relu outputs are never stored — backward
@@ -382,7 +382,6 @@ class TrainStep:
                 self.collective_quant = "int8"
         qcoll = self.collective_quant is not None
         if qcoll:
-            from .collectives import shard_map as _shard_map
             # each chip quantizes into [-cap, cap] so the int8 psum of
             # dp_size addends stays within int8 by construction
             _cap = float(max(1, 127 // dp_size))
@@ -418,8 +417,8 @@ class TrainStep:
                                   / dp_size).astype(g.dtype))
                 return loss_val, aux_upd, tuple(out_g), tuple(out_r)
 
-            _qcoll_sm = _shard_map(
-                _qcoll_grads, mesh_obj,
+            _qcoll_sm = jax.shard_map(
+                _qcoll_grads, mesh=mesh_obj,
                 in_specs=(P(), P(), P(dp_ax), P(dp_ax), P(), P(dp_ax)),
                 out_specs=(P(), P(), P(), P(dp_ax)), check_vma=False)
 

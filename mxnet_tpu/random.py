@@ -44,11 +44,10 @@ def _current_key():
         # functionalized eval-mode net drawing its lazy key) — caching it
         # would poison the thread's eager stream. Keep the pending seed
         # instead; the eager key materializes on the next eager call.
-        if jax.core.trace_state_clean():
-            _STATE.key = key
-        else:
+        if isinstance(key, jax.core.Tracer):
             _STATE.seed_value = seed_val
             return key
+        _STATE.key = key
     return _STATE.key
 
 
@@ -66,16 +65,18 @@ def next_key():
     if _STATE.trace_key is not None:
         _STATE.trace_counter += 1
         return jax.random.fold_in(_STATE.trace_key, _STATE.trace_counter)
-    if not jax.core.trace_state_clean():
+    key = _current_key()
+    new, sub = jax.random.split(key)
+    if isinstance(new, jax.core.Tracer):
         # inside someone else's jit trace with no trace_key_scope
         # installed (e.g. a functionalized eval-mode net being traced):
-        # splitting into _STATE.key would store a tracer and poison the
-        # NEXT trace (UnexpectedTracerError). Derive per-call keys off
-        # the eager key via the counter instead — distinct per call,
-        # eager stream untouched.
+        # every op is staged there, so the split came back traced and
+        # storing it would poison the NEXT trace (UnexpectedTracerError).
+        # Derive per-call keys off the eager key via the counter instead
+        # — distinct per call, eager stream untouched.
         _STATE.trace_counter += 1
-        return jax.random.fold_in(_current_key(), _STATE.trace_counter)
-    _STATE.key, sub = jax.random.split(_current_key())
+        return jax.random.fold_in(key, _STATE.trace_counter)
+    _STATE.key = new
     return sub
 
 

@@ -446,6 +446,11 @@ class TransformerLM:
         self._prefill_chunk_q_jit = None
         self._spec_score_q_jit = None
 
+    def place(self, device):
+        """Commit the parameters to one device (a one-chip replica's
+        window): the single-device steps then run where they live."""
+        self.params = jax.device_put(self.params, device)
+
     def cache_spec(self):
         dt = self.params["embed"].dtype
         return (self.cfg.n_layers, self.cfg.n_heads,
@@ -812,7 +817,7 @@ class Engine:
     #: flags the engine derives compiled state from — construction-only
     _FROZEN_FLAGS = frozenset(
         ("paged", "paged_requested", "prefill_chunk", "tp",
-         "tp_requested", "mesh", "prefix_cache", "aot_cache",
+         "tp_requested", "mesh", "device", "prefix_cache", "aot_cache",
          "spec", "spec_requested", "spec_k", "draft",
          "kv_quant", "kv_quant_requested", "weight_quant"))
 
@@ -822,7 +827,8 @@ class Engine:
                  prefix_cache=None, aot_cache=None, draft=None,
                  spec=None, spec_k=None, kv_quant=None,
                  weight_quant=None):
-        from ..ops.pallas_paged import paged_enabled, paged_eligible
+        from ..ops.pallas_paged import (paged_enabled,
+                                        paged_fallback_reason)
         from ..ops.pallas_attention import default_interpret
         from .tp import (serving_tp, tp_fallback_reason, build_tp_mesh,
                          kv_pool_spec, kv_scale_spec)
@@ -855,16 +861,19 @@ class Engine:
         self.tp_fallback = None
         self.tp = 1
         self.mesh = None
+        self.device = None      # the one chip of a placed tp=1 engine
         if tp_req > 1 and paged is False:
             self.tp_fallback = ("paged=False pins the single-device "
                                 "gather oracle")
             tp_req = 1
         # paged path: env default (MXNET_PAGED_ATTENTION), explicit
         # `paged=` overrides; shapes the Mosaic kernel can't tile fall
-        # back to the gather path (interpret mode takes anything)
+        # back to the gather path (interpret mode takes anything) with
+        # the reason recorded on `paged_fallback`
         self.paged_requested = (tp_req > 1) or (
             paged_enabled() if paged is None else bool(paged))
         self.paged = False
+        self.paged_fallback = None
         self.prefill_chunk = 0
         # quantized serving (ISSUE 20): env defaults
         # (MXNET_QUANTIZED_KV / MXNET_QUANTIZED_WEIGHTS), explicit
@@ -897,6 +906,9 @@ class Engine:
         if kvq_req and not model.uses_cache:
             self.kv_quant_fallback = ("model family has no cache hooks "
                                       "(int8 KV needs the paged pool)")
+        if self.paged_requested and not model.uses_cache:
+            self.paged_fallback = ("model family has no cache hooks "
+                                   "(there is no block pool to walk)")
         if model.uses_cache:
             nl, nh, dh, dt = model.cache_spec()
             self._nblk = max(1, math.ceil(self.max_len / block_size))
@@ -906,9 +918,9 @@ class Engine:
                 self.prefill_chunk = min(self.max_len,
                                          int(prefill_chunk
                                              or 2 * block_size))
-                self.paged = paged_eligible(dh, block_size,
-                                            self.prefill_chunk,
-                                            default_interpret())
+                self.paged_fallback = paged_fallback_reason(
+                    dh, block_size, default_interpret(), dt)
+                self.paged = self.paged_fallback is None
             if kvq_req:
                 if not self.paged:
                     self.kv_quant_fallback = (
@@ -916,15 +928,10 @@ class Engine:
                         "(MXNET_PAGED_ATTENTION=1 / Engine(paged=True) "
                         "and a tileable config); the gather oracle "
                         "reads the f32 pool")
-                elif not paged_eligible(dh, block_size,
-                                        self.prefill_chunk,
-                                        default_interpret(), quant=True):
-                    self.kv_quant_fallback = (
-                        "block_size %d is not a multiple of the int8 "
-                        "sublane tile (32) on this backend; the f32 "
-                        "pool stays" % block_size)
                 else:
-                    self.kv_quant = True
+                    self.kv_quant_fallback = paged_fallback_reason(
+                        dh, block_size, default_interpret(), jnp.int8)
+                    self.kv_quant = self.kv_quant_fallback is None
             self.cache = PagedKVCache(
                 nl, nh, dh, block_size=block_size,
                 num_blocks=num_blocks, dtype=dt,
@@ -950,6 +957,14 @@ class Engine:
                                       kv_quant=True)
                     else:
                         model.bind_tp(block_size, self.mesh)
+            if self.tp == 1 and devices:
+                # a one-chip engine that was given a window (the router
+                # gives every replica one) commits its parameters and
+                # its pool to that chip: every step then runs there, and
+                # N replicas hold N chips instead of all sharing chip 0
+                self.device = devices[0]
+                model.place(self.device)
+                self.cache.place(self.device, self.device)
         elif tp_req > 1:
             self.tp_fallback = ("model family has no cache hooks "
                                 "(BlockLM/ExportedLM run single-device)")
@@ -1006,9 +1021,7 @@ class Engine:
         self.spec_fallbacks = 0
         if self.spec_requested:
             d = _spec.build_draft(draft, model)
-            reason = _spec.spec_fallback_reason(
-                model, d, self.paged, self.spec_k, block_size,
-                default_interpret())
+            reason = _spec.spec_fallback_reason(model, d, self.paged)
             if reason is not None:
                 self.spec_fallback = reason
             else:

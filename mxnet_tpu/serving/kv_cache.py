@@ -17,14 +17,18 @@ branches for inactive slots. Reads from it are masked by sequence length.
 
 Host side (`BlockPool`) is a plain free-list — allocation policy is a
 scheduling decision and lives outside the compiled program. Device side,
-the pool arrays are CONTIGUOUS PER LAYER with an explicit block axis —
-(n_layers, num_blocks, block_size, n_heads, head_dim) — so a block-table
-entry indexes a whole (block_size, n_heads, head_dim) block directly:
-that is the unit the ragged paged-attention kernel
-(ops/pallas_paged.py) DMAs per grid step, and the per-token scatter and
-the by-table gather both remain single advanced-indexing ops XLA lowers
-without data-dependent shapes (`write_kv` splits a flat slot into
-(block, offset) with one divmod).
+the pool arrays are CONTIGUOUS PER LAYER with an explicit block axis and
+the HEADS AHEAD OF THE BLOCK — (n_layers, num_blocks, n_heads,
+block_size, head_dim) — so a (block id, head) pair indexes one
+(block_size, head_dim) slab: that is the unit the ragged paged-attention
+kernel (ops/pallas_paged.py) DMAs per grid step, and its two minor
+dimensions are the whole minor extent of the array, which is what Mosaic
+asks of a block (a head axis between them made the block's second-minor
+extent 1 of H, which it refuses). The per-token scatter and the by-table
+gather both remain single advanced-indexing ops XLA lowers without
+data-dependent shapes (`write_kv` splits a flat slot into (block,
+offset) with one divmod). Only this module and the kernel know the
+order of the axes.
 """
 from __future__ import annotations
 
@@ -171,8 +175,8 @@ class BlockPool:
 class PagedKVCache:
     """Device-side K/V pools plus the host free-list.
 
-    Arrays: ``k``/``v`` of shape (n_layers, num_blocks, block_size,
-    n_heads, head_dim) — contiguous-per-layer block layout (see module
+    Arrays: ``k``/``v`` of shape (n_layers, num_blocks, n_heads,
+    block_size, head_dim) — contiguous-per-layer block layout (see module
     docstring). They are plain jax arrays threaded through the jitted
     engine functions (functional update: each step returns the new
     pools).
@@ -199,7 +203,7 @@ class PagedKVCache:
             raise MXNetError("kv_dtype %r is not supported (int8 or "
                              "None)" % (kv_dtype,))
         self.kv_dtype = "int8" if kv_dtype is not None else None
-        shape = (n_layers, num_blocks, block_size, n_heads, head_dim)
+        shape = (n_layers, num_blocks, n_heads, block_size, head_dim)
         pool_dtype = jnp.int8 if self.kv_dtype else dtype
         self.k = jnp.zeros(shape, pool_dtype)
         self.v = jnp.zeros(shape, pool_dtype)
@@ -215,7 +219,8 @@ class PagedKVCache:
         return self.kv_dtype is not None
 
     def place(self, sharding, scale_sharding=None):
-        """Lay the device pools out under `sharding` (a NamedSharding).
+        """Lay the device pools out under `sharding` (a NamedSharding, or
+        the one device of a placed single-chip engine).
         The tensor-parallel engine shards the HEAD axis — each chip owns
         n_heads/k heads of every block — so block ids, tables, and the
         host free-list are placement-agnostic and unchanged. A quantized
@@ -273,10 +278,12 @@ def write_kv(k_pool, v_pool, layer, slots, k_new, v_new):
     """Scatter new K/V entries into one layer's flat slots (block id *
     block_size + offset). slots (...,) int32; k_new/v_new (..., n_heads,
     head_dim)."""
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     blk, off = slots // bs, slots % bs
-    k_pool = k_pool.at[layer, blk, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, blk, off].set(v_new.astype(v_pool.dtype))
+    # advanced indices split by the head slice: the indexed dims lead, so
+    # the update is (..., n_heads, head_dim) like k_new
+    k_pool = k_pool.at[layer, blk, :, off].set(k_new.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, blk, :, off].set(v_new.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -317,7 +324,7 @@ def write_kv_quant(k_pool, v_pool, k_scale, v_scale, layer, slots,
     aimed at the null block (padded rows) land there like the f32 path —
     its contents and scale are garbage that length masking never reads.
     """
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     n = slots.shape[0]
     if ncand is None:
         ncand = n
@@ -337,9 +344,9 @@ def write_kv_quant(k_pool, v_pool, k_scale, v_scale, layer, slots,
         s_new = plane[cand]
         s_safe = jnp.where(s_new > 0, s_new, 1.0)
         blk = pool[layer][cand].astype(jnp.float32) \
-            * s_old[:, None, :, None]                       # (ncand,bs,H,Dh)
-        blk = blk.at[ci, off].set(new)
-        q = jnp.clip(jnp.rint(blk / s_safe[:, None, :, None]),
+            * s_old[:, :, None, None]                       # (ncand,H,bs,Dh)
+        blk = blk.at[ci, :, off].set(new)
+        q = jnp.clip(jnp.rint(blk / s_safe[:, :, None, None]),
                      -127, 127).astype(jnp.int8)
         return (pool.at[layer, cand].set(q),
                 scale.at[layer].set(plane))
@@ -380,7 +387,11 @@ def gather_kv(k_pool, v_pool, layer, block_table, block_size):
     position-ordered; entries past each sequence's length are garbage and
     must be masked by the caller (mask = arange(T) <= position)."""
     B, nblk = block_table.shape
-    ks = k_pool[layer][block_table]       # (B, nblk, bs, H, Dh)
-    vs = v_pool[layer][block_table]
-    return (ks.reshape(B, nblk * block_size, *ks.shape[3:]),
-            vs.reshape(B, nblk * block_size, *vs.shape[3:]))
+
+    def read(pool):
+        blocks = pool[layer][block_table]       # (B, nblk, H, bs, Dh)
+        H, Dh = blocks.shape[2], blocks.shape[4]
+        return blocks.transpose(0, 1, 3, 2, 4).reshape(
+            B, nblk * block_size, H, Dh)
+
+    return read(k_pool), read(v_pool)

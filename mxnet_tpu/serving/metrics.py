@@ -929,6 +929,8 @@ class ServingMetrics:
                 "prefix_cache": getattr(engine, "prefix_cache",
                                         None) is not None,
             }
+            if getattr(engine, "paged_fallback", None):
+                snap["engine"]["paged_fallback"] = engine.paged_fallback
             if getattr(engine, "prefix_cache_fallback", None):
                 snap["engine"]["prefix_cache_fallback"] = \
                     engine.prefix_cache_fallback

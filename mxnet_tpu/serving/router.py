@@ -82,8 +82,8 @@ submit/HTTP front:
   keeps decoding locally (co-scheduled fallback — flags switch
   placement, never logits).
 
-With tensor parallelism, replica i runs on the contiguous device window
-[i*tp, (i+1)*tp) (parallel/mesh.replica_devices) — tp collectives stay
+Replica i runs on the contiguous device window [i*tp, (i+1)*tp)
+(parallel/mesh.replica_devices), one chip at tp=1 — tp collectives stay
 on neighboring chips, replicas never share one (when the host has
 enough devices). All placement is fixed at construction, same contract
 as the Engine flags.
@@ -96,6 +96,7 @@ import time
 
 from ..base import MXNetError
 from .. import telemetry
+from .engine import TransformerLM
 from .scheduler import QueueFull
 from .server import LMServer, _HTTPFrontend
 
@@ -369,8 +370,12 @@ class ReplicatedLMServer(_HTTPFrontend):
         if role is not None:
             kw.update(self._role_kwargs.get(role, {}))
         tp = int(kw.pop("tp", self._tp))
-        devs = replica_devices(i, tp) if tp > 1 else None
+        devs = replica_devices(i, tp)
         model = self._models.get(version, self._model)
+        if isinstance(model, TransformerLM):
+            # replicas place their parameters on their own chips, so each
+            # needs its own adapter over the shared arrays
+            model = (model.params, model.cfg)
         rep = LMServer(model, tp=tp, devices=devs,
                        replica_id=i, role=role, **kw)
         # the death hook runs ON the dying serving thread: queued and

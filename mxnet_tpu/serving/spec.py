@@ -59,9 +59,7 @@ def spec_decode_enabled():
 
 def spec_k(default=4):
     """`MXNET_SPEC_K`: draft tokens proposed per decode iteration.
-    The target scores k+1 positions per pass; on real TPUs the Mosaic
-    lane tiling wants k+1 in {1} or a multiple of 8 (k=7, k=15 — see
-    `paged_eligible`), while CPU interpret mode takes any k."""
+    The target scores k+1 positions per pass."""
     v = os.environ.get("MXNET_SPEC_K", "")
     return int(v) if v else default
 
@@ -163,7 +161,7 @@ def build_draft(draft, model):
         "or a model with .params/.cfg, got %r" % (type(draft).__name__,))
 
 
-def spec_fallback_reason(model, draft, paged, k, block_size, interpret):
+def spec_fallback_reason(model, draft, paged):
     """Why speculation must fall back to the verbatim per-token decode
     (None = eligible). Mirrors `tp_fallback_reason` /
     `prefix_cache_fallback`: the flag switches SPEED, never logits, so
@@ -188,12 +186,6 @@ def spec_fallback_reason(model, draft, paged, k, block_size, interpret):
         return ("draft max_len %d < target max_len %d — the draft must "
                 "reach every position the target can decode"
                 % (draft.max_len, model.max_len))
-    from ..ops.pallas_paged import paged_eligible
-    _nl, _nh, dh, _dt = model.cache_spec()
-    if not paged_eligible(dh, block_size, k + 1, interpret):
-        return ("scoring width k+1=%d is not tileable on this backend "
-                "(needs 1 or a multiple of 8 on real TPUs — pick k=7 "
-                "or k=15, or run interpret mode)" % (k + 1))
     return None
 
 

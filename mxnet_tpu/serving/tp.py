@@ -43,7 +43,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..base import MXNetError
 from ..ops.quantization import maybe_quant_matmul as _mm
 from ..parallel.mesh import build_mesh
-from ..parallel.collectives import shard_map, allreduce
+from ..parallel.collectives import allreduce
 
 #: the serving mesh axis name — deliberately the same axis name the
 #: training dp×tp mesh uses for its tensor dimension.
@@ -94,10 +94,10 @@ def tp_cache_variant(mesh):
 
 
 def kv_pool_spec():
-    """The block pool (L, num_blocks, block_size, H, Dh) shards over the
+    """The block pool (L, num_blocks, H, block_size, Dh) shards over the
     head axis: every chip owns H/k heads of every block, tables stay
     replicated."""
-    return P(None, None, None, TP_AXIS, None)
+    return P(None, None, TP_AXIS, None, None)
 
 
 def kv_scale_spec():
@@ -194,11 +194,11 @@ def quantize_tp_params(tp_params, cfg, mesh):
         q, s = _quant_shard(w)
         return q, s[None]
 
-    col_fn = jax.jit(shard_map(
-        _quant_shard, mesh, in_specs=(P(None, TP_AXIS),),
+    col_fn = jax.jit(jax.shard_map(
+        _quant_shard, mesh=mesh, in_specs=(P(None, TP_AXIS),),
         out_specs=(P(None, TP_AXIS), P(TP_AXIS)), check_vma=False))
-    row_fn = jax.jit(shard_map(
-        _row_quant, mesh, in_specs=(P(TP_AXIS, None),),
+    row_fn = jax.jit(jax.shard_map(
+        _row_quant, mesh=mesh, in_specs=(P(TP_AXIS, None),),
         out_specs=(P(TP_AXIS, None), P(TP_AXIS, None)),
         check_vma=False))
     for i in range(cfg.n_layers):
@@ -403,8 +403,8 @@ def build_tp_decode(cfg, block_size, mesh, kv_quant=False,
             return _decode_body(params, k, v, toks, pos, tabs, cfg,
                                 block_size, k_scale=ks, v_scale=vs)
 
-        return jax.jit(shard_map(
-            body, mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(specs, pool, pool, P(None), P(None),
                       P(None, None), sc, sc),
             out_specs=(pool, pool, sc, sc, P(None, None), P(None)),
@@ -414,8 +414,8 @@ def build_tp_decode(cfg, block_size, mesh, kv_quant=False,
         return _decode_body(params, k, v, toks, pos, tabs, cfg,
                             block_size)
 
-    return jax.jit(shard_map(
-        body, mesh,
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(specs, pool, pool, P(None), P(None), P(None, None)),
         out_specs=(pool, pool, P(None, None), P(None)),
         check_vma=False))
@@ -438,8 +438,8 @@ def build_tp_prefill_chunk(cfg, block_size, mesh, kv_quant=False,
                                        block_size, k_scale=ks,
                                        v_scale=vs)
 
-        return jax.jit(shard_map(
-            body, mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(specs, pool, pool, P(None), P(), P(), P(),
                       P(None), sc, sc),
             out_specs=(pool, pool, sc, sc, P(None)),
@@ -449,8 +449,8 @@ def build_tp_prefill_chunk(cfg, block_size, mesh, kv_quant=False,
         return _prefill_chunk_body(params, k, v, toks, qs, length,
                                    last_idx, table_row, cfg, block_size)
 
-    return jax.jit(shard_map(
-        body, mesh,
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(specs, pool, pool, P(None), P(), P(), P(), P(None)),
         out_specs=(pool, pool, P(None)),
         check_vma=False))
@@ -471,8 +471,8 @@ def build_tp_spec_score(cfg, block_size, mesh, kv_quant=False,
                                     tabs, cfg, block_size, k_scale=ks,
                                     v_scale=vs)
 
-        return jax.jit(shard_map(
-            body, mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(specs, pool, pool, P(None, None), P(None),
                       P(None), P(None, None), sc, sc),
             out_specs=(pool, pool, sc, sc, P(None, None, None)),
@@ -482,8 +482,8 @@ def build_tp_spec_score(cfg, block_size, mesh, kv_quant=False,
         return _spec_score_body(params, k, v, toks, qs, counts, tabs,
                                 cfg, block_size)
 
-    return jax.jit(shard_map(
-        body, mesh,
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(specs, pool, pool, P(None, None), P(None), P(None),
                   P(None, None)),
         out_specs=(pool, pool, P(None, None, None)),

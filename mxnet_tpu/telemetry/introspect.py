@@ -24,8 +24,8 @@ inside jax's dispatch:
     and the engine's recompile counters stay functional: they are
     behavior, not telemetry).
   * **Memory & cost accounting**: after each compile the executable's
-    `memory_analysis()` / `cost_analysis()` (version-portable, absent
-    gracefully on older jax) land in per-site gauges —
+    `memory_analysis()` / `cost_analysis()` (None where a backend does
+    not provide them) land in per-site gauges —
     `exec_<site>_{argument,output,temp,code,hbm}_bytes` and
     `exec_<site>_flops` — exported through the Prometheus exposition
     and every flight dump.
@@ -310,10 +310,9 @@ class CompileSite:
 
 
 def _analyses(compiled):
-    """(memory dict, flops, bytes accessed) from a compiled executable —
-    the version-portable seam: every accessor is optional and a missing
-    or failing one degrades to None, never to an exception (older jax /
-    backends without CompiledMemoryStats)."""
+    """(memory dict, flops, bytes accessed) from a compiled executable.
+    Telemetry at a boundary that must keep running: an analysis a backend
+    does not provide reads as None, never as an exception."""
     memory = None
     try:
         ma = compiled.memory_analysis()
@@ -338,8 +337,6 @@ def _analyses(compiled):
     bytes_accessed = None
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0)) or None
         bytes_accessed = float(cost.get("bytes accessed", 0.0)) or None
     except Exception:
@@ -371,6 +368,13 @@ _COLLECTIVE_RE = re.compile(
     r"collective-permute)((?:-start)?)\(([^)]*)")
 
 
+#: any instruction: `%name = RESULT opcode(` — the installed XLA prints
+#: operands by name only (`reduce-scatter(%param.2)`), so an operand's
+#: shape is its defining instruction's result
+_DEFINITION_RE = re.compile(r"%([\w.\-]+)\s+=\s+(\([^)]*\)|\S+)\s")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
+
+
 def _shape_bytes(text):
     """Summed byte size of every `dtype[dims]` shape token in `text`."""
     total = 0
@@ -397,9 +401,12 @@ def comms_from_hlo(hlo_text):
     bound for loop-heavy programs (trip counts are not recoverable
     from HLO text in general; docs/OBSERVABILITY.md discloses this)."""
     kinds = {}
+    defined = dict(_DEFINITION_RE.findall(hlo_text))
     for result, opcode, started, operands in \
             _COLLECTIVE_RE.findall(hlo_text):
-        in_bytes = _shape_bytes(operands)
+        in_bytes = _shape_bytes(operands) or sum(
+            _shape_bytes(defined.get(name, ""))
+            for name in _OPERAND_NAME_RE.findall(operands))
         out_bytes = _shape_bytes(result)
         if started:
             # async form: the result tuple is (aliased input, real
@@ -942,7 +949,8 @@ class InstrumentedJit:
             return None
         payload, in_tree, out_tree, meta = rec
         try:
-            compiled = aot.load_executable(payload, in_tree, out_tree)
+            compiled = aot.load_executable(payload, in_tree, out_tree,
+                                           meta["devices"])
         except Exception:
             cache.invalidate(site.sane, key)
             wd.record_cache_corrupt(site)
@@ -960,14 +968,13 @@ class InstrumentedJit:
     def _cache_store(self, wd, cache, site, key, compiled, memory):
         try:
             from .. import aot
-            blob = aot.serialize_executable_blob(compiled)
-            if blob is None:
-                return
-            payload, trees = blob
+            payload, trees, devices = \
+                aot.serialize_executable_blob(compiled)
             if cache.store(site.sane, key, payload, trees,
                            extra={"watchdog_site": site.name,
                                   "variant": self._variant,
-                                  "memory": memory}):
+                                  "memory": memory,
+                                  "devices": devices}):
                 wd.record_cache_store(site)
         except Exception:
             # persistence must never break the serving/train path: an

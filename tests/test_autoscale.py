@@ -421,9 +421,12 @@ def test_warm_replica_gauge_tracks_aot_loads(tiny_lm, tmp_path,
     from mxnet_tpu import aot
     params, cfg = tiny_lm
     try:
-        # populate the cache with one cold engine outside the router
+        # populate the cache with one cold engine outside the router,
+        # placed like replica 0 (entries are keyed by the device the
+        # executable was compiled for)
         eng = serving.Engine(serving.TransformerLM(params, cfg),
                              max_batch=1, block_size=8,
+                             devices=jax.devices()[:1],
                              aot_cache=tmp_path)
         s = eng.start(arith_prompt(1, 1, 6), max_new=2)
         while not s.done:

@@ -1,7 +1,7 @@
 """Ragged paged-attention kernel tests (ops/pallas_paged.py).
 
 Interpreter mode on CPU — the same kernel compiles for the TPU via
-Mosaic (the slow-marked variant at the bottom runs it there). The
+Mosaic (`chip_smoke.py` runs it there against this same reference). The
 load-bearing claims: (1) the kernel's block-table walk + ragged mask
 reproduce the dense gather-by-table attention exactly, across table
 widths and dtypes; (2) the engine's paged decode logits equal the
@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu import serving
-from mxnet_tpu.ops.pallas_paged import (paged_attention, paged_eligible,
+from mxnet_tpu.ops.pallas_paged import (paged_attention,
+                                        paged_fallback_reason,
                                         paged_enabled)
 from mxnet_tpu.models.transformer import (TransformerConfig,
                                           init_transformer_params,
@@ -32,8 +33,11 @@ def _dense_ref(q, k_pool, v_pool, tables, q_start, block_size):
     masked-softmax over the padded width — the PR 1 read path."""
     B, Tq, H, Dh = q.shape
     w = tables.shape[1]
-    ks = k_pool[tables].reshape(B, w * block_size, H, Dh)
-    vs = v_pool[tables].reshape(B, w * block_size, H, Dh)
+    # pool blocks are (H, bs, Dh): heads ahead of the block
+    ks = k_pool[tables].transpose(0, 1, 3, 2, 4).reshape(
+        B, w * block_size, H, Dh)
+    vs = v_pool[tables].transpose(0, 1, 3, 2, 4).reshape(
+        B, w * block_size, H, Dh)
     s = jnp.einsum("bqhd,bthd->bhqt", q.astype(jnp.float32),
                    ks.astype(jnp.float32)) / math.sqrt(Dh)
     kp = jnp.arange(w * block_size)[None, None, None, :]
@@ -46,8 +50,8 @@ def _dense_ref(q, k_pool, v_pool, tables, q_start, block_size):
 
 
 def _pool(rng, nb, bs, H, Dh, dtype):
-    k = jnp.asarray(rng.randn(nb, bs, H, Dh).astype(np.float32))
-    v = jnp.asarray(rng.randn(nb, bs, H, Dh).astype(np.float32))
+    k = jnp.asarray(rng.randn(nb, H, bs, Dh).astype(np.float32))
+    v = jnp.asarray(rng.randn(nb, H, bs, Dh).astype(np.float32))
     return k.astype(dtype), v.astype(dtype)
 
 
@@ -203,14 +207,35 @@ def test_blocks_for_agrees_with_kernel_table_width(tiny_lm):
 
 
 def test_paged_eligibility_gate():
+    f32, bf16 = jnp.float32, jnp.bfloat16
     # interpreter mode takes any shape
-    assert paged_eligible(8, 4, 1, interpret=True)
-    # Mosaic: lane dim must be 128-aligned, sublanes 8-aligned
-    assert paged_eligible(128, 16, 1, interpret=False)
-    assert paged_eligible(128, 16, 32, interpret=False)
-    assert not paged_eligible(32, 16, 1, interpret=False)
-    assert not paged_eligible(128, 4, 1, interpret=False)
-    assert not paged_eligible(128, 16, 12, interpret=False)
+    assert paged_fallback_reason(8, 4, True, f32) is None
+    # Mosaic: a (block_size, head_dim) slab must be whole tiles of the
+    # pool dtype — 128 lanes, and 8 (f32) / 16 (bf16) rows
+    assert paged_fallback_reason(128, 16, False, f32) is None
+    assert paged_fallback_reason(128, 8, False, f32) is None
+    assert paged_fallback_reason(128, 16, False, bf16) is None
+    assert "head_dim" in paged_fallback_reason(32, 16, False, f32)
+    assert "block_size" in paged_fallback_reason(128, 4, False, f32)
+    assert "block_size" in paged_fallback_reason(128, 8, False, bf16)
+
+
+def test_engine_records_paged_fallback(tiny_lm, monkeypatch):
+    """`paged=True` on a shape the compiled kernel cannot tile serves the
+    gather path AND says why, like tp/kv_quant/spec do."""
+    params, cfg = tiny_lm
+    eng = serving.Engine(serving.TransformerLM(params, cfg), max_batch=1,
+                         block_size=8, paged=True)
+    assert eng.paged and eng.paged_fallback is None   # CPU: interpreter
+    eng.close()
+    from mxnet_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "default_interpret",
+                        lambda: False)                # as on the chip
+    eng = serving.Engine(serving.TransformerLM(params, cfg), max_batch=1,
+                         block_size=8, paged=True)
+    assert eng.paged_requested and not eng.paged
+    assert "head_dim" in eng.paged_fallback
+    eng.close()
 
 
 def test_paged_env_flag(tiny_lm, monkeypatch):
@@ -235,9 +260,9 @@ def test_contrib_paged_attention_op_flag_equivalence(monkeypatch):
     import mxnet_tpu as mx
     nb, bs, H, Dh, B, w = 6, 4, 2, 8, 2, 2
     rng = np.random.RandomState(3)
-    kp = mx.nd.NDArray(jnp.asarray(rng.randn(nb, bs, H, Dh)
+    kp = mx.nd.NDArray(jnp.asarray(rng.randn(nb, H, bs, Dh)
                                    .astype(np.float32)))
-    vp = mx.nd.NDArray(jnp.asarray(rng.randn(nb, bs, H, Dh)
+    vp = mx.nd.NDArray(jnp.asarray(rng.randn(nb, H, bs, Dh)
                                    .astype(np.float32)))
     q = mx.nd.NDArray(jnp.asarray(rng.randn(B, 3, H, Dh)
                                   .astype(np.float32)))
@@ -250,25 +275,3 @@ def test_contrib_paged_attention_op_flag_equivalence(monkeypatch):
     assert a.shape == (B, 3, H, Dh)
     np.testing.assert_allclose(a.asnumpy(), b.asnumpy(), rtol=1e-5,
                                atol=1e-5)
-
-
-@pytest.mark.slow
-def test_paged_kernel_compiles_on_tpu():
-    """Real-hardware variant: the Mosaic-compiled kernel (interpret off)
-    matches the dense gather reference at TPU-eligible shapes. Runs in
-    the TPU session (tpu_session.sh); skipped on CPU tiers."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("needs a real TPU backend")
-    bs, H, Dh, nb, w, B = 16, 2, 128, 10, 4, 4
-    rng = np.random.RandomState(0)
-    k_pool = jnp.asarray(rng.randn(nb, bs, H, Dh).astype(np.float32))
-    v_pool = jnp.asarray(rng.randn(nb, bs, H, Dh).astype(np.float32))
-    q = jnp.asarray(rng.randn(B, 1, H, Dh).astype(np.float32))
-    tables = jnp.asarray(rng.choice(np.arange(1, nb), (B, w))
-                         .astype(np.int32))
-    q_start = jnp.asarray([w * bs - 1, bs + 3, 0, 2 * bs], jnp.int32)
-    out = paged_attention(q, k_pool, v_pool, tables, q_start, bs,
-                          interpret=False)
-    ref = _dense_ref(q, k_pool, v_pool, tables, q_start, bs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)

@@ -2,8 +2,8 @@
 
 Interpreter mode on CPU: the lax.scan path in ops/nn.py is the parity
 oracle — every test pins the fused kernel's forward AND backward against
-it, so the TPU session (tpu_session.sh step 2e) is a pure measurement
-question. Tolerance contract: f32 at 1e-5; bf16 (kernel accumulates in
+it; `chip_smoke.py` compiles the kernel with Mosaic and compares it with
+the same path on the chip. Tolerance contract: f32 at 1e-5; bf16 (kernel accumulates in
 f32 VMEM scratch) at dtype tolerance.
 """
 import os
@@ -292,26 +292,3 @@ def test_word_lm_trainstep_end_to_end(monkeypatch):
     np.testing.assert_allclose(losses[False], losses[True],
                                rtol=1e-4, atol=1e-5)
     assert losses[True][2] < losses[True][0]  # it actually learns
-
-
-@pytest.mark.slow
-def test_fused_rnn_on_tpu_mosaic():
-    """Real-TPU variant: the Mosaic-compiled kernel (no interpreter) at a
-    tile-eligible width vs the scan path. Skipped off-TPU."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("needs a real TPU backend")
-    args = _layer_args("lstm", 35, 32, 128, 128, jnp.float32)
-
-    def loss(fused, wh):
-        a = list(args)
-        a[4] = wh
-        ys, hT, cT = nn._scan_layer("lstm", *a, fused=fused)
-        return jnp.sum(ys * ys) + jnp.sum(hT) + jnp.sum(cT)
-
-    l0 = float(loss(False, args[4]))
-    l1 = float(loss(True, args[4]))
-    np.testing.assert_allclose(l0, l1, rtol=1e-5, atol=1e-5)
-    g0 = jax.grad(lambda w: loss(False, w))(args[4])
-    g1 = jax.grad(lambda w: loss(True, w))(args[4])
-    np.testing.assert_allclose(np.asarray(g0), np.asarray(g1),
-                               rtol=1e-4, atol=1e-4)

@@ -38,44 +38,41 @@ def test_shard_batch_and_replicate():
 
 
 def test_collectives_psum_allgather():
-    from mxnet_tpu.parallel.collectives import shard_map
     m = pmesh.build_mesh({"dp": 8})
     x = jnp.arange(8.0)
 
-    out = shard_map(lambda v: coll.allreduce(v, "dp"), mesh=m,
+    out = jax.shard_map(lambda v: coll.allreduce(v, "dp"), mesh=m,
                     in_specs=P("dp"), out_specs=P("dp"))(x)
     assert_almost_equal(np.asarray(out), np.full(8, x.sum()))
 
-    mean = shard_map(lambda v: coll.allreduce_mean(v, "dp"), mesh=m,
+    mean = jax.shard_map(lambda v: coll.allreduce_mean(v, "dp"), mesh=m,
                      in_specs=P("dp"), out_specs=P("dp"))(x)
     assert_almost_equal(np.asarray(mean), np.full(8, float(np.mean(
         np.arange(8.0)))))
 
     # all_gather output is replicated, which the static VMA checker can't
     # infer — disable it (the value check below proves replication)
-    gath = shard_map(lambda v: coll.all_gather(v, "dp"), mesh=m,
+    gath = jax.shard_map(lambda v: coll.all_gather(v, "dp"), mesh=m,
                      in_specs=P("dp"), out_specs=P(),
                      check_vma=False)(x)
     assert_almost_equal(np.asarray(gath), np.arange(8.0))
 
 
 def test_ring_permute():
-    from mxnet_tpu.parallel.collectives import shard_map
     m = pmesh.build_mesh({"dp": 8})
     x = jnp.arange(8.0)
-    out = shard_map(lambda v: coll.ring_permute(v, "dp", shift=1), mesh=m,
+    out = jax.shard_map(lambda v: coll.ring_permute(v, "dp", shift=1), mesh=m,
                     in_specs=P("dp"), out_specs=P("dp"))(x)
     # each shard receives its left neighbor's value
     assert_almost_equal(np.asarray(out), np.roll(np.arange(8.0), 1))
 
 
 def test_reduce_scatter():
-    from mxnet_tpu.parallel.collectives import shard_map
     m = pmesh.build_mesh({"dp": 8})
     x = jnp.asarray(rand(8, 8))
     # each device holds one row; psum_scatter leaves device i with element i
     # of the row-sum
-    out = shard_map(lambda v: coll.reduce_scatter(v[0], "dp"), mesh=m,
+    out = jax.shard_map(lambda v: coll.reduce_scatter(v[0], "dp"), mesh=m,
                     in_specs=P("dp", None), out_specs=P("dp"))(x)
     assert_almost_equal(np.asarray(out), np.asarray(x).sum(0), rtol=1e-5,
                         atol=1e-5)

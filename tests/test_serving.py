@@ -511,7 +511,7 @@ def test_block_pool_high_water_and_layout():
 
     cache = kv_cache.PagedKVCache(n_layers=2, n_heads=2, head_dim=4,
                                   block_size=4, num_blocks=6)
-    assert cache.k.shape == (2, 6, 4, 2, 4)     # (L, nb, bs, H, Dh)
+    assert cache.k.shape == (2, 6, 2, 4, 4)     # (L, nb, H, bs, Dh)
     # write positions 0..5 of a sequence whose table is [3, 1] and read
     # them back by table: position order must round-trip exactly
     table = np.asarray([3, 1], np.int32)

@@ -36,7 +36,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models.transformer import (TransformerConfig,
                                           init_transformer_params)
 from mxnet_tpu.ops.pallas_paged import (paged_attention, paged_call_cost,
-                                        paged_eligible)
+                                        paged_fallback_reason)
 from mxnet_tpu.serving.kv_cache import (PagedKVCache, write_kv_quant,
                                         copy_block_quant,
                                         zero_block_scales)
@@ -85,11 +85,11 @@ def _max_err(a, b):
 
 
 def _quantize_pool(pool):
-    """Per-block-per-head symmetric int8 of an (NB, bs, H, Dh) pool."""
-    a = np.max(np.abs(np.asarray(pool, np.float32)), axis=(1, 3))
+    """Per-block-per-head symmetric int8 of an (NB, H, bs, Dh) pool."""
+    a = np.max(np.abs(np.asarray(pool, np.float32)), axis=(2, 3))
     s = np.maximum(a, 1e-12) / 127.0                       # (NB, H)
     q = np.clip(np.rint(np.asarray(pool, np.float32)
-                        / s[:, None, :, None]), -127, 127).astype(np.int8)
+                        / s[:, :, None, None]), -127, 127).astype(np.int8)
     return jnp.asarray(q), jnp.asarray(s.astype(np.float32))
 
 
@@ -101,12 +101,12 @@ def test_paged_kernel_int8_matches_dequantized_f32(dtype, width, tq):
     pool: in-VMEM dequant moves bytes, never values."""
     bs, H, Dh, nb = 4, 2, 8, 12
     rng = np.random.RandomState(0)
-    k_f = jnp.asarray(rng.randn(nb, bs, H, Dh).astype(np.float32))
-    v_f = jnp.asarray(rng.randn(nb, bs, H, Dh).astype(np.float32))
+    k_f = jnp.asarray(rng.randn(nb, H, bs, Dh).astype(np.float32))
+    v_f = jnp.asarray(rng.randn(nb, H, bs, Dh).astype(np.float32))
     k_q, k_s = _quantize_pool(k_f)
     v_q, v_s = _quantize_pool(v_f)
-    k_deq = k_q.astype(jnp.float32) * k_s[:, None, :, None]
-    v_deq = v_q.astype(jnp.float32) * v_s[:, None, :, None]
+    k_deq = k_q.astype(jnp.float32) * k_s[:, :, None, None]
+    v_deq = v_q.astype(jnp.float32) * v_s[:, :, None, None]
     B = 3
     q = jnp.asarray(rng.randn(B, tq, H, Dh).astype(np.float32)) \
         .astype(dtype)
@@ -140,10 +140,10 @@ def test_paged_call_cost_declares_int8_bytes():
 def test_paged_eligible_int8_tile_gate():
     """Real hardware wants block_size % 32 for the (32, 128) int8 tile;
     interpret mode takes any shape."""
-    assert paged_eligible(128, 32, 1, interpret=False, quant=True)
-    assert not paged_eligible(128, 16, 1, interpret=False, quant=True)
-    assert paged_eligible(128, 16, 1, interpret=False, quant=False)
-    assert paged_eligible(32, 8, 1, interpret=True, quant=True)
+    assert paged_fallback_reason(128, 32, False, jnp.int8) is None
+    assert "block_size" in paged_fallback_reason(128, 16, False, jnp.int8)
+    assert paged_fallback_reason(128, 16, False, jnp.float32) is None
+    assert paged_fallback_reason(32, 8, True, jnp.int8) is None
 
 
 # ---------------------------------------------------------------------------
@@ -165,8 +165,9 @@ def test_quant_pool_layout_and_write_roundtrip():
     s = np.asarray(ks)[0, 1]                               # (H,)
     expect = np.max(np.abs(np.asarray(kn)), axis=(0, 2)) / 127.0
     np.testing.assert_allclose(s, expect, rtol=1e-6)
-    deq = np.asarray(k)[0, 1].astype(np.float32) * s[None, :, None]
-    np.testing.assert_allclose(deq, np.asarray(kn),
+    # block 1 is (H, bs, Dh); the rows went in as (bs, H, Dh)
+    deq = np.asarray(k)[0, 1].astype(np.float32) * s[:, None, None]
+    np.testing.assert_allclose(deq.transpose(1, 0, 2), np.asarray(kn),
                                atol=float(np.max(s)) * 0.51)
     # monotonic: a smaller later row must not shrink the block's scale
     k2, v2, ks2, vs2 = write_kv_quant(k, v, ks, vs, 0,

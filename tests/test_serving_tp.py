@@ -120,15 +120,15 @@ def test_tp_decode_parity_bf16(tiny_lm):
 
 
 def test_tp_pool_sharded_over_heads(tiny_lm):
-    """The KV block pool is laid out with H/k heads per chip (axis 3 of
-    (L, nb, bs, H, Dh)); block tables stay host-side replicated ints."""
+    """The KV block pool is laid out with H/k heads per chip (axis 2 of
+    (L, nb, H, bs, Dh)); block tables stay host-side replicated ints."""
     params, cfg = tiny_lm
     eng = make_engine(params, cfg, paged=True, tp=2)
     assert eng.tp == 2, eng.tp_fallback
     spec = eng.cache.k.sharding.spec
-    assert tuple(spec) == (None, None, None, "tp", None)
+    assert tuple(spec) == (None, None, "tp", None, None)
     shard = eng.cache.k.addressable_shards[0].data
-    assert shard.shape[3] == cfg.n_heads // 2
+    assert shard.shape[2] == cfg.n_heads // 2
     assert eng.cache.v.sharding == eng.cache.k.sharding
     # H/k heads per chip => per-chip pool bytes are 1/k of the total
     total = np.prod(eng.cache.k.shape)

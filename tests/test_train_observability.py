@@ -152,7 +152,6 @@ def test_comms_ledger_analytic_zero1_pin():
     must report them within 10%. The ledger is tested against theory,
     not against itself."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from mxnet_tpu.parallel.collectives import shard_map
 
     n_dp = 4
     mesh = Mesh(np.array(jax.devices()[:n_dp]), ("dp",))
@@ -168,7 +167,7 @@ def test_comms_ledger_analytic_zero1_pin():
         return jax.lax.all_gather(ws - 0.1 * gs, "dp", tiled=True)
 
     fn = introspect.instrument(
-        jax.jit(shard_map(zero1, mesh=mesh, in_specs=(P(), P()),
+        jax.jit(jax.shard_map(zero1, mesh=mesh, in_specs=(P(), P()),
                           out_specs=P(), check_vma=False)),
         site="test.zero1")
     g = np.random.randn(rows, cols).astype(np.float32)
@@ -241,7 +240,6 @@ def test_comms_ledger_tp_two_psums_per_layer():
     row-parallel w2 — TWO all-reduces per layer, no more, and each
     moves exactly the activation bytes."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from mxnet_tpu.parallel.collectives import shard_map
 
     n_layers, batch, d = 3, 4, 32
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
@@ -253,7 +251,7 @@ def test_comms_ledger_tp_two_psums_per_layer():
         return x
 
     fn = introspect.instrument(
-        jax.jit(shard_map(block, mesh=mesh, in_specs=(P(), P()),
+        jax.jit(jax.shard_map(block, mesh=mesh, in_specs=(P(), P()),
                           out_specs=P(), check_vma=False)),
         site="test.tp_block")
     fn(np.random.randn(batch, d).astype(np.float32),

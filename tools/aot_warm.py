@@ -78,8 +78,7 @@ def warm(args):
                          aot_cache=args.cache)
     if eng.aot_cache is None:
         raise SystemExit("no cache directory (pass --cache or set "
-                         "MXNET_AOT_CACHE_DIR) or this jax build has "
-                         "no AOT serialization support")
+                         "MXNET_AOT_CACHE_DIR)")
     max_len = getattr(adapter, "max_len", None) or 128
     lens = _buckets(args.prompt_buckets, max(1, max_len - 2))
     print("warming %s: paged=%s tp=%s max_batch=%d block_size=%d "

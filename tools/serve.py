@@ -175,10 +175,26 @@ def main():
     if args.max_replicas is not None:
         os.environ["MXNET_SERVING_MAX_REPLICAS"] = str(args.max_replicas)
 
+    import jax
     from mxnet_tpu import serving
+    from mxnet_tpu.base import enable_compile_cache
+
+    # jax's persistent compile cache and the AOT executable cache poison
+    # each other in one process (an executable jax loaded from its own
+    # cache serializes to a payload the AOT loader rejects), so a server
+    # started with an AOT cache leaves jax's alone
+    if args.aot_cache or os.environ.get("MXNET_AOT_CACHE_DIR"):
+        if jax.config.jax_compilation_cache_dir:
+            print("WARNING: JAX_COMPILATION_CACHE_DIR is set together with "
+                  "the AOT cache: entries published by this process will "
+                  "be quarantined and recompiled on load")
+    else:
+        print("compile cache: %s" % enable_compile_cache())
+    dev = jax.devices()[0]
+    print("device: %s %s x%d" % (dev.platform, dev.device_kind,
+                                 len(jax.devices())))
 
     if args.demo:
-        import jax
         from mxnet_tpu.models.transformer import (TransformerConfig,
                                                   init_transformer_params)
         cfg = TransformerConfig(vocab=256, d_model=64, n_heads=4,
@@ -248,6 +264,8 @@ def main():
           "max_batch=%d max_queue=%d"
           % ("on" if eng.paged else "off", eng.prefill_chunk or "-",
              args.block_size, args.max_batch, args.max_queue))
+    if eng.paged_fallback:
+        print("paged attention: OFF — %s" % eng.paged_fallback)
     if eng.prefix_cache is not None:
         print("prefix cache: on (content-addressed KV block reuse, "
               "copy-on-write, LRU eviction)")
