@@ -471,8 +471,8 @@ class TrainStep:
 
         guard = self._guard
 
-        def step(grad_vals, nograd_vals, opt_state, x, y, key, lr, t,
-                 poison, residuals=None):
+        def train_step(grad_vals, nograd_vals, opt_state, x, y, key, lr,
+                       t, poison, residuals=None):
             # independent streams: forward-trace keys (dropout masks etc.)
             # derive from fwd_key; optimizer noise (SGLD) from noise_key —
             # fold_in on the SAME base key would collide with the trace keys
@@ -568,7 +568,7 @@ class TrainStep:
             argnames = argnames + ("residuals",)
             donate = donate + (9,)
         self._step_fn = _introspect.instrument(
-            jax.jit(step, donate_argnums=donate), site="train.step",
+            jax.jit(train_step, donate_argnums=donate), site="train.step",
             phase="train", argnames=argnames, variant="train_step")
         self._names = names
         self._plist = plist
@@ -630,6 +630,14 @@ class TrainStep:
                 for w in grad_vals)
 
     def __call__(self, x, y):
+        # one span from the first line to the return: the host's share of
+        # a step (placing the batch, the dispatch), a child of
+        # ResilientLoop's `train.device_step` where that loop drives it
+        from .. import telemetry as _telemetry
+        with _telemetry.span("train.dispatch", category="trainstep") as sp:
+            return self._dispatch(x, y, sp)
+
+    def _dispatch(self, x, y, sp):
         from .. import profiler as _profiler
         xv = x._data if isinstance(x, NDArray) else jnp.asarray(x)
         yv = y._data if isinstance(y, NDArray) else jnp.asarray(y)
@@ -643,6 +651,11 @@ class TrainStep:
             xv = shard_batch(self._mesh, xv, self._data_axis)
             yv = shard_batch(self._mesh, yv, self._data_axis)
         self._t += 1
+        # compile vs run split in the profiler table: the first dispatch pays
+        # XLA compilation, later ones are cached executions (parity with the
+        # reference's symbolic bind-vs-run accounting)
+        sp.alias = "TrainStep::compile" if first_call else "TrainStep::run"
+        sp.attrs.update(step=self._t, first_call=first_call)
         if self._lr_schedule is not None:
             lr = self._lr_schedule(self._t)
         elif self._opt.lr_scheduler is not None:
@@ -662,22 +675,17 @@ class TrainStep:
                 lambda v: jax.ShapeDtypeStruct(jnp.shape(v),
                                                jnp.asarray(v).dtype),
                 call_args)
-        # compile vs run split in the profiler table: the first dispatch pays
-        # XLA compilation, later ones are cached executions (parity with the
-        # reference's symbolic bind-vs-run accounting)
-        label = "TrainStep::compile" if first_call else "TrainStep::run"
-        with _profiler.scope(label, "trainstep"):
-            out = self._step_fn(*call_args)
-            if self.collective_quant:
-                out, self._quant_residuals = out[:-1], out[-1]
-            if self._guard:
-                (loss, self._grad_vals, self._nograd_vals, self._opt_state,
-                 self.last_step_ok, self.last_grad_norm) = out
-            else:
-                loss, self._grad_vals, self._nograd_vals, self._opt_state \
-                    = out
-            if _profiler.profile_sync():
-                jax.block_until_ready(loss)
+        out = self._step_fn(*call_args)
+        if self.collective_quant:
+            out, self._quant_residuals = out[:-1], out[-1]
+        if self._guard:
+            (loss, self._grad_vals, self._nograd_vals, self._opt_state,
+             self.last_step_ok, self.last_grad_norm) = out
+        else:
+            loss, self._grad_vals, self._nograd_vals, self._opt_state \
+                = out
+        if _profiler.profile_sync():
+            jax.block_until_ready(loss)
         self._compiled = True
         # register the step's output buffers so mx.nd.waitall() blocks on
         # in-flight optimizer updates (the benchmark timing pattern)

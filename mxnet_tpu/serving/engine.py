@@ -47,6 +47,7 @@ the bounds for both paths.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import os
 import time
@@ -79,6 +80,13 @@ def quantized_weights_env():
     Unset/empty = f32 weights."""
     v = os.environ.get("MXNET_QUANTIZED_WEIGHTS", "").strip()
     return v or None
+
+
+def _named(name, fn):
+    """`fn` under `name`, so its program is `jit_<name>` in a device trace
+    (a lambda's is `jit__lambda`, whichever step it is)."""
+    fn.__name__ = name
+    return fn
 
 
 def pow2_bucket(n, lo=1, hi=None):
@@ -498,54 +506,62 @@ class TransformerLM:
         # serving.decode SITE and can trace equal signatures — the tag
         # (plus the lowered-text hash in the key) keeps their disk
         # entries apart, so a warm load can never swap implementations
-        self._prefill_jit = instrument(jax.jit(
+        self._prefill_jit = instrument(jax.jit(_named(
+            "serving_prefill",
             lambda p, k, v, t, ln, tb: _tf_prefill(p, k, v, t, ln, tb,
-                                                   cfg, block_size)),
+                                                   cfg, block_size))),
             site="serving.prefill", phase="prefill",
             argnames=self._PREFILL_ARGS, variant="prefill_dense")
-        self._decode_jit = instrument(jax.jit(
+        self._decode_jit = instrument(jax.jit(_named(
+            "serving_decode",
             lambda p, k, v, t, pos, tb: _tf_decode(p, k, v, t, pos, tb,
-                                                   cfg, block_size)),
+                                                   cfg, block_size))),
             site="serving.decode", phase="decode",
             argnames=self._DECODE_ARGS, variant="decode_gather")
-        self._decode_paged_jit = instrument(jax.jit(
+        self._decode_paged_jit = instrument(jax.jit(_named(
+            "serving_decode_paged",
             lambda p, k, v, t, pos, tb: _tf_decode_paged(
-                p, k, v, t, pos, tb, cfg, block_size)),
+                p, k, v, t, pos, tb, cfg, block_size))),
             site="serving.decode", phase="decode",
             argnames=self._DECODE_ARGS, variant="decode_paged")
-        self._prefill_chunk_jit = instrument(jax.jit(
+        self._prefill_chunk_jit = instrument(jax.jit(_named(
+            "serving_prefill_chunk",
             lambda p, k, v, t, qs, ln, li, tb: _tf_prefill_chunk(
-                p, k, v, t, qs, ln, li, tb, cfg, block_size)),
+                p, k, v, t, qs, ln, li, tb, cfg, block_size))),
             site="serving.prefill", phase="prefill",
             argnames=self._CHUNK_ARGS, variant="prefill_chunk")
         # speculative k+1 scoring (one site, AOT-cacheable): the batched
         # chunk signature against the live block tables
-        self._spec_score_jit = instrument(jax.jit(
+        self._spec_score_jit = instrument(jax.jit(_named(
+            "serving_spec_score",
             lambda p, k, v, t, qs, cn, tb: _tf_spec_score(
-                p, k, v, t, qs, cn, tb, cfg, block_size)),
+                p, k, v, t, qs, cn, tb, cfg, block_size))),
             site="serving.spec_score", phase="decode",
             argnames=self._SPEC_ARGS, variant="spec_score")
         if kv_quant:
             # int8-pool variants (ISSUE 20): distinct AOT variant tags —
             # the quant step traces extra scale operands, and a warm
             # load must never hand the f32 path a quantized executable
-            self._decode_paged_q_jit = instrument(jax.jit(
+            self._decode_paged_q_jit = instrument(jax.jit(_named(
+                "serving_decode_paged_q8",
                 lambda p, k, v, t, pos, tb, ks, vs: _tf_decode_paged(
                     p, k, v, t, pos, tb, cfg, block_size,
-                    k_scale=ks, v_scale=vs)),
+                    k_scale=ks, v_scale=vs))),
                 site="serving.decode", phase="decode",
                 argnames=self._DECODE_Q_ARGS, variant="decode_paged_q8")
-            self._prefill_chunk_q_jit = instrument(jax.jit(
+            self._prefill_chunk_q_jit = instrument(jax.jit(_named(
+                "serving_prefill_chunk_q8",
                 lambda p, k, v, t, qs, ln, li, tb, ks, vs:
                     _tf_prefill_chunk(p, k, v, t, qs, ln, li, tb, cfg,
                                       block_size, k_scale=ks,
-                                      v_scale=vs)),
+                                      v_scale=vs))),
                 site="serving.prefill", phase="prefill",
                 argnames=self._CHUNK_Q_ARGS, variant="prefill_chunk_q8")
-            self._spec_score_q_jit = instrument(jax.jit(
+            self._spec_score_q_jit = instrument(jax.jit(_named(
+                "serving_spec_score_q8",
                 lambda p, k, v, t, qs, cn, tb, ks, vs: _tf_spec_score(
                     p, k, v, t, qs, cn, tb, cfg, block_size,
-                    k_scale=ks, v_scale=vs)),
+                    k_scale=ks, v_scale=vs))),
                 site="serving.spec_score", phase="decode",
                 argnames=self._SPEC_Q_ARGS, variant="spec_score_q8")
 
@@ -718,7 +734,7 @@ class BlockLM:
                 out = apply_fn(vals, toks)
             return out                                   # (B, S, V)
 
-        def step(vals, toks, lengths):
+        def serving_step_full(vals, toks, lengths):
             out = logits_fn(vals, toks)
             rows = jnp.take_along_axis(
                 out, (lengths - 1)[:, None, None], axis=1)[:, 0]
@@ -726,7 +742,7 @@ class BlockLM:
 
         self._values = values
         self._step_jit = telemetry.introspect.instrument(
-            jax.jit(step), site="serving.step_full",
+            jax.jit(serving_step_full), site="serving.step_full",
             argnames=("values", "tokens", "lengths"))
 
     def step_full(self, tokens, lengths, phase=None):
@@ -1395,8 +1411,12 @@ class Engine:
             # fall through: the un-touched single-token path IS the
             # degradation target (and the parity oracle)
         t0_us = time.perf_counter_ns() // 1000
+        # the cache path's host work in three child spans (to label the
+        # device's idle gaps, PERF.md); ring and profiler only
+        part = functools.partial(telemetry.span, category="serving",
+                                 to_flight=False, batch=len(seqs))
         with telemetry.span("serving.decode", category="serving",
-                            batch=len(seqs)):
+                            batch=len(seqs)) as step_span:
             if self.model.uses_cache:
                 # paged path: the table width handed to the kernel is
                 # bucketed to the longest LIVE sequence, so a decode
@@ -1407,13 +1427,16 @@ class Engine:
                     w = pow2_bucket(
                         max(self.cache.blocks_for(len(s.tokens))
                             for s in seqs), lo=1, hi=self._nblk)
-                toks = np.zeros((bb,), np.int32)
-                pos = np.zeros((bb,), np.int32)
-                tabs = np.zeros((bb, w), np.int32)
-                for i, s in enumerate(seqs):
-                    toks[i] = s.tokens[-1]
-                    pos[i] = len(s.tokens) - 1
-                    tabs[i] = s.table_row[:w]
+                with part("serving.decode.build"):
+                    toks = np.zeros((bb,), np.int32)
+                    pos = np.zeros((bb,), np.int32)
+                    tabs = np.zeros((bb, w), np.int32)
+                    for i, s in enumerate(seqs):
+                        toks[i] = s.tokens[-1]
+                        pos[i] = len(s.tokens) - 1
+                        tabs[i] = s.table_row[:w]
+                    toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
+                                       jnp.asarray(tabs))
                 step_fn = self.model.decode
                 if self.paged:
                     # same (batch, width) signature lattice whether the
@@ -1427,21 +1450,22 @@ class Engine:
                     sig = (bb, w)
                 else:
                     sig = bb
-                with self._count("decode", sig):
+                with part("serving.decode.dispatch"), \
+                        self._count("decode", sig):
                     if self.kv_quant:
                         (self.cache.k, self.cache.v, self.cache.k_scale,
                          self.cache.v_scale, logits, nxt) = step_fn(
                             self.cache.k, self.cache.v,
                             self.cache.k_scale, self.cache.v_scale,
-                            jnp.asarray(toks), jnp.asarray(pos),
-                            jnp.asarray(tabs))
+                            toks, pos, tabs)
                     else:
                         self.cache.k, self.cache.v, logits, nxt = \
                             step_fn(self.cache.k, self.cache.v,
-                                    jnp.asarray(toks), jnp.asarray(pos),
-                                    jnp.asarray(tabs))
-                nxt = np.asarray(nxt)
-                logits = np.asarray(logits) if self.keep_logits else None
+                                    toks, pos, tabs)
+                with part("serving.decode.readback"):
+                    nxt = np.asarray(nxt)
+                    logits = np.asarray(logits) if self.keep_logits \
+                        else None
             else:
                 s_pad = pow2_bucket(max(len(s.tokens) for s in seqs),
                                     lo=1, hi=self.max_len)
@@ -1459,18 +1483,19 @@ class Engine:
         # its decode steps (ring-only: the batch span above already
         # covers the interval in the chrome trace)
         dur_us = time.perf_counter_ns() // 1000 - t0_us
-        for i, s in enumerate(seqs):
-            if self.keep_logits and logits is not None:
-                s.last_logits = logits[i]
-                if s.token_logits is not None:
-                    s.token_logits.append(logits[i])
-            self._append(s, int(nxt[i]))
-            if s.request is not None:
-                telemetry.record_span("serving.decode", t0_us, dur_us,
-                                      trace=s.request.trace,
-                                      category="serving",
-                                      to_profiler=False, to_flight=False,
-                                      position=len(s.tokens) - 1)
+        with part("serving.decode.append"):
+            for i, s in enumerate(seqs):
+                if self.keep_logits and logits is not None:
+                    s.last_logits = logits[i]
+                    if s.token_logits is not None:
+                        s.token_logits.append(logits[i])
+                self._append(s, int(nxt[i]))
+                if s.request is not None:
+                    telemetry.record_span(
+                        "serving.decode", t0_us, dur_us,
+                        trace=s.request.trace, category="serving",
+                        to_profiler=False, to_flight=False,
+                        parent=step_span.id, position=len(s.tokens) - 1)
         return seqs
 
     def _draft_propose(self, seqs, bb, k, poison):

@@ -674,24 +674,40 @@ class LMServer(_HTTPFrontend):
             raise
 
     def _loop_inner(self):
-        eng, sched, met = self.engine, self.scheduler, self.metrics
         rid = self.replica_id if self.replica_id is not None else 0
         it = 0
         while not self._closed:
             it += 1
             self._last_beat = time.perf_counter()
-            # chaos seams (no-ops unless armed; utils/chaos.py): a kill
-            # raises HERE — outside the engine-fault isolation — so the
-            # loop dies like a real bug; a wedge sleeps so the beat goes
-            # stale; exhaustion steals the free list for a few rounds
-            chaos.maybe_kill_serving_loop(rid, it)
-            chaos.maybe_wedge_serving_loop(rid, it)
-            # rollout chaos (ISSUE 18): a standing per-iteration sleep
-            # on ONE replica — the healthy-but-slow canary the rollout
-            # judge must roll back on SLO burn instead of promoting
-            chaos.rollout_slow_canary(rid, it)
-            self._chaos_pool_pressure(rid, it)
+            # one tree of spans per iteration that did work (PERF.md:
+            # they label the device's idle gaps between decode steps)
+            with telemetry.span("serving.loop", category="serving",
+                                it=it) as loop_span:
+                self._iterate(rid, it, loop_span)
+
+    def _iterate(self, rid, it, loop_span):
+        """One pass of the serving loop: admit, prefill, one decode step,
+        the step's bookkeeping; or, with nothing to do, a wait (and then
+        no span is recorded)."""
+        eng, sched, met = self.engine, self.scheduler, self.metrics
+        # chaos seams (no-ops unless armed; utils/chaos.py): a kill
+        # raises HERE — outside the engine-fault isolation — so the
+        # loop dies like a real bug; a wedge sleeps so the beat goes
+        # stale; exhaustion steals the free list for a few rounds
+        chaos.maybe_kill_serving_loop(rid, it)
+        chaos.maybe_wedge_serving_loop(rid, it)
+        # rollout chaos (ISSUE 18): a standing per-iteration sleep
+        # on ONE replica — the healthy-but-slow canary the rollout
+        # judge must roll back on SLO burn instead of promoting
+        chaos.rollout_slow_canary(rid, it)
+        self._chaos_pool_pressure(rid, it)
+        with telemetry.span("serving.admit",
+                            category="serving") as admit_span:
             admitted, expired = sched.admit(eng)
+            if not (admitted or expired or sched.prefilling
+                    or sched.running):
+                admit_span.cancel()     # nothing to do: this pass waits
+                loop_span.cancel()
             for req in expired:
                 if isinstance(req.error, DeadlineExceeded):
                     met.request_deadline_shed()
@@ -706,39 +722,45 @@ class LMServer(_HTTPFrontend):
                 self._prefill_chunks()
             else:
                 self._admit_dense(admitted)
-            if sched.running:
-                t0 = time.perf_counter()
-                try:
-                    if chaos.decode_poison(rid, it):
-                        raise MXNetError("chaos: decode step poisoned")
-                    if eng.spec:
-                        # spec-poison seam: NaN-fill THIS iteration's
-                        # draft logits — the engine must degrade the
-                        # batch to the non-speculative path, token-
-                        # identical to the undisturbed oracle
-                        eng.chaos_spec_poison = chaos.spec_poison(rid, it)
-                    # pre-step lengths of the sequences decode_step will
-                    # return (it filters done ones in the same order):
-                    # a speculative step emits a BURST per sequence, so
-                    # tokens = post-len minus pre-len, not 1 per step
-                    pre_lens = [len(s.tokens) for s in sched.running
-                                if not s.done]
-                    advanced = eng.decode_step(sched.running)
-                except Exception as e:
-                    # a decode fault poisons the STEP, not the history:
-                    # every token already appended came from a step that
-                    # completed. Re-home the batch onto this server's own
-                    # queue as failover replays (prompt + generated so
-                    # far re-prefills, decode continues token-identically)
-                    # instead of failing user-visible work; a request
-                    # that keeps hitting faults exhausts max_failovers
-                    # and surfaces the error
-                    met.engine_failure()
-                    err = MXNetError("engine decode failed: %s: %s"
-                                     % (type(e).__name__, e))
-                    self._resume_locally(sched.running, err)
-                    sched.running = []
-                    continue
+            loop_span.attrs["batch"] = len(sched.running)
+            admit_span.attrs.update(batch=len(sched.running),
+                                    admitted=len(admitted),
+                                    expired=len(expired))
+        if sched.running:
+            t0 = time.perf_counter()
+            try:
+                if chaos.decode_poison(rid, it):
+                    raise MXNetError("chaos: decode step poisoned")
+                if eng.spec:
+                    # spec-poison seam: NaN-fill THIS iteration's
+                    # draft logits — the engine must degrade the
+                    # batch to the non-speculative path, token-
+                    # identical to the undisturbed oracle
+                    eng.chaos_spec_poison = chaos.spec_poison(rid, it)
+                # pre-step lengths of the sequences decode_step will
+                # return (it filters done ones in the same order):
+                # a speculative step emits a BURST per sequence, so
+                # tokens = post-len minus pre-len, not 1 per step
+                pre_lens = [len(s.tokens) for s in sched.running
+                            if not s.done]
+                advanced = eng.decode_step(sched.running)
+            except Exception as e:
+                # a decode fault poisons the STEP, not the history:
+                # every token already appended came from a step that
+                # completed. Re-home the batch onto this server's own
+                # queue as failover replays (prompt + generated so
+                # far re-prefills, decode continues token-identically)
+                # instead of failing user-visible work; a request
+                # that keeps hitting faults exhausts max_failovers
+                # and surfaces the error
+                met.engine_failure()
+                err = MXNetError("engine decode failed: %s: %s"
+                                 % (type(e).__name__, e))
+                self._resume_locally(sched.running, err)
+                sched.running = []
+                return
+            with telemetry.span("serving.account", category="serving",
+                                to_flight=False, batch=len(advanced)):
                 self._last_step_t = time.perf_counter()
                 if advanced:  # count only sequences that really stepped
                     emitted = sum(len(s.tokens) - n
@@ -765,16 +787,16 @@ class LMServer(_HTTPFrontend):
                 for req in (s.request for s in sched.evict(eng)
                             if s.request is not None):
                     met.request_finished(req)
-            elif sched.prefilling:
-                pass      # chunk work ran this iteration; no decode to
-                          # pace against, so loop straight into the next
-                          # chunk round (sleeping here would throttle
-                          # TTFT on an otherwise-idle server)
-            elif not sched.pending():
-                self._work.clear()
-                self._work.wait(self._idle_wait * 20)
-            else:
-                time.sleep(self._idle_wait)
+        elif sched.prefilling:
+            pass      # chunk work ran this iteration; no decode to
+                      # pace against, so loop straight into the next
+                      # chunk round (sleeping here would throttle
+                      # TTFT on an otherwise-idle server)
+        elif not sched.pending():
+            self._work.clear()
+            self._work.wait(self._idle_wait * 20)
+        else:
+            time.sleep(self._idle_wait)
 
     def _admit_dense(self, admitted):
         """PR 1 admission: each admitted request runs its WHOLE prefill

@@ -111,7 +111,7 @@ class DraftLM:
         self.vocab = cfg.vocab
         self.max_len = cfg.max_len
 
-        def _last_logits(p, toks, lengths):
+        def serving_draft(p, toks, lengths):
             out = transformer_apply(p, toks, cfg)          # (B, S, V)
             idx = (lengths - 1)[:, None, None]
             rows = jnp.take_along_axis(
@@ -120,7 +120,7 @@ class DraftLM:
             return rows[:, 0].astype(jnp.float32)
 
         self._logits_jit = telemetry.introspect.instrument(
-            jax.jit(_last_logits), site="serving.draft", phase="decode",
+            jax.jit(serving_draft), site="serving.draft", phase="decode",
             argnames=("params", "tokens", "lengths"),
             variant="draft_full")
 

@@ -399,23 +399,23 @@ def build_tp_decode(cfg, block_size, mesh, kv_quant=False,
     sc = kv_scale_spec()
 
     if kv_quant:
-        def body(params, k, v, toks, pos, tabs, ks, vs):
+        def serving_decode_tp_q8(params, k, v, toks, pos, tabs, ks, vs):
             return _decode_body(params, k, v, toks, pos, tabs, cfg,
                                 block_size, k_scale=ks, v_scale=vs)
 
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            serving_decode_tp_q8, mesh=mesh,
             in_specs=(specs, pool, pool, P(None), P(None),
                       P(None, None), sc, sc),
             out_specs=(pool, pool, sc, sc, P(None, None), P(None)),
             check_vma=False))
 
-    def body(params, k, v, toks, pos, tabs):
+    def serving_decode_tp(params, k, v, toks, pos, tabs):
         return _decode_body(params, k, v, toks, pos, tabs, cfg,
                             block_size)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        serving_decode_tp, mesh=mesh,
         in_specs=(specs, pool, pool, P(None), P(None), P(None, None)),
         out_specs=(pool, pool, P(None, None), P(None)),
         check_vma=False))
@@ -431,26 +431,27 @@ def build_tp_prefill_chunk(cfg, block_size, mesh, kv_quant=False,
     sc = kv_scale_spec()
 
     if kv_quant:
-        def body(params, k, v, toks, qs, length, last_idx, table_row,
-                 ks, vs):
+        def serving_prefill_chunk_tp_q8(params, k, v, toks, qs, length,
+                                        last_idx, table_row, ks, vs):
             return _prefill_chunk_body(params, k, v, toks, qs, length,
                                        last_idx, table_row, cfg,
                                        block_size, k_scale=ks,
                                        v_scale=vs)
 
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            serving_prefill_chunk_tp_q8, mesh=mesh,
             in_specs=(specs, pool, pool, P(None), P(), P(), P(),
                       P(None), sc, sc),
             out_specs=(pool, pool, sc, sc, P(None)),
             check_vma=False))
 
-    def body(params, k, v, toks, qs, length, last_idx, table_row):
+    def serving_prefill_chunk_tp(params, k, v, toks, qs, length, last_idx,
+                                 table_row):
         return _prefill_chunk_body(params, k, v, toks, qs, length,
                                    last_idx, table_row, cfg, block_size)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        serving_prefill_chunk_tp, mesh=mesh,
         in_specs=(specs, pool, pool, P(None), P(), P(), P(), P(None)),
         out_specs=(pool, pool, P(None)),
         check_vma=False))
@@ -466,24 +467,25 @@ def build_tp_spec_score(cfg, block_size, mesh, kv_quant=False,
     sc = kv_scale_spec()
 
     if kv_quant:
-        def body(params, k, v, toks, qs, counts, tabs, ks, vs):
+        def serving_spec_score_tp_q8(params, k, v, toks, qs, counts, tabs,
+                                     ks, vs):
             return _spec_score_body(params, k, v, toks, qs, counts,
                                     tabs, cfg, block_size, k_scale=ks,
                                     v_scale=vs)
 
         return jax.jit(jax.shard_map(
-            body, mesh=mesh,
+            serving_spec_score_tp_q8, mesh=mesh,
             in_specs=(specs, pool, pool, P(None, None), P(None),
                       P(None), P(None, None), sc, sc),
             out_specs=(pool, pool, sc, sc, P(None, None, None)),
             check_vma=False))
 
-    def body(params, k, v, toks, qs, counts, tabs):
+    def serving_spec_score_tp(params, k, v, toks, qs, counts, tabs):
         return _spec_score_body(params, k, v, toks, qs, counts, tabs,
                                 cfg, block_size)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        serving_spec_score_tp, mesh=mesh,
         in_specs=(specs, pool, pool, P(None, None), P(None), P(None),
                   P(None, None)),
         out_specs=(pool, pool, P(None, None, None)),

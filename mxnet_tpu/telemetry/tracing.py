@@ -18,6 +18,11 @@ a thread-local (set once at the root span, inherited below), or
 explicitly with `span(..., trace=id)` / `record_span(..., trace=id)` for
 regions timed outside a `with` block (e.g. one decode step fanned out to
 every sequence it advanced).
+
+Parents connect layers: a span takes its id when it opens and every record
+carries `parent`, the id of the span open on the same thread when it
+started (None at a root), so a layer's self time is its span less its
+children.
 """
 from __future__ import annotations
 
@@ -44,10 +49,13 @@ _tls = threading.local()
 _ring_size = int(os.environ.get("MXNET_TELEMETRY_SPAN_RING", "8192"))
 _spans = deque(maxlen=_ring_size)
 _lock = threading.Lock()
-#: highest span id the last export_perfetto() saw: an overwrite of a
-#: NEWER span is a drop the operator never got to see (ISSUE 13 — drops
-#: were silent before; now they land on `spans_dropped_total` and the
-#: ring fill rides the `span_ring_occupancy` gauge)
+#: spans appended so far, and how many of them the last export_perfetto()
+#: saw: an overwrite of a NEWER span is a drop the operator never got to
+#: see (ISSUE 13 — drops were silent before; now they land on
+#: `spans_dropped_total` and the ring fill rides the
+#: `span_ring_occupancy` gauge). Counted in appends, not ids: a parent's
+#: id is older than its children's but it is appended after them
+_appended = 0
 _exported_upto = 0
 
 
@@ -142,8 +150,17 @@ def _now_us():
     return time.perf_counter_ns() // 1000
 
 
+def _open_spans():
+    """The ids of the spans open on this thread, outermost first."""
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    return stack
+
+
 def record_span(name, start_us, dur_us, trace=None, category="trace",
-                to_profiler=True, to_flight=True, **attrs):
+                to_profiler=True, to_flight=True, parent=None, alias=None,
+                _id=None, **attrs):
     """Record one already-timed span. The seam for fan-out: a batched
     decode step is timed once but attributed to every request it
     advanced, so each request's row stays connected. The per-request
@@ -151,25 +168,32 @@ def record_span(name, start_us, dur_us, trace=None, category="trace",
     `to_profiler=False` keeps them out of the chrome trace and
     `to_flight=False` out of the flight-recorder ring, where B duplicate
     copies per decode step would evict the history the black box exists
-    to keep (the batch-level span covers the interval in both)."""
+    to keep (the batch-level span covers the interval in both).
+    `parent` is the span open on this thread unless `parent=` names one
+    (the copies name the batch-level span, closed by then); `alias` is the
+    name the legacy profiler table files the span under."""
     if not enabled():
         return
     if trace is None:
         trace = current_trace()
-    rec = {"id": next(_ids), "name": name, "cat": category,
-           "trace": trace, "ts": start_us, "dur": dur_us,
+    if parent is None:
+        stack = _open_spans()
+        parent = stack[-1] if stack else None
+    rec = {"id": _id or next(_ids), "parent": parent, "name": name,
+           "cat": category, "trace": trace, "ts": start_us, "dur": dur_us,
            "pid": os.getpid(), "tid": threading.get_ident()}
     if attrs:
         rec["attrs"] = attrs
-    global _occupancy_last
+    global _occupancy_last, _appended
     dropped, occupancy = 0, 0.0
     with _lock:
         if len(_spans) == _spans.maxlen \
-                and _spans[0]["id"] > _exported_upto:
+                and _appended - len(_spans) >= _exported_upto:
             # the ring is about to overwrite a span no export has seen:
             # a silent gap in the next Perfetto row (satellite, ISSUE 13)
             dropped = 1
         _spans.append(rec)
+        _appended += 1
         occupancy = len(_spans) / float(_spans.maxlen or 1)
     # quantize the occupancy gauge so a full (or slowly-filling) ring
     # doesn't pay a locked gauge.set per span on the decode hot path;
@@ -188,7 +212,7 @@ def record_span(name, start_us, dur_us, trace=None, category="trace",
         gauge.set(occupancy)
         _occupancy_last = occ_q
     if to_profiler:
-        profiler.record_event(name, category, start_us, dur_us,
+        profiler.record_event(alias or name, category, start_us, dur_us,
                               dict(attrs, trace=trace) if attrs
                               else {"trace": trace})
     if to_flight:
@@ -206,28 +230,46 @@ class span:
 
     `trace=None` inherits the thread's current trace id; passing an
     explicit id also makes it the thread's current id for the duration
-    (nested spans connect automatically)."""
+    (nested spans connect automatically). While it is open it is the
+    parent of every span the thread records (`id`; None with telemetry
+    off). `attrs` and `alias` may be set until it closes; `cancel()`
+    closes it without a record, for a region that turned out to do no
+    work. `to_flight=False` keeps a fine-grained span, whose parent covers
+    the interval there, out of the flight ring."""
 
-    def __init__(self, name, trace=None, category="trace", **attrs):
+    def __init__(self, name, trace=None, category="trace", to_flight=True,
+                 **attrs):
         self.name = name
         self.category = category
         self.attrs = attrs
+        self.alias = self.id = None
+        self._to_flight = to_flight
         self._trace = trace
         self._prev = None
+
+    def cancel(self):
+        self.name = None
 
     def __enter__(self):
         if self._trace is not None:
             self._prev = set_trace(self._trace)
+        self.id = next(_ids) if enabled() else None
+        if self.id is not None:
+            _open_spans().append(self.id)
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = _now_us()
+        if self.id is not None:
+            _open_spans().pop()     # `with` nests: the top is this span
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
-        record_span(self.name, self._t0, t1 - self._t0,
-                    trace=self._trace, category=self.category,
-                    **self.attrs)
+        if self.name is not None:
+            record_span(self.name, self._t0, t1 - self._t0,
+                        trace=self._trace, category=self.category,
+                        to_flight=self._to_flight, alias=self.alias,
+                        _id=self.id, **self.attrs)
         if self._trace is not None:
             set_trace(self._prev)
         return False
@@ -244,10 +286,10 @@ def spans(trace=None):
 
 def clear():
     """Drop the ring (tests)."""
-    global _exported_upto, _occupancy_last
+    global _appended, _exported_upto, _occupancy_last
     with _lock:
         _spans.clear()
-        _exported_upto = 0
+        _appended = _exported_upto = 0
     _occupancy_last = -1
 
 
@@ -283,9 +325,9 @@ def export_perfetto(path=None):
     global _exported_upto
     with _lock:
         recs = list(_spans)
-        if recs:    # spans up to here have been exported: only younger
-            # ones count as dropped if the ring overwrites them
-            _exported_upto = max(_exported_upto, recs[-1]["id"])
+        # spans up to here have been exported: only younger ones count
+        # as dropped if the ring overwrites them
+        _exported_upto = _appended
     host = _host_label()
     events = []
     rows = {}
@@ -301,7 +343,8 @@ def export_perfetto(path=None):
               "ts": r["ts"], "dur": r["dur"], "pid": pid,
               "tid": tid,
               "args": dict(r.get("attrs") or {}, trace=r["trace"],
-                           span_id=r["id"], host=host)}
+                           span_id=r["id"], parent=r["parent"],
+                           host=host)}
         events.append(ev)
     this_pid = host_pid(host, os.getpid())
     pids.setdefault(this_pid, os.getpid())

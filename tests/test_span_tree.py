@@ -1,0 +1,301 @@
+"""Spans with parents (ISSUE 24): the stack behind `parent`, the tree one
+serving-loop iteration records, `TrainStep`'s `train.dispatch`, and the names
+the jitted step programs carry into a device trace."""
+import collections
+import threading
+
+import pytest
+
+import jax
+
+import mxnet_tpu as mx
+from mxnet_tpu import profiler, serving, telemetry
+from mxnet_tpu.models.transformer import (TransformerConfig,
+                                          init_transformer_params)
+
+
+@pytest.fixture(autouse=True)
+def _clean_rings():
+    telemetry.tracing.clear()
+    telemetry.flight().clear()
+    yield
+    telemetry.tracing.clear()
+    telemetry.flight().clear()
+
+
+def by_name(spans=None):
+    out = collections.defaultdict(list)
+    for s in telemetry.spans() if spans is None else spans:
+        out[s["name"]].append(s)
+    return out
+
+
+# -- parents -----------------------------------------------------------------
+
+
+def test_a_span_is_the_parent_of_what_opens_inside_it():
+    with telemetry.span("outer") as outer:
+        with telemetry.span("inner") as inner:
+            telemetry.record_span("timed", 0, 1)
+        with telemetry.span("second"):
+            pass
+    got = by_name()
+    assert got["outer"][0]["parent"] is None
+    assert got["outer"][0]["id"] == outer.id
+    assert got["inner"][0]["parent"] == outer.id
+    assert got["timed"][0]["parent"] == inner.id
+    assert got["second"][0]["parent"] == outer.id      # `inner` was popped
+    assert all("parent" in s for s in telemetry.spans())
+    args = {e["name"]: e["args"] for e in
+            telemetry.export_perfetto()["traceEvents"] if e["ph"] == "X"}
+    assert args["inner"]["parent"] == outer.id
+    assert args["outer"]["parent"] is None
+
+
+def test_a_copy_names_its_parent_whatever_is_open():
+    with telemetry.span("step") as step:
+        pass
+    with telemetry.span("bookkeeping") as later:
+        telemetry.record_span("copy", 0, 1, parent=step.id)
+        telemetry.record_span("plain", 0, 1)
+    got = by_name()
+    assert got["copy"][0]["parent"] == step.id
+    assert got["plain"][0]["parent"] == later.id
+
+
+def test_two_threads_do_not_share_a_stack():
+    inside, release = threading.Event(), threading.Event()
+
+    def other():
+        with telemetry.span("other.outer"):
+            inside.set()
+            assert release.wait(30)
+
+    t = threading.Thread(target=other)
+    t.start()
+    assert inside.wait(30)
+    with telemetry.span("mine"):            # `other.outer` is open meanwhile
+        pass
+    release.set()
+    t.join(30)
+    assert not t.is_alive()
+    got = by_name()
+    assert got["mine"][0]["parent"] is None
+    assert got["other.outer"][0]["parent"] is None
+
+
+def test_an_exception_pops_the_stack():
+    with pytest.raises(ValueError):
+        with telemetry.span("outer"):
+            with telemetry.span("failing"):
+                raise ValueError("boom")
+    with telemetry.span("after"):
+        pass
+    got = by_name()
+    assert got["failing"][0]["attrs"]["error"] == "ValueError"
+    assert got["after"][0]["parent"] is None
+    assert telemetry.tracing._open_spans() == []
+
+
+def test_a_cancelled_span_leaves_no_record_and_no_parent_behind():
+    with telemetry.span("idle") as idle:
+        idle.cancel()
+    with telemetry.span("after"):
+        pass
+    got = by_name()
+    assert "idle" not in got and got["after"][0]["parent"] is None
+
+
+def test_telemetry_off_records_and_pushes_nothing(monkeypatch):
+    monkeypatch.setenv("MXNET_TELEMETRY", "0")
+    with telemetry.span("dead") as dead:
+        assert dead.id is None
+        assert telemetry.tracing._open_spans() == []
+        assert telemetry.record_span("copy", 0, 1, parent=dead.id) is None
+    assert telemetry.spans() == []
+
+
+def test_the_ring_counts_a_drop_by_appends_not_by_ids(monkeypatch):
+    """A parent's id is older than its children's and it is appended after
+    them: an export must bless every span it wrote, the children too."""
+    monkeypatch.setattr(telemetry.tracing, "_spans",
+                        collections.deque(maxlen=3))
+    dropped = telemetry.default_registry().counter("spans_dropped_total")
+    with telemetry.span("parent"):
+        telemetry.record_span("child1", 0, 1)
+        telemetry.record_span("child2", 0, 1)
+    base = dropped.value
+    telemetry.export_perfetto()
+    for i in range(3):                      # overwrite what was exported
+        telemetry.record_span("next%d" % i, 0, 1)
+    assert dropped.value == base
+    telemetry.record_span("unseen", 0, 1)   # overwrites `next0`, never seen
+    assert dropped.value == base + 1
+
+
+# -- the serving thread ------------------------------------------------------
+
+TREE = {"serving.admit": "serving.loop", "serving.decode": "serving.loop",
+        "serving.decode.build": "serving.decode",
+        "serving.decode.dispatch": "serving.decode",
+        "serving.decode.readback": "serving.decode",
+        "serving.decode.append": "serving.loop",
+        "serving.account": "serving.loop"}
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    cfg = TransformerConfig(vocab=48, d_model=32, n_heads=4, n_layers=2,
+                            d_ff=64, max_len=64)
+    return init_transformer_params(jax.random.PRNGKey(0), cfg), cfg
+
+
+def serve_three(tiny_lm, **options):
+    srv = serving.serve(tiny_lm, max_batch=4, num_blocks=64, **options)
+    try:
+        handles = [srv.submit([1 + i, 2, 3, 4, 5], max_new_tokens=5 + i)
+                   for i in range(3)]
+        tokens = [list(h.result(timeout=120)) for h in handles]
+        thread = srv._thread.ident
+    finally:
+        srv.close()
+    return tokens, thread
+
+
+def inside(child, parent):
+    return parent["ts"] <= child["ts"] and \
+        child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
+@pytest.mark.parametrize("paged", [None, True])
+def test_every_decoding_iteration_records_one_tree(tiny_lm, paged):
+    _, thread = serve_three(tiny_lm, paged=paged)
+    spans = [s for s in telemetry.spans() if s["tid"] == thread]
+    ids = {s["id"]: s for s in spans}
+    assert spans and all("parent" in s for s in spans)
+    roots = {s["name"] for s in spans if s["parent"] is None}
+    assert roots == {"serving.loop"}
+    loops = [s for s in spans if s["name"] == "serving.loop"]
+    assert len({s["attrs"]["it"] for s in loops}) == len(loops)
+    decoding = 0
+    for loop in loops:
+        under = by_name(s for s in spans if s["parent"] == loop["id"]
+                        or ids.get(s["parent"], {}).get("parent") == loop["id"])
+        assert len(under["serving.admit"]) == 1        # every working pass
+        if "serving.decode" not in under:
+            continue
+        decoding += 1
+        step = [s for s in under["serving.decode"] if "batch" in s["attrs"]]
+        copies = [s for s in under["serving.decode"] if "batch" not in s["attrs"]]
+        assert len(step) == 1 and len(copies) == step[0]["attrs"]["batch"]
+        assert all(c["parent"] == step[0]["id"]
+                   and abs(c["ts"] - step[0]["ts"]) < 1000 for c in copies)
+        for name, parent in TREE.items():
+            found = [s for s in under[name] if "batch" in s["attrs"]]
+            assert len(found) == 1, (name, loop["attrs"])
+            assert ids[found[0]["parent"]]["name"] == parent
+            assert inside(found[0], ids[found[0]["parent"]])
+        step, = step
+        build, = under["serving.decode.build"]
+        back, = under["serving.decode.readback"]
+        append, = under["serving.decode.append"]
+        account, = under["serving.account"]
+        # today's interval: opens before the arrays are built, closes with
+        # the readback, and does not cover what follows
+        assert step["ts"] <= build["ts"]
+        assert back["ts"] + back["dur"] <= step["ts"] + step["dur"] \
+            <= back["ts"] + back["dur"] + 1000
+        assert append["ts"] >= step["ts"] + step["dur"]
+        assert account["ts"] >= append["ts"] + append["dur"]
+        assert loop["attrs"]["batch"] == step["attrs"]["batch"]
+    assert decoding >= 6        # the longest request decodes 6 tokens
+    admits = [s for s in spans if s["name"] == "serving.admit"]
+    assert sum(s["attrs"]["admitted"] for s in admits) == 3
+    for s in spans:             # prefills and queue waits hang under admit
+        if s["name"] in ("serving.prefill", "serving.queue"):
+            assert ids[s["parent"]]["name"] == "serving.admit"
+    # the fine-grained spans stay out of the flight ring
+    flown = {e["name"] for e in telemetry.flight().events()}
+    assert {"serving.loop", "serving.admit", "serving.decode"} <= flown
+    assert not flown & {"serving.decode.build", "serving.decode.dispatch",
+                        "serving.decode.readback", "serving.decode.append",
+                        "serving.account"}
+
+
+def test_a_pass_that_only_waits_records_nothing(tiny_lm):
+    import time
+    srv = serving.serve(tiny_lm, max_batch=4, num_blocks=64)
+    try:
+        time.sleep(0.3)         # the loop wakes a few times and finds nothing
+    finally:
+        srv.close()
+    assert telemetry.spans() == []
+
+
+def test_served_tokens_do_not_depend_on_telemetry(tiny_lm, monkeypatch):
+    on, _ = serve_three(tiny_lm)
+    assert telemetry.spans()
+    telemetry.tracing.clear()
+    monkeypatch.setenv("MXNET_TELEMETRY", "0")
+    off, _ = serve_three(tiny_lm)
+    assert telemetry.spans() == []
+    assert on == off and [len(t) for t in on] == [5, 6, 7]
+
+
+# -- the trainer ---------------------------------------------------------------
+
+
+def test_trainstep_records_train_dispatch_and_keeps_the_profilers_names():
+    import mxnet_tpu.gluon as gluon
+    from mxnet_tpu.parallel.trainer import TrainStep
+    net = gluon.nn.Dense(2, in_units=3)
+    net.initialize()
+    step = TrainStep(net, gluon.loss.L2Loss(), "sgd", {"learning_rate": 0.1})
+    x, y = mx.nd.ones((4, 3)), mx.nd.zeros((4, 2))
+    profiler._state["events"] = []
+    profiler._state["flushed"] = []
+    profiler.set_state("run")
+    try:
+        with telemetry.span("train.device_step") as outer:
+            step(x, y)
+        step(x, y)
+    finally:
+        profiler.set_state("stop")
+    first, second = by_name()["train.dispatch"]
+    assert first["attrs"] == {"step": 1, "first_call": True}
+    assert second["attrs"] == {"step": 2, "first_call": False}
+    assert first["parent"] == outer.id and second["parent"] is None
+    table = profiler.dumps()
+    assert "TrainStep::compile" in table and "TrainStep::run" in table
+    assert "train.dispatch" not in table
+    import inspect
+    from mxnet_tpu.parallel import trainer
+    assert "profiler.scope" not in inspect.getsource(trainer)
+
+
+# -- program names -------------------------------------------------------------
+
+
+def test_the_step_programs_carry_their_own_names(tiny_lm):
+    """A device trace names a program after the function handed to
+    `jax.jit`: every serving step and the train step can be told apart."""
+    params, cfg = tiny_lm
+    model = serving.TransformerLM(params, cfg)
+    model.bind(8, kv_quant=True)
+    names = {attr: getattr(model, attr).__wrapped__.__name__
+             for attr in vars(model) if attr.endswith("_jit")}
+    assert names == {
+        "_prefill_jit": "serving_prefill", "_decode_jit": "serving_decode",
+        "_decode_paged_jit": "serving_decode_paged",
+        "_prefill_chunk_jit": "serving_prefill_chunk",
+        "_spec_score_jit": "serving_spec_score",
+        "_decode_paged_q_jit": "serving_decode_paged_q8",
+        "_prefill_chunk_q_jit": "serving_prefill_chunk_q8",
+        "_spec_score_q_jit": "serving_spec_score_q8"}
+    import jax.numpy as jnp
+    text = model._decode_jit.lower(
+        params, *(jnp.zeros((cfg.n_layers, 4, cfg.n_heads, 8,
+                             cfg.d_model // cfg.n_heads)),) * 2,
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 3), jnp.int32)).as_text()
+    assert "module @jit_serving_decode " in text
