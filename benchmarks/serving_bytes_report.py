@@ -132,6 +132,8 @@ def analyze(eng, model, padded_T, width, true_lens):
         np.asarray(nxt)
         info["decode_ms_per_step"] = round(
             1e3 * (time.perf_counter() - t0) / n, 3)
+        # the step consumed the engine's pools: hand it the last results
+        eng.cache.k, eng.cache.v = k, v
     return info
 
 

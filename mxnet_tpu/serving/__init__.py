@@ -29,7 +29,7 @@ Quickstart::
 from .kv_cache import BlockPool, PagedKVCache, CacheOverflow
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .engine import (Engine, Sequence, TransformerLM, BlockLM, ExportedLM,
-                     pow2_bucket)
+                     PoolsLost, pow2_bucket)
 from .scheduler import (Scheduler, Request, QueueFull, RequestTimeout,
                         DeadlineExceeded, DeadlineUnmeetable,
                         BrownoutShed, make_resume)
@@ -50,7 +50,7 @@ __all__ = [
     "BlockPool", "PagedKVCache", "CacheOverflow",
     "PrefixCache", "prefix_cache_enabled",
     "Engine", "Sequence", "TransformerLM", "BlockLM", "ExportedLM",
-    "pow2_bucket",
+    "PoolsLost", "pow2_bucket",
     "Scheduler", "Request", "QueueFull", "RequestTimeout",
     "DeadlineExceeded", "DeadlineUnmeetable", "BrownoutShed",
     "make_resume", "spawn_resume", "spawn_migrate",

@@ -60,9 +60,9 @@ import jax.numpy as jnp
 from ..base import MXNetError
 from .. import telemetry
 from ..ops.quantization import maybe_quant_matmul as _mm
-from .kv_cache import (PagedKVCache, flat_slots, prompt_slots, write_kv,
-                       gather_kv, copy_block, write_kv_quant,
-                       copy_block_quant, zero_block_scales)
+from .kv_cache import (POOL_ARGS, PagedKVCache, flat_slots, write_kv,
+                       append_kv, write_kv_prompt, gather_kv, copy_block,
+                       write_kv_quant, copy_block_quant, zero_block_scales)
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 
 
@@ -82,11 +82,16 @@ def quantized_weights_env():
     return v or None
 
 
-def _named(name, fn):
-    """`fn` under `name`, so its program is `jit_<name>` in a device trace
-    (a lambda's is `jit__lambda`, whichever step it is)."""
+def _step_jit(name, fn, argnames):
+    """`fn` jitted as the step program `jit_<name>` (a device trace names
+    a program after its function; a lambda's is `jit__lambda`, whichever
+    step it is) that CONSUMES its pools: the arguments named in
+    `POOL_ARGS` are donated, the executable aliases them to the pools it
+    returns and writes the new K/V into those same buffers, so a step
+    holds the pool once and copies none of it."""
     fn.__name__ = name
-    return fn
+    return jax.jit(fn, donate_argnums=tuple(
+        i for i, a in enumerate(argnames) if a in POOL_ARGS))
 
 
 def pow2_bucket(n, lo=1, hi=None):
@@ -164,7 +169,6 @@ def _tf_prefill(params, k_pool, v_pool, tokens, length, table_row, cfg,
     D, H = cfg.d_model, cfg.n_heads
     Dh = D // H
     x = params["embed"][tokens] + params["pos_embed"][:S]          # (S, D)
-    slots = prompt_slots(table_row, S, block_size)                 # (S,)
     for i in range(cfg.n_layers):
         pre = "layer%d_" % i
         h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
@@ -172,7 +176,8 @@ def _tf_prefill(params, k_pool, v_pool, tokens, length, table_row, cfg,
         q, kk, vv = jnp.split(qkv, 3, axis=-1)
         kh = kk.reshape(S, H, Dh)
         vh = vv.reshape(S, H, Dh)
-        k_pool, v_pool = write_kv(k_pool, v_pool, i, slots, kh, vh)
+        k_pool, v_pool = write_kv_prompt(k_pool, v_pool, i, table_row,
+                                         kh, vh)
         att = attention_reference(
             q.reshape(S, H, Dh).transpose(1, 0, 2)[None],
             kh.transpose(1, 0, 2)[None],
@@ -202,7 +207,8 @@ def _tf_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
     scale = 1.0 / math.sqrt(Dh)
     x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
     slots = flat_slots(tables, positions, block_size)              # (B,)
-    T = tables.shape[1] * block_size
+    nblk = tables.shape[1]
+    T = nblk * block_size
     live = jnp.arange(T)[None, :] <= positions[:, None]            # (B, T)
     for i in range(cfg.n_layers):
         pre = "layer%d_" % i
@@ -210,17 +216,20 @@ def _tf_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
         qkv = _mm(h, params[pre + "wqkv"])
         q, kk, vv = jnp.split(qkv, 3, axis=-1)
         qh = q.reshape(B, H, Dh)
-        k_pool, v_pool = write_kv(k_pool, v_pool, i,
-                                  slots, kk.reshape(B, H, Dh),
-                                  vv.reshape(B, H, Dh))
-        ks, vs = gather_kv(k_pool, v_pool, i, tables, block_size)  # (B,T,H,Dh)
+        k_pool, v_pool = append_kv(k_pool, v_pool, i,
+                                   slots, kk.reshape(B, H, Dh),
+                                   vv.reshape(B, H, Dh))
+        ks, vs = gather_kv(k_pool, v_pool, i, tables)      # (B,nblk,H,bs,Dh)
         # same masking/upcast semantics as attention_reference, with the
         # length mask standing in for the causal mask (the query IS the
-        # newest position)
-        s = jnp.einsum("bhd,bthd->bht", qh, ks).astype(jnp.float32) * scale
-        s = jnp.where(live[:, None, :], s, -jnp.inf)
+        # newest position); position t is (block n, offset s) = divmod(t,
+        # block_size), contracted over as the blocks lie in the pool
+        s = jnp.einsum("bhd,bnhsd->bhns", qh, ks).astype(jnp.float32) * scale
+        s = jnp.where(live[:, None, :], s.reshape(B, H, T), -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        att = jnp.einsum("bht,bthd->bhd", p, vs.astype(p.dtype))
+        att = jnp.einsum("bhns,bnhsd->bhd",
+                         p.reshape(B, H, nblk, block_size),
+                         vs.astype(p.dtype))
         x = x + _mm(att.astype(x.dtype).reshape(B, D), params[pre + "wo"])
         h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
         x = x + _ffn(params, pre, h[:, None], cfg)[:, 0]
@@ -266,9 +275,9 @@ def _tf_decode_paged(params, k_pool, v_pool, tokens, positions, tables,
                                   block_size, k_scale=k_scale[i],
                                   v_scale=v_scale[i])[:, 0]
         else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i,
-                                      slots, kk.reshape(B, H, Dh),
-                                      vv.reshape(B, H, Dh))
+            k_pool, v_pool = append_kv(k_pool, v_pool, i,
+                                       slots, kk.reshape(B, H, Dh),
+                                       vv.reshape(B, H, Dh))
             att = paged_attention(q.reshape(B, 1, H, Dh), k_pool[i],
                                   v_pool[i], tables, positions,
                                   block_size)[:, 0]                # (B,H,Dh)
@@ -317,7 +326,7 @@ def _tf_prefill_chunk(params, k_pool, v_pool, toks, qs, length, last_idx,
     qs_row = jnp.reshape(qs, (1,)).astype(jnp.int32)
     # a contiguous C-token chunk touches at most ceil-plus-straddle
     # blocks plus the null block — a tight candidate set keeps the
-    # requantizing writer's gather/scatter small
+    # writer's read and write of whole blocks small
     ncand = (C - 1) // block_size + 2
     for i in range(cfg.n_layers):
         pre = "layer%d_" % i
@@ -336,7 +345,7 @@ def _tf_prefill_chunk(params, k_pool, v_pool, toks, qs, length, last_idx,
         else:
             k_pool, v_pool = write_kv(k_pool, v_pool, i,
                                       slots, kk.reshape(C, H, Dh),
-                                      vv.reshape(C, H, Dh))
+                                      vv.reshape(C, H, Dh), ncand=ncand)
             att = paged_attention(q.reshape(C, H, Dh)[None], k_pool[i],
                                   v_pool[i], tables, qs_row,
                                   block_size)[0]                   # (C,H,Dh)
@@ -410,7 +419,8 @@ def _tf_spec_score(params, k_pool, v_pool, toks, q_starts, counts,
         else:
             k_pool, v_pool = write_kv(k_pool, v_pool, i, flat,
                                       kk.reshape(B * C, H, Dh),
-                                      vv.reshape(B * C, H, Dh))
+                                      vv.reshape(B * C, H, Dh),
+                                      ncand=ncand)
             att = paged_attention(q.reshape(B, C, H, Dh), k_pool[i],
                                   v_pool[i], tables,
                                   q_starts.astype(jnp.int32),
@@ -506,62 +516,70 @@ class TransformerLM:
         # serving.decode SITE and can trace equal signatures — the tag
         # (plus the lowered-text hash in the key) keeps their disk
         # entries apart, so a warm load can never swap implementations
-        self._prefill_jit = instrument(jax.jit(_named(
+        self._prefill_jit = instrument(_step_jit(
             "serving_prefill",
             lambda p, k, v, t, ln, tb: _tf_prefill(p, k, v, t, ln, tb,
-                                                   cfg, block_size))),
+                                                   cfg, block_size),
+            self._PREFILL_ARGS),
             site="serving.prefill", phase="prefill",
             argnames=self._PREFILL_ARGS, variant="prefill_dense")
-        self._decode_jit = instrument(jax.jit(_named(
+        self._decode_jit = instrument(_step_jit(
             "serving_decode",
             lambda p, k, v, t, pos, tb: _tf_decode(p, k, v, t, pos, tb,
-                                                   cfg, block_size))),
+                                                   cfg, block_size),
+            self._DECODE_ARGS),
             site="serving.decode", phase="decode",
             argnames=self._DECODE_ARGS, variant="decode_gather")
-        self._decode_paged_jit = instrument(jax.jit(_named(
+        self._decode_paged_jit = instrument(_step_jit(
             "serving_decode_paged",
             lambda p, k, v, t, pos, tb: _tf_decode_paged(
-                p, k, v, t, pos, tb, cfg, block_size))),
+                p, k, v, t, pos, tb, cfg, block_size),
+            self._DECODE_ARGS),
             site="serving.decode", phase="decode",
             argnames=self._DECODE_ARGS, variant="decode_paged")
-        self._prefill_chunk_jit = instrument(jax.jit(_named(
+        self._prefill_chunk_jit = instrument(_step_jit(
             "serving_prefill_chunk",
             lambda p, k, v, t, qs, ln, li, tb: _tf_prefill_chunk(
-                p, k, v, t, qs, ln, li, tb, cfg, block_size))),
+                p, k, v, t, qs, ln, li, tb, cfg, block_size),
+            self._CHUNK_ARGS),
             site="serving.prefill", phase="prefill",
             argnames=self._CHUNK_ARGS, variant="prefill_chunk")
         # speculative k+1 scoring (one site, AOT-cacheable): the batched
         # chunk signature against the live block tables
-        self._spec_score_jit = instrument(jax.jit(_named(
+        self._spec_score_jit = instrument(_step_jit(
             "serving_spec_score",
             lambda p, k, v, t, qs, cn, tb: _tf_spec_score(
-                p, k, v, t, qs, cn, tb, cfg, block_size))),
+                p, k, v, t, qs, cn, tb, cfg, block_size),
+            self._SPEC_ARGS),
             site="serving.spec_score", phase="decode",
             argnames=self._SPEC_ARGS, variant="spec_score")
         if kv_quant:
             # int8-pool variants (ISSUE 20): distinct AOT variant tags —
             # the quant step traces extra scale operands, and a warm
             # load must never hand the f32 path a quantized executable
-            self._decode_paged_q_jit = instrument(jax.jit(_named(
+            self._decode_paged_q_jit = instrument(_step_jit(
                 "serving_decode_paged_q8",
                 lambda p, k, v, t, pos, tb, ks, vs: _tf_decode_paged(
                     p, k, v, t, pos, tb, cfg, block_size,
-                    k_scale=ks, v_scale=vs))),
+                    k_scale=ks, v_scale=vs),
+                self._DECODE_Q_ARGS),
                 site="serving.decode", phase="decode",
                 argnames=self._DECODE_Q_ARGS, variant="decode_paged_q8")
-            self._prefill_chunk_q_jit = instrument(jax.jit(_named(
+            self._prefill_chunk_q_jit = instrument(_step_jit(
                 "serving_prefill_chunk_q8",
                 lambda p, k, v, t, qs, ln, li, tb, ks, vs:
                     _tf_prefill_chunk(p, k, v, t, qs, ln, li, tb, cfg,
                                       block_size, k_scale=ks,
-                                      v_scale=vs))),
+                                      v_scale=vs),
+                self._CHUNK_Q_ARGS),
                 site="serving.prefill", phase="prefill",
                 argnames=self._CHUNK_Q_ARGS, variant="prefill_chunk_q8")
-            self._spec_score_q_jit = instrument(jax.jit(_named(
+            self._spec_score_q_jit = instrument(_step_jit(
                 "serving_spec_score_q8",
                 lambda p, k, v, t, qs, cn, tb, ks, vs: _tf_spec_score(
                     p, k, v, t, qs, cn, tb, cfg, block_size,
-                    k_scale=ks, v_scale=vs))),
+                    k_scale=ks, v_scale=vs),
+                self._SPEC_Q_ARGS),
                 site="serving.spec_score", phase="decode",
                 argnames=self._SPEC_Q_ARGS, variant="spec_score_q8")
 
@@ -810,6 +828,14 @@ class ExportedLM:
 # the engine
 # ---------------------------------------------------------------------------
 
+class PoolsLost(MXNetError):
+    """A step failed after it had consumed the KV pools. The engine has
+    made empty pools and dropped its prefix cache; every sequence it
+    holds has lost its history on the device and must be replayed
+    (prompt plus the tokens generated so far) — the failed step alone
+    is not enough."""
+
+
 #: every live Engine, weakly held — the serving tests' shared quiescence
 #: fixture audits the pools of engines a test created (leak check: after
 #: a clean close, in-use blocks == prefix-cache residents, nothing else)
@@ -820,7 +846,8 @@ class Engine:
     """Owns the compiled step functions, the cache pool, and the shape
     buckets. Thread-compatible, not thread-safe: all compute entry points
     (`start`, `decode_step`) must be called from one serving thread (the
-    server loop); that keeps the functional cache update race-free.
+    server loop): every step consumes the pool arrays and the cache is
+    rebound from its results (`_step`), which only one thread may do.
 
     Placement flags (`paged`, `tp`, `prefill_chunk`) are read at
     CONSTRUCTION only and frozen afterwards: the compiled step functions,
@@ -1059,6 +1086,7 @@ class Engine:
         # compiling — kept apart from _compile_counts so the
         # recompile-bound tests stay meaningful with the cache on
         self._warm_counts = {"prefill": 0, "decode": 0}
+        self.pools_lost = 0     # times `_donating` had to remake the pools
         self._constructed = True
         _LIVE.add(self)
 
@@ -1176,6 +1204,42 @@ class Engine:
             self._warm_counts[kind] += \
                 telemetry.introspect.dispatch_warm_loads_since(wmark)
 
+    # -- the pools' one way in and out -----------------------------------------
+
+    @contextlib.contextmanager
+    def _donating(self):
+        """Round every call that donates pool arrays. A fault raised
+        before the launch (tracing, a shape error, a chaos seam) leaves
+        the pools whole and passes through as it is. A fault after the
+        launch leaves them deleted, and every later step would raise for
+        ever: make them anew under the same placement, drop every
+        prefix-cache entry (the blocks' contents are gone), and raise
+        `PoolsLost`, which tells the caller to replay every sequence
+        this engine holds, not only the one at hand."""
+        try:
+            yield
+        except Exception as e:
+            if not self.cache.lost():
+                raise
+            self.cache.remake()
+            if self.prefix_cache is not None:
+                self.prefix_cache.clear()
+            self.pools_lost += 1
+            raise PoolsLost(
+                "a step failed after it consumed the KV pools (%s: %s); "
+                "the pools were made anew, empty: replay every running "
+                "and prefilling sequence" % (type(e).__name__, e)) from e
+
+    def _step(self, fn, *args):
+        """Call one step program (or the copy-on-write op): hand it the
+        pools first, take the pools it returns as the cache's, return
+        the rest of its results."""
+        pools = self.cache.arrays()
+        with self._donating():
+            out = fn(*pools, *args)
+        self.cache.rebind(out[:len(pools)])
+        return out[len(pools):]
+
     # -- prefill -------------------------------------------------------------
 
     def begin(self, prompt, max_new, eos_id=None):
@@ -1194,7 +1258,7 @@ class Engine:
             if self.prefix_cache is None:
                 ids = self.cache.pool.try_alloc(n)
                 if ids is not None and self.kv_quant:
-                    self._zero_scales(ids)
+                    self._zero_scales(ids, held=ids)
             else:
                 ids = self._begin_cached(seq, prompt, n)
             if ids is None:
@@ -1203,13 +1267,15 @@ class Engine:
             seq.table_row = self.cache.table_row(ids, self._nblk)
         return seq
 
-    def _zero_scales(self, ids):
+    def _zero_scales(self, ids, held):
         """Reset the int8 pool's scale sidecars for freshly allocated
         (possibly reclaimed) blocks: `write_kv_quant`'s per-block scale
         is a monotonic max, so a previous occupant's scale would pin the
         new tokens' quantization step far too coarse. Padded to pow2
         id-array buckets so the jit lattice stays bounded; the pad
-        entries hit block 0 (the null block, whose scale is always 0)."""
+        entries hit block 0 (the null block, whose scale is always 0).
+        A fault gives `held`, the admission's blocks, back to the pool:
+        no sequence owns them yet."""
         if not ids:
             return
         n = pow2_bucket(len(ids), lo=1, hi=self.cache.num_blocks)
@@ -1218,8 +1284,14 @@ class Engine:
         if self._zero_jit is None:
             self._zero_jit = jax.jit(zero_block_scales,
                                      donate_argnums=(0, 1))
-        self.cache.k_scale, self.cache.v_scale = self._zero_jit(
-            self.cache.k_scale, self.cache.v_scale, jnp.asarray(arr))
+        try:
+            with self._donating():
+                self.cache.k_scale, self.cache.v_scale = self._zero_jit(
+                    self.cache.k_scale, self.cache.v_scale,
+                    jnp.asarray(arr))
+        except Exception:
+            self.cache.pool.free(held)
+            raise
 
     def _begin_cached(self, seq, prompt, n):
         """Prefix-cache admission: point the leading table entries at
@@ -1235,38 +1307,34 @@ class Engine:
                             prompt_len=len(prompt)):
             full, tail = self.prefix_cache.lookup(prompt)
         fresh = pool.try_alloc(n - len(full))
+        held = full + ([tail[0]] if tail else [])
         if fresh is None:
-            held = full + ([tail[0]] if tail else [])
             if held:
                 pool.free(held)
             return None
+        held = held + fresh
         if self.kv_quant:
             # fresh (possibly reclaimed) blocks first — a COW copy below
             # then installs the shared block's scales over fresh[0]
-            self._zero_scales(fresh)
+            self._zero_scales(fresh, held)
         hit = len(full) * self.cache.block_size
         if tail is not None:
             src, m = tail
             if self._cow_jit is None:
-                # donate the pools so XLA updates the one block in
-                # place instead of materializing a full-pool copy per
-                # COW (backends without donation just warn and copy)
+                # like every step it consumes the pools, so XLA updates
+                # the one block in place
                 if self.kv_quant:
                     self._cow_jit = jax.jit(copy_block_quant,
                                             donate_argnums=(0, 1, 2, 3))
                 else:
                     self._cow_jit = jax.jit(copy_block,
                                             donate_argnums=(0, 1))
-            if self.kv_quant:
-                (self.cache.k, self.cache.v, self.cache.k_scale,
-                 self.cache.v_scale) = self._cow_jit(
-                    self.cache.k, self.cache.v, self.cache.k_scale,
-                    self.cache.v_scale, jnp.int32(src),
-                    jnp.int32(fresh[0]))
-            else:
-                self.cache.k, self.cache.v = self._cow_jit(
-                    self.cache.k, self.cache.v, jnp.int32(src),
-                    jnp.int32(fresh[0]))
+            try:
+                self._step(self._cow_jit, jnp.int32(src),
+                           jnp.int32(fresh[0]))
+            except Exception:
+                pool.free(held)       # no sequence owns them yet
+                raise
             pool.free([src])          # drop the transient tail ref: the
                                       # private copy replaces it in the
                                       # table
@@ -1310,22 +1378,10 @@ class Engine:
                     chunk_fn = self.model.prefill_chunk_tp \
                         if self.tp > 1 else self.model.prefill_chunk
                 with self._count("prefill", (C, w)):
-                    if self.kv_quant:
-                        (self.cache.k, self.cache.v, self.cache.k_scale,
-                         self.cache.v_scale, logits) = chunk_fn(
-                            self.cache.k, self.cache.v,
-                            self.cache.k_scale, self.cache.v_scale,
-                            jnp.asarray(toks), jnp.int32(qs),
-                            jnp.int32(L),
-                            jnp.int32(min(L - 1 - qs, C - 1)),
-                            jnp.asarray(seq.table_row[:w]))
-                    else:
-                        self.cache.k, self.cache.v, logits = chunk_fn(
-                            self.cache.k, self.cache.v,
-                            jnp.asarray(toks), jnp.int32(qs),
-                            jnp.int32(L),
-                            jnp.int32(min(L - 1 - qs, C - 1)),
-                            jnp.asarray(seq.table_row[:w]))
+                    logits, = self._step(
+                        chunk_fn, jnp.asarray(toks), jnp.int32(qs),
+                        jnp.int32(L), jnp.int32(min(L - 1 - qs, C - 1)),
+                        jnp.asarray(seq.table_row[:w]))
                 seq.prefilled = min(L, qs + C)
                 if seq.prefilled < L:
                     return False
@@ -1343,10 +1399,9 @@ class Engine:
                 toks = np.zeros((s_pad,), np.int32)
                 toks[:L] = prompt
                 with self._count("prefill", s_pad):
-                    self.cache.k, self.cache.v, logits = \
-                        self.model.prefill(
-                            self.cache.k, self.cache.v, jnp.asarray(toks),
-                            jnp.int32(L), jnp.asarray(seq.table_row))
+                    logits, = self._step(
+                        self.model.prefill, jnp.asarray(toks),
+                        jnp.int32(L), jnp.asarray(seq.table_row))
                 seq.prefilled = L
                 logits = np.asarray(logits)
             else:
@@ -1376,8 +1431,12 @@ class Engine:
         seq = self.begin(prompt, max_new, eos_id=eos_id)
         if seq is None:
             return None
-        while not self.prefill_step(seq):
-            pass
+        try:
+            while not self.prefill_step(seq):
+                pass
+        except Exception:
+            self.release(seq, reusable=False)   # nobody else holds it
+            raise
         return seq
 
     # -- decode --------------------------------------------------------------
@@ -1452,16 +1511,7 @@ class Engine:
                     sig = bb
                 with part("serving.decode.dispatch"), \
                         self._count("decode", sig):
-                    if self.kv_quant:
-                        (self.cache.k, self.cache.v, self.cache.k_scale,
-                         self.cache.v_scale, logits, nxt) = step_fn(
-                            self.cache.k, self.cache.v,
-                            self.cache.k_scale, self.cache.v_scale,
-                            toks, pos, tabs)
-                    else:
-                        self.cache.k, self.cache.v, logits, nxt = \
-                            step_fn(self.cache.k, self.cache.v,
-                                    toks, pos, tabs)
+                    logits, nxt = self._step(step_fn, toks, pos, tabs)
                 with part("serving.decode.readback"):
                     nxt = np.asarray(nxt)
                     logits = np.asarray(logits) if self.keep_logits \
@@ -1584,18 +1634,9 @@ class Engine:
                 score_fn = self.model.spec_score_tp if self.tp > 1 \
                     else self.model.spec_score
             with self._count("decode", ("spec", bb, w)):
-                if self.kv_quant:
-                    (self.cache.k, self.cache.v, self.cache.k_scale,
-                     self.cache.v_scale, logits) = score_fn(
-                        self.cache.k, self.cache.v, self.cache.k_scale,
-                        self.cache.v_scale, jnp.asarray(toks),
-                        jnp.asarray(qs), jnp.asarray(counts),
-                        jnp.asarray(tabs))
-                else:
-                    self.cache.k, self.cache.v, logits = score_fn(
-                        self.cache.k, self.cache.v, jnp.asarray(toks),
-                        jnp.asarray(qs), jnp.asarray(counts),
-                        jnp.asarray(tabs))
+                logits, = self._step(
+                    score_fn, jnp.asarray(toks), jnp.asarray(qs),
+                    jnp.asarray(counts), jnp.asarray(tabs))
             logits = np.asarray(logits)                    # (bb, C, V)
             accepted = proposed = emitted_n = 0
             dur_us = time.perf_counter_ns() // 1000 - t0_us

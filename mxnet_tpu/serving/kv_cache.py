@@ -24,11 +24,21 @@ block_size, head_dim) — so a (block id, head) pair indexes one
 kernel (ops/pallas_paged.py) DMAs per grid step, and its two minor
 dimensions are the whole minor extent of the array, which is what Mosaic
 asks of a block (a head axis between them made the block's second-minor
-extent 1 of H, which it refuses). The per-token scatter and the by-table
-gather both remain single advanced-indexing ops XLA lowers without
-data-dependent shapes (`write_kv` splits a flat slot into (block,
-offset) with one divmod). Only this module and the kernel know the
-order of the axes.
+extent 1 of H, which it refuses). Only this module and the kernel know
+the order of the axes.
+
+Every write UPDATES THE POOL IN PLACE, a whole (n_heads, block_size,
+head_dim) block at a time: read the blocks the new tokens fall in, set
+their rows, write the blocks back (`append_kv`, `write_kv`,
+`write_kv_prompt`). A scatter of single tokens, whose window (n_heads,
+head_dim) is split by the block_size axis, makes XLA's layout assignment
+move the whole pool to a layout with that axis ahead of the heads and
+back: four copies of a 1 GB pool in every step, a quarter of a decode
+step on a v5e (PERF.md, PR 26). Whole blocks are the pool's trailing
+axes as they lie, and with the pools donated (`PagedKVCache`) no step
+program holds an operation of the pool's size. The by-table gather hands
+the blocks out as they lie too, and the attention contracts over them
+(`gather_kv`).
 """
 from __future__ import annotations
 
@@ -38,6 +48,11 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..base import MXNetError
+
+
+#: the names under which every step function takes the pool arrays: the
+#: arguments a step donates (engine `_step_jit`)
+POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale")
 
 
 class CacheOverflow(MXNetError):
@@ -178,8 +193,14 @@ class PagedKVCache:
     Arrays: ``k``/``v`` of shape (n_layers, num_blocks, n_heads,
     block_size, head_dim) — contiguous-per-layer block layout (see module
     docstring). They are plain jax arrays threaded through the jitted
-    engine functions (functional update: each step returns the new
-    pools).
+    engine functions, and every such function CONSUMES the arrays it is
+    given (they are donated: the step writes the new K/V into the same
+    buffers and returns them, and the arrays handed in are deleted). So
+    the pool has one owner, this object, which is rebound from every
+    call's result (`Engine._donating`); no caller may keep ``k``/``v``
+    or the scale sidecars across a step. A step that fails after its
+    launch leaves them deleted: `lost()` says so and `remake()` makes
+    them anew, empty, under the same placement.
 
     With ``kv_dtype="int8"`` the pools store symmetric-per-block int8
     and grow f32 scale SIDECARS ``k_scale``/``v_scale`` of shape
@@ -203,20 +224,49 @@ class PagedKVCache:
             raise MXNetError("kv_dtype %r is not supported (int8 or "
                              "None)" % (kv_dtype,))
         self.kv_dtype = "int8" if kv_dtype is not None else None
-        shape = (n_layers, num_blocks, n_heads, block_size, head_dim)
-        pool_dtype = jnp.int8 if self.kv_dtype else dtype
-        self.k = jnp.zeros(shape, pool_dtype)
-        self.v = jnp.zeros(shape, pool_dtype)
-        if self.kv_dtype:
-            sshape = (n_layers, num_blocks, n_heads)
-            self.k_scale = jnp.zeros(sshape, jnp.float32)
-            self.v_scale = jnp.zeros(sshape, jnp.float32)
-        else:
-            self.k_scale = self.v_scale = None
+        self._dtype = jnp.int8 if self.kv_dtype else dtype
+        self._sharding = self._scale_sharding = None
+        self.remake()
 
     @property
     def quantized(self):
         return self.kv_dtype is not None
+
+    def arrays(self):
+        """The device arrays in the order every step takes and returns
+        them: (k, v), and the scale sidecars of an int8 pool."""
+        if self.quantized:
+            return (self.k, self.v, self.k_scale, self.v_scale)
+        return (self.k, self.v)
+
+    def rebind(self, arrays):
+        """Take a step's results as the pool (see `arrays`)."""
+        if self.quantized:
+            self.k, self.v, self.k_scale, self.v_scale = arrays
+        else:
+            self.k, self.v = arrays
+
+    def lost(self):
+        """Did a step consume the arrays and give nothing back (it
+        failed after its launch)? Block contents are gone then."""
+        return any(a.is_deleted() for a in self.arrays())
+
+    def remake(self):
+        """Empty pools (and sidecars) under the placement `place` gave:
+        at construction, when placed, and after `lost()`. The host
+        free-list is not touched — whoever holds blocks still frees
+        them."""
+        shape = (self.n_layers, self.num_blocks, self.n_heads,
+                 self.block_size, self.head_dim)
+        # let go first: the old and the new pool never lie side by side
+        self.k = self.v = self.k_scale = self.v_scale = None
+        self.k = jnp.zeros(shape, self._dtype, device=self._sharding)
+        self.v = jnp.zeros(shape, self._dtype, device=self._sharding)
+        if self.quantized:
+            self.k_scale = jnp.zeros(shape[:3], jnp.float32,
+                                     device=self._scale_sharding)
+            self.v_scale = jnp.zeros(shape[:3], jnp.float32,
+                                     device=self._scale_sharding)
 
     def place(self, sharding, scale_sharding=None):
         """Lay the device pools out under `sharding` (a NamedSharding, or
@@ -227,12 +277,8 @@ class PagedKVCache:
         pool's scale sidecars shard on the same head axis via
         `scale_sharding` (their (L, NB, H) layout drops the trailing
         token/dim axes), so each chip's scales are chip-local."""
-        import jax
-        self.k = jax.device_put(self.k, sharding)
-        self.v = jax.device_put(self.v, sharding)
-        if self.quantized and scale_sharding is not None:
-            self.k_scale = jax.device_put(self.k_scale, scale_sharding)
-            self.v_scale = jax.device_put(self.v_scale, scale_sharding)
+        self._sharding, self._scale_sharding = sharding, scale_sharding
+        self.remake()
 
     def blocks_for(self, n_tokens):
         """Blocks needed to hold n_tokens KV entries — by construction
@@ -266,25 +312,81 @@ def flat_slots(block_table, positions, block_size):
     return blk * block_size + positions % block_size
 
 
-def prompt_slots(table_row, length_cap, block_size):
-    """Flat slots for prompt positions 0..length_cap-1 of ONE sequence.
-    table_row (nblk,) -> (length_cap,). Positions past the allocated
-    blocks hit null-padded table entries -> the null block."""
-    pos = jnp.arange(length_cap)
-    return table_row[pos // block_size] * block_size + pos % block_size
+def _touched_blocks(slots, block_size, ncand):
+    """Split flat slots (N,) into (block, offset) and name the DISTINCT
+    blocks they fall in: `cand` (ncand,) block ids, sorted, padded with
+    the null block, and `ci` (N,) each token's row in `cand`. `ncand`
+    is the static bound on distinct blocks (default N): N contiguous
+    positions span at most (N-1)//block_size + 2 blocks with the null
+    block, and a caller that knows the span passes it to shrink the
+    read. A padding row of `cand` duplicates the null block, whose
+    contents nothing reads."""
+    n = slots.shape[0]
+    ncand = n if ncand is None else min(ncand, n)
+    tb, off = slots // block_size, slots % block_size
+    cand = jnp.unique(tb, size=ncand, fill_value=0)
+    # every tb[i] is present in cand by the ncand bound
+    ci = jnp.argmax(cand[None, :] == tb[:, None], axis=1)
+    return tb, off, cand, ci
 
 
-def write_kv(k_pool, v_pool, layer, slots, k_new, v_new):
-    """Scatter new K/V entries into one layer's flat slots (block id *
-    block_size + offset). slots (...,) int32; k_new/v_new (..., n_heads,
-    head_dim)."""
+def write_kv(k_pool, v_pool, layer, slots, k_new, v_new, ncand=None):
+    """Write N new K/V entries into one layer's flat slots (block id *
+    block_size + offset), any number of them to a block. slots (N,)
+    int32; k_new/v_new (N, n_heads, head_dim). In place, by whole blocks
+    (module docstring): the `ncand` distinct blocks are read, the rows
+    set, the blocks written back. The rows are set by a scatter over the
+    small gathered array, about 1.6 us a token on a v5e: right for a
+    prefill chunk or a speculative pass, not for a whole prompt
+    (`write_kv_prompt`) nor for the decode step (`append_kv`)."""
+    _, off, cand, ci = _touched_blocks(slots, k_pool.shape[3], ncand)
+
+    def put(pool, new):
+        blocks = pool[layer, cand]                    # (ncand, H, bs, Dh)
+        blocks = blocks.at[ci, :, off].set(new.astype(pool.dtype))
+        return pool.at[layer, cand].set(blocks)
+
+    return put(k_pool, k_new), put(v_pool, v_new)
+
+
+def append_kv(k_pool, v_pool, layer, slots, k_new, v_new):
+    """`write_kv` for the decode step: ONE new token a sequence, so no
+    two real tokens share a block (a shared prefix block is never
+    written; padded rows all hit the null block, whose contents nothing
+    reads) and the distinct blocks need not be looked for. slots (B,);
+    k_new/v_new (B, n_heads, head_dim)."""
     bs = k_pool.shape[3]
     blk, off = slots // bs, slots % bs
-    # advanced indices split by the head slice: the indexed dims lead, so
-    # the update is (..., n_heads, head_dim) like k_new
-    k_pool = k_pool.at[layer, blk, :, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, blk, :, off].set(v_new.astype(v_pool.dtype))
-    return k_pool, v_pool
+    here = (jnp.arange(bs)[None, :] == off[:, None])[:, None, :, None]
+
+    def put(pool, new):
+        blocks = pool[layer, blk]                     # (B, H, bs, Dh)
+        blocks = jnp.where(here, new.astype(pool.dtype)[:, :, None, :],
+                           blocks)
+        return pool.at[layer, blk].set(blocks)
+
+    return put(k_pool, k_new), put(v_pool, v_new)
+
+
+def write_kv_prompt(k_pool, v_pool, layer, table_row, k_new, v_new):
+    """`write_kv` for positions 0..S-1 of ONE sequence: whole blocks,
+    with no read. table_row (nblk,) int32; k_new/v_new (S, n_heads,
+    head_dim). S is padded up to whole blocks with zeros: like the
+    positions past the prompt's true length they fall in slots of this
+    sequence that decode writes before anything reads them, or, past
+    the allocated blocks, in the null block."""
+    bs = k_pool.shape[3]
+    S, H, Dh = k_new.shape
+    nb = -(-S // bs)
+    ids = table_row[:nb]
+
+    def put(pool, new):
+        new = jnp.pad(new.astype(pool.dtype),
+                      ((0, nb * bs - S), (0, 0), (0, 0)))
+        blocks = new.reshape(nb, bs, H, Dh).transpose(0, 2, 1, 3)
+        return pool.at[layer, ids].set(blocks)
+
+    return put(k_pool, k_new), put(v_pool, v_new)
 
 
 def copy_block(k_pool, v_pool, src, dst):
@@ -312,10 +414,8 @@ def write_kv_quant(k_pool, v_pool, k_scale, v_scale, layer, slots,
     are rescaled in place (dequant with s_old, requant with s_new; when
     the scale is unchanged requantization is the exact identity, so a
     block is only re-rounded when a larger row actually arrives). The
-    write unit is the whole block, not the token: an append rewrites
-    block_size slots where the f32 path rewrites one. That amplification
-    is on the (small) write side; the ~2x saving is on the read side the
-    kernel DMAs every step.
+    write unit is the whole block, as in `write_kv`; the ~2x saving is
+    on the read side the kernel DMAs every step.
 
     `ncand` is the static upper bound on DISTINCT blocks the N slots can
     touch (default N): the N contiguous positions of a prefill chunk
@@ -324,17 +424,7 @@ def write_kv_quant(k_pool, v_pool, k_scale, v_scale, layer, slots,
     aimed at the null block (padded rows) land there like the f32 path —
     its contents and scale are garbage that length masking never reads.
     """
-    bs = k_pool.shape[3]
-    n = slots.shape[0]
-    if ncand is None:
-        ncand = n
-    ncand = min(ncand, n)
-    tb, off = slots // bs, slots % bs                       # (N,)
-    cand = jnp.unique(tb, size=ncand, fill_value=0)         # (ncand,)
-    # token i updates candidate row ci: every tb[i] is present in cand
-    # by the ncand bound, and duplicate fill rows compute identical
-    # updates from identical inputs, so the scatter below is consistent
-    ci = jnp.argmax(cand[None, :] == tb[:, None], axis=1)   # (N,)
+    tb, off, cand, ci = _touched_blocks(slots, k_pool.shape[3], ncand)
 
     def upd(pool, scale, new):
         new = new.astype(jnp.float32)
@@ -381,17 +471,13 @@ def zero_block_scales(k_scale, v_scale, ids):
     return k_scale, v_scale
 
 
-def gather_kv(k_pool, v_pool, layer, block_table, block_size):
+def gather_kv(k_pool, v_pool, layer, block_table):
     """Read one layer's K/V for a batch of sequences by block table.
-    block_table (B, nblk) -> k/v (B, nblk*block_size, n_heads, head_dim),
-    position-ordered; entries past each sequence's length are garbage and
-    must be masked by the caller (mask = arange(T) <= position)."""
-    B, nblk = block_table.shape
-
-    def read(pool):
-        blocks = pool[layer][block_table]       # (B, nblk, H, bs, Dh)
-        H, Dh = blocks.shape[2], blocks.shape[4]
-        return blocks.transpose(0, 1, 3, 2, 4).reshape(
-            B, nblk * block_size, H, Dh)
-
-    return read(k_pool), read(v_pool)
+    block_table (B, nblk) -> k/v (B, nblk, n_heads, block_size,
+    head_dim): the blocks AS THEY LIE in the pool, in table order, so
+    position t of a sequence is [t // block_size, :, t % block_size].
+    The caller contracts over them as they are (engine `_tf_decode`): a
+    transpose to (B, T, n_heads, head_dim) would copy everything
+    gathered once more. Entries past each sequence's length are garbage
+    and must be masked by the caller (mask = arange(T) <= position)."""
+    return k_pool[layer][block_table], v_pool[layer][block_table]

@@ -277,6 +277,15 @@ class PrefixCache:
         return sum(1 for e in self._by_hash.values()
                    if self.pool.refcount(e.block_id) == 1)
 
+    def clear(self):
+        """Drop EVERY entry, pinned or not: the pool's contents are gone
+        (`Engine._donating`). A live sequence that shares a block keeps
+        its own ref until its owner releases it; the cache's is given
+        back here, so nothing can hit a block whose K/V no longer is
+        what its hash says."""
+        for e in list(self._by_hash.values()):
+            self._drop(e)
+
     def flush(self):
         """Evict everything no live sequence pins (tests, shutdown)."""
         return self.reclaim(len(self._by_hash))

@@ -25,7 +25,8 @@ import time
 from ..base import MXNetError
 from .. import telemetry
 from ..utils import chaos
-from .engine import Engine, TransformerLM, BlockLM, ExportedLM
+from .engine import (Engine, TransformerLM, BlockLM, ExportedLM,
+                     PoolsLost)
 from .scheduler import (Scheduler, Request, QueueFull, BrownoutShed,
                         DeadlineExceeded, DeadlineUnmeetable, make_resume)
 from .metrics import ServingMetrics
@@ -745,9 +746,10 @@ class LMServer(_HTTPFrontend):
                             if not s.done]
                 advanced = eng.decode_step(sched.running)
             except Exception as e:
-                # a decode fault poisons the STEP, not the history:
-                # every token already appended came from a step that
-                # completed. Re-home the batch onto this server's own
+                # a decode fault poisons the STEP, not the history
+                # (unless the step had consumed the KV pools: PoolsLost,
+                # below): every token already appended came from a step
+                # that completed. Re-home the batch onto this server's own
                 # queue as failover replays (prompt + generated so
                 # far re-prefills, decode continues token-identically)
                 # instead of failing user-visible work; a request
@@ -756,6 +758,9 @@ class LMServer(_HTTPFrontend):
                 met.engine_failure()
                 err = MXNetError("engine decode failed: %s: %s"
                                  % (type(e).__name__, e))
+                if isinstance(e, PoolsLost):
+                    self._replay_all(err)     # the prefilling ones too
+                    return
                 self._resume_locally(sched.running, err)
                 sched.running = []
                 return
@@ -816,9 +821,13 @@ class LMServer(_HTTPFrontend):
                     telemetry.set_trace(prev)
             except Exception as e:  # engine fault: fail THIS request,
                 met.engine_failure()  # the loop (and the rest of the
-                req._finish(error=MXNetError(  # batch) live on
+                err = MXNetError(     # batch) live on
                     "engine prefill failed: %s: %s"
-                    % (type(e).__name__, e)))
+                    % (type(e).__name__, e))
+                if isinstance(e, PoolsLost):
+                    self._replay_all(err, req)
+                    continue
+                req._finish(error=err)
                 met.request_finished(req)
                 continue
             if seq is None:       # transient block shortage: requeue
@@ -851,9 +860,12 @@ class LMServer(_HTTPFrontend):
                                 eos_id=req.eos_id)
             except Exception as e:
                 met.engine_failure()
-                req._finish(error=MXNetError(
-                    "engine prefill failed: %s: %s"
-                    % (type(e).__name__, e)))
+                err = MXNetError("engine prefill failed: %s: %s"
+                                 % (type(e).__name__, e))
+                if isinstance(e, PoolsLost):    # the copy-on-write or
+                    self._replay_all(err, req)  # the scale reset
+                    continue
+                req._finish(error=err)
                 met.request_finished(req)
                 continue
             if seq is None:       # transient block shortage: requeue
@@ -909,6 +921,11 @@ class LMServer(_HTTPFrontend):
                 done = eng.prefill_step(seq)
             except Exception as e:  # chunk fault: fail THIS request,
                 met.engine_failure()  # free its blocks, keep serving
+                if isinstance(e, PoolsLost):
+                    self._replay_all(MXNetError(
+                        "engine prefill failed: %s: %s"
+                        % (type(e).__name__, e)))
+                    return
                 sched.prefilling.remove(seq)
                 try:
                     eng.release(seq, reusable=False)
@@ -1010,22 +1027,43 @@ class LMServer(_HTTPFrontend):
                 self.engine.release(seq, reusable=False)
             except Exception:
                 pass
-            if req is None or req._event.is_set():
-                continue
-            if req.failovers >= self.max_failovers:
-                req._finish(error=err)
-                self.metrics.request_finished(req)
-                continue
-            try:
-                resume, carried = spawn_resume(req, tokens, self)
-            except QueueFull:
-                req._finish(error=err)
-                self.metrics.request_finished(req)
-                continue
-            if resume is None:      # generation was already complete
-                self.metrics.request_finished(req)
-            else:
-                self.metrics.request_failover(req, carried)
+            if req is not None:
+                self._replay(req, tokens, err)
+
+    def _replay(self, req, tokens, err):
+        """Re-queue one request here as a failover replay of `tokens`,
+        or surface `err` when its failover budget is spent or the queue
+        is full."""
+        if req._event.is_set():
+            return
+        if req.failovers >= self.max_failovers:
+            req._finish(error=err)
+            self.metrics.request_finished(req)
+            return
+        try:
+            resume, carried = spawn_resume(req, tokens, self)
+        except QueueFull:
+            req._finish(error=err)
+            self.metrics.request_finished(req)
+            return
+        if resume is None:      # generation was already complete
+            self.metrics.request_finished(req)
+        else:
+            self.metrics.request_failover(req, carried)
+
+    def _replay_all(self, err, req=None):
+        """The engine lost its KV pools (`PoolsLost`: a step failed after
+        it had consumed them, and the engine made them anew, empty). No
+        sequence's history is on the device any more, so a fault that
+        would have cost one step or one request costs every sequence
+        its cache: replay everything running and prefilling, and `req`,
+        the request being admitted, which has no sequence yet."""
+        sched = self.scheduler
+        seqs = sched.running + sched.prefilling
+        sched.running, sched.prefilling = [], []
+        self._resume_locally(seqs, err)
+        if req is not None:
+            self._replay(req, list(req.prompt), err)
 
     # -- chaos seams ---------------------------------------------------------
 

@@ -234,7 +234,7 @@ def _decode_body(params, k_pool, v_pool, tokens, positions, tables, cfg,
     slice — scales are per-head, so head-sharding them is exact."""
     from ..models.transformer import _layer_norm
     from ..ops.pallas_paged import paged_attention
-    from .kv_cache import flat_slots, write_kv, write_kv_quant
+    from .kv_cache import flat_slots, append_kv, write_kv_quant
 
     quant = k_scale is not None
     B = tokens.shape[0]
@@ -254,7 +254,7 @@ def _decode_body(params, k_pool, v_pool, tokens, positions, tables, cfg,
                                   k_scale=k_scale[i],
                                   v_scale=v_scale[i])[:, 0]
         else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i, slots, kk, vv)
+            k_pool, v_pool = append_kv(k_pool, v_pool, i, slots, kk, vv)
             att = paged_attention(q[:, None], k_pool[i], v_pool[i],
                                   tables, positions,
                                   block_size)[:, 0]          # (B,Hl,Dh)
@@ -308,7 +308,8 @@ def _prefill_chunk_body(params, k_pool, v_pool, toks, qs, length,
                                   k_scale=k_scale[i],
                                   v_scale=v_scale[i])[0]
         else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i, slots, kk, vv)
+            k_pool, v_pool = write_kv(k_pool, v_pool, i, slots, kk, vv,
+                                      ncand=ncand)
             att = paged_attention(q[None], k_pool[i], v_pool[i], tables,
                                   qs_row, block_size)[0]      # (C,Hl,Dh)
         x = x + allreduce(_mm(att.reshape(C, -1), params[pre + "wo"]),
@@ -369,7 +370,8 @@ def _spec_score_body(params, k_pool, v_pool, toks, q_starts, counts,
                                   block_size, k_scale=k_scale[i],
                                   v_scale=v_scale[i])
         else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i, flat, kk, vv)
+            k_pool, v_pool = write_kv(k_pool, v_pool, i, flat, kk, vv,
+                                      ncand=ncand)
             att = paged_attention(q.reshape(B, C, -1, Dh), k_pool[i],
                                   v_pool[i], tables,
                                   q_starts.astype(jnp.int32),
@@ -388,6 +390,18 @@ def _spec_score_body(params, k_pool, v_pool, toks, q_starts, counts,
     return k_pool, v_pool, logits
 
 
+def _tp_step(fn, mesh, in_specs, out_specs):
+    """jit(shard_map(fn)) that CONSUMES its pools, like the single-device
+    steps (engine `_step_jit`): every argument laid out as a pool or a
+    scale sidecar is donated, so each chip updates its shard in place."""
+    pools = (kv_pool_spec(), kv_scale_spec())
+    return jax.jit(
+        jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        donate_argnums=tuple(i for i, spec in enumerate(in_specs)
+                             if spec in pools))
+
+
 def build_tp_decode(cfg, block_size, mesh, kv_quant=False,
                     weight_quant=False):
     """jit(shard_map(decode)) over the tp mesh. Signature matches the
@@ -403,22 +417,20 @@ def build_tp_decode(cfg, block_size, mesh, kv_quant=False,
             return _decode_body(params, k, v, toks, pos, tabs, cfg,
                                 block_size, k_scale=ks, v_scale=vs)
 
-        return jax.jit(jax.shard_map(
-            serving_decode_tp_q8, mesh=mesh,
+        return _tp_step(
+            serving_decode_tp_q8, mesh,
             in_specs=(specs, pool, pool, P(None), P(None),
                       P(None, None), sc, sc),
-            out_specs=(pool, pool, sc, sc, P(None, None), P(None)),
-            check_vma=False))
+            out_specs=(pool, pool, sc, sc, P(None, None), P(None)))
 
     def serving_decode_tp(params, k, v, toks, pos, tabs):
         return _decode_body(params, k, v, toks, pos, tabs, cfg,
                             block_size)
 
-    return jax.jit(jax.shard_map(
-        serving_decode_tp, mesh=mesh,
+    return _tp_step(
+        serving_decode_tp, mesh,
         in_specs=(specs, pool, pool, P(None), P(None), P(None, None)),
-        out_specs=(pool, pool, P(None, None), P(None)),
-        check_vma=False))
+        out_specs=(pool, pool, P(None, None), P(None)))
 
 
 def build_tp_prefill_chunk(cfg, block_size, mesh, kv_quant=False,
@@ -438,23 +450,21 @@ def build_tp_prefill_chunk(cfg, block_size, mesh, kv_quant=False,
                                        block_size, k_scale=ks,
                                        v_scale=vs)
 
-        return jax.jit(jax.shard_map(
-            serving_prefill_chunk_tp_q8, mesh=mesh,
+        return _tp_step(
+            serving_prefill_chunk_tp_q8, mesh,
             in_specs=(specs, pool, pool, P(None), P(), P(), P(),
                       P(None), sc, sc),
-            out_specs=(pool, pool, sc, sc, P(None)),
-            check_vma=False))
+            out_specs=(pool, pool, sc, sc, P(None)))
 
     def serving_prefill_chunk_tp(params, k, v, toks, qs, length, last_idx,
                                  table_row):
         return _prefill_chunk_body(params, k, v, toks, qs, length,
                                    last_idx, table_row, cfg, block_size)
 
-    return jax.jit(jax.shard_map(
-        serving_prefill_chunk_tp, mesh=mesh,
+    return _tp_step(
+        serving_prefill_chunk_tp, mesh,
         in_specs=(specs, pool, pool, P(None), P(), P(), P(), P(None)),
-        out_specs=(pool, pool, P(None)),
-        check_vma=False))
+        out_specs=(pool, pool, P(None)))
 
 
 def build_tp_spec_score(cfg, block_size, mesh, kv_quant=False,
@@ -473,20 +483,18 @@ def build_tp_spec_score(cfg, block_size, mesh, kv_quant=False,
                                     tabs, cfg, block_size, k_scale=ks,
                                     v_scale=vs)
 
-        return jax.jit(jax.shard_map(
-            serving_spec_score_tp_q8, mesh=mesh,
+        return _tp_step(
+            serving_spec_score_tp_q8, mesh,
             in_specs=(specs, pool, pool, P(None, None), P(None),
                       P(None), P(None, None), sc, sc),
-            out_specs=(pool, pool, sc, sc, P(None, None, None)),
-            check_vma=False))
+            out_specs=(pool, pool, sc, sc, P(None, None, None)))
 
     def serving_spec_score_tp(params, k, v, toks, qs, counts, tabs):
         return _spec_score_body(params, k, v, toks, qs, counts, tabs,
                                 cfg, block_size)
 
-    return jax.jit(jax.shard_map(
-        serving_spec_score_tp, mesh=mesh,
+    return _tp_step(
+        serving_spec_score_tp, mesh,
         in_specs=(specs, pool, pool, P(None, None), P(None), P(None),
                   P(None, None)),
-        out_specs=(pool, pool, P(None, None, None)),
-        check_vma=False))
+        out_specs=(pool, pool, P(None, None, None)))

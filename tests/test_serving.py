@@ -519,9 +519,14 @@ def test_block_pool_high_water_and_layout():
     slots = jnp.asarray(table)[pos // 4] * 4 + pos % 4
     kv = jnp.arange(6 * 2 * 4, dtype=jnp.float32).reshape(6, 2, 4)
     k, v = kv_cache.write_kv(cache.k, cache.v, 1, slots, kv, 2 * kv)
-    ks, vs = kv_cache.gather_kv(k, v, 1, jnp.asarray(table[None]), 4)
-    np.testing.assert_array_equal(np.asarray(ks[0, :6]), np.asarray(kv))
-    np.testing.assert_array_equal(np.asarray(vs[0, :6]), 2 * np.asarray(kv))
+    ks, vs = kv_cache.gather_kv(k, v, 1, jnp.asarray(table[None]))
+    assert ks.shape == (1, 2, 2, 4, 4)          # the blocks as they lie
+
+    def by_position(blocks):                    # (nblk,H,bs,Dh) -> (T,H,Dh)
+        return np.asarray(blocks).transpose(0, 2, 1, 3).reshape(8, 2, 4)
+
+    np.testing.assert_array_equal(by_position(ks[0])[:6], np.asarray(kv))
+    np.testing.assert_array_equal(by_position(vs[0])[:6], 2 * np.asarray(kv))
     # layer 0 untouched
     assert float(jnp.abs(k[0]).sum()) == 0.0
 
