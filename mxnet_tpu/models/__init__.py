@@ -16,3 +16,7 @@ from .transformer import (TransformerConfig, init_transformer_params,
                           transformer_apply, transformer_shardings,
                           make_train_step as make_transformer_train_step,
                           lm_loss)
+
+# latent attention + dropless sparse experts (the second served LM family)
+from .latent_moe import (LatentMoEConfig, init_latent_moe_params,
+                         latent_moe_apply)

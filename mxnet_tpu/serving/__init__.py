@@ -30,6 +30,7 @@ from .kv_cache import BlockPool, PagedKVCache, CacheOverflow
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .engine import (Engine, Sequence, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost, pow2_bucket)
+from .latent_lm import LatentMoELM
 from .scheduler import (Scheduler, Request, QueueFull, RequestTimeout,
                         DeadlineExceeded, DeadlineUnmeetable,
                         BrownoutShed, make_resume)
@@ -49,7 +50,8 @@ from .spec import (DraftLM, self_draft, spec_decode_enabled, spec_k,
 __all__ = [
     "BlockPool", "PagedKVCache", "CacheOverflow",
     "PrefixCache", "prefix_cache_enabled",
-    "Engine", "Sequence", "TransformerLM", "BlockLM", "ExportedLM",
+    "Engine", "Sequence", "TransformerLM", "LatentMoELM", "BlockLM",
+    "ExportedLM",
     "PoolsLost", "pow2_bucket",
     "Scheduler", "Request", "QueueFull", "RequestTimeout",
     "DeadlineExceeded", "DeadlineUnmeetable", "BrownoutShed",

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 from ..base import MXNetError
 from .. import telemetry
 from ..ops.quantization import maybe_quant_matmul as _mm
-from .kv_cache import (POOL_ARGS, PagedKVCache, flat_slots, write_kv,
+from .kv_cache import (POOL_ARGS, CacheSpec, PagedKVCache, flat_slots, write_kv,
                        append_kv, write_kv_prompt, gather_kv, copy_block,
                        write_kv_quant, copy_block_quant, zero_block_scales)
 from .prefix_cache import PrefixCache, prefix_cache_enabled
@@ -447,7 +447,10 @@ class TransformerLM:
                 "serving: top-k MoE routing is capacity-dependent across "
                 "the token group, so padded decode batches would change "
                 "real tokens' routing; serve dense-FFN or dense-dispatch "
-                "MoE configs (moe_top_k=0)")
+                "MoE configs (moe_top_k=0). Sparse experts are served by "
+                "the dropless family (models/latent_moe.py, LatentMoELM): "
+                "its routing has no capacity, so a token's experts depend "
+                "on that token alone")
         self.params = params
         self.cfg = cfg
         self.vocab = cfg.vocab
@@ -470,9 +473,9 @@ class TransformerLM:
         self.params = jax.device_put(self.params, device)
 
     def cache_spec(self):
-        dt = self.params["embed"].dtype
-        return (self.cfg.n_layers, self.cfg.n_heads,
-                self.cfg.d_model // self.cfg.n_heads, dt)
+        return CacheSpec(self.cfg.n_layers, self.params["embed"].dtype,
+                         n_heads=self.cfg.n_heads,
+                         head_dim=self.cfg.d_model // self.cfg.n_heads)
 
     def quantize_weights(self, mode="int8"):
         """Quantize the matmul weights ONCE at load (ISSUE 20):
@@ -953,11 +956,17 @@ class Engine:
             self.paged_fallback = ("model family has no cache hooks "
                                    "(there is no block pool to walk)")
         if model.uses_cache:
-            nl, nh, dh, dt = model.cache_spec()
+            cspec = model.cache_spec()
+            dh, dt = cspec.head_dim, cspec.dtype
             self._nblk = max(1, math.ceil(self.max_len / block_size))
             if num_blocks is None:
                 num_blocks = max_batch * self._nblk + 1
-            if self.paged_requested:
+            if self.paged_requested and cspec.layout != "kv":
+                self.paged_fallback = (
+                    "the pool holds %s rows, not keys and values: the "
+                    "paged kernel and the chunked prefill read the K and "
+                    "V planes" % cspec.layout)
+            elif self.paged_requested:
                 self.prefill_chunk = min(self.max_len,
                                          int(prefill_chunk
                                              or 2 * block_size))
@@ -975,9 +984,8 @@ class Engine:
                     self.kv_quant_fallback = paged_fallback_reason(
                         dh, block_size, default_interpret(), jnp.int8)
                     self.kv_quant = self.kv_quant_fallback is None
-            self.cache = PagedKVCache(
-                nl, nh, dh, block_size=block_size,
-                num_blocks=num_blocks, dtype=dt,
+            self.cache = PagedKVCache.of(
+                cspec, block_size=block_size, num_blocks=num_blocks,
                 kv_dtype="int8" if self.kv_quant else None)
             if self.kv_quant:
                 model.bind(block_size, kv_quant=True)
@@ -1133,23 +1141,24 @@ class Engine:
 
     def kv_bytes_per_token(self):
         """Bytes of KV-cache one token occupies on this engine (both the
-        K and the V plane, every layer): the unit the migration ledger
+        K and the V plane, or the one latent row, every layer): the unit
+        the migration ledger
         prices a prefix-cache hit in — a migration hop whose target
         already holds a block skips re-prefilling block_size tokens,
         i.e. this many bytes per token of KV it did not have to
         rebuild. 0 when the model family keeps no cache."""
         if self.cache is None:
             return 0
-        nl, nh, dh, dt = self.model.cache_spec()
+        spec = self.cache.spec
         if self.cache.quantized:
             # int8 payload plus the f32 per-block-per-head scale
             # sidecars amortized over the block's tokens — the ledger
             # must price the QUANTIZED layout or disagg bytes-saved
             # overstates a migration hop's savings ~4x
-            scale_bytes = math.ceil(2 * nl * nh * 4
+            scale_bytes = math.ceil(2 * spec.n_layers * spec.n_heads * 4
                                     / float(self.cache.block_size))
-            return 2 * nl * nh * dh * 1 + scale_bytes
-        return 2 * nl * nh * dh * np.dtype(dt).itemsize
+            return spec.values_per_token() + scale_bytes
+        return spec.values_per_token() * np.dtype(spec.dtype).itemsize
 
     @property
     def prefill_compilations(self):
@@ -1229,6 +1238,17 @@ class Engine:
                 "a step failed after it consumed the KV pools (%s: %s); "
                 "the pools were made anew, empty: replay every running "
                 "and prefilling sequence" % (type(e).__name__, e)) from e
+
+    def _read_back(self, step_span, result, stats):
+        """A step's result on the host. What a family's step returns
+        beside its results (`stats`: the dropless family's rows per held
+        expert) comes over in the same transfer and goes to the family's
+        `note_step`, whose answer the step's span carries."""
+        if not stats:
+            return np.asarray(result)
+        result, *stats = jax.device_get([result, *stats])
+        step_span.attrs.update(self.model.note_step(*stats))
+        return result
 
     def _step(self, fn, *args):
         """Call one step program (or the copy-on-write op): hand it the
@@ -1363,7 +1383,7 @@ class Engine:
         rid = seq.request.trace if seq.request is not None else None
         with telemetry.span("serving.prefill", trace=rid,
                             category="serving", prompt_len=L,
-                            chunk_start=seq.prefilled):
+                            chunk_start=seq.prefilled) as step_span:
             if self.model.uses_cache and self.paged:
                 C = self.prefill_chunk
                 qs = seq.prefilled
@@ -1399,11 +1419,11 @@ class Engine:
                 toks = np.zeros((s_pad,), np.int32)
                 toks[:L] = prompt
                 with self._count("prefill", s_pad):
-                    logits, = self._step(
+                    logits, *stats = self._step(
                         self.model.prefill, jnp.asarray(toks),
                         jnp.int32(L), jnp.asarray(seq.table_row))
                 seq.prefilled = L
-                logits = np.asarray(logits)
+                logits = self._read_back(step_span, logits, stats)
             else:
                 s_pad = pow2_bucket(L, lo=1, hi=self.max_len)
                 toks = np.zeros((1, s_pad), np.int32)
@@ -1511,9 +1531,10 @@ class Engine:
                     sig = bb
                 with part("serving.decode.dispatch"), \
                         self._count("decode", sig):
-                    logits, nxt = self._step(step_fn, toks, pos, tabs)
+                    logits, nxt, *stats = self._step(step_fn, toks, pos,
+                                                     tabs)
                 with part("serving.decode.readback"):
-                    nxt = np.asarray(nxt)
+                    nxt = self._read_back(step_span, nxt, stats)
                     logits = np.asarray(logits) if self.keep_logits \
                         else None
             else:

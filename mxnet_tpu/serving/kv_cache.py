@@ -42,6 +42,7 @@ the blocks out as they lie too, and the attention contracts over them
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import numpy as np
@@ -51,8 +52,45 @@ from ..base import MXNetError
 
 
 #: the names under which every step function takes the pool arrays: the
-#: arguments a step donates (engine `_step_jit`)
-POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale")
+#: arguments a step donates (engine `_step_jit`); `kv_pool` is the one
+#: array of the latent layout
+POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale", "kv_pool")
+#: lanes of a TPU tile: the latent pool's rows are whole tiles wide
+LANES = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a model family keeps per token, as its adapter's
+    `cache_spec()` answers it. Two layouts: `kv`, full keys and values,
+    two arrays (n_layers, num_blocks, n_heads, block_size, head_dim);
+    and `latent` (`latent_dim` > 0), ONE array (n_layers, num_blocks,
+    block_size, row_width) holding a compressed row a token from which
+    attention rebuilds, or never builds, keys and values (multi-head
+    latent attention: no head axis and no second plane). `row_width` is
+    `latent_dim` rounded up to whole 128-lane tiles: a TPU lays a 576-wide
+    bf16 row out as 640 in any case, and where the logical width is not
+    whole tiles its default layout for the array avoids the padding by
+    moving the BLOCK axis innermost, which every step then pays for with
+    two copies of the whole pool (PERF.md, PR 27)."""
+    n_layers: int
+    dtype: object
+    n_heads: int = 0
+    head_dim: int = 0
+    latent_dim: int = 0
+
+    @property
+    def layout(self):
+        return "latent" if self.latent_dim else "kv"
+
+    @property
+    def row_width(self):
+        return -(-self.latent_dim // LANES) * LANES
+
+    def values_per_token(self):
+        """Cached values one token occupies over all layers."""
+        return self.n_layers * (self.row_width
+                                or 2 * self.n_heads * self.head_dim)
 
 
 class CacheOverflow(MXNetError):
@@ -210,13 +248,22 @@ class PagedKVCache:
     head and dequantizes in VMEM. `blocks_for`, tables, and the host
     free-list are precision-agnostic — a block id means the same thing
     in both layouts.
+
+    The LATENT layout (`PagedKVCache.of(spec, ...)` with a latent
+    `CacheSpec`) has one array, ``kv``, of shape (n_layers, num_blocks,
+    block_size, row_width) in the served dtype: `arrays()` has length
+    one, and ``k``/``v`` do not exist. Blocks, tables and the free-list
+    are the same.
     """
 
     def __init__(self, n_layers, n_heads, head_dim, block_size=16,
-                 num_blocks=64, dtype=jnp.float32, kv_dtype=None):
+                 num_blocks=64, dtype=jnp.float32, kv_dtype=None,
+                 latent_dim=0):
         self.n_layers = n_layers
         self.n_heads = n_heads
         self.head_dim = head_dim
+        self.latent_dim = latent_dim
+        self.spec = CacheSpec(n_layers, dtype, n_heads, head_dim, latent_dim)
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.pool = BlockPool(num_blocks)
@@ -225,26 +272,50 @@ class PagedKVCache:
                              "None)" % (kv_dtype,))
         self.kv_dtype = "int8" if kv_dtype is not None else None
         self._dtype = jnp.int8 if self.kv_dtype else dtype
+        if latent_dim and self.kv_dtype:
+            raise MXNetError("the latent layout has no int8 pool")
         self._sharding = self._scale_sharding = None
         self.remake()
+
+    @classmethod
+    def of(cls, spec, block_size=16, num_blocks=64, kv_dtype=None):
+        """The pool a `CacheSpec` describes."""
+        return cls(spec.n_layers, spec.n_heads, spec.head_dim,
+                   block_size=block_size, num_blocks=num_blocks,
+                   dtype=spec.dtype, kv_dtype=kv_dtype,
+                   latent_dim=spec.latent_dim)
 
     @property
     def quantized(self):
         return self.kv_dtype is not None
 
+    @property
+    def layout(self):
+        return self.spec.layout
+
     def arrays(self):
         """The device arrays in the order every step takes and returns
-        them: (k, v), and the scale sidecars of an int8 pool."""
+        them: (k, v), and the scale sidecars of an int8 pool; (kv,) in
+        the latent layout."""
+        if self.latent_dim:
+            return (self.kv,)
         if self.quantized:
             return (self.k, self.v, self.k_scale, self.v_scale)
         return (self.k, self.v)
 
     def rebind(self, arrays):
         """Take a step's results as the pool (see `arrays`)."""
-        if self.quantized:
+        if self.latent_dim:
+            self.kv, = arrays
+        elif self.quantized:
             self.k, self.v, self.k_scale, self.v_scale = arrays
         else:
             self.k, self.v = arrays
+
+    def drop(self):
+        """Let the device arrays go (a server being torn down hands its
+        pool's memory back before its successor's is made)."""
+        self.k = self.v = self.k_scale = self.v_scale = self.kv = None
 
     def lost(self):
         """Did a step consume the arrays and give nothing back (it
@@ -256,10 +327,15 @@ class PagedKVCache:
         at construction, when placed, and after `lost()`. The host
         free-list is not touched — whoever holds blocks still frees
         them."""
+        # let go first: the old and the new pool never lie side by side
+        self.drop()
+        if self.latent_dim:
+            self.kv = jnp.zeros((self.n_layers, self.num_blocks,
+                                 self.block_size, self.spec.row_width),
+                                self._dtype, device=self._sharding)
+            return
         shape = (self.n_layers, self.num_blocks, self.n_heads,
                  self.block_size, self.head_dim)
-        # let go first: the old and the new pool never lie side by side
-        self.k = self.v = self.k_scale = self.v_scale = None
         self.k = jnp.zeros(shape, self._dtype, device=self._sharding)
         self.v = jnp.zeros(shape, self._dtype, device=self._sharding)
         if self.quantized:
@@ -481,3 +557,47 @@ def gather_kv(k_pool, v_pool, layer, block_table):
     gathered once more. Entries past each sequence's length are garbage
     and must be masked by the caller (mask = arange(T) <= position)."""
     return k_pool[layer][block_table], v_pool[layer][block_table]
+
+
+# ---------------------------------------------------------------------------
+# the latent layout: one array (n_layers, num_blocks, block_size, width)
+# ---------------------------------------------------------------------------
+
+
+def _rows(pool, new):
+    """`new` (..., latent_dim) as rows of the pool: its dtype, zeros up to
+    its width."""
+    pad = [(0, 0)] * (new.ndim - 1) + [(0, pool.shape[-1] - new.shape[-1])]
+    return jnp.pad(new.astype(pool.dtype), pad)
+
+
+def append_latent(pool, layer, slots, new):
+    """`append_kv` for the latent pool: ONE new row a sequence. slots
+    (B,); new (B, latent_dim). The block each row falls in is read, the row
+    set, the block written back: whole trailing axes as they lie, so
+    the donated pool is updated in place."""
+    bs = pool.shape[2]
+    blk, off = slots // bs, slots % bs
+    here = (jnp.arange(bs)[None, :] == off[:, None])[:, :, None]
+    blocks = jnp.where(here, _rows(pool, new)[:, None, :],
+                       pool[layer, blk])                     # (B, bs, W)
+    return pool.at[layer, blk].set(blocks)
+
+
+def write_latent_prompt(pool, layer, table_row, new):
+    """`write_kv_prompt` for the latent pool: positions 0..S-1 of ONE
+    sequence, whole blocks, no read. table_row (nblk,); new (S,
+    latent_dim), padded up to whole blocks with zeros."""
+    bs, W = pool.shape[2:]
+    S = new.shape[0]
+    nb = -(-S // bs)
+    new = jnp.pad(_rows(pool, new), ((0, nb * bs - S), (0, 0)))
+    return pool.at[layer, table_row[:nb]].set(new.reshape(nb, bs, W))
+
+
+def gather_latent(pool, layer, block_table):
+    """One layer's cached rows for a batch of sequences by block table:
+    (B, nblk) -> (B, nblk, block_size, row_width), the blocks as they
+    lie; entries past a sequence's length are garbage the caller masks,
+    lanes past `latent_dim` are zero."""
+    return pool[layer][block_table]

@@ -24,6 +24,8 @@ from __future__ import annotations
 import threading
 import time
 
+import numpy as np
+
 from .. import profiler
 from .. import telemetry
 from ..telemetry import slo as _slo
@@ -761,6 +763,20 @@ class ServingMetrics:
             self._g_prefix_resident.set(pc.resident_tokens)
             self._g_prefix_blocks.set(len(pc))
             self._g_prefix_hit_rate.set(pc.hit_rate)
+        # the dropless family's rows per held expert: counters declared
+        # only when such an engine is observed, brought up to the
+        # adapter's own tally by delta (a step adds to one array, not to
+        # a counter per expert)
+        rows = getattr(getattr(engine, "model", None), "expert_rows", None)
+        if rows is not None:
+            for (layer, expert), total in np.ndenumerate(rows):
+                ctr = self.registry.counter(
+                    "serving.moe.expert_tokens.layer%s.expert%s"
+                    % (layer, expert),
+                    help="rows of real tokens sent to this held expert "
+                         "of this expert layer, prefill and decode")
+                if total > ctr.value:
+                    ctr.inc(int(total - ctr.value))
         # quantized-serving observables (ISSUE 20): declared only when a
         # quant-enabled engine is observed, so the flags-off exposition
         # stays byte-for-byte identical to the unquantized stack

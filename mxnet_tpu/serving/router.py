@@ -97,6 +97,7 @@ import time
 from ..base import MXNetError
 from .. import telemetry
 from .engine import TransformerLM
+from .latent_lm import LatentMoELM
 from .scheduler import QueueFull
 from .server import LMServer, _HTTPFrontend
 
@@ -372,7 +373,7 @@ class ReplicatedLMServer(_HTTPFrontend):
         tp = int(kw.pop("tp", self._tp))
         devs = replica_devices(i, tp)
         model = self._models.get(version, self._model)
-        if isinstance(model, TransformerLM):
+        if isinstance(model, (TransformerLM, LatentMoELM)):
             # replicas place their parameters on their own chips, so each
             # needs its own adapter over the shared arrays
             model = (model.params, model.cfg)
@@ -563,7 +564,7 @@ class ReplicatedLMServer(_HTTPFrontend):
             # the pool's host-side bookkeeping — drop the device K/V
             # buffers (the dominant allocation) so retired engines
             # never pin HBM the replacement pools need
-            old.engine.cache.k = old.engine.cache.v = None
+            old.engine.cache.drop()
         self._c_respawn.inc(replica=i)
         telemetry.record_span(
             "serving.respawn", time.perf_counter_ns() // 1000, 0,
@@ -1006,7 +1007,7 @@ class ReplicatedLMServer(_HTTPFrontend):
             return False
         if old.engine.cache is not None:
             # keep the corpse for the leak audit, drop its device K/V
-            old.engine.cache.k = old.engine.cache.v = None
+            old.engine.cache.drop()
         self._retired_engines.append(old.engine)
         del self._retired_engines[:-4]
         telemetry.record_span(

@@ -25,8 +25,10 @@ import time
 from ..base import MXNetError
 from .. import telemetry
 from ..utils import chaos
+from ..models import latent_moe
 from .engine import (Engine, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost)
+from .latent_lm import LatentMoELM
 from .scheduler import (Scheduler, Request, QueueFull, BrownoutShed,
                         DeadlineExceeded, DeadlineUnmeetable, make_resume)
 from .metrics import ServingMetrics
@@ -43,12 +45,14 @@ def _queue_span(req):
 
 
 def _resolve_model(model, vocab=None, max_len=None, time_major=False):
-    if isinstance(model, (TransformerLM, BlockLM, ExportedLM)):
+    if isinstance(model, (TransformerLM, LatentMoELM, BlockLM, ExportedLM)):
         return model
     if isinstance(model, str):
         return ExportedLM(model)
     if isinstance(model, tuple) and len(model) == 2:
         params, cfg = model
+        if isinstance(cfg, latent_moe.LatentMoEConfig):
+            return LatentMoELM(params, cfg)
         return TransformerLM(params, cfg)
     if hasattr(model, "collect_params"):          # Gluon Block
         if vocab is None or max_len is None:

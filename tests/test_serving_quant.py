@@ -344,7 +344,8 @@ def test_kv_bytes_per_token_prices_quant_layout(tiny_lm):
     e0, _, _ = _rollout(tiny_lm, _prompt(), paged=True)
     e1, _, _ = _rollout(tiny_lm, _prompt(), paged=True, kv_quant=True)
     try:
-        nl, nh, dh, _ = e0.model.cache_spec()
+        cs = e0.model.cache_spec()
+        nl, nh, dh = cs.n_layers, cs.n_heads, cs.head_dim
         assert e0.kv_bytes_per_token() == 2 * nl * nh * dh * 4
         expect = 2 * nl * nh * dh + math.ceil(2 * nl * nh * 4 / 16.0)
         assert e1.kv_bytes_per_token() == expect
