@@ -2,10 +2,11 @@
 block pool.
 
 Why a hand kernel: the PR 1 serving engine decodes by GATHERING each
-sequence's K/V blocks into a dense (B, T, H, Dh) tensor per layer
-(serving/kv_cache.gather_kv) and then running a masked softmax over the
-full padded width — every decoded token pays O(padded-history) HBM reads
-plus a fully materialized copy of the cache. Following "Ragged Paged
+sequence's K/V blocks per layer (serving/kv_cache.gather_kv), a chunk of
+the block table at a time up to the BATCH's longest live sequence
+(serving/engine.py `_attend_live`), and running a masked online softmax
+over them — every decoded token pays HBM reads of the longest history in
+its batch plus a materialized copy of each chunk. Following "Ragged Paged
 Attention" (arxiv 2604.15464, PAPERS.md) the decode read should instead
 be ONE kernel that walks the block table in place: the grid iterates
 (batch row, head, table slot), a scalar-prefetched block table drives the

@@ -6,11 +6,15 @@ Two model families plug in behind one `Engine`:
   with a real paged-cache decode path. Two implementations of that path
   coexist:
 
-  - the GATHER path (PR 1, the fallback and parity oracle): decode
-    gathers each sequence's K/V blocks into a dense (B, T, H, Dh)
-    tensor per layer and masked-softmaxes over the full padded width;
-    prefill runs the dense causal forward once per request over a
-    power-of-two length bucket.
+  - the GATHER path (PR 1, the default, the fallback and the parity
+    oracle): decode gathers each sequence's K/V blocks by table, a
+    chunk of the table's columns at a time, and folds the chunks into
+    an online softmax in ONE loop a layer whose trip count is read on
+    the device from the batch's longest live position (`_attend_live`):
+    the table handed in is always full-capacity, one program serves a
+    batch bucket at every length, and the bytes a step moves follow the
+    longest live sequence; prefill runs the dense causal forward once
+    per request over a power-of-two length bucket.
   - the PAGED path (`MXNET_PAGED_ATTENTION=1`, or `Engine(paged=True)`):
     decode attention runs as ONE Pallas kernel per layer that walks the
     block table in place with per-sequence true lengths
@@ -191,45 +195,87 @@ def _tf_prefill(params, k_pool, v_pool, tokens, length, table_row, cfg,
     return k_pool, v_pool, logits
 
 
-def _tf_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
-               block_size):
-    """One decode step for a (padded) batch: tokens (B,) at positions
-    (B,), block tables (B, nblk). Writes the new K/V, gathers each
-    sequence's cache by table, masked-softmax attention, returns logits
-    (B, V) and the greedy next token. Padded rows carry the all-null
-    table — their writes hit the null block and their logits are
-    discarded by the caller."""
-    from ..models.transformer import _layer_norm
+#: keys one pass of the gather decode step's attention loop folds in: a
+#: whole number of blocks (PERF.md, PR 28: 128, 256 and 512 on the chip)
+_DECODE_CHUNK_TOKENS = 128
 
-    B = tokens.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
-    scale = 1.0 / math.sqrt(Dh)
-    x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
-    slots = flat_slots(tables, positions, block_size)              # (B,)
+
+def _attend_live(qh, k_pool, v_pool, layer, tables, positions, block_size):
+    """Attention of one query a sequence (qh (B, H, Dh), the newest
+    position) over layer `layer` of the pools, walking the block table
+    only as far as the batch's longest live sequence: ONE loop whose
+    body (a chunk of the table's columns gathered as the blocks lie,
+    contracted, masked by position, folded into a running maximum,
+    denominator and weighted sum in float32: the online softmax of
+    ops/pallas_paged.py) is compiled once and whose trip count is read
+    from `positions` on the device. So the bytes a step moves follow
+    the live lengths with one program per batch bucket and no branch.
+    The pools are only read. Returns (B, H, Dh) float32."""
+    B, H, Dh = qh.shape
     nblk = tables.shape[1]
-    T = nblk * block_size
-    live = jnp.arange(T)[None, :] <= positions[:, None]            # (B, T)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        qkv = _mm(h, params[pre + "wqkv"])
-        q, kk, vv = jnp.split(qkv, 3, axis=-1)
-        qh = q.reshape(B, H, Dh)
-        k_pool, v_pool = append_kv(k_pool, v_pool, i,
-                                   slots, kk.reshape(B, H, Dh),
-                                   vv.reshape(B, H, Dh))
-        ks, vs = gather_kv(k_pool, v_pool, i, tables)      # (B,nblk,H,bs,Dh)
+    cb = max(1, min(nblk, _DECODE_CHUNK_TOKENS // block_size))
+    ct = cb * block_size
+    scale = 1.0 / math.sqrt(Dh)
+    # whole chunks: the columns added hold the null block, past every
+    # position
+    tables = jnp.pad(tables, ((0, 0), (0, -nblk % cb)))
+    offs = jnp.arange(ct)
+
+    def fold(c, carry):
+        m, l, acc = carry
+        tab = jax.lax.dynamic_slice_in_dim(tables, c * cb, cb, axis=1)
+        ks, vs = gather_kv(k_pool, v_pool, layer, tab)   # (B,cb,H,bs,Dh)
         # same masking/upcast semantics as attention_reference, with the
         # length mask standing in for the causal mask (the query IS the
         # newest position); position t is (block n, offset s) = divmod(t,
         # block_size), contracted over as the blocks lie in the pool
         s = jnp.einsum("bhd,bnhsd->bhns", qh, ks).astype(jnp.float32) * scale
-        s = jnp.where(live[:, None, :], s.reshape(B, H, T), -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        att = jnp.einsum("bhns,bnhsd->bhd",
-                         p.reshape(B, H, nblk, block_size),
-                         vs.astype(p.dtype))
+        live = (c * ct + offs)[None, :] <= positions[:, None]     # (B, ct)
+        s = jnp.where(live[:, None, :], s.reshape(B, H, ct), -jnp.inf)
+        # position 0 is live in every row, so `m` is finite from the
+        # first chunk on and a chunk wholly past a row adds exact zeros
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bhns,bnhsd->bhd", p.reshape(B, H, cb, block_size),
+            vs.astype(p.dtype))
+        return m_new, l, acc
+
+    init = (jnp.full((B, H), -jnp.inf, jnp.float32),
+            jnp.zeros((B, H), jnp.float32),
+            jnp.zeros((B, H, Dh), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, jnp.max(positions) // ct + 1, fold,
+                                  init)
+    return acc / l[..., None]
+
+
+def _tf_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
+               block_size):
+    """One decode step for a (padded) batch: tokens (B,) at positions
+    (B,), block tables (B, nblk). Writes the new K/V, attends over each
+    sequence's cache by table as far as the longest live one reaches
+    (`_attend_live`), returns logits (B, V) and the greedy next token.
+    Padded rows carry the all-null table — their writes hit the null
+    block and their logits are discarded by the caller."""
+    from ..models.transformer import _layer_norm
+
+    B = tokens.shape[0]
+    D, H = cfg.d_model, cfg.n_heads
+    Dh = D // H
+    x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
+    slots = flat_slots(tables, positions, block_size)              # (B,)
+    for i in range(cfg.n_layers):
+        pre = "layer%d_" % i
+        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
+        qkv = _mm(h, params[pre + "wqkv"])
+        q, kk, vv = jnp.split(qkv, 3, axis=-1)
+        k_pool, v_pool = append_kv(k_pool, v_pool, i,
+                                   slots, kk.reshape(B, H, Dh),
+                                   vv.reshape(B, H, Dh))
+        att = _attend_live(q.reshape(B, H, Dh), k_pool, v_pool, i, tables,
+                           positions, block_size)
         x = x + _mm(att.astype(x.dtype).reshape(B, D), params[pre + "wo"])
         h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
         x = x + _ffn(params, pre, h[:, None], cfg)[:, 0]
@@ -1500,7 +1546,9 @@ class Engine:
                 # paged path: the table width handed to the kernel is
                 # bucketed to the longest LIVE sequence, so a decode
                 # step's bytes track true lengths, not max_len; the
-                # gather path always sees the full-capacity table
+                # gather path gets the full-capacity table and its one
+                # program walks it as far as the longest live sequence
+                # (`_attend_live`: the trip count is read from `pos`)
                 w = self._nblk
                 if self.paged:
                     w = pow2_bucket(
@@ -1514,6 +1562,7 @@ class Engine:
                         toks[i] = s.tokens[-1]
                         pos[i] = len(s.tokens) - 1
                         tabs[i] = s.table_row[:w]
+                    step_span.attrs["live_max"] = int(pos.max()) + 1
                     toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
                                        jnp.asarray(tabs))
                 step_fn = self.model.decode

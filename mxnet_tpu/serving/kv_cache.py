@@ -552,11 +552,15 @@ def gather_kv(k_pool, v_pool, layer, block_table):
     block_table (B, nblk) -> k/v (B, nblk, n_heads, block_size,
     head_dim): the blocks AS THEY LIE in the pool, in table order, so
     position t of a sequence is [t // block_size, :, t % block_size].
-    The caller contracts over them as they are (engine `_tf_decode`): a
+    The caller contracts over them as they are (engine `_attend_live`,
+    which hands in a chunk of the table's columns at a time): a
     transpose to (B, T, n_heads, head_dim) would copy everything
     gathered once more. Entries past each sequence's length are garbage
-    and must be masked by the caller (mask = arange(T) <= position)."""
-    return k_pool[layer][block_table], v_pool[layer][block_table]
+    and must be masked by the caller (`_attend_live`: the chunk's first
+    position + arange(chunk tokens) <= position)."""
+    # one gather over (layer, block): `pool[layer][table]` has the chip's
+    # compiler write the layer's whole slice of the pool out first
+    return k_pool[layer, block_table], v_pool[layer, block_table]
 
 
 # ---------------------------------------------------------------------------

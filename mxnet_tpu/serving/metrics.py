@@ -240,6 +240,10 @@ class ServingMetrics:
                               buckets=_OCCUPANCY_BUCKETS,
                               help="decode batch fill fraction "
                                    "(active/max_batch)")
+        self._g_live_max = g("serving_decode_live_max",
+                             help="tokens of the longest sequence in "
+                                  "the last decode step (how far the "
+                                  "gather step walks its block table)")
         # speculative decoding (ISSUE 19)
         self._spec_passes = c("serving_spec_passes_total",
                               help="speculative scoring passes (one "
@@ -624,12 +628,15 @@ class ServingMetrics:
         self._g_prefill_backlog.set(queue_depth)
 
     def decode_step(self, active, max_batch, step_s, cache_util=None,
-                    paged=False, tokens=None):
+                    paged=False, tokens=None, live_max=None):
         """One decode iteration advanced `active` sequences. `tokens` is
         the number it actually EMITTED — equal to `active` on the plain
         path (the default keeps old callers exact), a burst of up to
-        active*(k+1) under speculation."""
+        active*(k+1) under speculation. `live_max` is the longest of
+        them in tokens as the step began."""
         tokens = active if tokens is None else tokens
+        if live_max is not None:
+            self._g_live_max.set(live_max)
         self._steps.inc()
         (self._steps_paged if paged else self._steps_gather).inc()
         self._h_batch.observe(active)

@@ -777,7 +777,8 @@ class LMServer(_HTTPFrontend):
                     met.decode_step(len(advanced), eng.max_batch,
                                     time.perf_counter() - t0,
                                     cache_util=eng.cache_utilization(),
-                                    paged=eng.paged, tokens=emitted)
+                                    paged=eng.paged, tokens=emitted,
+                                    live_max=max(pre_lens))
                     if eng.last_spec is not None:
                         met.spec_pass(**eng.last_spec)
                         eng.last_spec = None

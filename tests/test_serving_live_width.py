@@ -1,0 +1,280 @@
+"""The gather decode step walks its block table only as far as the longest
+live sequence (ISSUE 28).
+
+Load-bearing claims: (a) `_tf_decode`'s logits are those of a plain masked
+softmax over the table's full width, written here, at ragged lengths on
+both sides of a chunk boundary; (b) what `serve` emits is token for token
+what the step's earlier form (one contraction over the full width, kept
+here as the reference) gives; (c) a sequence that grows across chunk
+boundaries compiles nothing: one program per batch bucket; (d) the compiled
+step has one `while` a layer, no `conditional`, no copy of a pool, and
+aliases both pools, on the CPU and for a described v5e; (e) the
+`serving.decode` span's `live_max` is the longest sequence of its step.
+"""
+import math
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu import serving, telemetry
+from mxnet_tpu.models.transformer import (TransformerConfig, _layer_norm,
+                                          init_transformer_params)
+from mxnet_tpu.serving import engine as engine_mod
+from mxnet_tpu.serving import kv_cache
+
+H, DH, BS, L = 4, 8, 16, 2
+MAX_LEN = 512                               # 32 blocks: four chunks of 128
+NBLK = MAX_LEN // BS
+CHUNK = engine_mod._DECODE_CHUNK_TOKENS
+i32 = jnp.int32
+
+
+@pytest.fixture(scope="module")
+def lm():
+    cfg = TransformerConfig(vocab=48, d_model=H * DH, n_heads=H, n_layers=L,
+                            d_ff=64, max_len=MAX_LEN)
+    return init_transformer_params(jax.random.PRNGKey(0), cfg), cfg
+
+
+def full_width_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
+                      block_size):
+    """The decode step as it was before the loop: every layer gathers the
+    table's full width and runs one masked softmax over it."""
+    B = tokens.shape[0]
+    D, Hn = cfg.d_model, cfg.n_heads
+    Dh = D // Hn
+    x = params["embed"][tokens] + params["pos_embed"][positions]
+    slots = kv_cache.flat_slots(tables, positions, block_size)
+    nblk = tables.shape[1]
+    T = nblk * block_size
+    live = jnp.arange(T)[None, :] <= positions[:, None]
+    for i in range(cfg.n_layers):
+        pre = "layer%d_" % i
+        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
+        q, kk, vv = jnp.split(h @ params[pre + "wqkv"], 3, axis=-1)
+        k_pool, v_pool = kv_cache.append_kv(
+            k_pool, v_pool, i, slots, kk.reshape(B, Hn, Dh),
+            vv.reshape(B, Hn, Dh))
+        ks, vs = kv_cache.gather_kv(k_pool, v_pool, i, tables)
+        s = jnp.einsum("bhd,bnhsd->bhns", q.reshape(B, Hn, Dh),
+                       ks).astype(jnp.float32) / math.sqrt(Dh)
+        s = jnp.where(live[:, None, :], s.reshape(B, Hn, T), -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        att = jnp.einsum("bhns,bnhsd->bhd", p.reshape(B, Hn, nblk, block_size),
+                         vs.astype(p.dtype))
+        x = x + att.astype(x.dtype).reshape(B, D) @ params[pre + "wo"]
+        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
+        x = x + jax.nn.relu(h @ params[pre + "w1"]) @ params[pre + "w2"]
+    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
+    logits = (h @ params["head"]).astype(jnp.float32)
+    return k_pool, v_pool, logits, jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+def filled_pools(cfg, n_rows, seed=1):
+    """Pools whose every slot holds noise (what a reused block holds past a
+    sequence's length must not reach the logits) and a table a row."""
+    shape = (cfg.n_layers, n_rows * NBLK + 1, H, BS, DH)
+    kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    tables = 1 + np.arange(n_rows * NBLK, dtype=np.int32).reshape(n_rows, NBLK)
+    return (jax.random.normal(kk, shape), jax.random.normal(kv, shape), tables)
+
+
+# -- (a) -----------------------------------------------------------------------
+
+RAGGED = {
+    "a_batch_of_one": ([300], 0),
+    "padded_rows_on_the_null_table": ([37, 140], 2),
+    "one_short_of_a_chunk_boundary": ([CHUNK - 1, 5], 0),
+    "at_a_chunk_boundary": ([CHUNK, 2 * CHUNK, 9], 0),
+    "one_past_a_chunk_boundary": ([CHUNK + 1, 3 * CHUNK + 1], 0),
+    "one_at_max_len_beside_short_ones": ([MAX_LEN, 3, 70, 129], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_logits_are_the_full_width_masked_softmaxs(lm, case):
+    params, cfg = lm
+    lengths, padded = RAGGED[case]
+    k, v, tables = filled_pools(cfg, len(lengths))
+    tables = np.concatenate([tables, np.zeros((padded, NBLK), np.int32)])
+    # a sequence of n tokens decodes its last one at position n - 1
+    pos = np.asarray([n - 1 for n in lengths] + [0] * padded, np.int32)
+    toks = (np.arange(len(pos), dtype=np.int32) * 7 + 3) % cfg.vocab
+    args = (params, k, v, jnp.asarray(toks), jnp.asarray(pos),
+            jnp.asarray(tables), cfg, BS)
+    want_k, want_v, want, want_next = full_width_decode(*args)
+    got_k, got_v, got, got_next = jax.jit(
+        engine_mod._tf_decode, static_argnums=(6, 7))(*args)
+    live = len(lengths)
+    np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live],
+                               rtol=1e-4, atol=1e-5)
+    assert np.isfinite(np.asarray(got)).all()           # padded rows too
+    np.testing.assert_array_equal(np.asarray(got_next)[:live],
+                                  np.asarray(want_next)[:live])
+    # the pools are written as before; the loop only reads them. The null
+    # block takes every padded row's write: whichever lands last is kept
+    np.testing.assert_allclose(np.asarray(got_k)[:, 1:], np.asarray(want_k)[:, 1:],
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_v)[:, 1:], np.asarray(want_v)[:, 1:],
+                               rtol=1e-4, atol=1e-5)
+
+
+# -- (b), (c), (e): through the server ------------------------------------------
+
+
+def arith_prompt(start, stride, n, vocab=48):
+    return [(start + stride * t) % vocab for t in range(n)]
+
+
+def reference_tokens(params, cfg, prompt, max_new):
+    """Greedy tokens of one sequence from `_tf_prefill` and the full-width
+    step above, under a plain `jax.jit`, on a pool of this test's own."""
+    shape = (cfg.n_layers, NBLK + 1, H, BS, DH)
+    k, v = jnp.zeros(shape), jnp.zeros(shape)
+    row = jnp.arange(1, NBLK + 1, dtype=i32)
+    prefill = jax.jit(lambda p, k, v, t, n, tb: engine_mod._tf_prefill(
+        p, k, v, t, n, tb, cfg, BS))
+    decode = jax.jit(lambda p, k, v, t, pos, tb: full_width_decode(
+        p, k, v, t, pos, tb, cfg, BS))
+    toks = np.zeros((engine_mod.pow2_bucket(len(prompt), lo=8),), np.int32)
+    toks[:len(prompt)] = prompt
+    k, v, logits = prefill(params, k, v, jnp.asarray(toks), i32(len(prompt)),
+                           row)
+    out = list(prompt) + [int(np.argmax(np.asarray(logits)))]
+    while len(out) < len(prompt) + max_new:
+        k, v, _, nxt = decode(params, k, v, jnp.asarray(out[-1:], i32),
+                              jnp.asarray([len(out) - 1], i32), row[None])
+        out.append(int(nxt[0]))
+    return out[len(prompt):]
+
+
+def test_served_tokens_are_the_full_width_steps_tokens(lm):
+    """Two requests side by side, one growing from 100 tokens across the
+    boundaries at 128 and 256 while the other stays within the first chunk:
+    token for token the reference's; nothing compiles after the first step
+    of the batch; each step's span says how long its longest sequence was."""
+    params, cfg = lm
+    prompts = [arith_prompt(1, 1, 100), arith_prompt(5, 2, 9)]
+    new = [2 * CHUNK + 40 - 100, 30]
+    want = [reference_tokens(params, cfg, p, n) for p, n in zip(prompts, new)]
+    telemetry.tracing.clear()
+    srv = serving.serve((params, cfg), max_batch=2, block_size=BS,
+                        max_len=MAX_LEN)
+    try:
+        eng = srv.engine
+        assert not eng.paged                    # the default, gather path
+        reqs = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+        assert [r.result(timeout=300) for r in reqs] == want       # (b)
+        # (c): one program a batch bucket (2 while both ran, 1 after), and
+        # the long request crossed two chunk boundaries inside them
+        assert eng.decode_compilations <= 2, sorted(eng._sigs)
+        steps = [s for s in telemetry.spans()
+                 if s["name"] == "serving.decode" and "batch" in s["attrs"]]
+        assert len(steps) >= new[0] - 1
+        # (e): the long request is in every step and gains a token a step
+        assert [s["attrs"]["live_max"] for s in steps] \
+            == list(range(101, 101 + len(steps)))
+        assert steps[-1]["attrs"]["live_max"] > 2 * CHUNK
+        assert srv.metrics._g_live_max.value == steps[-1]["attrs"]["live_max"]
+    finally:
+        srv.close()
+
+
+def test_growing_across_chunk_boundaries_compiles_nothing(lm):
+    """The engine alone, one sequence: the step compiled at 100 tokens is the
+    step that runs at 300; `live_max` is on a span with no server too."""
+    params, cfg = lm
+    eng = serving.Engine(serving.TransformerLM(params, cfg), max_batch=2,
+                         block_size=BS, max_len=MAX_LEN)
+    seq = eng.start(arith_prompt(3, 1, 100), max_new=2 * CHUNK + 50 - 100)
+    eng.decode_step([seq])
+    compiled = eng.decode_compilations
+    assert compiled == 1
+    telemetry.tracing.clear()
+    lengths = []
+    while not seq.done:
+        lengths.append(len(seq.tokens))
+        eng.decode_step([seq])
+    assert lengths[0] < CHUNK and lengths[-1] > 2 * CHUNK
+    assert eng.decode_compilations == compiled
+    steps = [s for s in telemetry.spans()
+             if s["name"] == "serving.decode" and "batch" in s["attrs"]]
+    assert [s["attrs"]["live_max"] for s in steps] == lengths
+    eng.release(seq)
+
+
+# -- (d) -------------------------------------------------------------------------
+
+
+def pool_copies(hlo_text, shape, dtype):
+    tag = "%s[%s]" % (dtype, ",".join(str(d) for d in shape))
+    return [l for l in hlo_text.splitlines()
+            if re.search(r"= \S+ copy\(", l) and tag in l.split(" copy(")[0]]
+
+
+def step_shapes(cfg, batch, pool, dtype, sharding=None):
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype), jax.eval_shape(
+            lambda: init_transformer_params(jax.random.PRNGKey(0), cfg)))
+    nblk = cfg.max_len // pool[3]
+    return (params, sds(pool, dtype), sds(pool, dtype), sds((batch,), i32),
+            sds((batch,), i32), sds((batch, nblk), i32))
+
+
+def assert_one_loop_a_layer(compiled, cfg, k_pool):
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == cfg.n_layers
+    assert " conditional(" not in text
+    hlo_name = {"float32": "f32", "bfloat16": "bf16"}[k_pool.dtype.name]
+    assert not pool_copies(text, k_pool.shape, hlo_name)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * k_pool.size * k_pool.dtype.itemsize
+
+
+def test_the_step_is_one_loop_a_layer_and_updates_its_pools_in_place(lm):
+    params, cfg = lm
+    model = serving.TransformerLM(params, cfg)
+    model.bind(BS)
+    pool = (L, 2 * NBLK + 1, H, BS, DH)
+    shapes = step_shapes(cfg, 2, pool, jnp.float32)
+    compiled = model._decode_jit.lower(*shapes).compile()
+    assert_one_loop_a_layer(compiled, cfg, shapes[1])
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_cells_step_compiles_for_the_chip_as_one_loop_a_layer(one_chip):
+    """At the OPT cell's widths, table and pool (two of its eight layers),
+    for the v5e's compiler: no branch, no copy of a pool, both pools
+    aliased; no layer's slice of a pool written out before the gather (the
+    chip's compiler does that for `pool[layer][table]`, once a pass of the
+    loop: 0.13 GB each of K and V); and the scratch far under the 0.8 GB a
+    layer that the full width's gathered and upcast V took."""
+    cfg = TransformerConfig(vocab=50272, d_model=4096, n_heads=32, n_layers=2,
+                            d_ff=16384, max_len=2048, dtype=jnp.bfloat16)
+    pool = (2, 1025, 32, 16, 128)
+    step = engine_mod._step_jit(
+        "serving_decode",
+        lambda p, k, v, t, pos, tb: engine_mod._tf_decode(
+            p, k, v, t, pos, tb, cfg, 16),
+        serving.TransformerLM._DECODE_ARGS)
+    shapes = step_shapes(cfg, 16, pool, jnp.bfloat16, one_chip)
+    compiled = step.lower(*shapes).compile()
+    assert_one_loop_a_layer(compiled, cfg, shapes[1])
+    assert "bf16[%s]" % ",".join(map(str, pool[1:])) not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
