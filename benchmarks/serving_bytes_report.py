@@ -100,12 +100,8 @@ def paged_width(eng, true_lens):
 def analyze(eng, model, padded_T, width, true_lens):
     import jax.numpy as jnp
     toks, pos, tabs = decode_args(eng, true_lens, width)
-    if eng.tp > 1:
-        fn, params = model._decode_tp_jit, model._tp_params
-    elif eng.paged:
-        fn, params = model._decode_paged_jit, model.params
-    else:
-        fn, params = model._decode_jit, model.params
+    # the decode program this engine's configuration bound
+    fn, params = model.programs["decode"], model.step_params
     args = (params, eng.cache.k, eng.cache.v, jnp.asarray(toks),
             jnp.asarray(pos), jnp.asarray(tabs))
     t0 = time.perf_counter()
@@ -274,11 +270,10 @@ def main():
                                       block_size, kv_quant=True)
         assert eng_q.kv_quant, eng_q.kv_quant_fallback
         toks, pos, tabs = decode_args(eng_q, true_lens, w_paged)
-        args = (model_q.params, eng_q.cache.k, eng_q.cache.v,
-                jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tabs),
-                eng_q.cache.k_scale, eng_q.cache.v_scale)
+        args = (model_q.step_params, *eng_q.cache.arrays(),
+                jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tabs))
         t0 = time.perf_counter()
-        cost = model_q._decode_paged_q_jit.lower(*args).compile() \
+        cost = model_q.programs["decode"].lower(*args).compile() \
             .cost_analysis()
         fl4, by4 = paged_call_cost(batch, 1, cfg_heads, cfg_dh,
                                    w_paged, block_size)

@@ -20,23 +20,21 @@ chips that hold them. Held experts are computed over the (token,
 expert) pairs that chose them, sorted by expert and cut into tiles
 (`grouped_experts`), not densely over every token.
 
-The layer is written ONCE, over a cache view that says how attention
-reads its keys:
+The layer is written ONCE (`block`), over a cache view that says how
+attention reads its keys; the layer knows a view by its `attend` and
+nothing of pools. Here is the one with no cache, `DenseView`: the rows
+are one sequence in order and every position attends by the EXPANDED
+form (keys and values rebuilt from the latent, scores blocked over
+queries; never a (heads, S, S) array). The views that write to and read
+from the paged pool, and the step functions over them, are the serving
+engine's (`serving/latent_lm.py`): prefill attends expanded after
+writing the prompt's latents, decode attends in the ABSORBED form over
+the cached latents (`absorbed_attention`: the key up-projection is
+folded into the query and the value up-projection applied after the
+weighted sum, so keys and values of cached tokens are never rebuilt).
 
-* `DenseView`: no cache; the rows are one sequence in order and every
-  position attends by the EXPANDED form (keys and values rebuilt from
-  the latent, scores blocked over queries; never a (heads, S, S) array).
-* `PromptView`: the same, after writing the whole prompt's latent
-  into the paged pool (prefill).
-* `DecodeView`: each row is another sequence's newest token; its latent
-  is appended to the pool and attention runs in the ABSORBED form over
-  the cached latents gathered by block table: the key up-projection is
-  folded into the query and the value up-projection applied after the
-  weighted sum, so keys and values of cached tokens are never rebuilt.
-
-What the cache holds per token per layer is `[c_kv after its norm
-(kv_rank) ; k_rope after RoPE (rope_dim)]` and nothing else
-(`serving/kv_cache.py`, the latent layout).
+What a cache holds per token per layer is `[c_kv after its norm
+(kv_rank) ; k_rope after RoPE (rope_dim)]` and nothing else.
 """
 from __future__ import annotations
 
@@ -45,9 +43,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-from ..serving.kv_cache import (append_latent, write_latent_prompt,
-                                gather_latent, flat_slots)
 
 #: query rows scored at once by the expanded form: (heads, Q_BLOCK, keys)
 #: float32 is the largest array attention makes
@@ -276,37 +271,6 @@ class DenseView:
         return expanded_attention(q_nope, q_rope, latent, wk_b, wv_b, cfg)
 
 
-class PromptView(DenseView):
-    """Prefill: the whole prompt's latents go into the blocks of
-    `table_row`, then every position attends by the expanded form."""
-
-    def __init__(self, pool, table_row):
-        self.pool, self.table_row = pool, table_row
-
-    def attend(self, layer, q_nope, q_rope, latent, wk_b, wv_b, cfg):
-        self.pool = write_latent_prompt(self.pool, layer, self.table_row,
-                                        latent)
-        return expanded_attention(q_nope, q_rope, latent, wk_b, wv_b, cfg)
-
-
-class DecodeView:
-    """Decode: row b is sequence b's token at `positions[b]`; append its
-    latent, gather the sequence's blocks by table, attend absorbed."""
-
-    def __init__(self, pool, tables, positions):
-        self.pool, self.tables = pool, tables
-        bs = pool.shape[2]
-        self.slots = flat_slots(tables, positions, bs)
-        self.live = jnp.arange(tables.shape[1] * bs)[None, :] \
-            <= positions[:, None]
-
-    def attend(self, layer, q_nope, q_rope, latent, wk_b, wv_b, cfg):
-        self.pool = append_latent(self.pool, layer, self.slots, latent)
-        cached = gather_latent(self.pool, layer, self.tables)
-        return absorbed_attention(q_nope, q_rope, cached, self.live,
-                                  wk_b, wv_b, cfg)
-
-
 # ---------------------------------------------------------------------------
 # the expert layer
 # ---------------------------------------------------------------------------
@@ -407,7 +371,7 @@ def moe_ffn(params, pre, h, real, cfg):
 
 
 # ---------------------------------------------------------------------------
-# the layer, once, and the three forwards over it
+# the layer, once, and the dense forward over it
 # ---------------------------------------------------------------------------
 
 
@@ -465,30 +429,3 @@ def latent_moe_apply(params, tokens, cfg, length=None):
     real = positions < (S if length is None else length)
     x, counts = _trunk(params, tokens, positions, real, cfg, DenseView())
     return _logits(params, x, cfg), counts
-
-
-def prefill(params, pool, tokens, length, table_row, cfg):
-    """One padded prompt (S,) of true `length`: writes every layer's
-    latents into the blocks of `table_row` and returns (pool, logits at
-    position length-1, pairs per (expert layer, held expert)). Padded
-    positions lie after the real ones, so no real position attends to
-    them; what they write is overwritten by decode before it is read."""
-    positions = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    view = PromptView(pool, table_row)
-    x, counts = _trunk(params, tokens, positions, positions < length, cfg,
-                       view)
-    return view.pool, _logits(params, x[length - 1], cfg), counts
-
-
-def decode(params, pool, tokens, positions, tables, cfg):
-    """One decode step of a padded batch: tokens (B,) at positions (B,),
-    block tables (B, nblk). A padded row carries the all-null table: it
-    writes to the null block, is routed to no expert and its logits are
-    dropped by the caller. Returns (pool, logits (B, vocab), greedy next
-    token (B,), pairs per (expert layer, held expert))."""
-    view = DecodeView(pool, tables, positions)
-    x, counts = _trunk(params, tokens, positions, tables[:, 0] != 0, cfg,
-                       view)
-    logits = _logits(params, x, cfg)
-    return (view.pool, logits, jnp.argmax(logits, -1).astype(jnp.int32),
-            counts)

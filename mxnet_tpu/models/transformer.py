@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..ops.quantization import maybe_quant_matmul as _mm
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -149,6 +151,52 @@ def _moe_ffn(x, wg, w1, w2):
     h = jax.nn.relu(h)
     y = jnp.einsum("besf,efd->besd", h, w2)
     return jnp.einsum("bse,besd->bsd", gates, y)
+
+
+class OneChip:
+    """How `block` is laid over chips, when it is not: the fused `wqkv`
+    product is three contiguous thirds (all heads' q, then k, then v)
+    and the two products whose inputs a tensor-parallel mesh would split
+    (`wo`, `w2`) are whole as they come. `serving/tp.py` holds the other
+    answer, one chip's share of the heads."""
+
+    @staticmethod
+    def heads(qkv, head_dim):
+        """(..., 3 * H * head_dim) -> q, k, v, each (N, H, head_dim)."""
+        return tuple(t.reshape(-1, t.shape[-1] // head_dim, head_dim)
+                     for t in jnp.split(qkv, 3, axis=-1))
+
+    @staticmethod
+    def close(y):
+        return y
+
+
+def block(params, i, x, cfg, view, shard=OneChip):
+    """Layer i of the serving forward over rows x (..., D), N of them
+    under any leading axes: pre-LayerNorm attention through `view` and a
+    ReLU feed-forward (dense, or the dense-dispatch experts), each added
+    to the residual. Every step program of the paged engine
+    (serving/engine.py) is this layer; what a row is (a prompt's
+    position, a sequence's newest token, a chunk's or a speculative
+    pass's position) and where its keys and values are kept and read is
+    the view's: `view.attend(layer, q, k, v)` takes the rows' heads
+    (N, H, Dh) and returns their attention (N, H, Dh). Matrices may be
+    int8 `{"q", "s"}` pairs (`_mm`). Every map but attention is a row's
+    own, so padded rows cannot perturb real ones."""
+    pre = "layer%d_" % i
+    h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
+    q, k, v = shard.heads(_mm(h, params[pre + "wqkv"]),
+                          cfg.d_model // cfg.n_heads)
+    att = view.attend(i, q, k, v)
+    x = x + shard.close(_mm(att.astype(x.dtype).reshape(*x.shape[:-1], -1),
+                            params[pre + "wo"]))
+    h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
+    if cfg.n_experts:
+        rows = h.reshape(1, -1, h.shape[-1])
+        return x + _moe_ffn(rows, params[pre + "wg"], params[pre + "w1"],
+                            params[pre + "w2"]).reshape(h.shape)
+    return x + shard.close(_mm(jax.nn.relu(_mm(h, params[pre + "w1"])),
+                               params[pre + "w2"]))
 
 
 def _route_group_topk(xg, wg, w1, w2, k, capacity):

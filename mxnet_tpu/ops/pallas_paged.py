@@ -4,7 +4,7 @@ block pool.
 Why a hand kernel: the PR 1 serving engine decodes by GATHERING each
 sequence's K/V blocks per layer (serving/kv_cache.gather_kv), a chunk of
 the block table at a time up to the BATCH's longest live sequence
-(serving/engine.py `_attend_live`), and running a masked online softmax
+(serving/kv_cache.py `_attend_live`), and running a masked online softmax
 over them — every decoded token pays HBM reads of the longest history in
 its batch plus a materialized copy of each chunk. Following "Ragged Paged
 Attention" (arxiv 2604.15464, PAPERS.md) the decode read should instead

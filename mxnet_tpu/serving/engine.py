@@ -3,26 +3,28 @@
 Two model families plug in behind one `Engine`:
 
 * `TransformerLM` — the functional transformer (models/transformer.py)
-  with a real paged-cache decode path. Two implementations of that path
-  coexist:
+  with a real paged-cache decode path. Its layer is written once
+  (`models.transformer.block`); the four step functions here (`prefill`,
+  `decode`, `prefill_chunk`, `spec_score`) say what a row is and which
+  rows' logits come back, and a cache view (kv_cache.py) says where a
+  layer's keys and values are written and how they are read. An engine
+  runs one of two configurations:
 
   - the GATHER path (PR 1, the default, the fallback and the parity
-    oracle): decode gathers each sequence's K/V blocks by table, a
-    chunk of the table's columns at a time, and folds the chunks into
-    an online softmax in ONE loop a layer whose trip count is read on
-    the device from the batch's longest live position (`_attend_live`):
-    the table handed in is always full-capacity, one program serves a
-    batch bucket at every length, and the bytes a step moves follow the
-    longest live sequence; prefill runs the dense causal forward once
-    per request over a power-of-two length bucket.
+    oracle): prefill runs the dense causal forward once per request over
+    a power-of-two length bucket (`PromptView`); decode gathers each
+    sequence's K/V blocks by table as far as the batch's longest live
+    position (`LiveGatherView`): the table handed in is always
+    full-capacity, one program serves a batch bucket at every length, and
+    the bytes a step moves follow the longest live sequence.
   - the PAGED path (`MXNET_PAGED_ATTENTION=1`, or `Engine(paged=True)`):
-    decode attention runs as ONE Pallas kernel per layer that walks the
-    block table in place with per-sequence true lengths
-    (ops/pallas_paged.py) — no dense gather is ever materialized, and
+    attention runs as ONE Pallas kernel per layer that walks the block
+    table in place with per-sequence true lengths (`PagedView`,
+    ops/pallas_paged.py) — no dense gather is ever materialized, and
     the table WIDTH handed to the kernel is bucketed to the longest
     live sequence, so the bytes per decoded token track true lengths
     rather than the padded pool capacity. Prefill is CHUNKED: long
-    prompts stream through a fixed-shape chunk kernel that appends K/V
+    prompts stream through a fixed-shape chunk that appends K/V
     into the pool chunk-by-chunk — one compiled chunk shape replaces
     the per-length-bucket dense prefill lattice, and the serving loop
     co-schedules pending chunks with decode steps under the
@@ -63,10 +65,11 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from .. import telemetry
-from ..ops.quantization import maybe_quant_matmul as _mm
-from .kv_cache import (POOL_ARGS, CacheSpec, PagedKVCache, flat_slots, write_kv,
-                       append_kv, write_kv_prompt, gather_kv, copy_block,
-                       write_kv_quant, copy_block_quant, zero_block_scales)
+from ..models.transformer import OneChip, block, _layer_norm
+from . import tp
+from .kv_cache import (POOL_ARGS, CacheSpec, PagedKVCache, PromptView,
+                       LiveGatherView, PagedView, flat_slots, copy_block,
+                       zero_block_scales)
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 
 
@@ -86,16 +89,48 @@ def quantized_weights_env():
     return v or None
 
 
-def _step_jit(name, fn, argnames):
-    """`fn` jitted as the step program `jit_<name>` (a device trace names
-    a program after its function; a lambda's is `jit__lambda`, whichever
-    step it is) that CONSUMES its pools: the arguments named in
-    `POOL_ARGS` are donated, the executable aliases them to the pools it
-    returns and writes the new K/V into those same buffers, so a step
-    holds the pool once and copies none of it."""
+#: operation -> (watchdog site, phase, the arguments after the pools, how
+#: many results follow the pools)
+_STEPS = {
+    "prefill": ("serving.prefill", "prefill",
+                ("tokens", "length", "table_row"), 1),
+    "decode": ("serving.decode", "decode",
+               ("tokens", "positions", "tables"), 2),
+    "prefill_chunk": ("serving.prefill", "prefill",
+                      ("tokens", "q_start", "length", "last_idx",
+                       "table_row"), 1),
+    "spec_score": ("serving.spec_score", "decode",
+                   ("tokens", "q_starts", "counts", "tables"), 1),
+}
+
+
+def _program(op, name, variant, step, pool_names, shard_over=None):
+    """`step(params, pools, *args) -> (*pools, *results)` as the step
+    program `jit_<name>` of operation `op`, taking `(params, *pools,
+    *args)` (a device trace names a program after its function; a
+    lambda's is `jit__lambda`, whichever step it is), registered with
+    the compile watchdog at the operation's site under the AOT tag
+    `variant`. It CONSUMES its pools: the arguments named in `POOL_ARGS`
+    are donated, the executable aliases them to the pools it returns
+    and writes the new K/V into those same buffers, so a step holds the
+    pool once and copies none of it. `shard_over` = (mesh, the
+    parameters' specs) runs it under shard_map over the tp mesh, each
+    chip on its shard (serving/tp.py)."""
+    site, phase, args, n_results = _STEPS[op]
+    n = len(pool_names)
+
+    def fn(params, *rest):
+        return step(params, rest[:n], *rest[n:])
+
     fn.__name__ = name
-    return jax.jit(fn, donate_argnums=tuple(
-        i for i, a in enumerate(argnames) if a in POOL_ARGS))
+    if shard_over is not None:
+        fn = tp.shard_step(fn, *shard_over, len(pool_names), len(args),
+                           n_results)
+    argnames = ("params",) + tuple(pool_names) + args
+    return telemetry.introspect.instrument(
+        jax.jit(fn, donate_argnums=tuple(
+            i for i, a in enumerate(argnames) if a in POOL_ARGS)),
+        site=site, phase=phase, argnames=argnames, variant=variant)
 
 
 def pow2_bucket(n, lo=1, hi=None):
@@ -147,268 +182,84 @@ class Sequence:
 # ---------------------------------------------------------------------------
 
 
-def _ffn(params, pre, x, cfg):
-    """Position-wise FFN on (B, S, D); dense or dense-dispatch MoE. Both
-    are per-token maps, so padded positions cannot perturb real ones."""
-    from ..models.transformer import _moe_ffn
-    if cfg.n_experts:
-        return _moe_ffn(x, params[pre + "wg"], params[pre + "w1"],
-                        params[pre + "w2"])
-    return _mm(jax.nn.relu(_mm(x, params[pre + "w1"])),
-               params[pre + "w2"])
+def _layers(params, x, cfg, view, shard):
+    for i in range(cfg.n_layers):
+        x = block(params, i, x, cfg, view, shard)
+    return x
 
 
-def _tf_prefill(params, k_pool, v_pool, tokens, length, table_row, cfg,
-                block_size):
+def _logits(params, x):
+    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
+    return (h @ params["head"]).astype(jnp.float32)
+
+
+def prefill(params, pools, tokens, length, table_row, cfg):
     """Dense causal forward over one padded prompt (S,), writing every
     layer's K/V into the pool and returning the logits at position
     length-1. Padded positions (>= length) sit AFTER the real tokens, so
     under the causal mask no real position ever attends to them; their
     K/V writes land in not-yet-used or null-block slots and are
     overwritten by decode before they can be read."""
-    from ..models.transformer import _layer_norm
-    from ..parallel.ring_attention import attention_reference
-
-    S = tokens.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
-    x = params["embed"][tokens] + params["pos_embed"][:S]          # (S, D)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        qkv = _mm(h, params[pre + "wqkv"])
-        q, kk, vv = jnp.split(qkv, 3, axis=-1)
-        kh = kk.reshape(S, H, Dh)
-        vh = vv.reshape(S, H, Dh)
-        k_pool, v_pool = write_kv_prompt(k_pool, v_pool, i, table_row,
-                                         kh, vh)
-        att = attention_reference(
-            q.reshape(S, H, Dh).transpose(1, 0, 2)[None],
-            kh.transpose(1, 0, 2)[None],
-            vh.transpose(1, 0, 2)[None], causal=True)              # (1,H,S,Dh)
-        x = x + _mm(att[0].transpose(1, 0, 2).reshape(S, D),
-                    params[pre + "wo"])
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + _ffn(params, pre, h[None], cfg)[0]
-    h_last = _layer_norm(x[length - 1], params["lnf_g"], params["lnf_b"])
-    logits = (h_last @ params["head"]).astype(jnp.float32)         # (V,)
-    return k_pool, v_pool, logits
+    view = PromptView(pools, table_row)
+    x = params["embed"][tokens] + params["pos_embed"][:tokens.shape[0]]
+    x = _layers(params, x, cfg, view, OneChip)                     # (S, D)
+    return (*view.pools, _logits(params, x[length - 1]))
 
 
-#: keys one pass of the gather decode step's attention loop folds in: a
-#: whole number of blocks (PERF.md, PR 28: 128, 256 and 512 on the chip)
-_DECODE_CHUNK_TOKENS = 128
-
-
-def _attend_live(qh, k_pool, v_pool, layer, tables, positions, block_size):
-    """Attention of one query a sequence (qh (B, H, Dh), the newest
-    position) over layer `layer` of the pools, walking the block table
-    only as far as the batch's longest live sequence: ONE loop whose
-    body (a chunk of the table's columns gathered as the blocks lie,
-    contracted, masked by position, folded into a running maximum,
-    denominator and weighted sum in float32: the online softmax of
-    ops/pallas_paged.py) is compiled once and whose trip count is read
-    from `positions` on the device. So the bytes a step moves follow
-    the live lengths with one program per batch bucket and no branch.
-    The pools are only read. Returns (B, H, Dh) float32."""
-    B, H, Dh = qh.shape
-    nblk = tables.shape[1]
-    cb = max(1, min(nblk, _DECODE_CHUNK_TOKENS // block_size))
-    ct = cb * block_size
-    scale = 1.0 / math.sqrt(Dh)
-    # whole chunks: the columns added hold the null block, past every
-    # position
-    tables = jnp.pad(tables, ((0, 0), (0, -nblk % cb)))
-    offs = jnp.arange(ct)
-
-    def fold(c, carry):
-        m, l, acc = carry
-        tab = jax.lax.dynamic_slice_in_dim(tables, c * cb, cb, axis=1)
-        ks, vs = gather_kv(k_pool, v_pool, layer, tab)   # (B,cb,H,bs,Dh)
-        # same masking/upcast semantics as attention_reference, with the
-        # length mask standing in for the causal mask (the query IS the
-        # newest position); position t is (block n, offset s) = divmod(t,
-        # block_size), contracted over as the blocks lie in the pool
-        s = jnp.einsum("bhd,bnhsd->bhns", qh, ks).astype(jnp.float32) * scale
-        live = (c * ct + offs)[None, :] <= positions[:, None]     # (B, ct)
-        s = jnp.where(live[:, None, :], s.reshape(B, H, ct), -jnp.inf)
-        # position 0 is live in every row, so `m` is finite from the
-        # first chunk on and a chunk wholly past a row adds exact zeros
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l = alpha * l + p.sum(axis=-1)
-        acc = alpha[..., None] * acc + jnp.einsum(
-            "bhns,bnhsd->bhd", p.reshape(B, H, cb, block_size),
-            vs.astype(p.dtype))
-        return m_new, l, acc
-
-    init = (jnp.full((B, H), -jnp.inf, jnp.float32),
-            jnp.zeros((B, H), jnp.float32),
-            jnp.zeros((B, H, Dh), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, jnp.max(positions) // ct + 1, fold,
-                                  init)
-    return acc / l[..., None]
-
-
-def _tf_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
-               block_size):
+def decode(params, pools, tokens, positions, tables, cfg, block_size,
+           view_of, shard=OneChip):
     """One decode step for a (padded) batch: tokens (B,) at positions
     (B,), block tables (B, nblk). Writes the new K/V, attends over each
-    sequence's cache by table as far as the longest live one reaches
-    (`_attend_live`), returns logits (B, V) and the greedy next token.
-    Padded rows carry the all-null table — their writes hit the null
-    block and their logits are discarded by the caller."""
-    from ..models.transformer import _layer_norm
-
-    B = tokens.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
+    sequence's cache through `view_of` (`LiveGatherView`: the table at
+    full capacity, walked as far as the longest live sequence;
+    `PagedView`: the table width-bucketed by the caller to the longest
+    live sequence, walked in place by one kernel a layer, so no dense
+    gather is materialized), returns logits (B, V) and the greedy next
+    token. Padded rows carry the all-null table — their writes hit the
+    null block and their logits are discarded by the caller."""
     x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
-    slots = flat_slots(tables, positions, block_size)              # (B,)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        qkv = _mm(h, params[pre + "wqkv"])
-        q, kk, vv = jnp.split(qkv, 3, axis=-1)
-        k_pool, v_pool = append_kv(k_pool, v_pool, i,
-                                   slots, kk.reshape(B, H, Dh),
-                                   vv.reshape(B, H, Dh))
-        att = _attend_live(q.reshape(B, H, Dh), k_pool, v_pool, i, tables,
-                           positions, block_size)
-        x = x + _mm(att.astype(x.dtype).reshape(B, D), params[pre + "wo"])
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + _ffn(params, pre, h[:, None], cfg)[:, 0]
-    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)              # (B, V)
-    return k_pool, v_pool, logits, jnp.argmax(logits, -1).astype(jnp.int32)
+    view = view_of(pools, tables, positions,
+                   flat_slots(tables, positions, block_size))
+    logits = _logits(params, _layers(params, x, cfg, view, shard))
+    return (*view.pools, logits, jnp.argmax(logits, -1).astype(jnp.int32))
 
 
-def _tf_decode_paged(params, k_pool, v_pool, tokens, positions, tables,
-                     cfg, block_size, k_scale=None, v_scale=None):
-    """One decode step via the ragged paged-attention kernel: same
-    contract as `_tf_decode`, but the per-layer cache read is a single
-    Pallas kernel walking the block table in place (ops/pallas_paged.py)
-    — no dense (B, T, H, Dh) gather is materialized. `tables` is
-    width-bucketed by the caller to the longest live sequence, so the
-    compiled program's bytes track true lengths, not max_len.
-
-    With `k_scale`/`v_scale` (ISSUE 20: the int8 pool's per-block-per-
-    head f32 sidecars) the appends quantize via `write_kv_quant` and the
-    kernel dequantizes in VMEM; the branch is trace-time, so the
-    flag-off program is byte-identical to the f32 path, and the return
-    grows to (k, v, k_scale, v_scale, logits, next)."""
-    from ..models.transformer import _layer_norm
-    from ..ops.pallas_paged import paged_attention
-
-    quant = k_scale is not None
-    B = tokens.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
-    x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
-    slots = flat_slots(tables, positions, block_size)              # (B,)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        qkv = _mm(h, params[pre + "wqkv"])
-        q, kk, vv = jnp.split(qkv, 3, axis=-1)
-        if quant:
-            k_pool, v_pool, k_scale, v_scale = write_kv_quant(
-                k_pool, v_pool, k_scale, v_scale, i, slots,
-                kk.reshape(B, H, Dh), vv.reshape(B, H, Dh))
-            att = paged_attention(q.reshape(B, 1, H, Dh), k_pool[i],
-                                  v_pool[i], tables, positions,
-                                  block_size, k_scale=k_scale[i],
-                                  v_scale=v_scale[i])[:, 0]
-        else:
-            k_pool, v_pool = append_kv(k_pool, v_pool, i,
-                                       slots, kk.reshape(B, H, Dh),
-                                       vv.reshape(B, H, Dh))
-            att = paged_attention(q.reshape(B, 1, H, Dh), k_pool[i],
-                                  v_pool[i], tables, positions,
-                                  block_size)[:, 0]                # (B,H,Dh)
-        x = x + _mm(att.reshape(B, D), params[pre + "wo"])
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + _ffn(params, pre, h[:, None], cfg)[:, 0]
-    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)              # (B, V)
-    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-    if quant:
-        return k_pool, v_pool, k_scale, v_scale, logits, nxt
-    return k_pool, v_pool, logits, nxt
-
-
-def _tf_prefill_chunk(params, k_pool, v_pool, toks, qs, length, last_idx,
-                      table_row, cfg, block_size, k_scale=None,
-                      v_scale=None):
+def prefill_chunk(params, pools, toks, qs, length, last_idx, table_row, cfg,
+                  block_size, shard=OneChip):
     """One fixed-shape prefill chunk for ONE sequence: toks (C,) are the
     prompt tokens at positions qs..qs+C-1 (zero-padded past the true
     prompt `length`), table_row (w,) is the sequence's width-bucketed
     block table. Writes the chunk's K/V into the pool and attends via the
-    ragged paged kernel — the mask `key_pos <= qs+i` is exactly the
-    causal mask within the chunk and the full-history mask across earlier
-    chunks. Returns logits at chunk index `last_idx` (the prompt's final
-    token when this is the last chunk; earlier chunks' logits are
-    discarded by the caller).
+    ragged paged kernel (`PagedView`). Returns logits at chunk index
+    `last_idx` (the prompt's final token when this is the last chunk;
+    earlier chunks' logits are discarded by the caller).
 
     Padded positions (>= length) write their garbage K/V into the null
     block — NOT into their table slot, which belongs to a future decode
     position: the decode step that later owns that slot writes its own
     K/V before anything can read it, and real queries never attend past
     position length-1 anyway."""
-    from ..models.transformer import _layer_norm
-    from ..ops.pallas_paged import paged_attention
-
-    quant = k_scale is not None
     C = toks.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
     pos = qs + jnp.arange(C)                                       # (C,)
-    x = params["embed"][toks] + params["pos_embed"][pos]           # (C, D)
     slots = jnp.take(table_row, pos // block_size) * block_size \
         + pos % block_size
     slots = jnp.where(pos < length, slots, pos % block_size)       # null blk
-    tables = table_row[None]                                       # (1, w)
-    qs_row = jnp.reshape(qs, (1,)).astype(jnp.int32)
     # a contiguous C-token chunk touches at most ceil-plus-straddle
-    # blocks plus the null block — a tight candidate set keeps the
-    # writer's read and write of whole blocks small
-    ncand = (C - 1) // block_size + 2
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        qkv = _mm(h, params[pre + "wqkv"])
-        q, kk, vv = jnp.split(qkv, 3, axis=-1)
-        if quant:
-            k_pool, v_pool, k_scale, v_scale = write_kv_quant(
-                k_pool, v_pool, k_scale, v_scale, i, slots,
-                kk.reshape(C, H, Dh), vv.reshape(C, H, Dh),
-                ncand=ncand)
-            att = paged_attention(q.reshape(C, H, Dh)[None], k_pool[i],
-                                  v_pool[i], tables, qs_row,
-                                  block_size, k_scale=k_scale[i],
-                                  v_scale=v_scale[i])[0]
-        else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i,
-                                      slots, kk.reshape(C, H, Dh),
-                                      vv.reshape(C, H, Dh), ncand=ncand)
-            att = paged_attention(q.reshape(C, H, Dh)[None], k_pool[i],
-                                  v_pool[i], tables, qs_row,
-                                  block_size)[0]                   # (C,H,Dh)
-        x = x + _mm(att.reshape(C, D), params[pre + "wo"])
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + _ffn(params, pre, h[None], cfg)[0]
-    h_last = _layer_norm(x[last_idx], params["lnf_g"], params["lnf_b"])
-    logits = (h_last @ params["head"]).astype(jnp.float32)         # (V,)
-    if quant:
-        return k_pool, v_pool, k_scale, v_scale, logits
-    return k_pool, v_pool, logits
+    # blocks (it starts inside a block after a prefix-cache hit on a
+    # partial one) plus the null block — a tight candidate set keeps
+    # the writer's read and write of whole blocks small
+    view = PagedView(pools, table_row[None],
+                     jnp.reshape(qs, (1,)).astype(jnp.int32), slots,
+                     ncand=(C - 1) // block_size + 3)
+    x = params["embed"][toks] + params["pos_embed"][pos]           # (C, D)
+    x = _layers(params, x, cfg, view, shard)
+    return (*view.pools, _logits(params, x[last_idx]))
 
 
-def _tf_spec_score(params, k_pool, v_pool, toks, q_starts, counts,
-                   tables, cfg, block_size, k_scale=None, v_scale=None):
+def spec_score(params, pools, toks, q_starts, counts, tables, cfg,
+               block_size, shard=OneChip):
     """Speculative scoring pass: the batched generalization of
-    `_tf_prefill_chunk`. For each row, toks (B, C) holds [last history
+    `prefill_chunk`. For each row, toks (B, C) holds [last history
     token, draft_1..draft_k] (zero-padded past that row's true `counts`)
     at true positions q_starts[b]..q_starts[b]+C-1; tables (B, w) are the
     live width-bucketed block tables. ONE paged pass writes the C
@@ -417,73 +268,39 @@ def _tf_spec_score(params, k_pool, v_pool, toks, q_starts, counts,
     plus the first j draft tokens, exactly what greedy/rejection
     verification consumes.
 
-    Position truth: the paged kernel's per-row mask `key_pos <=
-    q_starts[b] + i` is the causal mask within the chunk plus the
-    full-history mask across the cache — each scored position attends
-    precisely the tokens a one-at-a-time decode would. Positions past
+    Position truth: each scored position attends precisely the tokens a
+    one-at-a-time decode would (`PagedView`'s mask). Positions past
     `counts` (shorter-than-k proposals, padded batch rows) write to the
     null block and their logits are discarded by the caller; positions
     past an eventual rejection DO land in real table slots, but they are
     rewritten by the next pass over this sequence (spec passes re-score
     from the new history end; a non-spec step writes its own slot)
     before any mask lets a query read them."""
-    from ..models.transformer import _layer_norm
-    from ..ops.pallas_paged import paged_attention
-
-    quant = k_scale is not None
     B, C = toks.shape
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
     w = tables.shape[1]
     pos = q_starts[:, None] + jnp.arange(C)[None, :]               # (B, C)
     valid = jnp.arange(C)[None, :] < counts[:, None]               # (B, C)
-    pe = jnp.minimum(pos, cfg.max_len - 1)
-    x = params["embed"][toks] + params["pos_embed"][pe]            # (B,C,D)
     blk = jnp.minimum(pos // block_size, w - 1)
     slots = jnp.take_along_axis(tables, blk, axis=1) * block_size \
         + pos % block_size
     slots = jnp.where(valid, slots, pos % block_size)              # null blk
-    flat = slots.reshape(B * C)
     # each row's C contiguous positions straddle at most
-    # (C-1)//block_size + 2 blocks (incl. the null block)
-    ncand = min(B * ((C - 1) // block_size + 2), B * C)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        qkv = _mm(h, params[pre + "wqkv"])
-        q, kk, vv = jnp.split(qkv, 3, axis=-1)
-        if quant:
-            k_pool, v_pool, k_scale, v_scale = write_kv_quant(
-                k_pool, v_pool, k_scale, v_scale, i, flat,
-                kk.reshape(B * C, H, Dh), vv.reshape(B * C, H, Dh),
-                ncand=ncand)
-            att = paged_attention(q.reshape(B, C, H, Dh), k_pool[i],
-                                  v_pool[i], tables,
-                                  q_starts.astype(jnp.int32),
-                                  block_size, k_scale=k_scale[i],
-                                  v_scale=v_scale[i])
-        else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i, flat,
-                                      kk.reshape(B * C, H, Dh),
-                                      vv.reshape(B * C, H, Dh),
-                                      ncand=ncand)
-            att = paged_attention(q.reshape(B, C, H, Dh), k_pool[i],
-                                  v_pool[i], tables,
-                                  q_starts.astype(jnp.int32),
-                                  block_size)                      # (B,C,H,Dh)
-        x = x + _mm(att.reshape(B, C, D), params[pre + "wo"])
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + _ffn(params, pre, h, cfg)
-    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)              # (B,C,V)
-    if quant:
-        return k_pool, v_pool, k_scale, v_scale, logits
-    return k_pool, v_pool, logits
+    # (C-1)//block_size + 2 blocks, and all padded ones share the null block
+    view = PagedView(pools, tables, q_starts.astype(jnp.int32),
+                     slots.reshape(B * C),
+                     ncand=min(B * ((C - 1) // block_size + 2) + 1, B * C))
+    x = params["embed"][toks] \
+        + params["pos_embed"][jnp.minimum(pos, cfg.max_len - 1)]   # (B,C,D)
+    x = _layers(params, x, cfg, view, shard)
+    return (*view.pools, _logits(params, x))
 
 
 class TransformerLM:
     """Paged-cache adapter for the functional transformer
-    (models/transformer.py): params dict + TransformerConfig."""
+    (models/transformer.py): params dict + TransformerConfig. It holds
+    the step programs of ONE engine's configuration (`bind`), under the
+    four names the engine calls: a gather engine `prefill` and `decode`,
+    a paged engine `prefill_chunk`, `decode` and `spec_score`."""
 
     uses_cache = True
 
@@ -504,14 +321,8 @@ class TransformerLM:
         self.weight_quant = None
         self.params_f32 = None    # original weights once quantized —
                                   # the tp placement + self-draft source
-        self._prefill_jit = None
-        self._decode_jit = None
-        self._decode_paged_jit = None
-        self._prefill_chunk_jit = None
-        self._spec_score_jit = None
-        self._decode_paged_q_jit = None
-        self._prefill_chunk_q_jit = None
-        self._spec_score_q_jit = None
+        self.programs = {}        # operation -> its bound step program
+        self._tp_params = None
 
     def place(self, device):
         """Commit the parameters to one device (a one-chip replica's
@@ -526,8 +337,8 @@ class TransformerLM:
     def quantize_weights(self, mode="int8"):
         """Quantize the matmul weights ONCE at load (ISSUE 20):
         per-channel symmetric int8 for wqkv/wo/w1/w2 (each becomes a
-        `{"q": int8, "s": f32-per-output-channel}` dict the step
-        bodies' `_mm` dispatch consumes); embeddings, positional table,
+        `{"q": int8, "s": f32-per-output-channel}` dict the layer's
+        `_mm` dispatch consumes); embeddings, positional table,
         layer norms, and the LM head stay f32 — they are small, and the
         logits' final projection dominates the error budget. MoE expert
         stacks (3-D w1/w2) stay f32 too. Idempotent; must run BEFORE
@@ -543,235 +354,100 @@ class TransformerLM:
                                          mode=mode)
         self.weight_quant = "int8"
 
-    #: compile-watchdog argument names, shared by every decode/prefill
-    #: signature diff ("tables: shape (1, 1) -> (1, 2) (axis 1)")
-    _DECODE_ARGS = ("params", "k_pool", "v_pool", "tokens", "positions",
-                    "tables")
-    _PREFILL_ARGS = ("params", "k_pool", "v_pool", "tokens", "length",
-                     "table_row")
-    _CHUNK_ARGS = ("params", "k_pool", "v_pool", "tokens", "q_start",
-                   "length", "last_idx", "table_row")
-    _SPEC_ARGS = ("params", "k_pool", "v_pool", "tokens", "q_starts",
-                  "counts", "tables")
-    _DECODE_Q_ARGS = _DECODE_ARGS + ("k_scale", "v_scale")
-    _CHUNK_Q_ARGS = _CHUNK_ARGS + ("k_scale", "v_scale")
-    _SPEC_Q_ARGS = _SPEC_ARGS + ("k_scale", "v_scale")
+    def bind(self, block_size, paged=False, kv_quant=False, mesh=None):
+        """Build the step programs of the configuration `Engine.__init__`
+        resolved, and no other. Every program takes `(params, *pools,
+        *args)` and returns `(*pools, *results)`, the pools in the order
+        of `PagedKVCache.arrays()` (the int8 pool's scale sidecars after
+        `v`), donated.
 
-    def bind(self, block_size, kv_quant=False):
-        cfg = self.cfg
-        instrument = telemetry.introspect.instrument
-        # `variant=` tags each jit's entries in the persistent AOT cache
-        # (mxnet_tpu/aot): the gather and paged decode steps share the
-        # serving.decode SITE and can trace equal signatures — the tag
-        # (plus the lowered-text hash in the key) keeps their disk
-        # entries apart, so a warm load can never swap implementations
-        self._prefill_jit = instrument(_step_jit(
-            "serving_prefill",
-            lambda p, k, v, t, ln, tb: _tf_prefill(p, k, v, t, ln, tb,
-                                                   cfg, block_size),
-            self._PREFILL_ARGS),
-            site="serving.prefill", phase="prefill",
-            argnames=self._PREFILL_ARGS, variant="prefill_dense")
-        self._decode_jit = instrument(_step_jit(
-            "serving_decode",
-            lambda p, k, v, t, pos, tb: _tf_decode(p, k, v, t, pos, tb,
-                                                   cfg, block_size),
-            self._DECODE_ARGS),
-            site="serving.decode", phase="decode",
-            argnames=self._DECODE_ARGS, variant="decode_gather")
-        self._decode_paged_jit = instrument(_step_jit(
-            "serving_decode_paged",
-            lambda p, k, v, t, pos, tb: _tf_decode_paged(
-                p, k, v, t, pos, tb, cfg, block_size),
-            self._DECODE_ARGS),
-            site="serving.decode", phase="decode",
-            argnames=self._DECODE_ARGS, variant="decode_paged")
-        self._prefill_chunk_jit = instrument(_step_jit(
-            "serving_prefill_chunk",
-            lambda p, k, v, t, qs, ln, li, tb: _tf_prefill_chunk(
-                p, k, v, t, qs, ln, li, tb, cfg, block_size),
-            self._CHUNK_ARGS),
-            site="serving.prefill", phase="prefill",
-            argnames=self._CHUNK_ARGS, variant="prefill_chunk")
-        # speculative k+1 scoring (one site, AOT-cacheable): the batched
-        # chunk signature against the live block tables
-        self._spec_score_jit = instrument(_step_jit(
-            "serving_spec_score",
-            lambda p, k, v, t, qs, cn, tb: _tf_spec_score(
-                p, k, v, t, qs, cn, tb, cfg, block_size),
-            self._SPEC_ARGS),
-            site="serving.spec_score", phase="decode",
-            argnames=self._SPEC_ARGS, variant="spec_score")
-        if kv_quant:
-            # int8-pool variants (ISSUE 20): distinct AOT variant tags —
-            # the quant step traces extra scale operands, and a warm
-            # load must never hand the f32 path a quantized executable
-            self._decode_paged_q_jit = instrument(_step_jit(
-                "serving_decode_paged_q8",
-                lambda p, k, v, t, pos, tb, ks, vs: _tf_decode_paged(
-                    p, k, v, t, pos, tb, cfg, block_size,
-                    k_scale=ks, v_scale=vs),
-                self._DECODE_Q_ARGS),
-                site="serving.decode", phase="decode",
-                argnames=self._DECODE_Q_ARGS, variant="decode_paged_q8")
-            self._prefill_chunk_q_jit = instrument(_step_jit(
-                "serving_prefill_chunk_q8",
-                lambda p, k, v, t, qs, ln, li, tb, ks, vs:
-                    _tf_prefill_chunk(p, k, v, t, qs, ln, li, tb, cfg,
-                                      block_size, k_scale=ks,
-                                      v_scale=vs),
-                self._CHUNK_Q_ARGS),
-                site="serving.prefill", phase="prefill",
-                argnames=self._CHUNK_Q_ARGS, variant="prefill_chunk_q8")
-            self._spec_score_q_jit = instrument(_step_jit(
-                "serving_spec_score_q8",
-                lambda p, k, v, t, qs, cn, tb, ks, vs: _tf_spec_score(
-                    p, k, v, t, qs, cn, tb, cfg, block_size,
-                    k_scale=ks, v_scale=vs),
-                self._SPEC_Q_ARGS),
-                site="serving.spec_score", phase="decode",
-                argnames=self._SPEC_Q_ARGS, variant="spec_score_q8")
-
-    def bind_tp(self, block_size, mesh, kv_quant=False):
-        """Build the tensor-parallel step functions over `mesh` (axis
-        'tp'): head-major-resharded params plus shard_map-wrapped
-        decode/prefill-chunk (serving/tp.py). `self.params` stays the
-        untouched replicated oracle for the single-device paths.
-
-        The tp jits register at the SAME watchdog sites as the
-        single-device paths: a tp restart over unchanged shapes is then
+        Over `mesh` (axis 'tp'; paged only) the same step functions run
+        under shard_map on head-major-resharded params (serving/tp.py);
+        `self.params` stays the untouched replicated original. The tp
+        programs register at the SAME watchdog sites as the
+        single-device ones: a tp restart over unchanged shapes is then
         attributed to the params/pool sharding diff, not misread as new
-        traffic shapes."""
-        from .tp import (place_tp_params, build_tp_decode,
-                         build_tp_prefill_chunk, build_tp_spec_score,
-                         tp_cache_variant, quantize_tp_params)
-        instrument = telemetry.introspect.instrument
-        # weight quant composes with tp by quantizing AFTER shard
-        # placement: the f32 originals are resharded, then each chip
-        # quantizes its own shard so scales are chip-local (a
-        # row-parallel shard's per-output-channel scales differ per
-        # chip — each dequantizes its partial before the psum)
-        src = self.params_f32 if self.weight_quant else self.params
-        self._tp_params = place_tp_params(src, self.cfg, mesh)
-        wq = bool(self.weight_quant)
-        if wq:
-            self._tp_params = quantize_tp_params(self._tp_params,
-                                                 self.cfg, mesh)
-        # the tp variant embeds the mesh's DEVICE WINDOW: two replicas'
-        # tp steps have equal shapes and identity-free sharding
-        # descriptions but compile against different chips — their AOT
-        # cache entries must never collide (aot.placement_key covers
-        # committed args; the tag is the belt under that brace)
-        tpv = tp_cache_variant(mesh)
-        self._decode_tp_jit = instrument(
-            build_tp_decode(self.cfg, block_size, mesh, weight_quant=wq),
-            site="serving.decode", phase="decode",
-            argnames=self._DECODE_ARGS, variant="decode_tp:" + tpv)
-        self._prefill_chunk_tp_jit = instrument(
-            build_tp_prefill_chunk(self.cfg, block_size, mesh,
-                                   weight_quant=wq),
-            site="serving.prefill", phase="prefill",
-            argnames=self._CHUNK_ARGS,
-            variant="prefill_chunk_tp:" + tpv)
-        self._spec_score_tp_jit = instrument(
-            build_tp_spec_score(self.cfg, block_size, mesh,
-                                weight_quant=wq),
-            site="serving.spec_score", phase="decode",
-            argnames=self._SPEC_ARGS, variant="spec_score_tp:" + tpv)
-        if kv_quant:
-            self._decode_tp_q_jit = instrument(
-                build_tp_decode(self.cfg, block_size, mesh,
-                                kv_quant=True, weight_quant=wq),
-                site="serving.decode", phase="decode",
-                argnames=self._DECODE_Q_ARGS,
-                variant="decode_tp_q8:" + tpv)
-            self._prefill_chunk_tp_q_jit = instrument(
-                build_tp_prefill_chunk(self.cfg, block_size, mesh,
-                                       kv_quant=True, weight_quant=wq),
-                site="serving.prefill", phase="prefill",
-                argnames=self._CHUNK_Q_ARGS,
-                variant="prefill_chunk_tp_q8:" + tpv)
-            self._spec_score_tp_q_jit = instrument(
-                build_tp_spec_score(self.cfg, block_size, mesh,
-                                    kv_quant=True, weight_quant=wq),
-                site="serving.spec_score", phase="decode",
-                argnames=self._SPEC_Q_ARGS,
-                variant="spec_score_tp_q8:" + tpv)
+        traffic shapes.
 
-    def prefill(self, k, v, tokens, length, table_row):
-        return self._prefill_jit(self.params, k, v, tokens, length,
-                                 table_row)
+        `variant=` tags each program's entries in the persistent AOT
+        cache (mxnet_tpu/aot): the gather and paged decode steps share
+        the serving.decode SITE and can trace equal signatures, and the
+        int8 steps trace extra scale operands — the tag (plus the
+        lowered-text hash in the key) keeps their disk entries apart,
+        so a warm load can never swap implementations."""
+        cfg = self.cfg
+        shard, where = OneChip, ""
+        self._tp_params = None
+        if mesh is not None:
+            shard = tp.HeadShard
+            # the tp variant embeds the mesh's DEVICE WINDOW: two
+            # replicas' tp steps have equal shapes and identity-free
+            # sharding descriptions but compile against different chips
+            # — their AOT cache entries must never collide
+            # (aot.placement_key covers committed args; the tag is the
+            # belt under that brace)
+            where = ":" + tp.tp_cache_variant(mesh)
+            # weight quant composes with tp by quantizing AFTER shard
+            # placement: the f32 originals are resharded, then each chip
+            # quantizes its own shard so scales are chip-local (a
+            # row-parallel shard's per-output-channel scales differ per
+            # chip — each dequantizes its partial before the psum)
+            self._tp_params = tp.place_tp_params(
+                self.params_f32 if self.weight_quant else self.params,
+                cfg, mesh)
+            if self.weight_quant:
+                self._tp_params = tp.quantize_tp_params(self._tp_params,
+                                                        cfg, mesh)
+        kw = dict(cfg=cfg, block_size=block_size, shard=shard)
+        # operation -> (the program's name, its AOT variant, the step)
+        if paged:
+            on_mesh = mesh is not None
+            steps = {
+                "decode": ("decode_tp" if on_mesh else "decode_paged",
+                           functools.partial(decode, view_of=PagedView,
+                                             **kw)),
+                "prefill_chunk": ("prefill_chunk_tp" if on_mesh
+                                  else "prefill_chunk",
+                                  functools.partial(prefill_chunk, **kw)),
+                "spec_score": ("spec_score_tp" if on_mesh else "spec_score",
+                               functools.partial(spec_score, **kw))}
+            q8 = "_q8" if kv_quant else ""
+            steps = {op: (name + q8, name + q8 + where, step)
+                     for op, (name, step) in steps.items()}
+        else:
+            steps = {
+                "prefill": ("prefill", "prefill_dense",
+                            functools.partial(prefill, cfg=cfg)),
+                "decode": ("decode", "decode_gather",
+                           functools.partial(decode, view_of=LiveGatherView,
+                                             **kw))}
+        shard_over = None if mesh is None else (
+            mesh, tp.tp_param_specs(cfg, bool(self.weight_quant)))
+        self.programs = {
+            op: _program(op, "serving_" + name, variant, step,
+                         POOL_ARGS[:4 if kv_quant else 2], shard_over)
+            for op, (name, variant, step) in steps.items()}
 
-    def decode(self, k, v, tokens, positions, tables):
-        return self._decode_jit(self.params, k, v, tokens, positions,
-                                tables)
+    @property
+    def step_params(self):
+        """What every bound program takes first: the parameters, as they
+        are laid over the tp mesh when there is one."""
+        return self.params if self._tp_params is None else self._tp_params
 
-    def decode_paged(self, k, v, tokens, positions, tables):
-        return self._decode_paged_jit(self.params, k, v, tokens,
-                                      positions, tables)
+    def _run(self, op, pools_and_args):
+        return self.programs[op](self.step_params, *pools_and_args)
 
-    def prefill_chunk(self, k, v, tokens, q_start, length, last_idx,
-                      table_row):
-        return self._prefill_chunk_jit(self.params, k, v, tokens, q_start,
-                                       length, last_idx, table_row)
+    def prefill(self, *pools_and_args):
+        return self._run("prefill", pools_and_args)
 
-    def spec_score(self, k, v, tokens, q_starts, counts, tables):
-        return self._spec_score_jit(self.params, k, v, tokens, q_starts,
-                                    counts, tables)
+    def decode(self, *pools_and_args):
+        return self._run("decode", pools_and_args)
 
-    def decode_tp(self, k, v, tokens, positions, tables):
-        return self._decode_tp_jit(self._tp_params, k, v, tokens,
-                                   positions, tables)
+    def prefill_chunk(self, *pools_and_args):
+        return self._run("prefill_chunk", pools_and_args)
 
-    def spec_score_tp(self, k, v, tokens, q_starts, counts, tables):
-        return self._spec_score_tp_jit(self._tp_params, k, v, tokens,
-                                       q_starts, counts, tables)
-
-    def prefill_chunk_tp(self, k, v, tokens, q_start, length, last_idx,
-                         table_row):
-        return self._prefill_chunk_tp_jit(self._tp_params, k, v, tokens,
-                                          q_start, length, last_idx,
-                                          table_row)
-
-    # int8-pool steps (ISSUE 20): same signatures plus the scale
-    # sidecars, returning the updated scales with the pools
-
-    def decode_paged_q(self, k, v, k_scale, v_scale, tokens, positions,
-                       tables):
-        return self._decode_paged_q_jit(self.params, k, v, tokens,
-                                        positions, tables, k_scale,
-                                        v_scale)
-
-    def prefill_chunk_q(self, k, v, k_scale, v_scale, tokens, q_start,
-                        length, last_idx, table_row):
-        return self._prefill_chunk_q_jit(self.params, k, v, tokens,
-                                         q_start, length, last_idx,
-                                         table_row, k_scale, v_scale)
-
-    def spec_score_q(self, k, v, k_scale, v_scale, tokens, q_starts,
-                     counts, tables):
-        return self._spec_score_q_jit(self.params, k, v, tokens,
-                                      q_starts, counts, tables,
-                                      k_scale, v_scale)
-
-    def decode_tp_q(self, k, v, k_scale, v_scale, tokens, positions,
-                    tables):
-        return self._decode_tp_q_jit(self._tp_params, k, v, tokens,
-                                     positions, tables, k_scale,
-                                     v_scale)
-
-    def prefill_chunk_tp_q(self, k, v, k_scale, v_scale, tokens,
-                           q_start, length, last_idx, table_row):
-        return self._prefill_chunk_tp_q_jit(self._tp_params, k, v,
-                                            tokens, q_start, length,
-                                            last_idx, table_row,
-                                            k_scale, v_scale)
-
-    def spec_score_tp_q(self, k, v, k_scale, v_scale, tokens, q_starts,
-                        counts, tables):
-        return self._spec_score_tp_q_jit(self._tp_params, k, v, tokens,
-                                         q_starts, counts, tables,
-                                         k_scale, v_scale)
+    def spec_score(self, *pools_and_args):
+        return self._run("spec_score", pools_and_args)
 
 
 # ---------------------------------------------------------------------------
@@ -1033,10 +709,6 @@ class Engine:
             self.cache = PagedKVCache.of(
                 cspec, block_size=block_size, num_blocks=num_blocks,
                 kv_dtype="int8" if self.kv_quant else None)
-            if self.kv_quant:
-                model.bind(block_size, kv_quant=True)
-            else:
-                model.bind(block_size)
             if tp_req > 1:
                 reason = tp_fallback_reason(model.cfg, self.paged,
                                             tp_req, devices)
@@ -1049,11 +721,8 @@ class Engine:
                         NamedSharding(self.mesh, kv_pool_spec()),
                         NamedSharding(self.mesh, kv_scale_spec())
                         if self.kv_quant else None)
-                    if self.kv_quant:
-                        model.bind_tp(block_size, self.mesh,
-                                      kv_quant=True)
-                    else:
-                        model.bind_tp(block_size, self.mesh)
+            model.bind(block_size, paged=self.paged, kv_quant=self.kv_quant,
+                       mesh=self.mesh)
             if self.tp == 1 and devices:
                 # a one-chip engine that was given a window (the router
                 # gives every replica one) commits its parameters and
@@ -1389,12 +1058,8 @@ class Engine:
             if self._cow_jit is None:
                 # like every step it consumes the pools, so XLA updates
                 # the one block in place
-                if self.kv_quant:
-                    self._cow_jit = jax.jit(copy_block_quant,
-                                            donate_argnums=(0, 1, 2, 3))
-                else:
-                    self._cow_jit = jax.jit(copy_block,
-                                            donate_argnums=(0, 1))
+                self._cow_jit = jax.jit(copy_block, donate_argnums=tuple(
+                    range(len(self.cache.arrays()))))
             try:
                 self._step(self._cow_jit, jnp.int32(src),
                            jnp.int32(fresh[0]))
@@ -1437,15 +1102,10 @@ class Engine:
                 toks[:min(C, L - qs)] = prompt[qs:qs + C]
                 w = pow2_bucket(self.cache.blocks_for(qs + C),
                                 lo=1, hi=self._nblk)
-                if self.kv_quant:
-                    chunk_fn = self.model.prefill_chunk_tp_q \
-                        if self.tp > 1 else self.model.prefill_chunk_q
-                else:
-                    chunk_fn = self.model.prefill_chunk_tp \
-                        if self.tp > 1 else self.model.prefill_chunk
                 with self._count("prefill", (C, w)):
                     logits, = self._step(
-                        chunk_fn, jnp.asarray(toks), jnp.int32(qs),
+                        self.model.prefill_chunk, jnp.asarray(toks),
+                        jnp.int32(qs),
                         jnp.int32(L), jnp.int32(min(L - 1 - qs, C - 1)),
                         jnp.asarray(seq.table_row[:w]))
                 seq.prefilled = min(L, qs + C)
@@ -1565,23 +1225,13 @@ class Engine:
                     step_span.attrs["live_max"] = int(pos.max()) + 1
                     toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
                                        jnp.asarray(tabs))
-                step_fn = self.model.decode
-                if self.paged:
-                    # same (batch, width) signature lattice whether the
-                    # step runs on one chip or sharded over the tp mesh
-                    if self.kv_quant:
-                        step_fn = self.model.decode_tp_q if self.tp > 1 \
-                            else self.model.decode_paged_q
-                    else:
-                        step_fn = self.model.decode_tp if self.tp > 1 \
-                            else self.model.decode_paged
-                    sig = (bb, w)
-                else:
-                    sig = bb
+                # same (batch, width) signature lattice whether the paged
+                # step runs on one chip or sharded over the tp mesh
+                sig = (bb, w) if self.paged else bb
                 with part("serving.decode.dispatch"), \
                         self._count("decode", sig):
-                    logits, nxt, *stats = self._step(step_fn, toks, pos,
-                                                     tabs)
+                    logits, nxt, *stats = self._step(self.model.decode,
+                                                     toks, pos, tabs)
                 with part("serving.decode.readback"):
                     nxt = self._read_back(step_span, nxt, stats)
                     logits = np.asarray(logits) if self.keep_logits \
@@ -1697,16 +1347,11 @@ class Engine:
                 qs[i] = len(s.tokens) - 1
                 counts[i] = 1 + nbs[i]
                 tabs[i] = s.table_row[:w]
-            if self.kv_quant:
-                score_fn = self.model.spec_score_tp_q if self.tp > 1 \
-                    else self.model.spec_score_q
-            else:
-                score_fn = self.model.spec_score_tp if self.tp > 1 \
-                    else self.model.spec_score
             with self._count("decode", ("spec", bb, w)):
                 logits, = self._step(
-                    score_fn, jnp.asarray(toks), jnp.asarray(qs),
-                    jnp.asarray(counts), jnp.asarray(tabs))
+                    self.model.spec_score, jnp.asarray(toks),
+                    jnp.asarray(qs), jnp.asarray(counts),
+                    jnp.asarray(tabs))
             logits = np.asarray(logits)                    # (bb, C, V)
             accepted = proposed = emitted_n = 0
             dur_us = time.perf_counter_ns() // 1000 - t0_us

@@ -46,13 +46,16 @@ import dataclasses
 import math
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..base import MXNetError
+from ..ops.pallas_paged import paged_attention
+from ..parallel.ring_attention import attention_reference
 
 
 #: the names under which every step function takes the pool arrays: the
-#: arguments a step donates (engine `_step_jit`); `kv_pool` is the one
+#: arguments a step donates (engine `_program`); `kv_pool` is the one
 #: array of the latent layout
 POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale", "kv_pool")
 #: lanes of a TPU tile: the latent pool's rows are whole tiles wide
@@ -465,17 +468,20 @@ def write_kv_prompt(k_pool, v_pool, layer, table_row, k_new, v_new):
     return put(k_pool, k_new), put(v_pool, v_new)
 
 
-def copy_block(k_pool, v_pool, src, dst):
-    """Copy one block's K/V across every layer — the prefix cache's
+def copy_block(*pools_src_dst):
+    """Copy one block across every layer of every array of the pool
+    (`PagedKVCache.arrays()`, then src and dst) — the prefix cache's
     copy-on-write op: a request that will write into a shared block
     (its tokens diverge mid-block, or its prompt/decode continues
     inside a cached tail) gets a private copy first, so a shared block
-    is never mutated by a reader. One dynamic-index update per pool;
-    under tensor-parallel placement the block axis is replicated and
-    the head axis sharded, so the copy stays chip-local."""
-    k_pool = k_pool.at[:, dst].set(k_pool[:, src])
-    v_pool = v_pool.at[:, dst].set(v_pool[:, src])
-    return k_pool, v_pool
+    is never mutated by a reader. An int8 pool's scale sidecars move
+    WITH the data: a private copy under the source's scale is
+    bit-identical to the shared original, so prefix-cache divergence
+    stays logit-invariant under quantization. One dynamic-index update
+    per array; under tensor-parallel placement the block axis is
+    replicated and the head axis sharded, so the copy stays chip-local."""
+    *pools, src, dst = pools_src_dst
+    return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
 
 def write_kv_quant(k_pool, v_pool, k_scale, v_scale, layer, slots,
@@ -522,18 +528,6 @@ def write_kv_quant(k_pool, v_pool, k_scale, v_scale, layer, slots,
     return k_pool, v_pool, k_scale, v_scale
 
 
-def copy_block_quant(k_pool, v_pool, k_scale, v_scale, src, dst):
-    """`copy_block` for an int8 pool: the COW copy moves the scale
-    sidecars WITH the data — a private copy under the source's scale is
-    bit-identical to the shared original, so prefix-cache divergence
-    stays logit-invariant under quantization."""
-    k_pool = k_pool.at[:, dst].set(k_pool[:, src])
-    v_pool = v_pool.at[:, dst].set(v_pool[:, src])
-    k_scale = k_scale.at[:, dst].set(k_scale[:, src])
-    v_scale = v_scale.at[:, dst].set(v_scale[:, src])
-    return k_pool, v_pool, k_scale, v_scale
-
-
 def zero_block_scales(k_scale, v_scale, ids):
     """Reset the scale sidecars of freshly ALLOCATED blocks (ids (m,)
     int32, null-padded — zeroing the null block's garbage scale is
@@ -552,15 +546,157 @@ def gather_kv(k_pool, v_pool, layer, block_table):
     block_table (B, nblk) -> k/v (B, nblk, n_heads, block_size,
     head_dim): the blocks AS THEY LIE in the pool, in table order, so
     position t of a sequence is [t // block_size, :, t % block_size].
-    The caller contracts over them as they are (engine `_attend_live`,
-    which hands in a chunk of the table's columns at a time): a
-    transpose to (B, T, n_heads, head_dim) would copy everything
-    gathered once more. Entries past each sequence's length are garbage
-    and must be masked by the caller (`_attend_live`: the chunk's first
-    position + arange(chunk tokens) <= position)."""
+    The caller contracts over them as they are (`_attend_live`, which
+    hands in a chunk of the table's columns at a time): a transpose to
+    (B, T, n_heads, head_dim) would copy everything gathered once more.
+    Entries past each sequence's length are garbage and must be masked
+    by the caller (`_attend_live`: the chunk's first position +
+    arange(chunk tokens) <= position)."""
     # one gather over (layer, block): `pool[layer][table]` has the chip's
     # compiler write the layer's whole slice of the pool out first
     return k_pool[layer, block_table], v_pool[layer, block_table]
+
+
+# ---------------------------------------------------------------------------
+# cache views: how one layer's attention writes its new keys and values and
+# reads the cache. The layer (models/transformer.py `block`) knows a view by
+# `attend(layer, q, k, v)`, heads (N, H, Dh) in and out, and a step program
+# (engine.py) reads the updated arrays off `pools` afterwards, in the order
+# of `PagedKVCache.arrays()`. On a tensor-parallel mesh the same views run
+# on a chip's shard of the heads.
+# ---------------------------------------------------------------------------
+
+
+class PromptView:
+    """Prefill of a whole prompt: the rows are positions 0..S-1 of ONE
+    sequence. Every layer's K/V go into the blocks of `table_row`
+    (`write_kv_prompt`), and attention is dense and causal over the
+    prompt's own K/V: the cache is written, not read."""
+
+    def __init__(self, pools, table_row):
+        self.pools, self.table_row = tuple(pools), table_row
+
+    def attend(self, layer, q, k, v):
+        self.pools = write_kv_prompt(*self.pools, layer, self.table_row,
+                                     k, v)
+        q, k, v = (t.transpose(1, 0, 2)[None] for t in (q, k, v))
+        return attention_reference(q, k, v, causal=True)[0] \
+            .transpose(1, 0, 2)                                # (S, H, Dh)
+
+
+#: keys one pass of the live-gather view's attention loop folds in: a
+#: whole number of blocks (PERF.md, PR 28: 128, 256 and 512 on the chip)
+_DECODE_CHUNK_TOKENS = 128
+
+
+def _attend_live(qh, k_pool, v_pool, layer, tables, positions):
+    """Attention of one query a sequence (qh (B, H, Dh), the newest
+    position) over layer `layer` of the pools, walking the block table
+    only as far as the batch's longest live sequence: ONE loop whose
+    body (a chunk of the table's columns gathered as the blocks lie,
+    contracted, masked by position, folded into a running maximum,
+    denominator and weighted sum in float32: the online softmax of
+    ops/pallas_paged.py) is compiled once and whose trip count is read
+    from `positions` on the device. So the bytes a step moves follow
+    the live lengths with one program per batch bucket and no branch.
+    The pools are only read. Returns (B, H, Dh) float32."""
+    B, H, Dh = qh.shape
+    block_size = k_pool.shape[3]
+    nblk = tables.shape[1]
+    cb = max(1, min(nblk, _DECODE_CHUNK_TOKENS // block_size))
+    ct = cb * block_size
+    scale = 1.0 / math.sqrt(Dh)
+    # whole chunks: the columns added hold the null block, past every
+    # position
+    tables = jnp.pad(tables, ((0, 0), (0, -nblk % cb)))
+    offs = jnp.arange(ct)
+
+    def fold(c, carry):
+        m, l, acc = carry
+        tab = jax.lax.dynamic_slice_in_dim(tables, c * cb, cb, axis=1)
+        ks, vs = gather_kv(k_pool, v_pool, layer, tab)   # (B,cb,H,bs,Dh)
+        # same masking/upcast semantics as attention_reference, with the
+        # length mask standing in for the causal mask (the query IS the
+        # newest position); position t is (block n, offset s) = divmod(t,
+        # block_size), contracted over as the blocks lie in the pool
+        s = jnp.einsum("bhd,bnhsd->bhns", qh, ks).astype(jnp.float32) * scale
+        live = (c * ct + offs)[None, :] <= positions[:, None]     # (B, ct)
+        s = jnp.where(live[:, None, :], s.reshape(B, H, ct), -jnp.inf)
+        # position 0 is live in every row, so `m` is finite from the
+        # first chunk on and a chunk wholly past a row adds exact zeros
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bhns,bnhsd->bhd", p.reshape(B, H, cb, block_size),
+            vs.astype(p.dtype))
+        return m_new, l, acc
+
+    init = (jnp.full((B, H), -jnp.inf, jnp.float32),
+            jnp.zeros((B, H), jnp.float32),
+            jnp.zeros((B, H, Dh), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, jnp.max(positions) // ct + 1, fold,
+                                  init)
+    return acc / l[..., None]
+
+
+class LiveGatherView:
+    """Decode on the gather path: row b is sequence b's newest token at
+    `positions[b]`. Its K/V are appended at `slots` (`append_kv`), then
+    the row attends over its sequence's blocks by table as far as the
+    batch's longest live sequence reaches (`_attend_live`). `tables` is
+    the full-capacity table; a padded row carries the all-null one."""
+
+    def __init__(self, pools, tables, positions, slots):
+        self.pools = tuple(pools)
+        self.tables, self.positions, self.slots = tables, positions, slots
+
+    def attend(self, layer, q, k, v):
+        self.pools = append_kv(*self.pools, layer, self.slots, k, v)
+        return _attend_live(q, *self.pools, layer, self.tables,
+                           self.positions)
+
+
+class PagedView:
+    """The paged path: the rows are B sequences x C consecutive positions
+    from `q_starts` (B,), row-major (C = 1 a decode step, a chunk's or
+    a speculative pass's length otherwise). Their K/V are written at
+    `slots` (B * C,), then ONE ragged paged-attention kernel a layer
+    (ops/pallas_paged.py) walks `tables` (B, w) in place over the
+    layer's planes: its mask `key_pos <= q_starts[b] + i` is the causal
+    mask within the C positions and the full-history mask across the
+    cache. `ncand` is the static bound on distinct blocks the slots
+    touch (`write_kv`); None says one token a sequence (`append_kv`).
+
+    With four arrays in `pools` the pool is int8 and the last two are
+    its scale sidecars: the writes quantize (`write_kv_quant`) and the
+    kernel dequantizes in VMEM. The choice is made while tracing, so the
+    f32 program holds nothing of the other."""
+
+    def __init__(self, pools, tables, q_starts, slots, ncand=None):
+        self.pools = tuple(pools)
+        self.tables, self.q_starts = tables, q_starts
+        self.slots, self.ncand = slots, ncand
+
+    def attend(self, layer, q, k, v):
+        scales = {}
+        if len(self.pools) == 4:
+            self.pools = write_kv_quant(*self.pools, layer, self.slots,
+                                        k, v, ncand=self.ncand)
+            scales = dict(k_scale=self.pools[2][layer],
+                          v_scale=self.pools[3][layer])
+        elif self.ncand is None:
+            self.pools = append_kv(*self.pools, layer, self.slots, k, v)
+        else:
+            self.pools = write_kv(*self.pools, layer, self.slots, k, v,
+                                  ncand=self.ncand)
+        k_pool, v_pool = self.pools[:2]
+        B = self.tables.shape[0]
+        att = paged_attention(q.reshape(B, -1, *q.shape[1:]), k_pool[layer],
+                              v_pool[layer], self.tables, self.q_starts,
+                              k_pool.shape[3], **scales)   # (B, C, H, Dh)
+        return att.reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------

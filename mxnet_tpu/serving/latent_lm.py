@@ -15,21 +15,77 @@ reads them back with the tokens and hands them to `note_step`.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .. import telemetry
 from ..models import latent_moe
-from .engine import _step_jit
-from .kv_cache import CacheSpec
+from .engine import _program
+from .kv_cache import (CacheSpec, append_latent, write_latent_prompt,
+                       gather_latent, flat_slots)
+
+
+class PromptView(latent_moe.DenseView):
+    """Prefill: the whole prompt's latents go into the blocks of
+    `table_row`, then every position attends by the expanded form."""
+
+    def __init__(self, pool, table_row):
+        self.pool, self.table_row = pool, table_row
+
+    def attend(self, layer, q_nope, q_rope, latent, wk_b, wv_b, cfg):
+        self.pool = write_latent_prompt(self.pool, layer, self.table_row,
+                                        latent)
+        return super().attend(layer, q_nope, q_rope, latent, wk_b, wv_b, cfg)
+
+
+class DecodeView:
+    """Decode: row b is sequence b's token at `positions[b]`; append its
+    latent, gather the sequence's blocks by table, attend absorbed."""
+
+    def __init__(self, pool, tables, positions):
+        self.pool, self.tables = pool, tables
+        bs = pool.shape[2]
+        self.slots = flat_slots(tables, positions, bs)
+        self.live = jnp.arange(tables.shape[1] * bs)[None, :] \
+            <= positions[:, None]
+
+    def attend(self, layer, q_nope, q_rope, latent, wk_b, wv_b, cfg):
+        self.pool = append_latent(self.pool, layer, self.slots, latent)
+        cached = gather_latent(self.pool, layer, self.tables)
+        return latent_moe.absorbed_attention(q_nope, q_rope, cached,
+                                             self.live, wk_b, wv_b, cfg)
+
+
+def prefill(params, pool, tokens, length, table_row, cfg):
+    """One padded prompt (S,) of true `length`: writes every layer's
+    latents into the blocks of `table_row` and returns (pool, logits at
+    position length-1, pairs per (expert layer, held expert)). Padded
+    positions lie after the real ones, so no real position attends to
+    them; what they write is overwritten by decode before it is read."""
+    positions = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    view = PromptView(pool, table_row)
+    x, counts = latent_moe._trunk(params, tokens, positions,
+                                  positions < length, cfg, view)
+    return view.pool, latent_moe._logits(params, x[length - 1], cfg), counts
+
+
+def decode(params, pool, tokens, positions, tables, cfg):
+    """One decode step of a padded batch: tokens (B,) at positions (B,),
+    block tables (B, nblk). A padded row carries the all-null table: it
+    writes to the null block, is routed to no expert and its logits are
+    dropped by the caller. Returns (pool, logits (B, vocab), greedy next
+    token (B,), pairs per (expert layer, held expert))."""
+    view = DecodeView(pool, tables, positions)
+    x, counts = latent_moe._trunk(params, tokens, positions,
+                                  tables[:, 0] != 0, cfg, view)
+    logits = latent_moe._logits(params, x, cfg)
+    return (view.pool, logits, jnp.argmax(logits, -1).astype(jnp.int32),
+            counts)
 
 
 class LatentMoELM:
     """params dict + `LatentMoEConfig` (models/latent_moe.py)."""
 
     uses_cache = True
-
-    _PREFILL_ARGS = ("params", "kv_pool", "tokens", "length", "table_row")
-    _DECODE_ARGS = ("params", "kv_pool", "tokens", "positions", "tables")
 
     def __init__(self, params, cfg):
         self.params = params
@@ -51,23 +107,19 @@ class LatentMoELM:
         return CacheSpec(self.cfg.n_layers, self.params["embed"].dtype,
                          latent_dim=self.cfg.latent_dim)
 
-    def bind(self, block_size):
+    def bind(self, block_size, paged=False, kv_quant=False, mesh=None):
+        """The family's two step programs. It has the gather path only:
+        an engine resolves the other three to off before it binds
+        (`Engine.paged_fallback`)."""
         cfg = self.cfg
-        instrument = telemetry.introspect.instrument
-        self._prefill_jit = instrument(_step_jit(
-            "serving_prefill",
-            lambda p, kv, t, ln, tb: latent_moe.prefill(p, kv, t, ln, tb,
-                                                        cfg),
-            self._PREFILL_ARGS),
-            site="serving.prefill", phase="prefill",
-            argnames=self._PREFILL_ARGS, variant="prefill_latent")
-        self._decode_jit = instrument(_step_jit(
-            "serving_decode",
-            lambda p, kv, t, pos, tb: latent_moe.decode(p, kv, t, pos, tb,
-                                                        cfg),
-            self._DECODE_ARGS),
-            site="serving.decode", phase="decode",
-            argnames=self._DECODE_ARGS, variant="decode_latent")
+        self._prefill_jit = _program(
+            "prefill", "serving_prefill", "prefill_latent",
+            lambda p, pools, t, ln, tb: prefill(p, *pools, t, ln, tb, cfg),
+            ("kv_pool",))
+        self._decode_jit = _program(
+            "decode", "serving_decode", "decode_latent",
+            lambda p, pools, t, pos, tb: decode(p, *pools, t, pos, tb, cfg),
+            ("kv_pool",))
 
     def prefill(self, kv, tokens, length, table_row):
         return self._prefill_jit(self.params, kv, tokens, length, table_row)

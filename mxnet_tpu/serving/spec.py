@@ -5,7 +5,7 @@ latency lever in the serving stack. Speculation (Leviathan et al.,
 arXiv:2211.17192) breaks that coupling: a small DRAFT model proposes k
 tokens autoregressively (cheap — the draft is tiny), then the TARGET
 scores all k+1 positions in ONE ragged paged pass reusing the chunked
-multi-token machinery `_tf_prefill_chunk` already proved out against the
+multi-token machinery `prefill_chunk` (engine.py) already proved out against the
 live block tables. A verification rule accepts a prefix of the draft so
 the emitted distribution is EXACTLY the target's:
 

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
-from ..ops.quantization import maybe_quant_matmul as _mm
 from ..parallel.mesh import build_mesh
 from ..parallel.collectives import allreduce
 
@@ -86,11 +85,8 @@ def tp_cache_variant(mesh):
     replicas' tp steps trace EQUAL signatures (the sharding description
     is deliberately identity-free) but compile against different chips —
     this tag keeps their persistent cache entries apart."""
-    try:
-        ids = ",".join(str(d.id) for d in mesh.devices.flat)
-    except Exception:                                    # pragma: no cover
-        ids = "?"
-    return "tp%d@%s" % (mesh.shape.get(TP_AXIS, 1), ids)
+    return "tp%d@%s" % (mesh.shape.get(TP_AXIS, 1),
+                        ",".join(str(d.id) for d in mesh.devices.flat))
 
 
 def kv_pool_spec():
@@ -211,290 +207,40 @@ def quantize_tp_params(tp_params, cfg, mesh):
 
 
 # ---------------------------------------------------------------------------
-# the sharded step bodies (run inside shard_map: every array is the
-# per-chip LOCAL shard; heads dimension is H/k)
+# the sharded steps: engine.py's step functions run inside shard_map, where
+# every array is the per-chip LOCAL shard and the heads dimension is H/k
 # ---------------------------------------------------------------------------
 
 
-def _local_qkv(h, wqkv_local, Dh):
-    """h (S, D) @ head-major wqkv shard -> per-head q/kk/vv (S, Hl, Dh)."""
-    S = h.shape[0]
-    qkv = _mm(h, wqkv_local).reshape(S, -1, 3, Dh)
-    return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+class HeadShard:
+    """One chip's share of the layer (models/transformer.py `block`, the
+    tensor-parallel answer beside `OneChip`): q/k/v and the pool carry
+    only this chip's heads, `wqkv`'s column shard is head-major
+    (`reorder_qkv_heads`), and the two row-parallel products (`wo`,
+    `w2`) are partial sums that a psum over the tp axis closes. The
+    residual stream is replicated by construction after every psum, so
+    the logits (and the argmax) are identical on every chip; per-head
+    int8 scales shard with the heads, so a quantized pool stays exact."""
+
+    @staticmethod
+    def heads(qkv, head_dim):
+        """(..., Hl * 3 * head_dim) head-major -> q, k, v (N, Hl, head_dim)."""
+        qkv = qkv.reshape(-1, qkv.shape[-1] // (3 * head_dim), 3, head_dim)
+        return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+    @staticmethod
+    def close(y):
+        return allreduce(y, TP_AXIS)
 
 
-def _decode_body(params, k_pool, v_pool, tokens, positions, tables, cfg,
-                 block_size, k_scale=None, v_scale=None):
-    """Per-chip half of `engine._tf_decode_paged`: same contract, but
-    q/k/v and the pool carry only this chip's heads and the output/FFN
-    projections psum over the tp axis. The residual stream `x` is
-    replicated-by-construction after every psum, so the logits (and the
-    argmax) are identical on every chip. With `k_scale`/`v_scale`
-    (ISSUE 20) the LOCAL head shard quantizes with its own sidecar
-    slice — scales are per-head, so head-sharding them is exact."""
-    from ..models.transformer import _layer_norm
-    from ..ops.pallas_paged import paged_attention
-    from .kv_cache import flat_slots, append_kv, write_kv_quant
-
-    quant = k_scale is not None
-    B = tokens.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
-    x = params["embed"][tokens] + params["pos_embed"][positions]
-    slots = flat_slots(tables, positions, block_size)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        q, kk, vv = _local_qkv(h, params[pre + "wqkv"], Dh)
-        if quant:
-            k_pool, v_pool, k_scale, v_scale = write_kv_quant(
-                k_pool, v_pool, k_scale, v_scale, i, slots, kk, vv)
-            att = paged_attention(q[:, None], k_pool[i], v_pool[i],
-                                  tables, positions, block_size,
-                                  k_scale=k_scale[i],
-                                  v_scale=v_scale[i])[:, 0]
-        else:
-            k_pool, v_pool = append_kv(k_pool, v_pool, i, slots, kk, vv)
-            att = paged_attention(q[:, None], k_pool[i], v_pool[i],
-                                  tables, positions,
-                                  block_size)[:, 0]          # (B,Hl,Dh)
-        x = x + allreduce(_mm(att.reshape(B, -1), params[pre + "wo"]),
-                          TP_AXIS)
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + allreduce(
-            _mm(jax.nn.relu(_mm(h, params[pre + "w1"])),
-                params[pre + "w2"]),
-            TP_AXIS)
-    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)
-    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-    if quant:
-        return k_pool, v_pool, k_scale, v_scale, logits, nxt
-    return k_pool, v_pool, logits, nxt
-
-
-def _prefill_chunk_body(params, k_pool, v_pool, toks, qs, length,
-                        last_idx, table_row, cfg, block_size,
-                        k_scale=None, v_scale=None):
-    """Per-chip half of `engine._tf_prefill_chunk` (one fixed-shape
-    chunk of ONE sequence): identical null-block padding semantics, this
-    chip's heads only, psum on the two output projections."""
-    from ..models.transformer import _layer_norm
-    from ..ops.pallas_paged import paged_attention
-    from .kv_cache import write_kv, write_kv_quant
-
-    quant = k_scale is not None
-    C = toks.shape[0]
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
-    pos = qs + jnp.arange(C)
-    x = params["embed"][toks] + params["pos_embed"][pos]
-    slots = jnp.take(table_row, pos // block_size) * block_size \
-        + pos % block_size
-    slots = jnp.where(pos < length, slots, pos % block_size)   # null blk
-    tables = table_row[None]
-    qs_row = jnp.reshape(qs, (1,)).astype(jnp.int32)
-    ncand = (C - 1) // block_size + 2
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        q, kk, vv = _local_qkv(h, params[pre + "wqkv"], Dh)
-        if quant:
-            k_pool, v_pool, k_scale, v_scale = write_kv_quant(
-                k_pool, v_pool, k_scale, v_scale, i, slots, kk, vv,
-                ncand=ncand)
-            att = paged_attention(q[None], k_pool[i], v_pool[i],
-                                  tables, qs_row, block_size,
-                                  k_scale=k_scale[i],
-                                  v_scale=v_scale[i])[0]
-        else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i, slots, kk, vv,
-                                      ncand=ncand)
-            att = paged_attention(q[None], k_pool[i], v_pool[i], tables,
-                                  qs_row, block_size)[0]      # (C,Hl,Dh)
-        x = x + allreduce(_mm(att.reshape(C, -1), params[pre + "wo"]),
-                          TP_AXIS)
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + allreduce(
-            _mm(jax.nn.relu(_mm(h, params[pre + "w1"])),
-                params[pre + "w2"]),
-            TP_AXIS)
-    h_last = _layer_norm(x[last_idx], params["lnf_g"], params["lnf_b"])
-    logits = (h_last @ params["head"]).astype(jnp.float32)
-    if quant:
-        return k_pool, v_pool, k_scale, v_scale, logits
-    return k_pool, v_pool, logits
-
-
-def _spec_score_body(params, k_pool, v_pool, toks, q_starts, counts,
-                     tables, cfg, block_size, k_scale=None,
-                     v_scale=None):
-    """Per-chip half of `engine._tf_spec_score` (the speculative k+1
-    scoring pass): same position/null-block semantics, this chip's
-    heads only, psum on the two output projections. The residual stream
-    stays replicated after every psum, so every chip computes identical
-    (B, C, V) logits — greedy verification on the host sees the same
-    argmaxes whether the target is sharded or not (placement, never
-    logits)."""
-    from ..models.transformer import _layer_norm
-    from ..ops.pallas_paged import paged_attention
-    from .kv_cache import write_kv, write_kv_quant
-
-    quant = k_scale is not None
-    B, C = toks.shape
-    D, H = cfg.d_model, cfg.n_heads
-    Dh = D // H
-    w = tables.shape[1]
-    pos = q_starts[:, None] + jnp.arange(C)[None, :]
-    valid = jnp.arange(C)[None, :] < counts[:, None]
-    pe = jnp.minimum(pos, cfg.max_len - 1)
-    x = params["embed"][toks] + params["pos_embed"][pe]        # (B,C,D)
-    blk = jnp.minimum(pos // block_size, w - 1)
-    slots = jnp.take_along_axis(tables, blk, axis=1) * block_size \
-        + pos % block_size
-    slots = jnp.where(valid, slots, pos % block_size)          # null blk
-    flat = slots.reshape(B * C)
-    ncand = min(B * ((C - 1) // block_size + 2), B * C)
-    for i in range(cfg.n_layers):
-        pre = "layer%d_" % i
-        h = _layer_norm(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-        q, kk, vv = _local_qkv(h.reshape(B * C, D),
-                               params[pre + "wqkv"], Dh)
-        if quant:
-            k_pool, v_pool, k_scale, v_scale = write_kv_quant(
-                k_pool, v_pool, k_scale, v_scale, i, flat, kk, vv,
-                ncand=ncand)
-            att = paged_attention(q.reshape(B, C, -1, Dh), k_pool[i],
-                                  v_pool[i], tables,
-                                  q_starts.astype(jnp.int32),
-                                  block_size, k_scale=k_scale[i],
-                                  v_scale=v_scale[i])
-        else:
-            k_pool, v_pool = write_kv(k_pool, v_pool, i, flat, kk, vv,
-                                      ncand=ncand)
-            att = paged_attention(q.reshape(B, C, -1, Dh), k_pool[i],
-                                  v_pool[i], tables,
-                                  q_starts.astype(jnp.int32),
-                                  block_size)                  # (B,C,Hl,Dh)
-        x = x + allreduce(_mm(att.reshape(B, C, -1), params[pre + "wo"]),
-                          TP_AXIS)
-        h = _layer_norm(x, params[pre + "ln2_g"], params[pre + "ln2_b"])
-        x = x + allreduce(
-            _mm(jax.nn.relu(_mm(h, params[pre + "w1"])),
-                params[pre + "w2"]),
-            TP_AXIS)
-    h = _layer_norm(x, params["lnf_g"], params["lnf_b"])
-    logits = (h @ params["head"]).astype(jnp.float32)          # (B,C,V)
-    if quant:
-        return k_pool, v_pool, k_scale, v_scale, logits
-    return k_pool, v_pool, logits
-
-
-def _tp_step(fn, mesh, in_specs, out_specs):
-    """jit(shard_map(fn)) that CONSUMES its pools, like the single-device
-    steps (engine `_step_jit`): every argument laid out as a pool or a
-    scale sidecar is donated, so each chip updates its shard in place."""
-    pools = (kv_pool_spec(), kv_scale_spec())
-    return jax.jit(
-        jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False),
-        donate_argnums=tuple(i for i, spec in enumerate(in_specs)
-                             if spec in pools))
-
-
-def build_tp_decode(cfg, block_size, mesh, kv_quant=False,
-                    weight_quant=False):
-    """jit(shard_map(decode)) over the tp mesh. Signature matches the
-    single-device `_decode_paged_jit`: (params, k, v, tokens, positions,
-    tables) -> (k, v, logits, next); with `kv_quant` the scale sidecars
-    ride along at the end of both tuples (matching the `_q` jits)."""
-    specs = tp_param_specs(cfg, weight_quant)
-    pool = kv_pool_spec()
-    sc = kv_scale_spec()
-
-    if kv_quant:
-        def serving_decode_tp_q8(params, k, v, toks, pos, tabs, ks, vs):
-            return _decode_body(params, k, v, toks, pos, tabs, cfg,
-                                block_size, k_scale=ks, v_scale=vs)
-
-        return _tp_step(
-            serving_decode_tp_q8, mesh,
-            in_specs=(specs, pool, pool, P(None), P(None),
-                      P(None, None), sc, sc),
-            out_specs=(pool, pool, sc, sc, P(None, None), P(None)))
-
-    def serving_decode_tp(params, k, v, toks, pos, tabs):
-        return _decode_body(params, k, v, toks, pos, tabs, cfg,
-                            block_size)
-
-    return _tp_step(
-        serving_decode_tp, mesh,
-        in_specs=(specs, pool, pool, P(None), P(None), P(None, None)),
-        out_specs=(pool, pool, P(None, None), P(None)))
-
-
-def build_tp_prefill_chunk(cfg, block_size, mesh, kv_quant=False,
-                           weight_quant=False):
-    """jit(shard_map(prefill_chunk)) over the tp mesh. Signature matches
-    the single-device `_prefill_chunk_jit`: (params, k, v, toks, qs,
-    length, last_idx, table_row) -> (k, v, logits)."""
-    specs = tp_param_specs(cfg, weight_quant)
-    pool = kv_pool_spec()
-    sc = kv_scale_spec()
-
-    if kv_quant:
-        def serving_prefill_chunk_tp_q8(params, k, v, toks, qs, length,
-                                        last_idx, table_row, ks, vs):
-            return _prefill_chunk_body(params, k, v, toks, qs, length,
-                                       last_idx, table_row, cfg,
-                                       block_size, k_scale=ks,
-                                       v_scale=vs)
-
-        return _tp_step(
-            serving_prefill_chunk_tp_q8, mesh,
-            in_specs=(specs, pool, pool, P(None), P(), P(), P(),
-                      P(None), sc, sc),
-            out_specs=(pool, pool, sc, sc, P(None)))
-
-    def serving_prefill_chunk_tp(params, k, v, toks, qs, length, last_idx,
-                                 table_row):
-        return _prefill_chunk_body(params, k, v, toks, qs, length,
-                                   last_idx, table_row, cfg, block_size)
-
-    return _tp_step(
-        serving_prefill_chunk_tp, mesh,
-        in_specs=(specs, pool, pool, P(None), P(), P(), P(), P(None)),
-        out_specs=(pool, pool, P(None)))
-
-
-def build_tp_spec_score(cfg, block_size, mesh, kv_quant=False,
-                        weight_quant=False):
-    """jit(shard_map(spec_score)) over the tp mesh. Signature matches
-    the single-device `_spec_score_jit`: (params, k, v, tokens,
-    q_starts, counts, tables) -> (k, v, logits (B, C, V))."""
-    specs = tp_param_specs(cfg, weight_quant)
-    pool = kv_pool_spec()
-    sc = kv_scale_spec()
-
-    if kv_quant:
-        def serving_spec_score_tp_q8(params, k, v, toks, qs, counts, tabs,
-                                     ks, vs):
-            return _spec_score_body(params, k, v, toks, qs, counts,
-                                    tabs, cfg, block_size, k_scale=ks,
-                                    v_scale=vs)
-
-        return _tp_step(
-            serving_spec_score_tp_q8, mesh,
-            in_specs=(specs, pool, pool, P(None, None), P(None),
-                      P(None), P(None, None), sc, sc),
-            out_specs=(pool, pool, sc, sc, P(None, None, None)))
-
-    def serving_spec_score_tp(params, k, v, toks, qs, counts, tabs):
-        return _spec_score_body(params, k, v, toks, qs, counts, tabs,
-                                cfg, block_size)
-
-    return _tp_step(
-        serving_spec_score_tp, mesh,
-        in_specs=(specs, pool, pool, P(None, None), P(None), P(None),
-                  P(None, None)),
-        out_specs=(pool, pool, P(None, None, None)))
+def shard_step(fn, mesh, param_specs, n_pools, n_args, n_results):
+    """`fn(params, *pools, *args) -> (*pools, *results)` as one program
+    over the tp mesh: the parameters laid out by `param_specs`, the
+    `n_pools` leading arrays as pools (and, past the second, scale
+    sidecars), everything else replicated. The caller jits it and
+    donates the pools (engine `_program`), so each chip updates its
+    shard in place."""
+    pools = (kv_pool_spec(),) * 2 + (kv_scale_spec(),) * (n_pools - 2)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(param_specs, *pools, *(P(),) * n_args),
+        out_specs=(*pools, *(P(),) * n_results), check_vma=False)

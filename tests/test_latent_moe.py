@@ -24,7 +24,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import latent_moe as lm
 from mxnet_tpu.models.transformer import (TransformerConfig,
                                           init_transformer_params)
-from mxnet_tpu.serving import kv_cache
+from mxnet_tpu.serving import kv_cache, latent_lm
 
 from chipbench.families import latent_moe_lm as family
 from chipbench.reference import latent_moe_lm as reference
@@ -218,8 +218,10 @@ def test_group_limited_selection_against_a_brute_force_ties_included(model):
 def test_a_real_rows_logits_do_not_depend_on_the_padded_rows(model):
     _, params, cfg = model
     pool = jnp.zeros((cfg.n_layers, 12, BS, 128), jnp.float32)
-    prefill = jax.jit(lambda kv, t, n, tb: lm.prefill(params, kv, t, n, tb, cfg))
-    decode = jax.jit(lambda kv, t, p, tb: lm.decode(params, kv, t, p, tb, cfg))
+    prefill = jax.jit(lambda kv, t, n, tb: latent_lm.prefill(params, kv, t, n,
+                                                           tb, cfg))
+    decode = jax.jit(lambda kv, t, p, tb: latent_lm.decode(params, kv, t, p,
+                                                         tb, cfg))
     table = jnp.asarray([1, 2, 0, 0, 0, 0, 0, 0], jnp.int32)
     toks = np.zeros((16,), np.int32)
     toks[:11] = prompt(2, 11)

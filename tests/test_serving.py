@@ -259,7 +259,7 @@ def test_decode_recompile_bound_mixed_lengths(tiny_lm):
         assert eng.decode_compilations <= 4, (
             "decode recompiled %d times" % eng.decode_compilations)
         # cross-check the proxy counter against jax's own jit cache
-        jit_fn = eng.model._decode_jit
+        jit_fn = eng.model.programs["decode"]
         if hasattr(jit_fn, "_cache_size"):
             assert jit_fn._cache_size() <= 4
     finally:

@@ -1,10 +1,11 @@
 """The serving steps update the KV pools in place (ISSUE 26).
 
-Load-bearing claims: (1) every step program of `TransformerLM` (eight on
-one device, six tensor-parallel) donates the pools it is handed: the
-compiled executable aliases at least their bytes, and the arrays handed in
-are deleted by a call; (2) what `serve` emits is token for token what the
-pure step functions (`_tf_prefill`, `_tf_decode`) give under a plain
+Load-bearing claims: (1) every step program `TransformerLM.bind` builds
+(eight on one device over its four configurations, six tensor-parallel)
+donates the pools it is handed: the compiled executable aliases at least
+their bytes, and the arrays handed in are deleted by a call; (2) what
+`serve` emits is token for token what the pure step functions
+(`engine.prefill`, `engine.decode` over the live-gather view) give under a plain
 non-donating `jax.jit` driven here, in the test; (3) a step that fails
 AFTER it consumed the pools costs every sequence its cache, not the server:
 the engine makes the pools anew, drops its prefix cache and raises
@@ -26,6 +27,7 @@ from mxnet_tpu import serving
 from mxnet_tpu.models.transformer import (TransformerConfig,
                                           init_transformer_params)
 from mxnet_tpu.serving import engine as engine_mod
+from mxnet_tpu.serving import kv_cache
 from mxnet_tpu.serving import tp as tp_mod
 
 L, NB, H, BS, DH = 2, 12, 4, 8, 8
@@ -48,44 +50,53 @@ def arith_prompt(start, stride, n, vocab=48):
 # ---------------------------------------------------------------------------
 
 i32 = jnp.int32
-#: attribute stem -> the arguments after (params, k, v), at the sizes above
+#: operation -> the arguments after (params, *pools), at the sizes above; a
+#: paged step takes a table of the live width, the gather decode the full one
 STEP_ARGS = {
-    "prefill": lambda: (jnp.zeros((C,), i32), i32(5), jnp.arange(1, 9, dtype=i32)),
-    "decode": lambda: (jnp.zeros((B,), i32), jnp.asarray([3, 9], i32),
-                       jnp.asarray([[1, 2] + [0] * 6, [3, 4] + [0] * 6], i32)),
-    "decode_paged": lambda: (jnp.zeros((B,), i32), jnp.asarray([3, 9], i32),
-                             jnp.asarray([[1, 2], [3, 4]], i32)),
-    "prefill_chunk": lambda: (jnp.zeros((C,), i32), i32(0), i32(5), i32(4),
-                              jnp.asarray([1, 2], i32)),
-    "spec_score": lambda: (jnp.zeros((B, 3), i32), jnp.asarray([3, 9], i32),
-                           jnp.asarray([3, 2], i32),
-                           jnp.asarray([[1, 2], [3, 4]], i32)),
+    "prefill": lambda paged: (jnp.zeros((C,), i32), i32(5),
+                              jnp.arange(1, 9, dtype=i32)),
+    "decode": lambda paged: (
+        jnp.zeros((B,), i32), jnp.asarray([3, 9], i32),
+        jnp.asarray([[1, 2], [3, 4]] if paged
+                    else [[1, 2] + [0] * 6, [3, 4] + [0] * 6], i32)),
+    "prefill_chunk": lambda paged: (jnp.zeros((C,), i32), i32(0), i32(5),
+                                    i32(4), jnp.asarray([1, 2], i32)),
+    "spec_score": lambda paged: (jnp.zeros((B, 3), i32),
+                                 jnp.asarray([3, 9], i32),
+                                 jnp.asarray([3, 2], i32),
+                                 jnp.asarray([[1, 2], [3, 4]], i32)),
 }
-ONE_DEVICE = ["_prefill_jit", "_decode_jit", "_decode_paged_jit",
-              "_prefill_chunk_jit", "_spec_score_jit", "_decode_paged_q_jit",
-              "_prefill_chunk_q_jit", "_spec_score_q_jit"]
-TENSOR_PARALLEL = ["_decode_tp_jit", "_prefill_chunk_tp_jit",
-                   "_spec_score_tp_jit", "_decode_tp_q_jit",
-                   "_prefill_chunk_tp_q_jit", "_spec_score_tp_q_jit"]
+#: configuration -> what `bind` is told
+CONFIGS = {"gather": dict(), "paged": dict(paged=True),
+           "paged_q8": dict(paged=True, kv_quant=True)}
+PAGED_OPS = ["decode", "prefill_chunk", "spec_score"]
+#: the fourteen programs, by (configuration, operation);
+#: tests/test_span_tree.py lists the name, site and tags of each
+ONE_DEVICE = [("gather", "prefill"), ("gather", "decode")] \
+    + [(c, op) for c in ("paged", "paged_q8") for op in PAGED_OPS]
+TENSOR_PARALLEL = [(c, op) for c in ("paged", "paged_q8") for op in PAGED_OPS]
 
 
-def step_call(model, attr, mesh=None):
-    """(jit, args, indices of the pool arguments) for one step program."""
-    quant = "_q_" in attr
-    stem = attr[1:].replace("_tp", "").replace("_q_jit", "").replace("_jit", "")
+def step_call(model, config, op, mesh=None):
+    """Bind `model` to `config` (over `mesh`) and give (jit, args, indices
+    of the pool arguments) for its step program `op`."""
+    opts = CONFIGS[config]
+    model.bind(BS, mesh=mesh, **opts)
+    assert sorted(model.programs) == sorted(
+        PAGED_OPS if opts.get("paged") else ["prefill", "decode"])
+    quant = bool(opts.get("kv_quant"))
     put = (lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))) \
         if mesh is not None else (lambda x, spec: x)
     dt = jnp.int8 if quant else jnp.float32
     pools = [put(jnp.zeros((L, NB, H, BS, DH), dt), tp_mod.kv_pool_spec())
              for _ in range(2)]
-    args = [model._tp_params if mesh is not None else model.params,
-            *pools, *STEP_ARGS[stem]()]
-    donated = [1, 2]
     if quant:
-        args += [put(jnp.zeros((L, NB, H), jnp.float32),
-                     tp_mod.kv_scale_spec()) for _ in range(2)]
-        donated += [len(args) - 2, len(args) - 1]
-    return getattr(model, attr), args, donated
+        pools += [put(jnp.zeros((L, NB, H), jnp.float32),
+                      tp_mod.kv_scale_spec()) for _ in range(2)]
+    # the pools first, as `PagedKVCache.arrays()` orders them
+    args = [model.step_params,
+            *pools, *STEP_ARGS[op](bool(opts.get("paged")))]
+    return model.programs[op], args, list(range(1, 1 + len(pools)))
 
 
 def assert_consumes_its_pools(jit, args, donated):
@@ -105,27 +116,21 @@ def assert_consumes_its_pools(jit, args, donated):
         assert o.sharding.is_equivalent_to(args[i].sharding, o.ndim)
 
 
-@pytest.mark.parametrize("attr", ONE_DEVICE)
-def test_step_program_aliases_and_consumes_its_pools(tiny_lm, attr):
+@pytest.mark.parametrize("config, op", ONE_DEVICE)
+def test_step_program_aliases_and_consumes_its_pools(tiny_lm, config, op):
     params, cfg = tiny_lm
     model = serving.TransformerLM(params, cfg)
-    model.bind(BS, kv_quant=True)
-    assert sorted(a for a in vars(model) if a.endswith("_jit")) \
-        == sorted(ONE_DEVICE)                   # none is left out above
-    assert_consumes_its_pools(*step_call(model, attr))
+    assert_consumes_its_pools(*step_call(model, config, op))
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="tp steps need >= 2 (emulated) devices")
-@pytest.mark.parametrize("attr", TENSOR_PARALLEL)
-def test_tp_step_program_aliases_and_consumes_its_pools(tiny_lm, attr):
+@pytest.mark.parametrize("config, op", TENSOR_PARALLEL)
+def test_tp_step_program_aliases_and_consumes_its_pools(tiny_lm, config, op):
     params, cfg = tiny_lm
     model = serving.TransformerLM(params, cfg)
     mesh = tp_mod.build_tp_mesh(2, None)
-    model.bind_tp(BS, mesh, kv_quant=True)
-    assert sorted(a for a in vars(model) if "_tp_" in a and a.endswith("_jit")) \
-        == sorted(TENSOR_PARALLEL)
-    assert_consumes_its_pools(*step_call(model, attr, mesh))
+    assert_consumes_its_pools(*step_call(model, config, op, mesh))
 
 
 def test_a_warm_loaded_step_consumes_its_pools_too(tiny_lm, tmp_path):
@@ -141,8 +146,7 @@ def test_a_warm_loaded_step_consumes_its_pools_too(tiny_lm, tmp_path):
         params, cfg = tiny_lm
         for warm in (False, True):
             model = serving.TransformerLM(params, cfg)
-            model.bind(BS)
-            jit, args, donated = step_call(model, "_decode_jit")
+            jit, args, donated = step_call(model, "gather", "decode")
             jit(*args)
             assert jit.warm_loads == int(warm) and jit.compiles == int(not warm)
             assert all(args[i].is_deleted() for i in donated)
@@ -158,18 +162,19 @@ def test_a_warm_loaded_step_consumes_its_pools_too(tiny_lm, tmp_path):
 
 
 def oracle_tokens(params, cfg, prompt, max_new, block_size=BS):
-    """Greedy tokens of one sequence from `_tf_prefill` / `_tf_decode`
-    under plain `jax.jit` (nothing donated; the pools are rebound from the
-    results, as a functional update), on a pool of this test's own."""
+    """Greedy tokens of one sequence from the step functions `prefill` /
+    `decode` (over the live-gather view) under plain `jax.jit` (nothing
+    donated; the pools are rebound from the results, as a functional
+    update), on a pool of this test's own."""
     nblk = cfg.max_len // block_size
     shape = (cfg.n_layers, nblk + 1, cfg.n_heads, block_size,
              cfg.d_model // cfg.n_heads)
     k, v = jnp.zeros(shape), jnp.zeros(shape)
     row = jnp.arange(1, nblk + 1, dtype=i32)
-    prefill = jax.jit(lambda p, k, v, t, n, tb: engine_mod._tf_prefill(
-        p, k, v, t, n, tb, cfg, block_size))
-    decode = jax.jit(lambda p, k, v, t, pos, tb: engine_mod._tf_decode(
-        p, k, v, t, pos, tb, cfg, block_size))
+    prefill = jax.jit(lambda p, k, v, t, n, tb: engine_mod.prefill(
+        p, (k, v), t, n, tb, cfg))
+    decode = jax.jit(lambda p, k, v, t, pos, tb: engine_mod.decode(
+        p, (k, v), t, pos, tb, cfg, block_size, kv_cache.LiveGatherView))
     s_pad = engine_mod.pow2_bucket(len(prompt), lo=8, hi=cfg.max_len)
     toks = np.zeros((s_pad,), np.int32)
     toks[:len(prompt)] = prompt
@@ -248,7 +253,7 @@ def test_engine_remakes_the_pools_under_the_same_placement(tiny_lm):
     assert len(eng.prefix_cache) > 0
     seq = eng.start(arith_prompt(1, 1, 20), max_new=6)
     assert seq.cache_hit_tokens > 0
-    fail_once(eng.model, "decode_paged_q", consumed=True)
+    fail_once(eng.model, "decode", consumed=True)
     with pytest.raises(serving.PoolsLost, match="replay every"):
         eng.decode_step([seq])
     assert eng.pools_lost == 1 and not eng.cache.lost()
@@ -274,7 +279,7 @@ def test_a_fault_before_the_launch_leaves_the_pools_and_the_cache(tiny_lm):
     resident = len(eng.prefix_cache)
     seq = eng.start(arith_prompt(1, 1, 20), max_new=6)
     k = eng.cache.k
-    fail_once(eng.model, "decode_paged", consumed=False)
+    fail_once(eng.model, "decode", consumed=False)
     with pytest.raises(RuntimeError, match="injected"):     # as it is
         eng.decode_step([seq])
     assert eng.pools_lost == 0 and eng.cache.k is k
@@ -313,7 +318,7 @@ SERVERS = {
     "gather_decode": (dict(), "decode", 3),
     "gather_prefill": (dict(), "prefill", 2),
     "paged_decode": (dict(paged=True, prefix_cache=True, prefill_chunk=8),
-                     "decode_paged", 3),
+                     "decode", 3),
     "paged_chunk": (dict(paged=True, prefix_cache=True, prefill_chunk=8),
                     "prefill_chunk", 4),
 }

@@ -1,7 +1,7 @@
 """The gather decode step walks its block table only as far as the longest
 live sequence (ISSUE 28).
 
-Load-bearing claims: (a) `_tf_decode`'s logits are those of a plain masked
+Load-bearing claims: (a) the gather `decode` step's logits are those of a plain masked
 softmax over the table's full width, written here, at ragged lengths on
 both sides of a chunk boundary; (b) what `serve` emits is token for token
 what the step's earlier form (one contraction over the full width, kept
@@ -29,7 +29,7 @@ from mxnet_tpu.serving import kv_cache
 H, DH, BS, L = 4, 8, 16, 2
 MAX_LEN = 512                               # 32 blocks: four chunks of 128
 NBLK = MAX_LEN // BS
-CHUNK = engine_mod._DECODE_CHUNK_TOKENS
+CHUNK = kv_cache._DECODE_CHUNK_TOKENS
 i32 = jnp.int32
 
 
@@ -74,6 +74,12 @@ def full_width_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
     return k_pool, v_pool, logits, jnp.argmax(logits, -1).astype(jnp.int32)
 
 
+def gather_decode(cfg):
+    """The gather engine's decode step function, pools as two arguments."""
+    return lambda p, k, v, t, pos, tb: engine_mod.decode(
+        p, (k, v), t, pos, tb, cfg, BS, kv_cache.LiveGatherView)
+
+
 def filled_pools(cfg, n_rows, seed=1):
     """Pools whose every slot holds noise (what a reused block holds past a
     sequence's length must not reach the logits) and a table a row."""
@@ -105,10 +111,9 @@ def test_logits_are_the_full_width_masked_softmaxs(lm, case):
     pos = np.asarray([n - 1 for n in lengths] + [0] * padded, np.int32)
     toks = (np.arange(len(pos), dtype=np.int32) * 7 + 3) % cfg.vocab
     args = (params, k, v, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(tables), cfg, BS)
-    want_k, want_v, want, want_next = full_width_decode(*args)
-    got_k, got_v, got, got_next = jax.jit(
-        engine_mod._tf_decode, static_argnums=(6, 7))(*args)
+            jnp.asarray(tables))
+    want_k, want_v, want, want_next = full_width_decode(*args, cfg, BS)
+    got_k, got_v, got, got_next = jax.jit(gather_decode(cfg))(*args)
     live = len(lengths)
     np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live],
                                rtol=1e-4, atol=1e-5)
@@ -131,13 +136,14 @@ def arith_prompt(start, stride, n, vocab=48):
 
 
 def reference_tokens(params, cfg, prompt, max_new):
-    """Greedy tokens of one sequence from `_tf_prefill` and the full-width
-    step above, under a plain `jax.jit`, on a pool of this test's own."""
+    """Greedy tokens of one sequence from the `prefill` step function and
+    the full-width step above, under a plain `jax.jit`, on a pool of this
+    test's own."""
     shape = (cfg.n_layers, NBLK + 1, H, BS, DH)
     k, v = jnp.zeros(shape), jnp.zeros(shape)
     row = jnp.arange(1, NBLK + 1, dtype=i32)
-    prefill = jax.jit(lambda p, k, v, t, n, tb: engine_mod._tf_prefill(
-        p, k, v, t, n, tb, cfg, BS))
+    prefill = jax.jit(lambda p, k, v, t, n, tb: engine_mod.prefill(
+        p, (k, v), t, n, tb, cfg))
     decode = jax.jit(lambda p, k, v, t, pos, tb: full_width_decode(
         p, k, v, t, pos, tb, cfg, BS))
     toks = np.zeros((engine_mod.pow2_bucket(len(prompt), lo=8),), np.int32)
@@ -242,7 +248,7 @@ def test_the_step_is_one_loop_a_layer_and_updates_its_pools_in_place(lm):
     model.bind(BS)
     pool = (L, 2 * NBLK + 1, H, BS, DH)
     shapes = step_shapes(cfg, 2, pool, jnp.float32)
-    compiled = model._decode_jit.lower(*shapes).compile()
+    compiled = model.programs["decode"].lower(*shapes).compile()
     assert_one_loop_a_layer(compiled, cfg, shapes[1])
 
 
@@ -268,13 +274,10 @@ def test_the_cells_step_compiles_for_the_chip_as_one_loop_a_layer(one_chip):
     cfg = TransformerConfig(vocab=50272, d_model=4096, n_heads=32, n_layers=2,
                             d_ff=16384, max_len=2048, dtype=jnp.bfloat16)
     pool = (2, 1025, 32, 16, 128)
-    step = engine_mod._step_jit(
-        "serving_decode",
-        lambda p, k, v, t, pos, tb: engine_mod._tf_decode(
-            p, k, v, t, pos, tb, cfg, 16),
-        serving.TransformerLM._DECODE_ARGS)
     shapes = step_shapes(cfg, 16, pool, jnp.bfloat16, one_chip)
-    compiled = step.lower(*shapes).compile()
+    model = serving.TransformerLM(shapes[0], cfg)
+    model.bind(16)
+    compiled = model.programs["decode"].lower(*shapes).compile()
     assert_one_loop_a_layer(compiled, cfg, shapes[1])
     assert "bf16[%s]" % ",".join(map(str, pool[1:])) not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9

@@ -38,7 +38,7 @@ from mxnet_tpu.models.transformer import (TransformerConfig,
 from mxnet_tpu.ops.pallas_paged import (paged_attention, paged_call_cost,
                                         paged_fallback_reason)
 from mxnet_tpu.serving.kv_cache import (PagedKVCache, write_kv_quant,
-                                        copy_block_quant,
+                                        copy_block,
                                         zero_block_scales)
 
 
@@ -186,7 +186,7 @@ def test_cow_copies_scales_and_reclaim_rezeroes():
     slots = jnp.asarray([4, 5, 6, 7], jnp.int32)
     k, v, ks, vs = write_kv_quant(c.k, c.v, c.k_scale, c.v_scale, 0,
                                   slots, big, big)
-    k, v, ks, vs = copy_block_quant(k, v, ks, vs, 1, 2)
+    k, v, ks, vs = copy_block(k, v, ks, vs, 1, 2)
     np.testing.assert_array_equal(np.asarray(k)[0, 2], np.asarray(k)[0, 1])
     np.testing.assert_array_equal(np.asarray(ks)[0, 2],
                                   np.asarray(ks)[0, 1])

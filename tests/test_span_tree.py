@@ -276,24 +276,72 @@ def test_trainstep_records_train_dispatch_and_keeps_the_profilers_names():
 # -- program names -------------------------------------------------------------
 
 
-def test_the_step_programs_carry_their_own_names(tiny_lm):
+#: what `TransformerLM.bind` registers for each configuration: operation ->
+#: (program name, watchdog site, phase, AOT variant; `@` stands for the tp
+#: mesh's device window). The benchmark's readers, the AOT cache and the
+#: watchdog key on every one of them.
+PROGRAMS = {
+    "gather": (dict(), {
+        "prefill": ("serving_prefill", "serving.prefill", "prefill",
+                    "prefill_dense"),
+        "decode": ("serving_decode", "serving.decode", "decode",
+                   "decode_gather")}),
+    "paged": (dict(paged=True), {
+        "decode": ("serving_decode_paged", "serving.decode", "decode",
+                   "decode_paged"),
+        "prefill_chunk": ("serving_prefill_chunk", "serving.prefill",
+                          "prefill", "prefill_chunk"),
+        "spec_score": ("serving_spec_score", "serving.spec_score", "decode",
+                       "spec_score")}),
+    "paged_q8": (dict(paged=True, kv_quant=True), {
+        "decode": ("serving_decode_paged_q8", "serving.decode", "decode",
+                   "decode_paged_q8"),
+        "prefill_chunk": ("serving_prefill_chunk_q8", "serving.prefill",
+                          "prefill", "prefill_chunk_q8"),
+        "spec_score": ("serving_spec_score_q8", "serving.spec_score",
+                       "decode", "spec_score_q8")}),
+    "tp": (dict(paged=True, tp=2), {
+        "decode": ("serving_decode_tp", "serving.decode", "decode",
+                   "decode_tp:@"),
+        "prefill_chunk": ("serving_prefill_chunk_tp", "serving.prefill",
+                          "prefill", "prefill_chunk_tp:@"),
+        "spec_score": ("serving_spec_score_tp", "serving.spec_score",
+                       "decode", "spec_score_tp:@")}),
+    "tp_q8": (dict(paged=True, kv_quant=True, tp=2), {
+        "decode": ("serving_decode_tp_q8", "serving.decode", "decode",
+                   "decode_tp_q8:@"),
+        "prefill_chunk": ("serving_prefill_chunk_tp_q8", "serving.prefill",
+                          "prefill", "prefill_chunk_tp_q8:@"),
+        "spec_score": ("serving_spec_score_tp_q8", "serving.spec_score",
+                       "decode", "spec_score_tp_q8:@")}),
+}
+
+
+@pytest.mark.parametrize("config", sorted(PROGRAMS))
+def test_the_step_programs_carry_their_own_names(tiny_lm, config):
     """A device trace names a program after the function handed to
-    `jax.jit`: every serving step and the train step can be told apart."""
+    `jax.jit`: every serving step and the train step can be told apart.
+    A configuration binds its own programs and no other's."""
+    from mxnet_tpu.serving import tp as tp_mod
     params, cfg = tiny_lm
+    opts, want = PROGRAMS[config]
+    opts = dict(opts)
+    mesh = tp_mod.build_tp_mesh(opts.pop("tp"), None) if "tp" in opts else None
     model = serving.TransformerLM(params, cfg)
-    model.bind(8, kv_quant=True)
-    names = {attr: getattr(model, attr).__wrapped__.__name__
-             for attr in vars(model) if attr.endswith("_jit")}
-    assert names == {
-        "_prefill_jit": "serving_prefill", "_decode_jit": "serving_decode",
-        "_decode_paged_jit": "serving_decode_paged",
-        "_prefill_chunk_jit": "serving_prefill_chunk",
-        "_spec_score_jit": "serving_spec_score",
-        "_decode_paged_q_jit": "serving_decode_paged_q8",
-        "_prefill_chunk_q_jit": "serving_prefill_chunk_q8",
-        "_spec_score_q_jit": "serving_spec_score_q8"}
+    model.bind(8, mesh=mesh, **opts)
+    where = tp_mod.tp_cache_variant(mesh) if mesh is not None else ""
+    assert {op: (jit.__wrapped__.__name__, jit.site, jit._phase, jit._variant)
+            for op, jit in model.programs.items()} \
+        == {op: (name, site, phase, variant.replace("@", where))
+            for op, (name, site, phase, variant) in want.items()}
+    pools = ("k_pool", "v_pool", "k_scale", "v_scale")[
+        :4 if opts.get("kv_quant") else 2]
+    for jit in model.programs.values():         # the pools first, donated
+        assert jit._argnames[:1 + len(pools)] == ("params",) + pools
+    if config != "gather":
+        return
     import jax.numpy as jnp
-    text = model._decode_jit.lower(
+    text = model.programs["decode"].lower(
         params, *(jnp.zeros((cfg.n_layers, 4, cfg.n_heads, 8,
                              cfg.d_model // cfg.n_heads)),) * 2,
         jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
