@@ -102,8 +102,10 @@ def analyze(eng, model, padded_T, width, true_lens):
     toks, pos, tabs = decode_args(eng, true_lens, width)
     # the decode program this engine's configuration bound
     fn, params = model.programs["decode"], model.step_params
-    args = (params, eng.cache.k, eng.cache.v, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(tabs))
+    # every row's token from the host: the carry (the last step's tokens
+    # on the device, at max_batch) is not referred to
+    args = (params, eng.cache.k, eng.cache.v, eng._no_carry,
+            jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tabs))
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     cost = compiled.cost_analysis()
@@ -123,8 +125,7 @@ def analyze(eng, model, padded_T, width, true_lens):
         t0 = time.perf_counter()
         n = 20
         for _ in range(n):
-            k, v, logits, nxt = fn(params, k, v, args[3], args[4],
-                                   args[5])
+            k, v, logits, nxt = fn(params, k, v, *args[3:])
         np.asarray(nxt)
         info["decode_ms_per_step"] = round(
             1e3 * (time.perf_counter() - t0) / n, 3)
@@ -271,7 +272,8 @@ def main():
         assert eng_q.kv_quant, eng_q.kv_quant_fallback
         toks, pos, tabs = decode_args(eng_q, true_lens, w_paged)
         args = (model_q.step_params, *eng_q.cache.arrays(),
-                jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(tabs))
+                eng_q._no_carry, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(tabs))
         t0 = time.perf_counter()
         cost = model_q.programs["decode"].lower(*args).compile() \
             .cost_analysis()

@@ -37,6 +37,15 @@ Two model families plug in behind one `Engine`:
   but it makes the whole serving stack (scheduler, batching, HTTP)
   available to every model the framework can express or export.
 
+One decode step stays in flight (`Engine.decode_pass`): a pass launches
+step n + 1 from step n's tokens on the device (`carried_tokens`) and only
+then reads step n, so the device runs while the host appends, accounts,
+admits and builds. The tokens are carried at one shape (max_batch), so a
+step launched with none in flight and a step launched ahead are one
+program per batch bucket. Engines whose next input exists on the host
+alone (speculative, cache-free, `keep_logits`: `Engine.sync_reason`)
+collect every step in the pass that launched it.
+
 jit stability: the engine never hands XLA a novel shape per request.
 Prompt lengths pad to power-of-two buckets (gather path) or one fixed
 chunk shape (paged path), the decode batch and the paged table width pad
@@ -95,7 +104,7 @@ _STEPS = {
     "prefill": ("serving.prefill", "prefill",
                 ("tokens", "length", "table_row"), 1),
     "decode": ("serving.decode", "decode",
-               ("tokens", "positions", "tables"), 2),
+               ("carry", "tokens", "positions", "tables"), 2),
     "prefill_chunk": ("serving.prefill", "prefill",
                       ("tokens", "q_start", "length", "last_idx",
                        "table_row"), 1),
@@ -177,6 +186,37 @@ class Sequence:
         return self.tokens[self.prompt_len:]
 
 
+class Step:
+    """One decode step from its launch to its collect
+    (`Engine.decode_pass`). In between, `nxt` (the tokens it chose, at
+    max_batch), `stats` (what the family returns beside them) and
+    `logits` (`keep_logits` engines) are on the device, and the next
+    step can be launched from `nxt` there. `ahead`: it was launched
+    while the step before's tokens had not been read on the host.
+    `drains`: why it is collected in the pass that launched it, or
+    launched with nothing in flight (the serving metrics count them by
+    reason). A collect fills `advanced`, [(sequence that took tokens, its
+    length before, its length after)], and `t_read`."""
+
+    __slots__ = ("seqs", "ahead", "nxt", "stats", "logits", "drains",
+                 "advanced", "t_launch", "t_read")
+
+    def __init__(self, seqs, ahead=False, drains=()):
+        self.seqs = seqs
+        self.ahead = ahead
+        self.nxt = self.logits = self.advanced = None
+        self.stats = []
+        self.drains = list(drains)
+        self.t_launch = time.perf_counter()
+        self.t_read = None
+
+    @property
+    def sealed(self):
+        """Nothing can be launched from it: it is collected in the pass
+        that launched it."""
+        return any(d != "first_step" for d in self.drains)
+
+
 # ---------------------------------------------------------------------------
 # paged-cache transformer adapter
 # ---------------------------------------------------------------------------
@@ -206,22 +246,43 @@ def prefill(params, pools, tokens, length, table_row, cfg):
     return (*view.pools, _logits(params, x[length - 1]))
 
 
-def decode(params, pools, tokens, positions, tables, cfg, block_size,
+def carried_tokens(carry, tokens):
+    """A decode step's input tokens (B,). A row gives its own token (>= 0,
+    from the host: a sequence that has just been prefilled), or -1 - r
+    for row r of `carry`: the tokens the step before chose, which are
+    still on the device and which the host may not have read yet. So a
+    step can be launched from the last one's result with nothing read
+    in between (`Engine.launch_step`)."""
+    return jnp.where(tokens < 0, carry[jnp.maximum(-1 - tokens, 0)], tokens)
+
+
+def carry_of(nxt, carry):
+    """A step's chosen tokens (B,) at the one shape every batch bucket
+    carries them in (`carry`'s: max_batch): whatever bucket the next
+    step has, it takes them under one signature."""
+    return jnp.zeros_like(carry).at[:nxt.shape[0]].set(nxt)
+
+
+def decode(params, pools, carry, tokens, positions, tables, cfg, block_size,
            view_of, shard=OneChip):
-    """One decode step for a (padded) batch: tokens (B,) at positions
+    """One decode step for a (padded) batch: tokens (B,) (`carried_tokens`
+    of the step before's `carry`) at positions
     (B,), block tables (B, nblk). Writes the new K/V, attends over each
     sequence's cache through `view_of` (`LiveGatherView`: the table at
     full capacity, walked as far as the longest live sequence;
     `PagedView`: the table width-bucketed by the caller to the longest
     live sequence, walked in place by one kernel a layer, so no dense
     gather is materialized), returns logits (B, V) and the greedy next
-    token. Padded rows carry the all-null table — their writes hit the
-    null block and their logits are discarded by the caller."""
+    token (`carry_of`: at max_batch). Padded rows carry the all-null
+    table — their writes hit the null block and their logits are
+    discarded by the caller."""
+    tokens = carried_tokens(carry, tokens)
     x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
     view = view_of(pools, tables, positions,
                    flat_slots(tables, positions, block_size))
     logits = _logits(params, _layers(params, x, cfg, view, shard))
-    return (*view.pools, logits, jnp.argmax(logits, -1).astype(jnp.int32))
+    return (*view.pools, logits,
+            carry_of(jnp.argmax(logits, -1).astype(jnp.int32), carry))
 
 
 def prefill_chunk(params, pools, toks, qs, length, last_idx, table_row, cfg,
@@ -570,9 +631,10 @@ _LIVE = weakref.WeakSet()
 class Engine:
     """Owns the compiled step functions, the cache pool, and the shape
     buckets. Thread-compatible, not thread-safe: all compute entry points
-    (`start`, `decode_step`) must be called from one serving thread (the
-    server loop): every step consumes the pool arrays and the cache is
-    rebound from its results (`_step`), which only one thread may do.
+    (`start`, `decode_step`, `decode_pass`) must be called from one
+    serving thread (the server loop): every step consumes the pool arrays
+    and the cache is rebound from its results (`_step`), which only one
+    thread may do.
 
     Placement flags (`paged`, `tp`, `prefill_chunk`) are read at
     CONSTRUCTION only and frozen afterwards: the compiled step functions,
@@ -600,7 +662,7 @@ class Engine:
         from ..ops.pallas_attention import default_interpret
         from .tp import (serving_tp, tp_fallback_reason, build_tp_mesh,
                          kv_pool_spec, kv_scale_spec)
-        from jax.sharding import NamedSharding
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from .. import aot
         # persistent AOT executable cache (ISSUE 16): `aot_cache=` names
         # a directory (configuring it process-wide — the watchdog seam
@@ -731,6 +793,15 @@ class Engine:
                 self.device = devices[0]
                 model.place(self.device)
                 self.cache.place(self.device, self.device)
+            # what a decode step launched with none in flight takes for
+            # the last step's tokens (`carried_tokens`: no row refers to
+            # it), placed as a step returns them, so that it and a step
+            # launched ahead are one signature: one program a bucket.
+            # Put there as it is: no program is compiled to make it
+            self._no_carry = jax.device_put(
+                np.zeros((max_batch,), np.int32),
+                NamedSharding(self.mesh, P()) if self.mesh is not None
+                else self.device)
         elif tp_req > 1:
             self.tp_fallback = ("model family has no cache hooks "
                                 "(BlockLM/ExportedLM run single-device)")
@@ -958,11 +1029,13 @@ class Engine:
         """A step's result on the host. What a family's step returns
         beside its results (`stats`: the dropless family's rows per held
         expert) comes over in the same transfer and goes to the family's
-        `note_step`, whose answer the step's span carries."""
+        `note_step`, whose answer (counts) the step's span carries, added
+        up where a pass collects two steps under one span."""
         if not stats:
             return np.asarray(result)
         result, *stats = jax.device_get([result, *stats])
-        step_span.attrs.update(self.model.note_step(*stats))
+        for name, n in self.model.note_step(*stats).items():
+            step_span.attrs[name] = step_span.attrs.get(name, 0) + n
         return result
 
     def _step(self, fn, *args):
@@ -1174,99 +1247,225 @@ class Engine:
         k+1 scored positions per step, a plain one exactly 1."""
         return self.spec_k + 1 if self.spec else 1
 
+    @property
+    def sync_reason(self):
+        """Why a step of this engine cannot stay in flight while the next
+        is launched, or None where it can: on the single-token cache path
+        the next step's input is the last one's result, on the device.
+        A speculative engine reads acceptance counts to place the next
+        tokens, a cache-free model is fed the token history from the
+        host, and a `keep_logits` engine hands every step's logits over:
+        their next input exists on the host alone."""
+        if self.spec:
+            return "spec"
+        if not self.model.uses_cache:
+            return "no_cache"
+        return "keep_logits" if self.keep_logits else None
+
     def decode_step(self, seqs):
         """Advance every sequence in `seqs` (one fused jit call over the
-        power-of-two padded batch). Non-speculative engines emit exactly
-        one token per sequence; speculative engines emit 1..spec_k+1
-        accepted tokens per sequence per call, token-identical to the
-        plain path. A draft fault (non-finite logits — the
-        `serve_spec_poison` chaos seam or a real draft bug) degrades
-        THIS batch to the verbatim non-speculative body below."""
-        seqs = [s for s in seqs if not s.done]
-        if not seqs:
-            return []
-        if len(seqs) > self.max_batch:
+        power-of-two padded batch): launch the step, then collect it,
+        the synchronous contract of `decode_pass` for direct Engine
+        users (bench.py, tools, tests, the parity oracles).
+        Non-speculative engines emit exactly one token per sequence;
+        speculative engines emit 1..spec_k+1 accepted tokens per
+        sequence per call, token-identical to the plain path. A draft
+        fault (non-finite logits — the `serve_spec_poison` chaos seam or
+        a real draft bug) degrades THIS batch to the verbatim
+        non-speculative path."""
+        collected, _ = self.decode_pass(seqs, hold=False)
+        return [s for s, _, _ in collected[0].advanced] if collected else []
+
+    def decode_pass(self, seqs, after=None, hold=True):
+        """One pass of the decode pipeline, under one `serving.decode`
+        span: LAUNCH the next step over `seqs` (build, dispatch; its
+        tokens stay on the device), then COLLECT `after`, the step the
+        pass before left in flight (the blocking read, `note_step`, the
+        appends). So the device runs the new step while the host reads,
+        appends and accounts for the old one, admits and builds again.
+        Returns (collected, in_flight): the steps whose tokens this pass
+        appended, oldest first, each with `advanced` (its sequences that
+        took a token, with their lengths before and after), and the
+        step left in flight for the next pass, or None.
+
+        The launched step is collected in this pass too where nothing
+        can be launched from it: `hold` is false, the engine's next input
+        exists on the host alone (`sync_reason`), or every one of its
+        rows ends with it by length (`Step.drains` says which). A fault
+        leaves both steps uncollected: the caller drops them, and the
+        tokens appended so far are exactly those of collected steps,
+        which is all a replay needs."""
+        rows = self._rows(seqs, after)
+        if len(rows) > self.max_batch:
             raise MXNetError("decode batch %d exceeds max_batch %d"
-                             % (len(seqs), self.max_batch))
-        bb = pow2_bucket(len(seqs), lo=1, hi=self.max_batch)
-        if self.spec:
-            out = self._spec_decode_step(seqs, bb)
-            if out is not None:
-                return out
+                             % (len(rows), self.max_batch))
+        if self.spec and rows:
+            step = self._spec_decode_step([row[0] for row in rows])
+            if step is not None:
+                return [step], None
             # fall through: the un-touched single-token path IS the
             # degradation target (and the parity oracle)
+        if not rows and after is None:
+            return [], None
         t0_us = time.perf_counter_ns() // 1000
-        # the cache path's host work in three child spans (to label the
-        # device's idle gaps, PERF.md); ring and profiler only
-        part = functools.partial(telemetry.span, category="serving",
-                                 to_flight=False, batch=len(seqs))
-        with telemetry.span("serving.decode", category="serving",
-                            batch=len(seqs)) as step_span:
-            if self.model.uses_cache:
-                # paged path: the table width handed to the kernel is
-                # bucketed to the longest LIVE sequence, so a decode
-                # step's bytes track true lengths, not max_len; the
-                # gather path gets the full-capacity table and its one
-                # program walks it as far as the longest live sequence
-                # (`_attend_live`: the trip count is read from `pos`)
-                w = self._nblk
-                if self.paged:
-                    w = pow2_bucket(
-                        max(self.cache.blocks_for(len(s.tokens))
-                            for s in seqs), lo=1, hi=self._nblk)
-                with part("serving.decode.build"):
-                    toks = np.zeros((bb,), np.int32)
-                    pos = np.zeros((bb,), np.int32)
-                    tabs = np.zeros((bb, w), np.int32)
-                    for i, s in enumerate(seqs):
-                        toks[i] = s.tokens[-1]
-                        pos[i] = len(s.tokens) - 1
-                        tabs[i] = s.table_row[:w]
-                    step_span.attrs["live_max"] = int(pos.max()) + 1
-                    toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
-                                       jnp.asarray(tabs))
-                # same (batch, width) signature lattice whether the paged
-                # step runs on one chip or sharded over the tp mesh
-                sig = (bb, w) if self.paged else bb
-                with part("serving.decode.dispatch"), \
-                        self._count("decode", sig):
-                    logits, nxt, *stats = self._step(self.model.decode,
-                                                     toks, pos, tabs)
-                with part("serving.decode.readback"):
-                    nxt = self._read_back(step_span, nxt, stats)
-                    logits = np.asarray(logits) if self.keep_logits \
-                        else None
-            else:
-                s_pad = pow2_bucket(max(len(s.tokens) for s in seqs),
-                                    lo=1, hi=self.max_len)
-                toks = np.zeros((bb, s_pad), np.int32)
-                lens = np.ones((bb,), np.int32)
-                for i, s in enumerate(seqs):
-                    toks[i, :len(s.tokens)] = s.tokens
-                    lens[i] = len(s.tokens)
-                with self._count("decode", (bb, s_pad)):
-                    logits = np.asarray(self.model.step_full(
-                        toks, lens, phase="decode"))
-                nxt = np.argmax(logits, axis=-1)
+        collected = []
+        with telemetry.span("serving.decode",
+                            category="serving") as step_span:
+            step = self._launch(rows, after, step_span) if rows else None
+            if after is not None:
+                collected.append(self._read(after, step_span))
+            if step is not None and (step.sealed or not hold):
+                collected.append(self._read(step, step_span))
+                step = None
         # fan the batch-level decode interval out to every request it
         # advanced, so each request's trace row stays connected through
         # its decode steps (ring-only: the batch span above already
         # covers the interval in the chrome trace)
         dur_us = time.perf_counter_ns() // 1000 - t0_us
-        with part("serving.decode.append"):
-            for i, s in enumerate(seqs):
-                if self.keep_logits and logits is not None:
-                    s.last_logits = logits[i]
+        for done in collected:
+            self._append_step(done, step_span.id, t0_us, dur_us)
+        return collected, step
+
+    def _rows(self, seqs, after):
+        """The rows of the next step, [(sequence, its length when the
+        step runs, its row in `after` or None)], given `after`, the step
+        launched and not yet collected: a row of it will be one token
+        longer by then, and one that `after` brings to its `max_total`
+        ends there, which is known before `after` has run, so it is left
+        out. A row that may end with `after` by its `eos_id` is launched
+        all the same (`_append_step`)."""
+        row_of = {} if after is None else {
+            id(s): r for r, s in enumerate(after.seqs)}
+        rows = [(s, len(s.tokens) + (id(s) in row_of), row_of.get(id(s)))
+                for s in seqs if not s.done]
+        return [row for row in rows if row[1] < row[0].max_total]
+
+    def _launch(self, rows, after, step_span):
+        """Build and dispatch one step; returns it in flight, with its
+        tokens, and what the family returns beside them, on the device.
+        A row that continues from `after` takes its token from
+        `after`'s result there (`carried_tokens`), at the position and
+        table width the host knows it will have; a row that joined since
+        (a sequence just prefilled) brings its token from the host. So a
+        step costs three uploads, as a step that reads first did, and
+        one program per batch bucket serves both."""
+        bb = pow2_bucket(len(rows), lo=1, hi=self.max_batch)
+        step = Step([row[0] for row in rows], ahead=after is not None)
+        step_span.attrs["batch"] = len(rows)
+        # the cache path's host work in three child spans (to label the
+        # device's idle gaps, PERF.md); ring and profiler only
+        part = functools.partial(telemetry.span, category="serving",
+                                 to_flight=False, batch=len(rows))
+        if not self.model.uses_cache:
+            s_pad = pow2_bucket(max(n for _, n, _ in rows),
+                                lo=1, hi=self.max_len)
+            toks = np.zeros((bb, s_pad), np.int32)
+            lens = np.ones((bb,), np.int32)
+            for i, (s, n, _) in enumerate(rows):
+                toks[i, :n] = s.tokens
+                lens[i] = n
+            with self._count("decode", (bb, s_pad)):
+                logits = np.asarray(self.model.step_full(
+                    toks, lens, phase="decode"))
+            step.nxt = np.argmax(logits, axis=-1)
+            step.logits = logits if self.keep_logits else None
+        else:
+            # paged path: the table width handed to the kernel is
+            # bucketed to the longest LIVE sequence, so a decode
+            # step's bytes track true lengths, not max_len; the
+            # gather path gets the full-capacity table and its one
+            # program walks it as far as the longest live sequence
+            # (`_attend_live`: the trip count is read from `pos`)
+            w = self._nblk
+            if self.paged:
+                w = pow2_bucket(max(self.cache.blocks_for(n)
+                                    for _, n, _ in rows),
+                                lo=1, hi=self._nblk)
+            with part("serving.decode.build"):
+                toks = np.zeros((bb,), np.int32)
+                pos = np.zeros((bb,), np.int32)
+                tabs = np.zeros((bb, w), np.int32)
+                for i, (s, n, r) in enumerate(rows):
+                    toks[i] = s.tokens[-1] if r is None else -1 - r
+                    pos[i] = n - 1
+                    tabs[i] = s.table_row[:w]
+                step_span.attrs["live_max"] = int(pos.max()) + 1
+                toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
+                                   jnp.asarray(tabs))
+            # same (batch, width) signature lattice whether the paged
+            # step runs on one chip or sharded over the tp mesh
+            sig = (bb, w) if self.paged else bb
+            with part("serving.decode.dispatch", ahead=int(step.ahead)), \
+                    self._count("decode", sig):
+                step.logits, step.nxt, *step.stats = self._step(
+                    self.model.decode,
+                    self._no_carry if after is None else after.nxt,
+                    toks, pos, tabs)
+                if not self.keep_logits:
+                    step.logits = None
+            # the copy back starts as soon as the step has run, not when
+            # the host comes to ask for it behind the next step's launch
+            for result in (step.nxt, *step.stats):
+                result.copy_to_host_async()
+        if self.sync_reason is not None:
+            step.drains.append(self.sync_reason)
+            return step
+        if after is None:
+            step.drains.append("first_step")
+        if all(n + 1 >= s.max_total for s, n, _ in rows):
+            step.drains.append("last_step")
+        return step
+
+    def _read(self, step, step_span):
+        """The blocking half of a collect: the step's tokens on the host
+        (the read returns when the step has run, whatever was launched
+        behind it), the family's `note_step`."""
+        if self.model.uses_cache:
+            with telemetry.span("serving.decode.readback",
+                                category="serving", to_flight=False,
+                                batch=len(step.seqs)):
+                step.nxt = self._read_back(step_span, step.nxt, step.stats)
+                if step.logits is not None:
+                    step.logits = np.asarray(step.logits)
+        step.t_read = time.perf_counter()
+        return step
+
+    def _append_step(self, step, parent, t0_us, dur_us):
+        """The other half: every row's token appended to its sequence. A
+        row whose sequence has ended since the step was launched is
+        dropped: it met its `eos_id` in the step before, which the host
+        learned only after this one was launched (or a failover detached
+        it meanwhile). Its token is not the sequence's: never appended,
+        counted or recorded. The one cache write the row made lies past
+        the sequence's end in blocks that were still its own when the
+        step was queued (`blocks_needed` reserves up to `max_total`, and
+        a row at `max_total` is never launched); blocks freed at a
+        collect can be handed out only to programs queued after every
+        step launched before it, so device order keeps the write from
+        anyone else's data. A prefix-cache entry made of such a sequence
+        is sound for the same reason: `release` registers `tokens[:-1]`,
+        the stray write is at the position after them, and a reader of a
+        partial tail block copies it and writes its own tokens from the
+        registered count on before it reads any."""
+        step.advanced = []
+        with telemetry.span("serving.decode.append", category="serving",
+                            to_flight=False, batch=len(step.seqs)):
+            for i, s in enumerate(step.seqs):
+                if s.done:
+                    continue
+                n = len(s.tokens)
+                step.advanced.append((s, n, n + 1))
+                if step.logits is not None:
+                    s.last_logits = step.logits[i]
                     if s.token_logits is not None:
-                        s.token_logits.append(logits[i])
-                self._append(s, int(nxt[i]))
+                        s.token_logits.append(step.logits[i])
+                self._append(s, int(step.nxt[i]))
                 if s.request is not None:
                     telemetry.record_span(
                         "serving.decode", t0_us, dur_us,
                         trace=s.request.trace, category="serving",
                         to_profiler=False, to_flight=False,
-                        parent=step_span.id, position=len(s.tokens) - 1)
-        return seqs
+                        parent=parent, position=len(s.tokens) - 1)
 
     def _draft_propose(self, seqs, bb, k, poison):
         """Draft proposal loop: k greedy autoregressive steps of the
@@ -1305,14 +1504,15 @@ class Engine:
                     hist[i].append(int(nxt[i]))
         return out, nbs
 
-    def _spec_decode_step(self, seqs, bb):
+    def _spec_decode_step(self, seqs):
         """One speculative iteration: draft proposes, the target scores
         all k+1 positions in ONE ragged paged pass against the live
         block tables, greedy verification accepts a prefix (plus the
         target's own token at the first disagreement, plus a bonus on a
         full sweep) — emitted tokens are EXACTLY the plain greedy
-        path's. Returns the advanced seqs, or None to degrade this
-        batch to the verbatim non-speculative step (draft fault).
+        path's. Returns the step, collected (its tokens are appended as
+        they are verified), or None to degrade this batch to the verbatim
+        non-speculative step (draft fault).
 
         KV discipline: the pass writes positions len-1..len-1+k per
         sequence. Accepted positions become ordinary history; rejected
@@ -1323,6 +1523,9 @@ class Engine:
         from .spec import greedy_verify
         k = self.spec_k
         C = k + 1
+        bb = pow2_bucket(len(seqs), lo=1, hi=self.max_batch)
+        step = Step(seqs, drains=["spec"])
+        before = [len(s.tokens) for s in seqs]
         poison, self.chaos_spec_poison = self.chaos_spec_poison, False
         t0_us = time.perf_counter_ns() // 1000
         with telemetry.span("serving.spec", category="serving",
@@ -1381,7 +1584,9 @@ class Engine:
         self.last_spec = {"fallback": False, "batch": B,
                           "proposed": proposed, "accepted": accepted,
                           "emitted": emitted_n}
-        return seqs
+        step.advanced = [(s, n, len(s.tokens)) for s, n in zip(seqs, before)]
+        step.t_read = time.perf_counter()
+        return step
 
     def _append(self, seq, token):
         seq.tokens.append(token)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import latent_moe
-from .engine import _program
+from .engine import _program, carried_tokens, carry_of
 from .kv_cache import (CacheSpec, append_latent, write_latent_prompt,
                        gather_latent, flat_slots)
 
@@ -68,18 +68,20 @@ def prefill(params, pool, tokens, length, table_row, cfg):
     return view.pool, latent_moe._logits(params, x[length - 1], cfg), counts
 
 
-def decode(params, pool, tokens, positions, tables, cfg):
-    """One decode step of a padded batch: tokens (B,) at positions (B,),
-    block tables (B, nblk). A padded row carries the all-null table: it
-    writes to the null block, is routed to no expert and its logits are
-    dropped by the caller. Returns (pool, logits (B, vocab), greedy next
-    token (B,), pairs per (expert layer, held expert))."""
+def decode(params, pool, carry, tokens, positions, tables, cfg):
+    """One decode step of a padded batch: tokens (B,) (`carried_tokens`
+    of the step before's `carry`) at positions (B,), block tables
+    (B, nblk). A padded row carries the all-null table: it writes to the
+    null block, is routed to no expert and its logits are dropped by the
+    caller. Returns (pool, logits (B, vocab), greedy next token
+    (`carry_of`: at max_batch), pairs per (expert layer, held expert))."""
+    tokens = carried_tokens(carry, tokens)
     view = DecodeView(pool, tables, positions)
     x, counts = latent_moe._trunk(params, tokens, positions,
                                   tables[:, 0] != 0, cfg, view)
     logits = latent_moe._logits(params, x, cfg)
-    return (view.pool, logits, jnp.argmax(logits, -1).astype(jnp.int32),
-            counts)
+    return (view.pool, logits,
+            carry_of(jnp.argmax(logits, -1).astype(jnp.int32), carry), counts)
 
 
 class LatentMoELM:
@@ -118,14 +120,16 @@ class LatentMoELM:
             ("kv_pool",))
         self._decode_jit = _program(
             "decode", "serving_decode", "decode_latent",
-            lambda p, pools, t, pos, tb: decode(p, *pools, t, pos, tb, cfg),
+            lambda p, pools, c, t, pos, tb: decode(p, *pools, c, t, pos, tb,
+                                                   cfg),
             ("kv_pool",))
 
     def prefill(self, kv, tokens, length, table_row):
         return self._prefill_jit(self.params, kv, tokens, length, table_row)
 
-    def decode(self, kv, tokens, positions, tables):
-        return self._decode_jit(self.params, kv, tokens, positions, tables)
+    def decode(self, kv, carry, tokens, positions, tables):
+        return self._decode_jit(self.params, kv, carry, tokens, positions,
+                                tables)
 
     def note_step(self, counts):
         """One step's rows per (expert layer, held expert), on the host:

@@ -40,6 +40,18 @@ _OCCUPANCY_BUCKETS = (0.1, 0.25, 0.5, 0.75, 0.9, 1.0)
 #: ceiling; fractional edges resolve the sub-token differences that
 #: decide whether speculation pays for the draft
 _SPEC_BUCKETS = (1.0, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0)
+#: why the decode pipeline ran empty (`engine.Step.drains`, and a step in
+#: flight dropped by the serving loop): the snapshot's `decode_drains`
+DECODE_DRAINS = {
+    "first_step": "a step launched with none in flight",
+    "last_step": "every row ended with the step by length, so it was "
+                 "collected in the pass that launched it",
+    "spec": "a speculative engine places the next tokens on the host",
+    "no_cache": "a cache-free model is fed the token history from the host",
+    "keep_logits": "the engine hands every step's logits over",
+    "fault": "a step in flight dropped by a fault, a replay or a dead loop",
+    "close": "a step in flight dropped as the loop closed",
+}
 
 #: per-tenant instrument-name templates (ISSUE 13; docs/OBSERVABILITY.md
 #: names these with a `<tenant>` placeholder). Token counters share the
@@ -172,6 +184,14 @@ class ServingMetrics:
         self._steps_gather = c("serving_decode_steps_gather_total",
                                help="decode steps served by the dense "
                                     "gather path")
+        self._steps_ahead = c(
+            "serving_decode_steps_ahead_total",
+            help="decode steps launched while the step before's tokens "
+                 "had not been read on the host")
+        self._drains = {reason: c(
+            "serving_decode_drains_%s_total" % reason,
+            help="times the decode pipeline ran empty: %s" % why)
+            for reason, why in DECODE_DRAINS.items()}
         self._chunks = c("serving_prefill_chunks_total",
                          help="chunked-prefill kernel calls")
         # prefix-cache observables (ISSUE 10): counters synced from the
@@ -650,6 +670,19 @@ class ServingMetrics:
             self._g_util.set(cache_util)
         self._counter.increment(tokens)
 
+    def decode_collected(self, ahead, drains):
+        """One decode step collected: was it launched ahead, and why (if
+        so) it was launched with nothing in flight or collected in the
+        pass that launched it (`engine.Step.drains`)."""
+        if ahead:
+            self._steps_ahead.inc()
+        for reason in drains:
+            self._drains[reason].inc()
+
+    def decode_drained(self, reason):
+        """A step in flight dropped uncollected (`fault`, `close`)."""
+        self._drains[reason].inc()
+
     def spec_pass(self, batch=0, proposed=0, accepted=0, emitted=0,
                   fallback=False):
         """One speculative decode round (engine.last_spec feed): either
@@ -923,6 +956,9 @@ class ServingMetrics:
                 "tokens_per_sec": (tokens / elapsed
                                    if elapsed > 0 else None),
                 "decode_steps": steps,
+                "decode_steps_ahead": int(self._steps_ahead.value),
+                "decode_drains": {reason: int(c.value) for reason, c
+                                  in self._drains.items() if c.value},
             },
             "batch": {
                 "mean_active": (self._h_batch.sum / steps

@@ -7,8 +7,10 @@
   * an initialized Gluon Block (give `vocab` and `max_len`)
 
 and returns a started `LMServer`: a background thread runs the
-continuous-batching loop (admit → prefill → decode step → evict), callers
-submit token prompts and block on per-request futures. The HTTP frontend
+continuous-batching loop (admit → prefill → launch the next decode step →
+collect the last one → evict: one step stays in flight, so the device
+runs while the host does its part of a pass), callers submit token
+prompts and block on per-request futures. The HTTP frontend
 (`LMServer.serve_http` / tools/serve.py) is a thin stdlib
 ThreadingHTTPServer over the same object — one handler thread per
 connection, all of them funneling into the single serving thread, so the
@@ -338,6 +340,9 @@ class LMServer(_HTTPFrontend):
         # iteration; decode progress stamps separately
         self._last_beat = time.perf_counter()
         self._last_step_t = None
+        # the decode step launched and not yet collected (`_iterate`):
+        # the serving thread's alone
+        self._flight = None
         self._wedge_dumped = False
         # HTTP submit-on-QueueFull retry budget (utils.retry): a briefly
         # full queue absorbs a burst instead of bouncing clients to 429
@@ -626,6 +631,7 @@ class LMServer(_HTTPFrontend):
             telemetry.flight().dump("serving_loop_died")
             self._died = True
             self._closed = True
+            self._drop_flight("fault")
             err = MXNetError("serving loop died: %s: %s"
                              % (type(e).__name__, e))
             # capture AND DETACH the in-flight survivors (under the
@@ -689,11 +695,17 @@ class LMServer(_HTTPFrontend):
             with telemetry.span("serving.loop", category="serving",
                                 it=it) as loop_span:
                 self._iterate(rid, it, loop_span)
+        # closed with work in flight (no drain, or its time ran out):
+        # `close` rescues or fails the requests from their tokens so far
+        self._drop_flight("close")
 
     def _iterate(self, rid, it, loop_span):
-        """One pass of the serving loop: admit, prefill, one decode step,
-        the step's bookkeeping; or, with nothing to do, a wait (and then
-        no span is recorded)."""
+        """One pass of the serving loop: admit, prefill, launch the next
+        decode step, collect the one the last pass launched, that step's
+        bookkeeping (`Engine.decode_pass`: one step stays in flight from
+        pass to pass wherever the engine can launch from a result it has
+        not read); or, with nothing to do, a wait (and then no span is
+        recorded)."""
         eng, sched, met = self.engine, self.scheduler, self.metrics
         # chaos seams (no-ops unless armed; utils/chaos.py): a kill
         # raises HERE — outside the engine-fault isolation — so the
@@ -731,8 +743,7 @@ class LMServer(_HTTPFrontend):
             admit_span.attrs.update(batch=len(sched.running),
                                     admitted=len(admitted),
                                     expired=len(expired))
-        if sched.running:
-            t0 = time.perf_counter()
+        if sched.running or self._flight is not None:
             try:
                 if chaos.decode_poison(rid, it):
                     raise MXNetError("chaos: decode step poisoned")
@@ -742,18 +753,19 @@ class LMServer(_HTTPFrontend):
                     # batch to the non-speculative path, token-
                     # identical to the undisturbed oracle
                     eng.chaos_spec_poison = chaos.spec_poison(rid, it)
-                # pre-step lengths of the sequences decode_step will
-                # return (it filters done ones in the same order):
-                # a speculative step emits a BURST per sequence, so
-                # tokens = post-len minus pre-len, not 1 per step
-                pre_lens = [len(s.tokens) for s in sched.running
-                            if not s.done]
-                advanced = eng.decode_step(sched.running)
+                # launch the next step, THEN collect the one the last
+                # pass left in flight: the device runs while the host
+                # appends, accounts, admits and builds again
+                collected, self._flight = eng.decode_pass(
+                    sched.running, after=self._flight)
             except Exception as e:
-                # a decode fault poisons the STEP, not the history
-                # (unless the step had consumed the KV pools: PoolsLost,
-                # below): every token already appended came from a step
-                # that completed. Re-home the batch onto this server's own
+                # a decode fault poisons the STEPS in flight, not the
+                # history (unless a step had consumed the KV pools:
+                # PoolsLost, below): every token already appended came
+                # from a step that was collected, and what was launched
+                # and not collected is dropped with the fault (the
+                # greedy replay chooses those tokens again). Re-home the
+                # batch onto this server's own
                 # queue as failover replays (prompt + generated so
                 # far re-prefills, decode continues token-identically)
                 # instead of failing user-visible work; a request
@@ -765,38 +777,14 @@ class LMServer(_HTTPFrontend):
                 if isinstance(e, PoolsLost):
                     self._replay_all(err)     # the prefilling ones too
                     return
+                self._drop_flight("fault")
                 self._resume_locally(sched.running, err)
                 sched.running = []
                 return
-            with telemetry.span("serving.account", category="serving",
-                                to_flight=False, batch=len(advanced)):
-                self._last_step_t = time.perf_counter()
-                if advanced:  # count only sequences that really stepped
-                    emitted = sum(len(s.tokens) - n
-                                  for s, n in zip(advanced, pre_lens))
-                    met.decode_step(len(advanced), eng.max_batch,
-                                    time.perf_counter() - t0,
-                                    cache_util=eng.cache_utilization(),
-                                    paged=eng.paged, tokens=emitted,
-                                    live_max=max(pre_lens))
-                    if eng.last_spec is not None:
-                        met.spec_pass(**eng.last_spec)
-                        eng.last_spec = None
-                    # per-request inter-token latency (ISSUE 13): the
-                    # ITL SLO and the lifecycle ledger see every gap,
-                    # including the one a failover replay opened — a
-                    # speculative burst records one observation per
-                    # EMITTED token (the burst's interior gaps are ~0:
-                    # the client receives those tokens back-to-back)
-                    for s, n in zip(advanced, pre_lens):
-                        if s.request is not None:
-                            for posn in range(n, len(s.tokens)):
-                                met.token_generated(
-                                    s.request, now=self._last_step_t,
-                                    position=posn)
-                for req in (s.request for s in sched.evict(eng)
-                            if s.request is not None):
-                    met.request_finished(req)
+            for step in collected:
+                self._account(step, evict=step is collected[-1])
+            if not collected:       # nothing read, but a sequence may have
+                self._evict()       # ended in its prefill or been detached
         elif sched.prefilling:
             pass      # chunk work ran this iteration; no decode to
                       # pace against, so loop straight into the next
@@ -807,6 +795,63 @@ class LMServer(_HTTPFrontend):
             self._work.wait(self._idle_wait * 20)
         else:
             time.sleep(self._idle_wait)
+
+    def _account(self, step, evict):
+        """A collected decode step's bookkeeping: the metrics, each
+        emitted token's record and, after the pass's last collect, the
+        eviction of what has ended. The step's time is the step
+        interval: from its launch, or from the collect before it where
+        that came later (a step launched ahead waits for the one before
+        it), to its own collect."""
+        eng, met = self.engine, self.metrics
+        advanced = step.advanced
+        with telemetry.span("serving.account", category="serving",
+                            to_flight=False, batch=len(advanced)):
+            since = max(step.t_launch, self._last_step_t or 0.0)
+            self._last_step_t = step.t_read
+            met.decode_collected(step.ahead, step.drains)
+            if advanced:  # count only sequences that really stepped
+                # a speculative step emits a BURST per sequence, so
+                # tokens = post-len minus pre-len, not 1 per step
+                emitted = sum(after - before for _, before, after in advanced)
+                met.decode_step(len(advanced), eng.max_batch,
+                                step.t_read - since,
+                                cache_util=eng.cache_utilization(),
+                                paged=eng.paged, tokens=emitted,
+                                live_max=max(n for _, n, _ in advanced))
+                if eng.last_spec is not None:
+                    met.spec_pass(**eng.last_spec)
+                    eng.last_spec = None
+                # per-request inter-token latency (ISSUE 13): the
+                # ITL SLO and the lifecycle ledger see every gap,
+                # including the one a failover replay opened — a
+                # speculative burst records one observation per
+                # EMITTED token (the burst's interior gaps are ~0:
+                # the client receives those tokens back-to-back)
+                for s, before, after in advanced:
+                    if s.request is not None:
+                        for posn in range(before, after):
+                            met.token_generated(
+                                s.request, now=self._last_step_t,
+                                position=posn)
+            if evict:
+                self._evict()
+
+    def _evict(self):
+        for req in (s.request for s in self.scheduler.evict(self.engine)
+                    if s.request is not None):
+            self.metrics.request_finished(req)
+
+    def _drop_flight(self, reason):
+        """Forget the step in flight, uncollected (a fault, a replay, the
+        loop's end): its tokens were never appended, so every sequence's
+        tokens are still exactly those of collected steps, and a replay
+        from them chooses the dropped ones again. What the step wrote
+        lies in its own sequences' blocks; whoever gets them next is
+        queued behind it on the device."""
+        if self._flight is not None:
+            self._flight = None
+            self.metrics.decode_drained(reason)
 
     def _admit_dense(self, admitted):
         """PR 1 admission: each admitted request runs its WHOLE prefill
@@ -1064,6 +1109,7 @@ class LMServer(_HTTPFrontend):
         its cache: replay everything running and prefilling, and `req`,
         the request being admitted, which has no sequence yet."""
         sched = self.scheduler
+        self._drop_flight("fault")
         seqs = sched.running + sched.prefilling
         sched.running, sched.prefilling = [], []
         self._resume_locally(seqs, err)

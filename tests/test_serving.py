@@ -425,15 +425,15 @@ def test_engine_decode_exception_resumes_batch_not_loop(tiny_lm):
         oracle.close()
     srv = serving.serve((params, cfg), max_batch=2, block_size=8)
     try:
-        real_decode = srv.engine.decode_step
+        real_decode = srv.engine.decode_pass
         boom = {"armed": True}
 
-        def flaky_decode(seqs):
+        def flaky_decode(*args, **kw):
             if boom.pop("armed", None):
                 raise RuntimeError("injected decode fault")
-            return real_decode(seqs)
+            return real_decode(*args, **kw)
 
-        srv.engine.decode_step = flaky_decode
+        srv.engine.decode_pass = flaky_decode
         req = srv.submit(arith_prompt(4, 1, 5), max_new_tokens=4)
         assert req.result(timeout=120) == want
         snap = srv.snapshot()
@@ -460,10 +460,10 @@ def test_engine_decode_fault_budget_exhausted_surfaces_error(tiny_lm):
     params, cfg = tiny_lm
     srv = serving.serve((params, cfg), max_batch=2, block_size=8)
     try:
-        def dead_decode(seqs):
+        def dead_decode(*args, **kw):
             raise RuntimeError("persistent decode fault")
 
-        srv.engine.decode_step = dead_decode
+        srv.engine.decode_pass = dead_decode
         req = srv.submit(arith_prompt(4, 1, 5), max_new_tokens=4)
         with pytest.raises(mx.MXNetError, match="decode failed"):
             req.result(timeout=120)
@@ -580,19 +580,19 @@ def test_chunked_prefill_does_not_starve_decode(tiny_lm):
     try:
         events = []
         real_chunk = srv.engine.prefill_step
-        real_decode = srv.engine.decode_step
+        real_decode = srv.engine.decode_pass
 
         def chunk_spy(seq):
             events.append(("chunk", seq.request.id
                            if seq.request else None))
             return real_chunk(seq)
 
-        def decode_spy(seqs):
+        def decode_spy(*args, **kw):
             events.append(("decode", None))
-            return real_decode(seqs)
+            return real_decode(*args, **kw)
 
         srv.engine.prefill_step = chunk_spy
-        srv.engine.decode_step = decode_spy
+        srv.engine.decode_pass = decode_spy
         # the short request decodes while the long prompt prefills
         short = srv.submit(arith_prompt(1, 1, 4), max_new_tokens=60)
         deadline = time.perf_counter() + 60

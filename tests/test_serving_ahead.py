@@ -1,0 +1,332 @@
+"""The serving loop keeps one decode step in flight (ISSUE 30): a pass
+launches the next step from the last step's tokens on the device, then
+collects the last one.
+
+Load-bearing claims: (a) what is served is, token for token, what the
+synchronous `Engine.decode_step` gives, on every configuration that
+launches ahead, through the events that change a batch between two steps
+(a row ending by length, an admission while a step is in flight, a change
+of batch bucket); (b) a row that met its `eos_id` in a step the host had
+not read when the next was launched is dropped there: no token after the
+end is appended, counted or recorded; (c) an engine whose next input
+exists on the host alone never launches ahead and says why; (d) the
+window costs no program: one decode compilation a signature after the
+benchmark's warm-up, and none, by the watchdog and by jax's own compile
+log, in a churned window after it.
+"""
+import logging
+import time
+
+import pytest
+
+import jax
+
+import mxnet_tpu as mx
+from mxnet_tpu import serving, telemetry
+from mxnet_tpu.models.transformer import (TransformerConfig,
+                                          init_transformer_params)
+from mxnet_tpu.serving.spec import self_draft
+from mxnet_tpu.telemetry import introspect
+
+from chipbench.families import latent_moe_lm as latent_family
+from chipbench.generators import serving as bench_serving
+
+BS, MAX_BATCH = 8, 4
+
+LATENT = {
+    "hidden_size": 48, "num_attention_heads": 4, "q_lora_rank": 20,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+    "v_head_dim": 8, "intermediate_size": 96, "moe_intermediate_size": 24,
+    "n_shared_experts": 1, "n_routed_experts": 4,
+    "n_routed_experts_published": 16, "expert_parallel": 4, "expert_rank": 2,
+    "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2,
+    "routed_scaling_factor": 2.5, "num_hidden_layers": 3,
+    "first_k_dense_replace": 1, "vocab_size": 96, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 4096, "type": "yarn"},
+    "dtype": "float32"}
+
+#: configuration -> (model family, what `serve` and `Engine` are told)
+CONFIGS = {
+    "gather": ("dense", dict()),
+    # chunked prefill co-scheduled with the decode steps: a 20-token prompt
+    # streams in three chunks while the others decode
+    "paged": ("dense", dict(paged=True, prefill_chunk=8)),
+    "paged_q8": ("dense", dict(paged=True, kv_quant=True)),
+    "tp2": ("dense", dict(paged=True, tp=2)),
+    "latent": ("latent", dict()),
+}
+
+
+@pytest.fixture(scope="module")
+def models():
+    cfg = TransformerConfig(vocab=48, d_model=32, n_heads=4, n_layers=2,
+                            d_ff=64, max_len=64)
+    weights = latent_family.make_weights(LATENT, 11)
+    return {"dense": (init_transformer_params(jax.random.PRNGKey(0), cfg),
+                      cfg),
+            "latent": (latent_family.program_params(weights),
+                       latent_family.program_config(LATENT, 64))}
+
+
+def prompt(start, n, vocab=48):
+    return [(start + 5 * t) % vocab for t in range(n)]
+
+
+def oracle(model, options, requests):
+    """Each request alone through the synchronous door: `start`, then
+    `decode_step` until it is done. Returns the generated tokens of each."""
+    eng = serving.Engine(serving.server._resolve_model(model),
+                         max_batch=MAX_BATCH, block_size=BS, **options)
+    out = []
+    try:
+        for tokens, max_new, eos in requests:
+            seq = eng.start(tokens, max_new, eos_id=eos)
+            while not seq.done:
+                assert eng.decode_step([seq]) == [seq]
+            out.append(list(seq.generated))
+            eng.release(seq, reusable=False)
+    finally:
+        eng.close()
+    return out
+
+
+def serve_churned(srv, first, later):
+    """`first` at once (a full batch and a queue behind it), `later` once
+    steps are under way: admissions land while a step is in flight, rows
+    end by length in the middle of a batch, and the batch shrinks through
+    its buckets as the last requests run out."""
+    handles = [srv.submit(p, max_new_tokens=n, eos_id=e) for p, n, e in first]
+    deadline = time.perf_counter() + 120
+    while srv.metrics.tokens_generated < 3:
+        assert time.perf_counter() < deadline
+        time.sleep(0.002)
+    handles += [srv.submit(p, max_new_tokens=n, eos_id=e)
+                for p, n, e in later]
+    return [list(h.result(timeout=300)) for h in handles]
+
+
+FIRST = [(prompt(1, 9), 3, None), (prompt(2, 20), 12, None),
+         (prompt(3, 5), 6, None), (prompt(4, 12), 16, None),
+         (prompt(5, 7), 2, None), (prompt(6, 10), 9, None)]
+LATER = [(prompt(7, 6), 5, None), (prompt(8, 11), 1, None),
+         (prompt(9, 9), 7, None)]
+
+
+# -- (a) -----------------------------------------------------------------------
+
+@pytest.mark.skipif(len(jax.devices()) < 2,
+                    reason="tp steps need >= 2 (emulated) devices")
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_served_tokens_are_the_synchronous_steps_tokens_under_churn(
+        models, config):
+    family, options = CONFIGS[config]
+    model = models[family]
+    want = oracle(model, options, FIRST + LATER)
+    srv = serving.serve(model, max_batch=MAX_BATCH, block_size=BS, **options)
+    try:
+        assert srv.engine.sync_reason is None
+        assert bool(srv.engine.paged) == bool(options.get("paged"))
+        assert srv.engine.tp == options.get("tp", 1)
+        got = serve_churned(srv, FIRST, LATER)
+        snap = srv.snapshot()["throughput"]
+    finally:
+        srv.close()
+    assert got == want
+    # a request's first token is the prefill's; every other is a step's
+    assert snap["tokens_generated"] == sum(len(g) - 1 for g in want)
+    assert snap["decode_steps_ahead"] > 0.8 * snap["decode_steps"]
+    assert set(snap["decode_drains"]) <= {"first_step", "last_step"}
+    # a full batch, and smaller ones down through the buckets as it drained
+    batches = {s["attrs"]["batch"] for s in telemetry.spans()
+               if s["name"] == "serving.decode.dispatch"}
+    assert 4 in batches and len(batches) >= 3, batches
+
+
+def test_a_request_that_ends_in_its_prefill_is_finished_with_no_step(models):
+    """Nothing to launch and nothing to collect: the pass still evicts."""
+    srv = serving.serve(models["dense"], max_batch=2, block_size=BS)
+    try:
+        assert len(srv.generate(prompt(2, 9), max_new_tokens=1,
+                                timeout=60)) == 1
+        snap = srv.snapshot()
+        assert snap["throughput"]["decode_steps"] == 0
+        assert snap["requests"]["completed"] == 1
+        assert srv.engine.cache.pool.in_use == 0
+    finally:
+        srv.close()
+
+
+# -- (b) -----------------------------------------------------------------------
+
+def mid_stream_eos(generated):
+    """(index, token) of a generated token, not the first nor the last two,
+    that no earlier generated token equals: declared `eos_id`, the request
+    ends there, in a step the host reads after the next was launched."""
+    for j in range(2, len(generated) - 2):
+        if generated[j] not in generated[:j]:
+            return j, generated[j]
+    raise AssertionError("no usable token in %r" % (generated,))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2,
+                    reason="tp steps need >= 2 (emulated) devices")
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_a_row_that_met_its_eos_is_dropped_from_the_step_launched_ahead(
+        models, config):
+    family, options = CONFIGS[config]
+    model = models[family]
+    plain = [(prompt(2, 20), 16, None), (prompt(4, 12), 14, None),
+             (prompt(6, 10), 12, None)]
+    free = oracle(model, options, plain)
+    ends = [mid_stream_eos(g) for g in free[:2]]
+    # two requests end by their eos, at different steps; the third runs on
+    requests = [(p, n, eos) for (p, n, _), (_, eos) in zip(plain, ends)] \
+        + plain[2:]
+    want = [g[:j + 1] for g, (j, _) in zip(free, ends)] + free[2:]
+    assert oracle(model, options, requests) == want
+    telemetry.tracing.clear()
+    srv = serving.serve(model, max_batch=MAX_BATCH, block_size=BS, **options)
+    try:
+        handles = [srv.submit(p, max_new_tokens=n, eos_id=e)
+                   for p, n, e in requests]
+        got = [list(h.result(timeout=300)) for h in handles]
+        met = srv.metrics
+        snap = srv.snapshot()["throughput"]
+        assert got == want
+        # never counted ...
+        assert snap["tokens_generated"] == sum(len(g) - 1 for g in want)
+        assert snap["decode_steps_ahead"] > 0
+        # ... never given a `token_generated` record (one a decode token:
+        # the gap from the token before it) ...
+        assert met._h_itl.count == sum(len(g) - 1 for g in want)
+        # ... nor a copy of a step's span on its request's row: one a
+        # decode token, the last at the request's last position
+        for h, g, (p, _, _) in zip(handles, want, requests):
+            assert h.tokens == p + g
+            copies = [s["attrs"]["position"] for s in telemetry.spans(h.trace)
+                      if s["name"] == "serving.decode"]
+            assert sorted(copies) == list(range(len(p) + 1, len(p) + len(g)))
+        # the rows that ran past their end were launched all the same: the
+        # steps dispatched hold more rows than the tokens appended
+        rows = sum(s["attrs"]["batch"] for s in telemetry.spans()
+                   if s["name"] == "serving.decode.dispatch")
+        assert rows == snap["tokens_generated"] + len(ends)
+    finally:
+        srv.close()         # the pool's audit: no block leaked
+
+
+# -- (c) -----------------------------------------------------------------------
+
+def word_lm():
+    net = mx.models.RNNModel(mode="lstm", vocab_size=32, num_embed=16,
+                             num_hidden=16, num_layers=1, dropout=0.0)
+    net.initialize(mx.init.Xavier())
+    net(mx.nd.zeros((4, 2)))                 # materialize params
+    return net
+
+
+SYNC = {
+    "spec": lambda m: (m["dense"], dict(
+        paged=True, draft=self_draft(*m["dense"], 1), spec_k=3)),
+    "no_cache": lambda m: (word_lm(), dict(vocab=32, max_len=32,
+                                           time_major=True)),
+    "keep_logits": lambda m: (m["dense"], dict(keep_logits=True)),
+}
+
+
+@pytest.mark.parametrize("reason", sorted(SYNC))
+def test_an_engine_whose_next_input_is_on_the_host_never_launches_ahead(
+        models, reason):
+    model, options = SYNC[reason](models)
+    srv = serving.serve(model, max_batch=2, **options)
+    try:
+        assert srv.engine.sync_reason == reason
+        handles = [srv.submit(prompt(1 + i, 5, vocab=32), max_new_tokens=6 + i)
+                   for i in range(3)]
+        assert [len(h.result(timeout=300)) for h in handles] == [6, 7, 8]
+        snap = srv.snapshot()["throughput"]
+        assert snap["decode_steps_ahead"] == 0
+        assert snap["decode_drains"] == {reason: snap["decode_steps"]}
+        assert srv._flight is None
+    finally:
+        srv.close()
+
+
+# -- (d) -----------------------------------------------------------------------
+
+class Door:
+    """What the benchmark's warm-up drives (`chipbench/families`)."""
+
+    def __init__(self, srv):
+        self.srv, self.max_batch = srv, srv.engine.max_batch
+
+    def submit(self, tokens, max_new):
+        return self.srv.submit(tokens, max_new_tokens=max_new)
+
+
+class CompileLog(logging.Handler):
+    """jax's own word on every program it compiles, instrumented or not."""
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.compiled = []
+
+    def emit(self, record):
+        text = record.getMessage()
+        if text.startswith(("Compiling ", "Finished XLA compilation")):
+            self.compiled.append(text[:120])
+
+    def __enter__(self):
+        jax.config.update("jax_log_compiles", True)
+        logging.getLogger("jax").addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        logging.getLogger("jax").removeHandler(self)
+        jax.config.update("jax_log_compiles", False)
+
+
+#: decode signatures the waves of 1 .. 4 requests of three tokens compile:
+#: one a batch bucket (and, paged, the one table width these lengths have),
+#: which is what the engine that read every step before the next compiled
+BUCKETS = 3
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2,
+                    reason="tp steps need >= 2 (emulated) devices")
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_the_window_compiles_nothing_after_the_benchmarks_warm_up(
+        models, config):
+    family, options = CONFIGS[config]
+    # prompts of 9 and 10 tokens, at most 6 more: two blocks throughout
+    window = [(prompt(1 + i, 9 + i % 2), n, None)
+              for i, n in enumerate([3, 6, 4, 5, 2, 6, 3, 5, 4])]
+    srv = serving.serve(models[family], max_batch=MAX_BATCH, block_size=BS,
+                        **options)
+    try:
+        eng = srv.engine
+        bench_serving.warm_up(Door(srv), [p for p, _, _ in window])
+        sigs = {sig for kind, sig in eng._sigs if kind == "decode"}
+        assert len(sigs) == BUCKETS, sorted(eng._sigs)
+        # a step launched with none in flight and a step launched ahead are
+        # one signature: no bucket was compiled twice
+        assert eng.decode_compilations == BUCKETS, sorted(eng._sigs)
+        # a wave is two steps, the second launched from the first's tokens
+        ahead = srv.snapshot()["throughput"]["decode_steps_ahead"]
+        assert ahead >= MAX_BATCH
+        mark = introspect.watchdog().mark()
+        compiled = eng.decode_compilations, eng.prefill_compilations
+        with CompileLog() as log:
+            got = serve_churned(srv, window[:6], window[6:])
+        assert [len(g) for g in got] == [n for _, n, _ in window]
+        assert (eng.decode_compilations, eng.prefill_compilations) == compiled
+        assert [e for e in introspect.watchdog().events()
+                if e["seq"] > mark] == []
+        assert log.compiled == []
+        snap = srv.snapshot()["throughput"]
+        assert snap["decode_steps_ahead"] > ahead
+    finally:
+        srv.close()

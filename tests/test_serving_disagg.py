@@ -314,19 +314,19 @@ def test_migration_racing_target_drain(tiny_lm):
     hold = None
     try:
         dec = fleet.replicas[1]
-        real = dec.engine.decode_step
+        real = dec.engine.decode_pass
         parked, hold = threading.Event(), threading.Event()
         state = {"n": 0}
 
-        def parking(seqs):
-            out = real(seqs)
+        def parking(*args, **kw):
+            out = real(*args, **kw)
             state["n"] += 1
             if state["n"] == 2:
                 parked.set()
                 hold.wait()
             return out
 
-        dec.engine.decode_step = parking
+        dec.engine.decode_pass = parking
         req = fleet.submit(prompt, max_new_tokens=max_new)
         calls = count_finishes(req)
         assert parked.wait(timeout=60)

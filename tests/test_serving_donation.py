@@ -55,8 +55,9 @@ i32 = jnp.int32
 STEP_ARGS = {
     "prefill": lambda paged: (jnp.zeros((C,), i32), i32(5),
                               jnp.arange(1, 9, dtype=i32)),
-    "decode": lambda paged: (
-        jnp.zeros((B,), i32), jnp.asarray([3, 9], i32),
+    "decode": lambda paged: (           # the carry at max_batch, then tokens
+        jnp.zeros((2 * B,), i32), jnp.zeros((B,), i32),
+        jnp.asarray([3, 9], i32),
         jnp.asarray([[1, 2], [3, 4]] if paged
                     else [[1, 2] + [0] * 6, [3, 4] + [0] * 6], i32)),
     "prefill_chunk": lambda paged: (jnp.zeros((C,), i32), i32(0), i32(5),
@@ -174,7 +175,8 @@ def oracle_tokens(params, cfg, prompt, max_new, block_size=BS):
     prefill = jax.jit(lambda p, k, v, t, n, tb: engine_mod.prefill(
         p, (k, v), t, n, tb, cfg))
     decode = jax.jit(lambda p, k, v, t, pos, tb: engine_mod.decode(
-        p, (k, v), t, pos, tb, cfg, block_size, kv_cache.LiveGatherView))
+        p, (k, v), jnp.zeros((1,), i32), t, pos, tb, cfg, block_size,
+        kv_cache.LiveGatherView))
     s_pad = engine_mod.pow2_bucket(len(prompt), lo=8, hi=cfg.max_len)
     toks = np.zeros((s_pad,), np.int32)
     toks[:len(prompt)] = prompt
@@ -361,6 +363,12 @@ def test_server_replays_everything_after_a_step_lost_the_pools(tiny_lm, case):
         snap = srv.snapshot()["requests"]
         assert snap["failed"] == 0 and snap["engine_failures"] == 1
         assert snap["failovers"] == seen["held"] >= 1
+        # the step in flight when the pools went (ISSUE 30; none yet where
+        # the first prefills fail) is dropped, and its tokens with it: what
+        # the replays resumed from were the tokens of collected steps
+        drains = srv.snapshot()["throughput"]["decode_drains"]
+        assert drains.get("fault", 0) == (0 if case == "gather_prefill" else 1)
+        assert srv._flight is None or srv._flight.seqs
         # the next step does not raise: a fresh request decodes, and the
         # blocks are all back but the prefix cache's
         assert srv.generate(prompts[0], max_new_tokens=10,

@@ -88,21 +88,22 @@ def count_finishes(req):
 
 def park_after_decodes(rep, n_calls):
     """Patch a replica's engine so its serving thread parks INSIDE the
-    decode seam after `n_calls` decode steps (tokens already appended)
-    — the wedged-mid-generation shape. Returns (parked, hold)."""
-    real = rep.engine.decode_step
+    decode seam after `n_calls` decode passes (the tokens of every step
+    but the one in flight already appended) — the wedged-mid-generation
+    shape. Returns (parked, hold)."""
+    real = rep.engine.decode_pass
     parked, hold = threading.Event(), threading.Event()
     state = {"n": 0}
 
-    def parking(seqs):
-        out = real(seqs)
+    def parking(*args, **kw):
+        out = real(*args, **kw)
         state["n"] += 1
         if state["n"] == n_calls:
             parked.set()
             hold.wait()
         return out
 
-    rep.engine.decode_step = parking
+    rep.engine.decode_pass = parking
     return parked, hold
 
 
@@ -173,8 +174,10 @@ def test_inflight_failover_token_identical(tiny_lm):
         req = victim.submit(prompt, max_new_tokens=max_new)
         calls = count_finishes(req)
         assert parked.wait(timeout=60)
-        # 3 tokens exist (prefill's first + 2 decode steps); the loop is
-        # parked mid-iteration and stops beating
+        # 2 tokens exist (prefill's first + the one step collected: the
+        # second pass launched a step that is still in flight, and its
+        # token goes with the wedge; the replay chooses it again); the
+        # loop is parked mid-iteration and stops beating
         victim._last_beat -= 999.0
         h = srv.health()                 # sweep: drain + failover
         assert srv._drained[0] is True and h["ok"] is True
@@ -183,7 +186,7 @@ def test_inflight_failover_token_identical(tiny_lm):
         assert calls["n"] == 1
         # the failover is visible on the TARGET replica's ledger
         assert srv.replicas[1].metrics.failovers == 1
-        assert srv.replicas[1].metrics.failover_resumed_tokens == 3
+        assert srv.replicas[1].metrics.failover_resumed_tokens == 2
         assert srv.snapshot()["aggregate"]["failovers"] == 1
         # unpark: the wedged loop resumes, must NOT double-finish, and
         # must release the detached sequence's blocks
@@ -346,6 +349,100 @@ def test_dead_replica_failover_then_respawn_serves_again(tiny_lm):
     finally:
         if hold is not None:
             hold.set()
+        srv.close()
+
+
+# -- a fault with a decode step in flight (ISSUE 30) ---------------------------
+#
+# From its second decoding pass on the loop holds a step that is launched
+# and not collected. Whatever ends the pass must drop or collect it so that
+# a sequence's tokens are exactly those of collected steps: then a replay
+# chooses the dropped step's tokens again and none is lost or doubled.
+
+IN_FLIGHT = [(arith_prompt(3, 2, 6), 9), (arith_prompt(4, 1, 9), 6),
+             (arith_prompt(5, 3, 7), 12)]
+
+
+def kill_at_evict(srv):
+    """The loop dies OUTSIDE the engine-fault isolation, in the eviction
+    that follows its fourth collected step."""
+    real, calls = srv.scheduler.evict, {"n": 0}
+
+    def bomb(engine):
+        calls["n"] += 1
+        if calls["n"] == 4:
+            raise RuntimeError("injected loop death")
+        return real(engine)
+
+    srv.scheduler.evict = bomb
+
+
+FAULTS = {
+    # the chaos seam raises where the next step would be launched
+    "decode_poison": (1, lambda srv: chaos.configure(serve_poison=(0, 4)),
+                      "fault"),
+    # the read of the step in flight fails (what an error on the device
+    # surfaces as): it and the step launched behind it are both dropped
+    "collect_fails": (1, lambda srv: fail_read(srv.engine, 3), "fault"),
+    "killed_loop_rescued": (2, lambda srv: kill_at_evict(srv.replicas[0]),
+                            "fault"),
+    "killed_loop_alone": (1, kill_at_evict, "fault"),
+    "close_drains": (1, lambda srv: None, None),
+}
+
+
+def fail_read(eng, at):
+    real, calls = eng._read_back, {"n": 0}
+
+    def read_back(span, result, stats):
+        if span.name == "serving.decode":
+            calls["n"] += 1
+            if calls["n"] == at:
+                raise RuntimeError("injected fault in the read")
+        return real(span, result, stats)
+
+    eng._read_back = read_back
+
+
+@pytest.mark.parametrize("case", sorted(FAULTS))
+def test_a_fault_with_a_step_in_flight_loses_and_doubles_no_token(tiny_lm,
+                                                                  case):
+    replicas, arm, drain = FAULTS[case]
+    want = [oracle_tokens(tiny_lm, p, n) for p, n in IN_FLIGHT]
+    srv = serving.serve(tiny_lm, replicas=replicas, max_batch=4,
+                        block_size=8, respawn_backoff=0.01) \
+        if replicas > 1 else serving.serve(tiny_lm, max_batch=4, block_size=8)
+    victim = srv.replicas[0] if replicas > 1 else srv
+    engine = victim.engine
+    try:
+        arm(srv)
+        reqs = [victim.submit(p, max_new_tokens=n) for p, n in IN_FLIGHT]
+        calls = [count_finishes(r) for r in reqs]
+        if case == "close_drains":
+            deadline = time.time() + 60
+            while victim.metrics.tokens_generated < 4:
+                assert time.time() < deadline
+                time.sleep(0.002)
+            srv.close(drain=True)       # its audit: no block leaked
+            assert victim._flight is None
+        if case == "killed_loop_alone":
+            for r in reqs:              # nobody to rescue them: failed, once
+                with pytest.raises(mx.MXNetError, match="loop died"):
+                    r.result(timeout=120)
+        else:
+            assert [r.result(timeout=120) for r in reqs] == want
+        assert [c["n"] for c in calls] == [1, 1, 1]
+        snap = victim.snapshot()
+        assert snap["throughput"]["decode_steps_ahead"] >= 2
+        if drain is not None:
+            assert snap["throughput"]["decode_drains"][drain] == 1
+            assert victim._flight is None
+        deadline = time.time() + 60
+        while engine.cache.pool.in_use and time.time() < deadline:
+            time.sleep(0.01)
+        assert engine.cache.pool.in_use == 0
+        engine.audit_quiescent()
+    finally:
         srv.close()
 
 

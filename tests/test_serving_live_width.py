@@ -77,7 +77,8 @@ def full_width_decode(params, k_pool, v_pool, tokens, positions, tables, cfg,
 def gather_decode(cfg):
     """The gather engine's decode step function, pools as two arguments."""
     return lambda p, k, v, t, pos, tb: engine_mod.decode(
-        p, (k, v), t, pos, tb, cfg, BS, kv_cache.LiveGatherView)
+        p, (k, v), jnp.zeros_like(t), t, pos, tb, cfg, BS,
+        kv_cache.LiveGatherView)
 
 
 def filled_pools(cfg, n_rows, seed=1):
@@ -229,7 +230,7 @@ def step_shapes(cfg, batch, pool, dtype, sharding=None):
             lambda: init_transformer_params(jax.random.PRNGKey(0), cfg)))
     nblk = cfg.max_len // pool[3]
     return (params, sds(pool, dtype), sds(pool, dtype), sds((batch,), i32),
-            sds((batch,), i32), sds((batch, nblk), i32))
+            sds((batch,), i32), sds((batch,), i32), sds((batch, nblk), i32))
 
 
 def assert_one_loop_a_layer(compiled, cfg, k_pool):

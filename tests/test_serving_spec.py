@@ -591,19 +591,19 @@ def test_spec_does_not_starve_prefill_chunks(tiny_lm):
         assert srv.engine.spec, srv.engine.spec_fallback
         events = []
         real_chunk = srv.engine.prefill_step
-        real_decode = srv.engine.decode_step
+        real_decode = srv.engine.decode_pass
 
         def chunk_spy(seq):
             events.append(("chunk", seq.request.id
                            if seq.request else None))
             return real_chunk(seq)
 
-        def decode_spy(seqs):
+        def decode_spy(*args, **kw):
             events.append(("decode", None))
-            return real_decode(seqs)
+            return real_decode(*args, **kw)
 
         srv.engine.prefill_step = chunk_spy
-        srv.engine.decode_step = decode_spy
+        srv.engine.decode_pass = decode_spy
         short = srv.submit(arith_prompt(1, 1, 4), max_new_tokens=40)
         deadline = time.perf_counter() + 60
         while srv.snapshot()["throughput"]["tokens_generated"] < 2:

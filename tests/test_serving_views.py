@@ -101,8 +101,8 @@ def run_decode(params, cfg, view):
     toks = jnp.asarray([t[-1] for t in TOKENS] + [0], i32)
     pos = jnp.asarray(lengths + [0], i32)
     tabs = jnp.asarray(np.concatenate([TABLES, np.zeros((1, NBLK), np.int32)]))
-    *_, logits, nxt = engine.decode(params, pools, toks, pos, tabs, cfg, BS,
-                                    view_of)
+    *_, logits, nxt = engine.decode(params, pools, jnp.zeros_like(toks), toks,
+                                    pos, tabs, cfg, BS, view_of)
     assert np.array_equal(np.asarray(nxt), np.asarray(logits).argmax(-1))
     return [(b, n, logits[b]) for b, n in enumerate(lengths)]
 

@@ -147,19 +147,19 @@ def test_http_fuzzed_traceparent_never_500(tiny_lm):
 
 
 def park_after_decodes(rep, n_calls):
-    real = rep.engine.decode_step
+    real = rep.engine.decode_pass
     parked, hold = threading.Event(), threading.Event()
     state = {"n": 0}
 
-    def parking(seqs):
-        out = real(seqs)
+    def parking(*args, **kw):
+        out = real(*args, **kw)
         state["n"] += 1
         if state["n"] == n_calls:
             parked.set()
             hold.wait()
         return out
 
-    rep.engine.decode_step = parking
+    rep.engine.decode_pass = parking
     return parked, hold
 
 

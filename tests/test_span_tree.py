@@ -169,46 +169,68 @@ def inside(child, parent):
 
 @pytest.mark.parametrize("paged", [None, True])
 def test_every_decoding_iteration_records_one_tree(tiny_lm, paged):
+    """A pass launches a step and collects the one the pass before launched
+    (ISSUE 30): its `serving.decode` span holds the build and the dispatch of
+    the first and the readback of the second; the append and the account of
+    the collected step follow it. The first pass collects nothing; the last
+    collects its own step too."""
     _, thread = serve_three(tiny_lm, paged=paged)
     spans = [s for s in telemetry.spans() if s["tid"] == thread]
     ids = {s["id"]: s for s in spans}
     assert spans and all("parent" in s for s in spans)
     roots = {s["name"] for s in spans if s["parent"] is None}
     assert roots == {"serving.loop"}
-    loops = [s for s in spans if s["name"] == "serving.loop"]
+    loops = sorted((s for s in spans if s["name"] == "serving.loop"),
+                   key=lambda s: s["attrs"]["it"])
     assert len({s["attrs"]["it"] for s in loops}) == len(loops)
-    decoding = 0
+    passes = []
     for loop in loops:
         under = by_name(s for s in spans if s["parent"] == loop["id"]
                         or ids.get(s["parent"], {}).get("parent") == loop["id"])
         assert len(under["serving.admit"]) == 1        # every working pass
-        if "serving.decode" not in under:
-            continue
-        decoding += 1
-        step = [s for s in under["serving.decode"] if "batch" in s["attrs"]]
+        if "serving.decode" in under:
+            passes.append(under)
+    assert len(passes) >= 6         # the longest request decodes 6 tokens
+    launched, appended = None, 0    # the batch of the step in flight
+    for n, under in enumerate(passes):
+        first, last = n == 0, n == len(passes) - 1
+        step, = [s for s in under["serving.decode"] if "batch" in s["attrs"]]
         copies = [s for s in under["serving.decode"] if "batch" not in s["attrs"]]
-        assert len(step) == 1 and len(copies) == step[0]["attrs"]["batch"]
-        assert all(c["parent"] == step[0]["id"]
-                   and abs(c["ts"] - step[0]["ts"]) < 1000 for c in copies)
+        collected = ([] if first else [launched]) \
+            + ([step["attrs"]["batch"]] if last else [])
+        # a copy a request for every token this pass appended
+        assert len(copies) == sum(collected)
+        appended += len(copies)
+        assert all(c["parent"] == step["id"]
+                   and abs(c["ts"] - step["ts"]) < 1000 for c in copies)
+        found = {name: [s for s in under[name] if "batch" in s["attrs"]]
+                 for name in TREE}
         for name, parent in TREE.items():
-            found = [s for s in under[name] if "batch" in s["attrs"]]
-            assert len(found) == 1, (name, loop["attrs"])
-            assert ids[found[0]["parent"]]["name"] == parent
-            assert inside(found[0], ids[found[0]["parent"]])
-        step, = step
-        build, = under["serving.decode.build"]
-        back, = under["serving.decode.readback"]
-        append, = under["serving.decode.append"]
-        account, = under["serving.account"]
-        # today's interval: opens before the arrays are built, closes with
-        # the readback, and does not cover what follows
+            for s in found[name]:
+                assert ids[s["parent"]]["name"] == parent
+                assert inside(s, ids[s["parent"]])
+        build, = found["serving.decode.build"]
+        sent, = found["serving.decode.dispatch"]
+        assert sent["attrs"]["ahead"] == (0 if first else 1)
+        assert build["attrs"]["batch"] == sent["attrs"]["batch"] \
+            == step["attrs"]["batch"]
+        for name in ("serving.decode.readback", "serving.decode.append",
+                     "serving.account"):
+            assert [s["attrs"]["batch"] for s in found[name]] == collected
+        # the interval: opens before the arrays are built, launches, then
+        # closes with the (last) readback, and does not cover what follows
         assert step["ts"] <= build["ts"]
-        assert back["ts"] + back["dur"] <= step["ts"] + step["dur"] \
-            <= back["ts"] + back["dur"] + 1000
-        assert append["ts"] >= step["ts"] + step["dur"]
-        assert account["ts"] >= append["ts"] + append["dur"]
-        assert loop["attrs"]["batch"] == step["attrs"]["batch"]
-    assert decoding >= 6        # the longest request decodes 6 tokens
+        assert build["ts"] + build["dur"] <= sent["ts"]
+        for back in found["serving.decode.readback"]:
+            assert back["ts"] >= sent["ts"] + sent["dur"]
+            assert back["ts"] + back["dur"] <= step["ts"] + step["dur"]
+        for append in found["serving.decode.append"]:
+            assert append["ts"] >= step["ts"] + step["dur"]
+        for account in found["serving.account"]:
+            assert account["ts"] >= found["serving.decode.append"][-1]["ts"]
+        launched = step["attrs"]["batch"]
+    # requests of 5, 6 and 7 tokens: one from the prefill, the rest a step
+    assert appended == 4 + 5 + 6
     admits = [s for s in spans if s["name"] == "serving.admit"]
     assert sum(s["attrs"]["admitted"] for s in admits) == 3
     for s in spans:             # prefills and queue waits hang under admit
@@ -345,5 +367,5 @@ def test_the_step_programs_carry_their_own_names(tiny_lm, config):
         params, *(jnp.zeros((cfg.n_layers, 4, cfg.n_heads, 8,
                              cfg.d_model // cfg.n_heads)),) * 2,
         jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
-        jnp.zeros((2, 3), jnp.int32)).as_text()
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2, 3), jnp.int32)).as_text()
     assert "module @jit_serving_decode " in text
