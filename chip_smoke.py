@@ -245,9 +245,10 @@ class Smoke:
                   run_s=round(run_s, 3))
         return outs, again
 
-    def margin(self, cfg, params, wave, waves):
+    def margin(self, cfg, params, wave, waves, forward=None):
         """How far the served tokens are from the argmax of the plain f32
-        forward (`transformer_apply`, teacher-forced, full precision): 0
+        forward (`transformer_apply`, or `forward(params, tokens (B, S))`,
+        teacher-forced, full precision): 0
         where a server agrees with the reference, the size of the tie it
         broke otherwise; the worst over `waves`. Batches are padded to one
         shape, so one compile. Two waves may differ from each other where
@@ -255,15 +256,15 @@ class Smoke:
         import jax
         import jax.numpy as jnp
         from mxnet_tpu.models.transformer import transformer_apply
+        forward = forward or (lambda p, t: transformer_apply(p, t, cfg))
         worst = 0.0
         for outs in waves:
             toks = np.zeros((len(wave), cfg.max_len), np.int32)
             for i, ((p, _), o) in enumerate(zip(wave, outs)):
                 toks[i, :len(p) + len(o)] = p + o
             with jax.default_matmul_precision("highest"):
-                logits = np.asarray(jax.jit(
-                    lambda p, t: transformer_apply(p, t, cfg))(
-                        params, jnp.asarray(toks)), np.float32)
+                logits = np.asarray(jax.jit(forward)(
+                    params, jnp.asarray(toks)), np.float32)
             for i, ((p, _), o) in enumerate(zip(wave, outs)):
                 rows = logits[i, len(p) - 1:len(p) + len(o) - 1]
                 worst = max(worst, float(np.max(
@@ -285,6 +286,7 @@ class Smoke:
                                      wave, paged=False)
             paged = self.serve_wave("server_f32_paged", (params, cfg),
                                     wave, paged=True)
+            self.kinds(wave, 1e-3)
         finally:
             jax.config.update("jax_default_matmul_precision", None)
         # a token may differ from the gather path's only where the
@@ -318,6 +320,43 @@ class Smoke:
             check(m <= budget, "%s strays from the f32 reference by %g "
                   "(budget %g)" % (leg, m, budget))
             self.emit(leg + "_check", max_margin=m, budget=budget)
+
+    def kinds(self, wave, tie):
+        """The family of window and full layers over a cache of two kinds
+        (`models/afmoe.py`), small: the same wave through `serving.serve`
+        on its gather path, prompts longer than the window and rings that
+        wrap, and its margin against its own dense forward in f32. Runs
+        inside `server`'s full-precision stretch."""
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.models.afmoe import (AfmoeConfig, afmoe_apply,
+                                            init_afmoe_params)
+        if self.rehearse:
+            cfg = AfmoeConfig(vocab=64, d_model=32, n_heads=4, n_kv_heads=2,
+                              head_dim=8, n_layers=3, n_dense_layers=1,
+                              layer_kinds=("window", "full", "window"),
+                              window=16, d_ff=64, d_expert=16, n_experts=8,
+                              experts_held=(2, 4), max_len=64)
+        else:
+            cfg = AfmoeConfig(vocab=8192, d_model=512, n_heads=8,
+                              n_kv_heads=2, head_dim=128, n_layers=3,
+                              n_dense_layers=1,
+                              layer_kinds=("window", "full", "window"),
+                              window=256, d_ff=2048, d_expert=512,
+                              n_experts=32, experts_held=(8, 16),
+                              max_len=1024)
+        params = init_afmoe_params(jax.random.PRNGKey(1), cfg)
+        waves = self.serve_wave("server_f32_kinds", (params, cfg), wave,
+                                paged=False)
+        m = self.margin(
+            cfg, params, wave, waves,
+            forward=lambda p, toks: jnp.stack(
+                [afmoe_apply(p, t, cfg)[0] for t in toks]))
+        check(m <= tie, "the two-kind cache strays from its f32 forward by "
+              "%g" % m)
+        self.emit("server_f32_kinds_check", max_margin=m, tie_tolerance=tie,
+                  window=cfg.window, kv_heads=cfg.n_kv_heads,
+                  experts_held=list(cfg.experts_held))
 
     # -- leg: kernels -----------------------------------------------------
 

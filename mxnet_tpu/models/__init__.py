@@ -20,3 +20,7 @@ from .transformer import (TransformerConfig, init_transformer_params,
 # latent attention + dropless sparse experts (the second served LM family)
 from .latent_moe import (LatentMoEConfig, init_latent_moe_params,
                          latent_moe_apply)
+
+# window and full attention, grouped-query and gated, over the same expert
+# layer (the third served LM family)
+from .afmoe import AfmoeConfig, init_afmoe_params, afmoe_apply

@@ -31,6 +31,7 @@ from .prefix_cache import PrefixCache, prefix_cache_enabled
 from .engine import (Engine, Sequence, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost, pow2_bucket)
 from .latent_lm import LatentMoELM
+from .afmoe_lm import AfmoeLM
 from .scheduler import (Scheduler, Request, QueueFull, RequestTimeout,
                         DeadlineExceeded, DeadlineUnmeetable,
                         BrownoutShed, make_resume)
@@ -50,7 +51,7 @@ from .spec import (DraftLM, self_draft, spec_decode_enabled, spec_k,
 __all__ = [
     "BlockPool", "PagedKVCache", "CacheOverflow",
     "PrefixCache", "prefix_cache_enabled",
-    "Engine", "Sequence", "TransformerLM", "LatentMoELM", "BlockLM",
+    "Engine", "Sequence", "TransformerLM", "LatentMoELM", "AfmoeLM", "BlockLM",
     "ExportedLM",
     "PoolsLost", "pow2_bucket",
     "Scheduler", "Request", "QueueFull", "RequestTimeout",

@@ -37,6 +37,11 @@ Two model families plug in behind one `Engine`:
   but it makes the whole serving stack (scheduler, batching, HTTP)
   available to every model the framework can express or export.
 
+Two further families bring their own step functions over the same views
+and pools and plug in beside `TransformerLM`: `serving/latent_lm.py`
+(a latent pool) and `serving/afmoe_lm.py` (window and full layers over a
+cache of two kinds, `kv_cache.CacheSpec.layer_kinds`).
+
 One decode step stays in flight (`Engine.decode_pass`): a pass launches
 step n + 1 from step n's tokens on the device (`carried_tokens`) and only
 then reads step n, so the device runs while the host appends, accounts,
@@ -157,7 +162,7 @@ class Sequence:
     chunk per `prefill_step`); `prefill_s` accumulates prefill wall time
     across chunks for the metrics roll-up."""
 
-    __slots__ = ("tokens", "prompt_len", "block_ids", "table_row",
+    __slots__ = ("tokens", "prompt_len", "blocks", "table_row",
                  "max_total", "eos_id", "done", "last_logits", "request",
                  "prefilled", "prefill_s", "cache_hit_tokens",
                  "shared_blocks", "token_logits")
@@ -165,8 +170,9 @@ class Sequence:
     def __init__(self, prompt, max_total, eos_id=None):
         self.tokens = list(prompt)
         self.prompt_len = len(prompt)
-        self.block_ids = []
-        self.table_row = None
+        self.blocks = ()              # its block ids, a list a kind of
+                                      # layer (`PagedKVCache.try_alloc`)
+        self.table_row = None         # `PagedKVCache.row` of them
         self.max_total = max_total
         self.eos_id = eos_id
         self.done = False
@@ -184,6 +190,12 @@ class Sequence:
     @property
     def generated(self):
         return self.tokens[self.prompt_len:]
+
+    @property
+    def block_ids(self):
+        """Its blocks of the first kind: all of them where layers are of
+        one kind."""
+        return self.blocks[0] if self.blocks else []
 
 
 class Step:
@@ -743,14 +755,18 @@ class Engine:
             cspec = model.cache_spec()
             dh, dt = cspec.head_dim, cspec.dtype
             self._nblk = max(1, math.ceil(self.max_len / block_size))
-            if num_blocks is None:
-                num_blocks = max_batch * self._nblk + 1
-            if self.paged_requested and cspec.layout != "kv":
-                self.paged_fallback = (
-                    "the pool holds %s rows, not keys and values: the "
-                    "paged kernel and the chunked prefill read the K and "
-                    "V planes" % cspec.layout)
-            elif self.paged_requested:
+            # a pool a kind of layer, each as large as max_batch
+            # sequences of max_len can fill: every block, or a ring's
+            # worth where a window is; `num_blocks` sizes the first
+            rings = [cspec.ring(k, block_size) for k in cspec.kinds]
+            sized = [max_batch * (min(r, self._nblk) if r else self._nblk)
+                     + 1 for r in rings]
+            if num_blocks is not None:
+                sized[0] = num_blocks
+            num_blocks = tuple(sized)
+            if self.paged_requested:
+                self.paged_fallback = cspec.paged_unfit()
+            if self.paged_requested and self.paged_fallback is None:
                 self.prefill_chunk = min(self.max_len,
                                          int(prefill_chunk
                                              or 2 * block_size))
@@ -896,10 +912,11 @@ class Engine:
     # -- admission accounting ------------------------------------------------
 
     def blocks_needed(self, prompt_len, max_new):
+        """Blocks a request reserves, a count a kind of layer."""
         if self.cache is None:
-            return 0
+            return ()
         total = min(self.max_len, prompt_len + max_new)
-        return self.cache.blocks_for(total)
+        return self.cache.blocks_by_kind(total)
 
     def can_admit(self, prompt_len, max_new):
         """Would this request's block reservation fit right now? With
@@ -917,10 +934,11 @@ class Engine:
                              % (prompt_len, self.max_len))
         if self.cache is None:
             return True
-        avail = self.cache.pool.available
+        avail = [pool.available for pool in self.cache.pools]
         if self.prefix_cache is not None:
-            avail += self.prefix_cache.reclaimable_blocks()
-        return self.blocks_needed(prompt_len, max_new) <= avail
+            avail[0] += self.prefix_cache.reclaimable_blocks()
+        return all(n <= a for n, a in
+                   zip(self.blocks_needed(prompt_len, max_new), avail))
 
     def cache_utilization(self):
         return self.cache.utilization() if self.cache else None
@@ -1064,15 +1082,16 @@ class Engine:
         if self.cache is not None:
             n = self.blocks_needed(L, max_new)
             if self.prefix_cache is None:
-                ids = self.cache.pool.try_alloc(n)
-                if ids is not None and self.kv_quant:
-                    self._zero_scales(ids, held=ids)
+                blocks = self.cache.try_alloc(n)
+                if blocks is not None and self.kv_quant:
+                    self._zero_scales(blocks[0], held=blocks[0])
             else:
-                ids = self._begin_cached(seq, prompt, n)
-            if ids is None:
+                ids = self._begin_cached(seq, prompt, n[0])
+                blocks = None if ids is None else (ids,)
+            if blocks is None:
                 return None
-            seq.block_ids = ids
-            seq.table_row = self.cache.table_row(ids, self._nblk)
+            seq.blocks = blocks
+            seq.table_row = self.cache.row(blocks, self._nblk)
         return seq
 
     def _zero_scales(self, ids, held):
@@ -1166,7 +1185,7 @@ class Engine:
         prompt = seq.tokens[:L]
         rid = seq.request.trace if seq.request is not None else None
         with telemetry.span("serving.prefill", trace=rid,
-                            category="serving", prompt_len=L,
+                            category="serving", prompt_len=L, length=L,
                             chunk_start=seq.prefilled) as step_span:
             if self.model.uses_cache and self.paged:
                 C = self.prefill_chunk
@@ -1197,6 +1216,7 @@ class Engine:
                                     hi=self.max_len)
                 toks = np.zeros((s_pad,), np.int32)
                 toks[:L] = prompt
+                step_span.attrs["bucket"] = s_pad
                 with self._count("prefill", s_pad):
                     logits, *stats = self._step(
                         self.model.prefill, jnp.asarray(toks),
@@ -1376,7 +1396,7 @@ class Engine:
             # gather path gets the full-capacity table and its one
             # program walks it as far as the longest live sequence
             # (`_attend_live`: the trip count is read from `pos`)
-            w = self._nblk
+            w = self.cache.table_width(self._nblk)
             if self.paged:
                 w = pow2_bucket(max(self.cache.blocks_for(n)
                                     for _, n, _ in rows),
@@ -1390,6 +1410,13 @@ class Engine:
                     pos[i] = n - 1
                     tabs[i] = s.table_row[:w]
                 step_span.attrs["live_max"] = int(pos.max()) + 1
+                if self.cache.spec.window:
+                    # tokens the rows hold on a layer that keeps every
+                    # one, and on a layer that keeps a window of them
+                    held = pos[:len(rows)] + 1
+                    step_span.attrs["live_full"] = int(held.sum())
+                    step_span.attrs["live_window"] = int(np.minimum(
+                        held, self.cache.spec.window).sum())
                 toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
                                    jnp.asarray(tabs))
             # same (batch, width) signature lattice whether the paged
@@ -1605,7 +1632,7 @@ class Engine:
         if self.prefix_cache is not None:
             resident = [e.block_id
                         for e in self.prefix_cache._by_hash.values()]
-        self.cache.pool.assert_quiescent(resident)
+        self.cache.assert_quiescent(resident)
 
     def close(self, audit=True):
         """End-of-life seam: with `audit=True` (the default) run the
@@ -1632,7 +1659,7 @@ class Engine:
         sequences whose KV cannot be trusted (a poisoned batch must not
         seed the cache), and a mid-prefill release registers nothing
         either way (its blocks may hold partial garbage)."""
-        if seq.block_ids:
+        if seq.blocks:
             if reusable and self.prefix_cache is not None and \
                     seq.prefilled >= seq.prompt_len:
                 # the final token was appended but its KV never written:
@@ -1640,5 +1667,6 @@ class Engine:
                 self.prefix_cache.insert(seq.tokens, seq.block_ids,
                                          len(seq.tokens) - 1,
                                          partial_ok=True)
-            self.cache.pool.free(seq.block_ids)
-            seq.block_ids = []
+            self.cache.note_recycled(len(seq.tokens) - 1, seq.blocks)
+            self.cache.free(seq.blocks)
+            seq.blocks = ()

@@ -39,6 +39,12 @@ axes as they lie, and with the pools donated (`PagedKVCache`) no step
 program holds an operation of the pool's size. The by-table gather hands
 the blocks out as they lie too, and the attention contracts over them
 (`gather_kv`).
+
+A model whose layers keep different things (`CacheSpec.layer_kinds`: every
+token, or a window of the last ones) has a pair of planes, a `BlockPool`
+and a group of table columns a KIND of layer; a window kind's columns are
+a ring. The views find a layer's planes and columns by its kind
+(`_place`); with one kind they are the whole of both.
 """
 from __future__ import annotations
 
@@ -51,13 +57,15 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..ops.pallas_paged import paged_attention
-from ..parallel.ring_attention import attention_reference
+from ..models.afmoe import banded_attention
 
 
 #: the names under which every step function takes the pool arrays: the
 #: arguments a step donates (engine `_program`); `kv_pool` is the one
-#: array of the latent layout
-POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale", "kv_pool")
+#: array of the latent layout, `k_ring`/`v_ring` the planes of a second
+#: kind of layer (`CacheSpec.layer_kinds`)
+POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale", "kv_pool",
+             "k_ring", "v_ring")
 #: lanes of a TPU tile: the latent pool's rows are whole tiles wide
 LANES = 128
 
@@ -75,12 +83,26 @@ class CacheSpec:
     bf16 row out as 640 in any case, and where the logical width is not
     whole tiles its default layout for the array avoids the padding by
     moving the BLOCK axis innermost, which every step then pays for with
-    two copies of the whole pool (PERF.md, PR 27)."""
+    two copies of the whole pool (PERF.md, PR 27).
+
+    `n_heads` is the heads the CACHE holds; a model whose query heads
+    outnumber them (grouped-query attention: query head h reads cached
+    head h // (n_q_heads / n_heads)) says so in `n_q_heads`.
+
+    Layers of two KINDS: `layer_kinds` names each layer "full" (every
+    token kept) or "window" (a query at position t sees key j iff t - j <
+    `window`, so only the last `window` tokens are kept). Each kind has
+    its own pair of K/V arrays over its own layers, its own `BlockPool`
+    and its own columns of a sequence's table; a window kind's columns
+    are a RING (`ring`). Empty: one kind, every layer full."""
     n_layers: int
     dtype: object
     n_heads: int = 0
     head_dim: int = 0
     latent_dim: int = 0
+    n_q_heads: int = 0
+    layer_kinds: tuple = ()
+    window: int = 0
 
     @property
     def layout(self):
@@ -91,9 +113,51 @@ class CacheSpec:
         return -(-self.latent_dim // LANES) * LANES
 
     def values_per_token(self):
-        """Cached values one token occupies over all layers."""
+        """Cached values one token occupies over all layers (a token
+        inside the window, where kinds differ)."""
         return self.n_layers * (self.row_width
                                 or 2 * self.n_heads * self.head_dim)
+
+    @property
+    def kinds(self):
+        """The kinds of layer present, "full" first: the order of the
+        pool's arrays, its block pools and a table's groups of columns."""
+        return tuple(k for k in ("full", "window")
+                     if k in (self.layer_kinds or ("full",)))
+
+    def layers_of(self, kind):
+        """The model's layers of one kind, in order: layer `i` of the
+        model is layer `layers_of(kind).index(i)` of its kind's arrays."""
+        if not self.layer_kinds:
+            return tuple(range(self.n_layers))
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
+
+    def ring(self, kind, block_size):
+        """Blocks a sequence holds at most for a layer of `kind`: 0 (no
+        bound) where every token is kept; `window / block_size + 1` for
+        a window, which is every block the last `window` positions can
+        touch. Position p lies in column `(p // block_size) % ring`: the
+        block it overwrites is wholly behind the window by then."""
+        return self.window // block_size + 1 if kind == "window" else 0
+
+    def paged_unfit(self):
+        """Why the paged kernel (and what is built on it: chunked
+        prefill, the prefix cache, the int8 pool, speculation, tensor
+        parallelism) cannot read this cache, or None."""
+        if self.layout != "kv":
+            return ("the pool holds %s rows, not keys and values: the "
+                    "paged kernel and the chunked prefill read the K and "
+                    "V planes" % self.layout)
+        if self.n_q_heads and self.n_q_heads != self.n_heads:
+            return ("%d query heads read %d cached heads: the paged "
+                    "kernel walks one block a query head"
+                    % (self.n_q_heads, self.n_heads))
+        if self.kinds != ("full",):
+            return ("layers of kinds %s keep different blocks: the paged "
+                    "kernel walks one table, and a window layer's blocks "
+                    "are recycled, so they cannot be shared by prefix"
+                    % "/".join(self.kinds))
+        return None
 
 
 class CacheOverflow(MXNetError):
@@ -229,7 +293,7 @@ class BlockPool:
 
 
 class PagedKVCache:
-    """Device-side K/V pools plus the host free-list.
+    """Device-side K/V pools plus the host free-lists.
 
     Arrays: ``k``/``v`` of shape (n_layers, num_blocks, n_heads,
     block_size, head_dim) — contiguous-per-layer block layout (see module
@@ -257,26 +321,52 @@ class PagedKVCache:
     block_size, row_width) in the served dtype: `arrays()` has length
     one, and ``k``/``v`` do not exist. Blocks, tables and the free-list
     are the same.
+
+    A spec with layers of two KINDS (`CacheSpec.layer_kinds`) has a pair
+    of planes a kind, each over that kind's layers and that kind's
+    blocks: ``k``/``v`` the first kind's, ``k_ring``/``v_ring`` the
+    window kind's, and a `BlockPool` a kind (`pools`; `pool` is the
+    first's). A sequence holds a list of blocks a kind (`try_alloc`,
+    `free`) and its table row is the kinds' columns side by side
+    (`row`): the first kind's at the engine's width, a window kind's at
+    its ring. One kind is the same code with one entry in each.
     """
 
     def __init__(self, n_layers, n_heads, head_dim, block_size=16,
                  num_blocks=64, dtype=jnp.float32, kv_dtype=None,
-                 latent_dim=0):
+                 latent_dim=0, spec=None):
+        self.spec = spec or CacheSpec(n_layers, dtype, n_heads, head_dim,
+                                      latent_dim)
         self.n_layers = n_layers
         self.n_heads = n_heads
         self.head_dim = head_dim
         self.latent_dim = latent_dim
-        self.spec = CacheSpec(n_layers, dtype, n_heads, head_dim, latent_dim)
         self.block_size = block_size
-        self.num_blocks = num_blocks
-        self.pool = BlockPool(num_blocks)
+        kinds = self.spec.kinds
+        #: blocks of each kind's planes; an int sizes the first kind and
+        #: leaves the others as large
+        self.blocks_of = tuple(num_blocks) if np.ndim(num_blocks) \
+            else (num_blocks,) * len(kinds)
+        self.num_blocks = self.blocks_of[0]
+        self.pools = tuple(BlockPool(n) for n in self.blocks_of)
+        self.pool = self.pools[0]
+        #: the ring of each kind (`CacheSpec.ring`), 0 where none
+        self.rings = tuple(self.spec.ring(k, block_size) for k in kinds)
+        #: a window kind's blocks written over again by the same
+        #: sequence (each was wholly behind the window), counted as
+        #: sequences end
+        self.recycled = 0
+        #: blocks in use, a kind, when the first kind's count was last at
+        #: its highest: what the kinds hold of the same sequences
+        self.held_at_high_water = (0,) * len(kinds)
         if kv_dtype is not None and str(kv_dtype) != "int8":
             raise MXNetError("kv_dtype %r is not supported (int8 or "
                              "None)" % (kv_dtype,))
         self.kv_dtype = "int8" if kv_dtype is not None else None
         self._dtype = jnp.int8 if self.kv_dtype else dtype
-        if latent_dim and self.kv_dtype:
-            raise MXNetError("the latent layout has no int8 pool")
+        if (latent_dim or len(kinds) > 1) and self.kv_dtype:
+            raise MXNetError("only the one-kind K/V layout has an int8 "
+                             "pool")
         self._sharding = self._scale_sharding = None
         self.remake()
 
@@ -286,7 +376,7 @@ class PagedKVCache:
         return cls(spec.n_layers, spec.n_heads, spec.head_dim,
                    block_size=block_size, num_blocks=num_blocks,
                    dtype=spec.dtype, kv_dtype=kv_dtype,
-                   latent_dim=spec.latent_dim)
+                   latent_dim=spec.latent_dim, spec=spec)
 
     @property
     def quantized(self):
@@ -296,29 +386,40 @@ class PagedKVCache:
     def layout(self):
         return self.spec.layout
 
+    def _planes(self):
+        """[(attribute, shape, dtype, is it a scale sidecar)] of the
+        device arrays, in the order every step takes and returns them:
+        (k, v), and the scale sidecars of an int8 pool; (kv,) in the
+        latent layout; (k, v, k_ring, v_ring) with a window kind."""
+        if self.latent_dim:
+            return [("kv", (self.n_layers, self.num_blocks, self.block_size,
+                            self.spec.row_width), self._dtype, False)]
+        planes = []
+        for kind, blocks, names in zip(self.spec.kinds, self.blocks_of,
+                                       (("k", "v"), ("k_ring", "v_ring"))):
+            shape = (len(self.spec.layers_of(kind)), blocks, self.n_heads,
+                     self.block_size, self.head_dim)
+            planes += [(n, shape, self._dtype, False) for n in names]
+        if self.quantized:
+            planes += [(n, planes[0][1][:3], jnp.float32, True)
+                       for n in ("k_scale", "v_scale")]
+        return planes
+
     def arrays(self):
         """The device arrays in the order every step takes and returns
-        them: (k, v), and the scale sidecars of an int8 pool; (kv,) in
-        the latent layout."""
-        if self.latent_dim:
-            return (self.kv,)
-        if self.quantized:
-            return (self.k, self.v, self.k_scale, self.v_scale)
-        return (self.k, self.v)
+        them (`_planes`)."""
+        return tuple(getattr(self, n) for n, _, _, _ in self._planes())
 
     def rebind(self, arrays):
         """Take a step's results as the pool (see `arrays`)."""
-        if self.latent_dim:
-            self.kv, = arrays
-        elif self.quantized:
-            self.k, self.v, self.k_scale, self.v_scale = arrays
-        else:
-            self.k, self.v = arrays
+        for (n, _, _, _), a in zip(self._planes(), arrays, strict=True):
+            setattr(self, n, a)
 
     def drop(self):
         """Let the device arrays go (a server being torn down hands its
         pool's memory back before its successor's is made)."""
-        self.k = self.v = self.k_scale = self.v_scale = self.kv = None
+        for n in ("k", "v", "k_scale", "v_scale", "kv", "k_ring", "v_ring"):
+            setattr(self, n, None)
 
     def lost(self):
         """Did a step consume the arrays and give nothing back (it
@@ -328,24 +429,14 @@ class PagedKVCache:
     def remake(self):
         """Empty pools (and sidecars) under the placement `place` gave:
         at construction, when placed, and after `lost()`. The host
-        free-list is not touched — whoever holds blocks still frees
+        free-lists are not touched — whoever holds blocks still frees
         them."""
         # let go first: the old and the new pool never lie side by side
         self.drop()
-        if self.latent_dim:
-            self.kv = jnp.zeros((self.n_layers, self.num_blocks,
-                                 self.block_size, self.spec.row_width),
-                                self._dtype, device=self._sharding)
-            return
-        shape = (self.n_layers, self.num_blocks, self.n_heads,
-                 self.block_size, self.head_dim)
-        self.k = jnp.zeros(shape, self._dtype, device=self._sharding)
-        self.v = jnp.zeros(shape, self._dtype, device=self._sharding)
-        if self.quantized:
-            self.k_scale = jnp.zeros(shape[:3], jnp.float32,
-                                     device=self._scale_sharding)
-            self.v_scale = jnp.zeros(shape[:3], jnp.float32,
-                                     device=self._scale_sharding)
+        for n, shape, dtype, scale in self._planes():
+            setattr(self, n, jnp.zeros(
+                shape, dtype,
+                device=self._scale_sharding if scale else self._sharding))
 
     def place(self, sharding, scale_sharding=None):
         """Lay the device pools out under `sharding` (a NamedSharding, or
@@ -366,14 +457,80 @@ class PagedKVCache:
         table's last occupied slot."""
         return max(1, math.ceil(n_tokens / self.block_size))
 
+    def blocks_by_kind(self, n_tokens):
+        """Blocks of each kind a sequence of n_tokens holds: all of them
+        where every token is kept, no more than the ring where a window
+        is."""
+        n = self.blocks_for(n_tokens)
+        return tuple(min(n, ring) if ring else n for ring in self.rings)
+
+    def try_alloc(self, counts):
+        """Reserve `counts[i]` blocks of kind i, all or none: a list of
+        ids a kind, or None when any kind is short right now (nothing is
+        taken then); `CacheOverflow` when a kind never could."""
+        for pool, n in zip(self.pools, counts, strict=True):
+            if n > pool.num_blocks - 1:
+                pool.try_alloc(n)             # raises, having taken nothing
+        got = []
+        for pool, n in zip(self.pools, counts):
+            ids = pool.try_alloc(n)
+            if ids is None:
+                self.free(got)
+                return None
+            got.append(ids)
+        if self.pool.in_use >= self.held_at_high_water[0]:
+            self.held_at_high_water = tuple(p.in_use for p in self.pools)
+        return tuple(got)
+
+    def free(self, blocks):
+        """Give back a sequence's blocks, a list a kind (`try_alloc`)."""
+        for pool, ids in zip(self.pools, blocks):
+            if ids:
+                pool.free(ids)
+
+    def note_recycled(self, n_tokens, blocks):
+        """A sequence that held `blocks` ends after n_tokens: count the
+        ring columns it wrote over again."""
+        for ring, ids in zip(self.rings, blocks):
+            if ring:
+                self.recycled += max(0, self.blocks_for(n_tokens) - len(ids))
+
     def table_row(self, block_ids, n_entries):
         """Fixed-width int32 table row: allocated ids, null-padded."""
         row = np.zeros((n_entries,), np.int32)
         row[:len(block_ids)] = block_ids
         return row
 
+    def table_width(self, n_entries):
+        """Columns of a whole table row: the first kind's `n_entries`,
+        then each further kind's ring."""
+        return n_entries + sum(self.rings[1:])
+
+    def row(self, blocks, n_entries):
+        """A sequence's table row over all kinds, side by side
+        (`table_width`)."""
+        return np.concatenate([
+            self.table_row(ids, ring if i else n_entries)
+            for i, (ids, ring) in enumerate(zip(blocks, self.rings))])
+
     def utilization(self):
         return self.pool.in_use / float(self.num_blocks - 1)
+
+    def further_kinds(self):
+        """{kind: its pool's counts} of every kind after the first, whose
+        counts are the pool's own (`pool`, `num_blocks`): what the
+        metrics publish by kind."""
+        return {kind: {"blocks_in_use": pool.in_use,
+                       "blocks_high_water": pool.high_water,
+                       "blocks_total": pool.num_blocks - 1,
+                       "blocks_recycled": self.recycled}
+                for kind, pool in list(zip(self.spec.kinds, self.pools))[1:]}
+
+    def assert_quiescent(self, cache_resident=()):
+        """`BlockPool.assert_quiescent` over every kind; only the first
+        kind's blocks can be prefix-cache residents."""
+        for i, pool in enumerate(self.pools):
+            pool.assert_quiescent(() if i else cache_resident)
 
 
 # ---------------------------------------------------------------------------
@@ -381,12 +538,13 @@ class PagedKVCache:
 # ---------------------------------------------------------------------------
 
 
-def flat_slots(block_table, positions, block_size):
+def flat_slots(block_table, positions, block_size, ring=0):
     """Flat pool slot for each (row, position): the position'th token of a
     sequence lives in its table's position//bs block at offset
-    position%bs. block_table (B, nblk), positions (B,) -> (B,)."""
-    blk = jnp.take_along_axis(block_table,
-                              positions[:, None] // block_size,
+    position%bs, or, where the columns are a ring, in column
+    (position//bs) % ring. block_table (B, nblk), positions (B,) -> (B,)."""
+    col = positions[:, None] // block_size
+    blk = jnp.take_along_axis(block_table, col % ring if ring else col,
                               axis=1)[:, 0]
     return blk * block_size + positions % block_size
 
@@ -464,6 +622,32 @@ def write_kv_prompt(k_pool, v_pool, layer, table_row, k_new, v_new):
                       ((0, nb * bs - S), (0, 0), (0, 0)))
         blocks = new.reshape(nb, bs, H, Dh).transpose(0, 2, 1, 3)
         return pool.at[layer, ids].set(blocks)
+
+    return put(k_pool, k_new), put(v_pool, v_new)
+
+
+def write_kv_prompt_ring(k_pool, v_pool, layer, table_row, k_new, v_new,
+                         length):
+    """`write_kv_prompt` into the columns of a RING (`CacheSpec.ring`;
+    table_row (ring,)): of a prompt's blocks, column r takes the newest
+    one that falls on it and is not past the prompt's last real token
+    (position length - 1), so a prompt longer than the window leaves
+    only what the window still sees, and the padding behind a prompt
+    never lands on a block that is still seen. A column no such block
+    falls on (the prompt is shorter than the ring) is not written: its
+    write goes to the null block."""
+    bs, ring = k_pool.shape[3], table_row.shape[0]
+    S, H, Dh = k_new.shape
+    nb = -(-S // bs)
+    last = (length - 1) // bs
+    src = last - (last - jnp.arange(ring)) % ring            # (ring,)
+    ids = jnp.where(src >= 0, table_row, 0)
+
+    def put(pool, new):
+        new = jnp.pad(new.astype(pool.dtype),
+                      ((0, nb * bs - S), (0, 0), (0, 0)))
+        blocks = new.reshape(nb, bs, H, Dh).transpose(0, 2, 1, 3)
+        return pool.at[layer, ids].set(blocks[jnp.maximum(src, 0)])
 
     return put(k_pool, k_new), put(v_pool, v_new)
 
@@ -567,29 +751,68 @@ def gather_kv(k_pool, v_pool, layer, block_table):
 # ---------------------------------------------------------------------------
 
 
+def _place(spec, layer, pools, tables):
+    """Where layer `layer` keeps its keys and values: (where its kind's
+    K plane is in `pools`, V after it; the layer's index in them; its
+    kind's columns of `tables` (B, W); the window; the ring). `spec`
+    None is one kind over every layer: the two planes, the whole table,
+    no window."""
+    if spec is None or spec.kinds == ("full",):
+        return 0, layer, tables, 0, 0
+    i = spec.kinds.index(spec.layer_kinds[layer])
+    rings = [spec.ring(k, pools[0].shape[3]) for k in spec.kinds]
+    first = tables.shape[1] - sum(rings[1:])
+    lo = first + sum(rings[1:i]) if i else 0
+    return (2 * i, spec.layers_of(spec.kinds[i]).index(layer),
+            tables[:, lo:lo + (rings[i] if i else first)],
+            spec.window if rings[i] else 0, rings[i])
+
+
+def _put(pools, i, planes):
+    """`pools` with the pair at `i` replaced."""
+    return pools[:i] + tuple(planes) + pools[i + 2:]
+
+
+#: query rows one pass of a prompt's attention scores at once, against
+#: the keys of its band (`banded_attention`)
+PROMPT_Q_BLOCK = 256
+
+
 class PromptView:
     """Prefill of a whole prompt: the rows are positions 0..S-1 of ONE
-    sequence. Every layer's K/V go into the blocks of `table_row`
-    (`write_kv_prompt`), and attention is dense and causal over the
-    prompt's own K/V: the cache is written, not read."""
+    sequence of true `length`. Every layer's K/V go into the blocks of
+    its kind's columns of `table_row` (`write_kv_prompt`; a ring's by
+    `write_kv_prompt_ring`), and attention is causal over the prompt's
+    own K/V inside the layer's band (`banded_attention`): the cache is
+    written, not read."""
 
-    def __init__(self, pools, table_row):
+    def __init__(self, pools, table_row, spec=None, length=None):
         self.pools, self.table_row = tuple(pools), table_row
+        self.spec, self.length = spec, length
 
     def attend(self, layer, q, k, v):
-        self.pools = write_kv_prompt(*self.pools, layer, self.table_row,
-                                     k, v)
-        q, k, v = (t.transpose(1, 0, 2)[None] for t in (q, k, v))
-        return attention_reference(q, k, v, causal=True)[0] \
-            .transpose(1, 0, 2)                                # (S, H, Dh)
+        i, j, row, window, ring = _place(self.spec, layer, self.pools,
+                                         self.table_row[None])
+        planes = self.pools[i:i + 2]
+        if ring:
+            planes = write_kv_prompt_ring(*planes, j, row[0], k, v,
+                                          self.length)
+        else:
+            planes = write_kv_prompt(*planes, j, row[0], k, v)
+        self.pools = _put(self.pools, i, planes)
+        return banded_attention(q, k, v, window, PROMPT_Q_BLOCK)
 
 
 #: keys one pass of the live-gather view's attention loop folds in: a
 #: whole number of blocks (PERF.md, PR 28: 128, 256 and 512 on the chip)
 _DECODE_CHUNK_TOKENS = 128
+#: what a key outside a row's window scores where a whole chunk may be
+#: outside it: finite, so that the running maximum is, and what such a
+#: chunk adds is wiped by the first chunk that holds a key the row sees
+_UNSEEN = -1e30
 
 
-def _attend_live(qh, k_pool, v_pool, layer, tables, positions):
+def _attend_live(qh, k_pool, v_pool, layer, tables, positions, window=0):
     """Attention of one query a sequence (qh (B, H, Dh), the newest
     position) over layer `layer` of the pools, walking the block table
     only as far as the batch's longest live sequence: ONE loop whose
@@ -599,9 +822,19 @@ def _attend_live(qh, k_pool, v_pool, layer, tables, positions):
     ops/pallas_paged.py) is compiled once and whose trip count is read
     from `positions` on the device. So the bytes a step moves follow
     the live lengths with one program per batch bucket and no branch.
+    The pools hold Hkv heads, H a multiple of it: query head h reads
+    head h // (H / Hkv), contracted as the blocks lie.
+
+    With a `window` the columns are a RING (`CacheSpec.ring`): column r
+    of row b holds the newest block that falls on it, block
+    `n - (n - r) % ring` for n the block of the row's position, or
+    nothing yet (a negative block). The walk is then bounded by the
+    ring as well, and a key is seen iff its position is real, not past
+    the query's and less than `window` behind it.
     The pools are only read. Returns (B, H, Dh) float32."""
     B, H, Dh = qh.shape
-    block_size = k_pool.shape[3]
+    Hkv, block_size = k_pool.shape[2:4]
+    G = H // Hkv
     nblk = tables.shape[1]
     cb = max(1, min(nblk, _DECODE_CHUNK_TOKENS // block_size))
     ct = cb * block_size
@@ -610,52 +843,78 @@ def _attend_live(qh, k_pool, v_pool, layer, tables, positions):
     # position
     tables = jnp.pad(tables, ((0, 0), (0, -nblk % cb)))
     offs = jnp.arange(ct)
+    qg = qh.reshape(B, Hkv, G, Dh)
+    unseen = _UNSEEN if window else -jnp.inf
 
     def fold(c, carry):
         m, l, acc = carry
         tab = jax.lax.dynamic_slice_in_dim(tables, c * cb, cb, axis=1)
-        ks, vs = gather_kv(k_pool, v_pool, layer, tab)   # (B,cb,H,bs,Dh)
+        ks, vs = gather_kv(k_pool, v_pool, layer, tab)   # (B,cb,Hkv,bs,Dh)
         # same masking/upcast semantics as attention_reference, with the
         # length mask standing in for the causal mask (the query IS the
         # newest position); position t is (block n, offset s) = divmod(t,
         # block_size), contracted over as the blocks lie in the pool
-        s = jnp.einsum("bhd,bnhsd->bhns", qh, ks).astype(jnp.float32) * scale
-        live = (c * ct + offs)[None, :] <= positions[:, None]     # (B, ct)
-        s = jnp.where(live[:, None, :], s.reshape(B, H, ct), -jnp.inf)
+        s = jnp.einsum("bkgd,bnksd->bkgns", qg, ks).astype(jnp.float32) \
+            * scale
+        if window:
+            n = positions[:, None] // block_size                   # (B, 1)
+            col = (c * cb + jnp.arange(cb))[None, :]
+            held = jnp.where(col < nblk, n - (n - col) % nblk, -1)
+            at = (held[:, :, None] * block_size
+                  + jnp.arange(block_size)).reshape(B, ct)
+            live = (at >= 0) & (at <= positions[:, None]) \
+                & (positions[:, None] - at < window)
+        else:
+            live = (c * ct + offs)[None, :] <= positions[:, None]  # (B, ct)
+        s = jnp.where(live[:, None, :], s.reshape(B, H, ct), unseen)
         # position 0 is live in every row, so `m` is finite from the
         # first chunk on and a chunk wholly past a row adds exact zeros
+        # (with a window: see `_UNSEEN`)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l = alpha * l + p.sum(axis=-1)
         acc = alpha[..., None] * acc + jnp.einsum(
-            "bhns,bnhsd->bhd", p.reshape(B, H, cb, block_size),
-            vs.astype(p.dtype))
+            "bkgns,bnksd->bkgd", p.reshape(B, Hkv, G, cb, block_size),
+            vs.astype(p.dtype)).reshape(B, H, Dh)
         return m_new, l, acc
 
-    init = (jnp.full((B, H), -jnp.inf, jnp.float32),
+    init = (jnp.full((B, H), unseen, jnp.float32),
             jnp.zeros((B, H), jnp.float32),
             jnp.zeros((B, H, Dh), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, jnp.max(positions) // ct + 1, fold,
-                                  init)
+    trips = jnp.max(positions) // ct + 1
+    if window:
+        trips = jnp.minimum(trips, tables.shape[1] // cb)
+    _, l, acc = jax.lax.fori_loop(0, trips, fold, init)
     return acc / l[..., None]
 
 
 class LiveGatherView:
     """Decode on the gather path: row b is sequence b's newest token at
-    `positions[b]`. Its K/V are appended at `slots` (`append_kv`), then
-    the row attends over its sequence's blocks by table as far as the
-    batch's longest live sequence reaches (`_attend_live`). `tables` is
-    the full-capacity table; a padded row carries the all-null one."""
+    `positions[b]`. Its K/V are appended at its slot in the layer's
+    kind's columns (`append_kv`), then the row attends over its
+    sequence's blocks by table as far as the batch's longest live
+    sequence reaches, or round the ring (`_attend_live`). `tables` is
+    the full-capacity table, every kind's columns side by side
+    (`PagedKVCache.row`); a padded row carries the all-null one.
+    `slots` are the one kind's (`flat_slots`), or None where the view
+    works them out a kind."""
 
-    def __init__(self, pools, tables, positions, slots):
+    def __init__(self, pools, tables, positions, slots=None, spec=None):
         self.pools = tuple(pools)
-        self.tables, self.positions, self.slots = tables, positions, slots
+        self.tables, self.positions, self.spec = tables, positions, spec
+        self.slots = slots
 
     def attend(self, layer, q, k, v):
-        self.pools = append_kv(*self.pools, layer, self.slots, k, v)
-        return _attend_live(q, *self.pools, layer, self.tables,
-                           self.positions)
+        i, j, tab, window, ring = _place(self.spec, layer, self.pools,
+                                         self.tables)
+        slots = self.slots
+        if slots is None:
+            slots = flat_slots(tab, self.positions, self.pools[i].shape[3],
+                               ring)
+        planes = append_kv(*self.pools[i:i + 2], j, slots, k, v)
+        self.pools = _put(self.pools, i, planes)
+        return _attend_live(q, *planes, j, tab, self.positions, window)
 
 
 class PagedView:

@@ -803,6 +803,17 @@ class ServingMetrics:
             self._g_prefix_resident.set(pc.resident_tokens)
             self._g_prefix_blocks.set(len(pc))
             self._g_prefix_hit_rate.set(pc.hit_rate)
+        # a cache of several kinds of layer (kv_cache.CacheSpec): the
+        # gauges above are its first kind's; each further kind's pool
+        # has its own, declared only when such an engine is observed
+        cache = getattr(engine, "cache", None)
+        if cache is not None:
+            for kind, counts in cache.further_kinds().items():
+                for name, n in counts.items():
+                    self.registry.gauge(
+                        "serving_%s_%s" % (kind, name),
+                        help="the %s layers' pool (recycled: ring blocks "
+                             "a sequence wrote over again)" % kind).set(n)
         # the dropless family's rows per held expert: counters declared
         # only when such an engine is observed, brought up to the
         # adapter's own tally by delta (a step adds to one array, not to
@@ -1037,6 +1048,7 @@ class ServingMetrics:
                 snap["cache"]["blocks_available"] = pool.available
                 snap["cache"]["blocks_high_water"] = pool.high_water
                 snap["cache"]["blocks_total"] = engine.cache.num_blocks - 1
+                snap["cache"].update(engine.cache.further_kinds())
         if scheduler is not None:
             snap["scheduler"] = {
                 "token_budget": scheduler.token_budget,

@@ -27,10 +27,11 @@ import time
 from ..base import MXNetError
 from .. import telemetry
 from ..utils import chaos
-from ..models import latent_moe
+from ..models import afmoe, latent_moe
 from .engine import (Engine, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost)
 from .latent_lm import LatentMoELM
+from .afmoe_lm import AfmoeLM
 from .scheduler import (Scheduler, Request, QueueFull, BrownoutShed,
                         DeadlineExceeded, DeadlineUnmeetable, make_resume)
 from .metrics import ServingMetrics
@@ -55,6 +56,8 @@ def _resolve_model(model, vocab=None, max_len=None, time_major=False):
         params, cfg = model
         if isinstance(cfg, latent_moe.LatentMoEConfig):
             return LatentMoELM(params, cfg)
+        if isinstance(cfg, afmoe.AfmoeConfig):
+            return AfmoeLM(params, cfg)
         return TransformerLM(params, cfg)
     if hasattr(model, "collect_params"):          # Gluon Block
         if vocab is None or max_len is None:

@@ -28,6 +28,7 @@ from mxnet_tpu.models.transformer import (TransformerConfig,
 from mxnet_tpu.serving.spec import self_draft
 from mxnet_tpu.telemetry import introspect
 
+from chipbench.families import afmoe_lm as kinds_family
 from chipbench.families import latent_moe_lm as latent_family
 from chipbench.generators import serving as bench_serving
 
@@ -48,6 +49,20 @@ LATENT = {
                      "original_max_position_embeddings": 4096, "type": "yarn"},
     "dtype": "float32"}
 
+#: window and full layers over a cache of two kinds, two KV heads under six
+#: query heads; a window of 8 at a block of 8 is a ring of two blocks, which
+#: a 20-token prompt and its 16 tokens wrap twice
+KINDS = {
+    "hidden_size": 48, "num_attention_heads": 6, "num_key_value_heads": 2,
+    "head_dim": 8, "intermediate_size": 96, "moe_intermediate_size": 24,
+    "num_shared_experts": 1, "num_experts": 2, "num_experts_published": 8,
+    "expert_parallel": 4, "expert_rank": 2, "num_experts_per_tok": 4,
+    "route_scale": 2.448, "num_hidden_layers": 3, "num_dense_layers": 1,
+    "layer_types": ["sliding_attention", "full_attention",
+                    "sliding_attention"],
+    "sliding_window": 8, "vocab_size": 96, "rms_norm_eps": 1e-5,
+    "rope_theta": 10000, "mup_enabled": True, "dtype": "float32"}
+
 #: configuration -> (model family, what `serve` and `Engine` are told)
 CONFIGS = {
     "gather": ("dense", dict()),
@@ -57,6 +72,7 @@ CONFIGS = {
     "paged_q8": ("dense", dict(paged=True, kv_quant=True)),
     "tp2": ("dense", dict(paged=True, tp=2)),
     "latent": ("latent", dict()),
+    "kinds": ("kinds", dict()),
 }
 
 
@@ -65,10 +81,13 @@ def models():
     cfg = TransformerConfig(vocab=48, d_model=32, n_heads=4, n_layers=2,
                             d_ff=64, max_len=64)
     weights = latent_family.make_weights(LATENT, 11)
+    kinds = kinds_family.make_weights(KINDS, 11)
     return {"dense": (init_transformer_params(jax.random.PRNGKey(0), cfg),
                       cfg),
             "latent": (latent_family.program_params(weights),
-                       latent_family.program_config(LATENT, 64))}
+                       latent_family.program_config(LATENT, 64)),
+            "kinds": (kinds_family.program_params(kinds),
+                      kinds_family.program_config(KINDS, 64))}
 
 
 def prompt(start, n, vocab=48):

@@ -282,3 +282,39 @@ def test_the_cells_step_compiles_for_the_chip_as_one_loop_a_layer(one_chip):
     assert_one_loop_a_layer(compiled, cfg, shapes[1])
     assert "bf16[%s]" % ",".join(map(str, pool[1:])) not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_the_two_kind_step_compiles_for_the_chip_without_a_copy_of_a_pool(
+        one_chip):
+    """At the `trinity_mixed_closed` cell's widths, tables and pools (a
+    window layer and a full layer of its five; 8 experts of 256 held), for
+    the v5e's compiler: a loop a layer, the four planes aliased, no copy of
+    either kind's plane (their trailing axes, 16 x 128 of 8 heads, are whole
+    tiles as they lie), and the scratch far under a plane."""
+    from mxnet_tpu.models import afmoe
+    from mxnet_tpu.serving import afmoe_lm
+    cfg = afmoe.AfmoeConfig(
+        vocab=25024, d_model=3072, n_heads=48, n_kv_heads=8, head_dim=128,
+        n_layers=2, n_dense_layers=1, layer_kinds=("window", "full"),
+        window=4096, d_ff=12288, d_expert=3072, n_experts=256,
+        experts_held=(0, 8), max_len=9728, dtype=jnp.bfloat16)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    mats, gains = afmoe.param_shapes(cfg)
+    params = {n: sds(s, jnp.bfloat16) for n, s in {**mats, **gains}.items()}
+    params["layer1_router_bias"] = sds((256,), jnp.float32)
+    model = afmoe_lm.AfmoeLM(params, cfg)
+    model.bind(16)
+    full, ring = (1, 32 * 608 + 1, 8, 16, 128), (1, 32 * 257 + 1, 8, 16, 128)
+    compiled = model._decode_jit.lower(
+        params, sds(full, jnp.bfloat16), sds(full, jnp.bfloat16),
+        sds(ring, jnp.bfloat16), sds(ring, jnp.bfloat16), sds((32,), i32),
+        sds((32,), i32), sds((32,), i32), sds((32, 608 + 257), i32)).compile()
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    # one attention walk a layer, one tile loop in the expert layer
+    assert len(re.findall(r" while\(", text)) == 3
+    for shape in (full, ring):
+        assert not pool_copies(text, shape, "bf16")
+    planes = 2 * (np.prod(full) + np.prod(ring)) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
