@@ -197,6 +197,10 @@ class Smoke:
                       % (eng.paged, eng.paged_fallback))
                 bad = {k: v for k, v in vars(eng).items()
                        if k.endswith("_fallback") and v is not None}
+                if self.rehearse:
+                    # off the chip the XLA loop is the gather walk's
+                    # own answer (`walk_fallback_reason`)
+                    bad.pop("walk_fallback", None)
                 check(not bad, "fallbacks: %r" % bad)
                 check(eng.tp == (kw.get("tp") or 1), "engine.tp is %r"
                       % eng.tp)
@@ -479,6 +483,67 @@ class Smoke:
         self.emit("kernel_fused_scan_layer", mode="lstm", T=T, N=N, H=Hd,
                   dtype="float32", interpret=interpret, rel_err=errs,
                   compile_and_run_s=secs)
+
+        self.decode_walk(interpret)
+
+    def decode_walk(self, interpret):
+        """The decode-walk kernel (ops/pallas_decode_walk.py) at the two
+        serving cells' shapes, bf16 planes, against the dense float32
+        reference: `opt-6.7b`'s 32 cached heads a query head each over a
+        full table, `trinity-large-preview`'s 8 cached heads a group of 6
+        over a ring of 257 that has wrapped, ragged rows and padded ones.
+        The margin is the largest gap of an output; the budget is bf16's:
+        probabilities rounded to bf16 for the second product, as XLA's
+        default precision rounds them."""
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.ops import pallas_decode_walk as walk
+        rng = np.random.RandomState(1)
+        shapes = {"opt-6.7b": dict(B=16, Hkv=32, G=1, W=128, window=0,
+                                   layers=2, longest=2047),
+                  "trinity-large-preview": dict(B=32, Hkv=8, G=6, W=257,
+                                                window=4096, layers=2,
+                                                longest=9727)}
+        if self.rehearse:
+            shapes = {"opt-6.7b": dict(B=4, Hkv=4, G=1, W=8, window=0,
+                                       layers=2, longest=127),
+                      "trinity-large-preview": dict(
+                          B=4, Hkv=2, G=3, W=5, window=64, layers=2,
+                          longest=300)}
+        bs, Dh = 16, 8 if self.rehearse else 128
+        for name, c in shapes.items():
+            B, W = c["B"], c["W"]
+            blocks = B * W + 1
+            k, v = (jax.random.normal(
+                key, (c["layers"], blocks, c["Hkv"], bs, Dh), jnp.bfloat16)
+                for key in jax.random.split(jax.random.PRNGKey(B)))
+            q = jnp.asarray(rng.randn(B, c["Hkv"] * c["G"], Dh),
+                            jnp.bfloat16)
+            # the shortest row, one block exactly, one token past it,
+            # ragged ones, the longest; the last two rows padded
+            pos = rng.randint(0, c["longest"], B)
+            pos[:4] = (0, bs - 1, bs, c["longest"])
+            pos[-2:] = 0
+            tables = (rng.permutation(blocks - 1)[:B * W] + 1).reshape(B, W)
+            tables[-2:] = 0
+            args = (q, k, v, jnp.asarray(tables, jnp.int32),
+                    jnp.asarray(pos, jnp.int32))
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(walk.decode_walk(
+                *args, jnp.int32(1), scale=1.0 / math.sqrt(Dh),
+                window=c["window"], ring=W if c["window"] else 0,
+                interpret=interpret))
+            secs = round(time.perf_counter() - t0, 2)
+            ref = jax.jit(walk.reference, static_argnums=(5, 6))(
+                *args, 1, c["window"])
+            m = float(jnp.max(jnp.abs(out - ref)))
+            check(np.isfinite(m) and m <= 0.05, "decode_walk at %s's shape "
+                  "strays from the dense reference by %g" % (name, m))
+            self.emit("kernel_decode_walk", shape_of=name, batch=B,
+                      kv_heads=c["Hkv"], group=c["G"], columns=W,
+                      window=c["window"], dtype="bfloat16",
+                      interpret=interpret, max_margin=m, budget=0.05,
+                      compile_and_run_s=secs)
 
     # -- leg: four chips --------------------------------------------------
 

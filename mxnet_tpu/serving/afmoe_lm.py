@@ -48,7 +48,8 @@ def decode(params, pools, carry, tokens, positions, tables, cfg, spec):
     next token (`carry_of`: at max_batch), pairs per (expert layer, held
     expert))."""
     tokens = carried_tokens(carry, tokens)
-    view = LiveGatherView(pools, tables, positions, spec=spec)
+    view = LiveGatherView(pools, tables, positions, spec=spec,
+                          rows=carry.shape[0])
     x, counts = afmoe.trunk(params, tokens, positions, tables[:, 0] != 0,
                             cfg, view)
     logits = afmoe.logits_of(params, x, cfg)

@@ -80,10 +80,11 @@ import jax.numpy as jnp
 from ..base import MXNetError
 from .. import telemetry
 from ..models.transformer import OneChip, block, _layer_norm
+from ..ops.pallas_decode_walk import preload as preload_walk
 from . import tp
 from .kv_cache import (POOL_ARGS, CacheSpec, PagedKVCache, PromptView,
-                       LiveGatherView, PagedView, flat_slots, copy_block,
-                       zero_block_scales)
+                       LiveGatherView, PagedView, walk_unfit, flat_slots,
+                       copy_block, zero_block_scales)
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 
 
@@ -207,15 +208,18 @@ class Step:
     while the step before's tokens had not been read on the host.
     `drains`: why it is collected in the pass that launched it, or
     launched with nothing in flight (the serving metrics count them by
-    reason). A collect fills `advanced`, [(sequence that took tokens, its
-    length before, its length after)], and `t_read`."""
+    reason). `walk`: what walks the cache in the step's program on the
+    gather path, the `kernel` (ops/pallas_decode_walk.py) or `xla`'s
+    loop; None elsewhere. A collect fills `advanced`, [(sequence that
+    took tokens, its length before, its length after)], and `t_read`."""
 
     __slots__ = ("seqs", "ahead", "nxt", "stats", "logits", "drains",
-                 "advanced", "t_launch", "t_read")
+                 "advanced", "t_launch", "t_read", "walk")
 
     def __init__(self, seqs, ahead=False, drains=()):
         self.seqs = seqs
         self.ahead = ahead
+        self.walk = None
         self.nxt = self.logits = self.advanced = None
         self.stats = []
         self.drains = list(drains)
@@ -290,8 +294,11 @@ def decode(params, pools, carry, tokens, positions, tables, cfg, block_size,
     discarded by the caller."""
     tokens = carried_tokens(carry, tokens)
     x = params["embed"][tokens] + params["pos_embed"][positions]   # (B, D)
+    # the gather view pads its kernel's operands to the rows the batch
+    # can hold, so that every bucket's step calls one lowered kernel
+    rows = {"rows": carry.shape[0]} if view_of is LiveGatherView else {}
     view = view_of(pools, tables, positions,
-                   flat_slots(tables, positions, block_size))
+                   flat_slots(tables, positions, block_size), **rows)
     logits = _logits(params, _layers(params, x, cfg, view, shard))
     return (*view.pools, logits,
             carry_of(jnp.argmax(logits, -1).astype(jnp.int32), carry))
@@ -716,6 +723,13 @@ class Engine:
             paged_enabled() if paged is None else bool(paged))
         self.paged = False
         self.paged_fallback = None
+        # why the gather path's decode step walks the cache with XLA's
+        # loop and not with the kernel (ops/pallas_decode_walk.py):
+        # `kv_cache.walk_unfit` asked of the pool itself, as the view
+        # asks it of the same pool while the step is traced. None where
+        # the kernel walks it, and where no gather step does (paged, no
+        # cache)
+        self.walk_fallback = None
         self.prefill_chunk = 0
         # quantized serving (ISSUE 20): env defaults
         # (MXNET_QUANTIZED_KV / MXNET_QUANTIZED_WEIGHTS), explicit
@@ -799,6 +813,10 @@ class Engine:
                         NamedSharding(self.mesh, kv_pool_spec()),
                         NamedSharding(self.mesh, kv_scale_spec())
                         if self.kv_quant else None)
+            if not self.paged:
+                self.walk_fallback = walk_unfit(self.cache.k, cspec.layout)
+                if self.walk_fallback is None:
+                    preload_walk()
             model.bind(block_size, paged=self.paged, kv_quant=self.kv_quant,
                        mesh=self.mesh)
             if self.tp == 1 and devices:
@@ -1372,6 +1390,9 @@ class Engine:
         bb = pow2_bucket(len(rows), lo=1, hi=self.max_batch)
         step = Step([row[0] for row in rows], ahead=after is not None)
         step_span.attrs["batch"] = len(rows)
+        if self.model.uses_cache and not self.paged:
+            step.walk = "xla" if self.walk_fallback else "kernel"
+            step_span.attrs["walk"] = step.walk
         # the cache path's host work in three child spans (to label the
         # device's idle gaps, PERF.md); ring and profiler only
         part = functools.partial(telemetry.span, category="serving",

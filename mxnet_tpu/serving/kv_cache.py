@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 
 from ..base import MXNetError
+from ..ops import pallas_decode_walk as _walk
+from ..ops.pallas_attention import default_interpret
 from ..ops.pallas_paged import paged_attention
 from ..models.afmoe import banded_attention
 
@@ -809,7 +811,8 @@ _DECODE_CHUNK_TOKENS = 128
 #: what a key outside a row's window scores where a whole chunk may be
 #: outside it: finite, so that the running maximum is, and what such a
 #: chunk adds is wiped by the first chunk that holds a key the row sees
-_UNSEEN = -1e30
+#: (the kernel's constant: both walks mask alike)
+_UNSEEN = _walk.UNSEEN
 
 
 def _attend_live(qh, k_pool, v_pool, layer, tables, positions, window=0):
@@ -889,21 +892,44 @@ def _attend_live(qh, k_pool, v_pool, layer, tables, positions, window=0):
     return acc / l[..., None]
 
 
+def walk_unfit(plane, layout="kv"):
+    """Why the gather path's decode step walks a cache with XLA's loop
+    (`_attend_live`) and not with the kernel (ops/pallas_decode_walk.py),
+    or None: asked of a plane as it lies (layers, blocks, heads,
+    block_size, head_dim), by `LiveGatherView.attend` while it traces
+    and by the engine of the plane it will hand that trace, so the two
+    cannot disagree. A `layout` other than keys and values never meets
+    the view."""
+    if layout != "kv":
+        return ("the pool holds %s rows, not keys and values: "
+                "`gather_latent` reads them" % layout)
+    return _walk.walk_fallback_reason(plane.shape[-1], plane.shape[3],
+                                      plane.dtype)
+
+
 class LiveGatherView:
     """Decode on the gather path: row b is sequence b's newest token at
     `positions[b]`. Its K/V are appended at its slot in the layer's
     kind's columns (`append_kv`), then the row attends over its
     sequence's blocks by table as far as the batch's longest live
-    sequence reaches, or round the ring (`_attend_live`). `tables` is
+    sequence reaches, or round the ring (`_attend_live`), or, where the
+    gate lets it (`walk_unfit`), by ONE kernel a layer that
+    reads each row's own live blocks (ops/pallas_decode_walk.py), every
+    layer of a kind a call site of one lowered function: the layer's
+    index goes in as data. `tables` is
     the full-capacity table, every kind's columns side by side
     (`PagedKVCache.row`); a padded row carries the all-null one.
     `slots` are the one kind's (`flat_slots`), or None where the view
-    works them out a kind."""
+    works them out a kind. `rows` is how many rows the engine's batch
+    can hold (the step's `carry` has as many): the kernel is handed its
+    operands at that many, so every batch bucket's step calls one
+    traced and lowered kernel (`decode_walk`)."""
 
-    def __init__(self, pools, tables, positions, slots=None, spec=None):
+    def __init__(self, pools, tables, positions, slots=None, spec=None,
+                 rows=None):
         self.pools = tuple(pools)
         self.tables, self.positions, self.spec = tables, positions, spec
-        self.slots = slots
+        self.slots, self.rows = slots, rows
 
     def attend(self, layer, q, k, v):
         i, j, tab, window, ring = _place(self.spec, layer, self.pools,
@@ -914,6 +940,11 @@ class LiveGatherView:
                                ring)
         planes = append_kv(*self.pools[i:i + 2], j, slots, k, v)
         self.pools = _put(self.pools, i, planes)
+        if walk_unfit(planes[0]) is None:
+            return _walk.decode_walk(
+                q, *planes, tab, self.positions, jnp.int32(j),
+                scale=1.0 / math.sqrt(q.shape[-1]), window=window,
+                ring=ring, rows=self.rows, interpret=default_interpret())
         return _attend_live(q, *planes, j, tab, self.positions, window)
 
 

@@ -188,6 +188,10 @@ class ServingMetrics:
             "serving_decode_steps_ahead_total",
             help="decode steps launched while the step before's tokens "
                  "had not been read on the host")
+        self._steps_walk_kernel = c(
+            "serving_decode_steps_walk_kernel_total",
+            help="gather-path decode steps whose program walks the cache "
+                 "with the decode-walk kernel, not with XLA's loop")
         self._drains = {reason: c(
             "serving_decode_drains_%s_total" % reason,
             help="times the decode pipeline ran empty: %s" % why)
@@ -670,12 +674,15 @@ class ServingMetrics:
             self._g_util.set(cache_util)
         self._counter.increment(tokens)
 
-    def decode_collected(self, ahead, drains):
-        """One decode step collected: was it launched ahead, and why (if
+    def decode_collected(self, ahead, drains, walk=None):
+        """One decode step collected: was it launched ahead, why (if
         so) it was launched with nothing in flight or collected in the
-        pass that launched it (`engine.Step.drains`)."""
+        pass that launched it (`engine.Step.drains`), and what walks the
+        cache in its program (`engine.Step.walk`)."""
         if ahead:
             self._steps_ahead.inc()
+        if walk == "kernel":
+            self._steps_walk_kernel.inc()
         for reason in drains:
             self._drains[reason].inc()
 
@@ -968,6 +975,8 @@ class ServingMetrics:
                                    if elapsed > 0 else None),
                 "decode_steps": steps,
                 "decode_steps_ahead": int(self._steps_ahead.value),
+                "decode_steps_walk_kernel": int(
+                    self._steps_walk_kernel.value),
                 "decode_drains": {reason: int(c.value) for reason, c
                                   in self._drains.items() if c.value},
             },
@@ -1001,6 +1010,8 @@ class ServingMetrics:
             }
             if getattr(engine, "paged_fallback", None):
                 snap["engine"]["paged_fallback"] = engine.paged_fallback
+            if getattr(engine, "walk_fallback", None):
+                snap["engine"]["walk_fallback"] = engine.walk_fallback
             if getattr(engine, "prefix_cache_fallback", None):
                 snap["engine"]["prefix_cache_fallback"] = \
                     engine.prefix_cache_fallback

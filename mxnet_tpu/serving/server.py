@@ -812,7 +812,7 @@ class LMServer(_HTTPFrontend):
                             to_flight=False, batch=len(advanced)):
             since = max(step.t_launch, self._last_step_t or 0.0)
             self._last_step_t = step.t_read
-            met.decode_collected(step.ahead, step.drains)
+            met.decode_collected(step.ahead, step.drains, step.walk)
             if advanced:  # count only sequences that really stepped
                 # a speculative step emits a BURST per sequence, so
                 # tokens = post-len minus pre-len, not 1 per step

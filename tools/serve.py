@@ -266,6 +266,8 @@ def main():
              args.block_size, args.max_batch, args.max_queue))
     if eng.paged_fallback:
         print("paged attention: OFF — %s" % eng.paged_fallback)
+    if eng.walk_fallback:
+        print("decode-walk kernel: OFF — %s" % eng.walk_fallback)
     if eng.prefix_cache is not None:
         print("prefix cache: on (content-addressed KV block reuse, "
               "copy-on-write, LRU eviction)")
