@@ -192,6 +192,7 @@ class Smoke:
         srv = serving.serve(model, **kw)
         try:
             engines = [r.engine for r in getattr(srv, "replicas", [srv])]
+            self.last_engines = engines
             for eng in engines:
                 check(eng.paged is kw["paged"], "engine.paged is %r (%s)"
                       % (eng.paged, eng.paged_fallback))
@@ -201,6 +202,7 @@ class Smoke:
                     # off the chip the XLA loop is the gather walk's
                     # own answer (`walk_fallback_reason`)
                     bad.pop("walk_fallback", None)
+                    bad.pop("state_step_fallback", None)
                 check(not bad, "fallbacks: %r" % bad)
                 check(eng.tp == (kw.get("tp") or 1), "engine.tp is %r"
                       % eng.tp)
@@ -291,6 +293,7 @@ class Smoke:
             paged = self.serve_wave("server_f32_paged", (params, cfg),
                                     wave, paged=True)
             self.kinds(wave, 1e-3)
+            self.state(wave, 1e-3)
         finally:
             jax.config.update("jax_default_matmul_precision", None)
         # a token may differ from the gather path's only where the
@@ -361,6 +364,45 @@ class Smoke:
         self.emit("server_f32_kinds_check", max_margin=m, tie_tolerance=tie,
                   window=cfg.window, kv_heads=cfg.n_kv_heads,
                   experts_held=list(cfg.experts_held))
+
+    def state(self, wave, tie):
+        """The family with a recurrent state beside its keys and values in
+        every layer (`models/falcon_h1.py`), small: the same wave through
+        `serving.serve` on its gather path (prefill as a chunked scan,
+        decode one recurrence step a row through the kernel where the gate
+        lets it), twice, so every slot is given again, and its margin against
+        its own dense forward in f32. Runs inside `server`'s full-precision
+        stretch."""
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.models.falcon_h1 import (FalconH1Config,
+                                                falcon_h1_apply,
+                                                init_falcon_h1_params)
+        if self.rehearse:
+            cfg = FalconH1Config(vocab=64, max_len=64)
+        else:
+            cfg = FalconH1Config(vocab=8192, d_model=512, n_heads=8,
+                                 n_kv_heads=2, head_dim=128, n_layers=2,
+                                 d_ff=2048, ssm_heads=16, ssm_head_dim=128,
+                                 ssm_state=64, ssm_groups=2, chunk=128,
+                                 key_multiplier=0.5, ssm_in_multiplier=0.25,
+                                 ssm_multipliers=(0.35, 0.25, 0.18, 0.5, 0.35),
+                                 max_len=1024)
+        params = init_falcon_h1_params(jax.random.PRNGKey(2), cfg)
+        waves = self.serve_wave("server_f32_state", (params, cfg), wave,
+                                paged=False)
+        engines = self.last_engines
+        m = self.margin(
+            cfg, params, wave, waves,
+            forward=lambda p, toks: jnp.stack(
+                [falcon_h1_apply(p, t, cfg) for t in toks]))
+        check(m <= tie, "the cache with a recurrent state strays from its "
+              "f32 forward by %g" % m)
+        self.emit("server_f32_state_check", max_margin=m, tie_tolerance=tie,
+                  state_dtype=str(engines[0].cache.ssm_state.dtype),
+                  state_shape=list(engines[0].cache.spec.state_shape),
+                  state_step_fallback=engines[0].state_step_fallback,
+                  walk_fallback=engines[0].walk_fallback)
 
     # -- leg: kernels -----------------------------------------------------
 
@@ -485,6 +527,60 @@ class Smoke:
                   compile_and_run_s=secs)
 
         self.decode_walk(interpret)
+        self.ssm_step(interpret)
+
+    def ssm_step(self, interpret):
+        """The recurrence-step kernel (ops/pallas_ssm_step.py) at the
+        `falconh1_chat_closed` cell's shape (64 rows of 2 groups x 256 x 16
+        heads x 128, two layers of its six), against `state_update` on
+        states gathered by hand: y, the rows' new states, and the slots no
+        row names untouched (the two padded rows share the null slot, which
+        the second reads while the first writes it: they are not compared). Then the call alone, timed, the plane donated:
+        its bytes over its time is what `ssm_step_hbm_share` reads."""
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.models.falcon_h1 import state_update
+        from mxnet_tpu.ops import pallas_ssm_step as step
+        R, slots_n, G, N, hpg, P = (4, 6, 2, 16, 8, 128) if self.rehearse \
+            else (64, 65, 2, 256, 16, 128)
+        keys = jax.random.split(jax.random.PRNGKey(3), 5)
+        plane = jax.random.normal(keys[0], (2, slots_n, G, N, hpg, P))
+        slots = np.random.RandomState(2).permutation(slots_n - 1)[:R] + 1
+        slots[-2:] = 0                               # padded rows
+        slots = jnp.asarray(slots, jnp.int32)
+        decay = jnp.exp(-jax.random.uniform(keys[1], (R, G, hpg, 1)))
+        dtx = jax.random.normal(keys[2], (R, G, hpg, P))
+        Bm, Cm = (jax.random.normal(k, (R, G, N)) for k in keys[3:])
+        with jax.default_matmul_precision("highest"):
+            want_h, want_y = jax.jit(state_update)(plane[1, slots], decay,
+                                                   dtx, Bm, Cm)
+        other = np.asarray(plane[0, 1])
+        call = jax.jit(lambda pl, *a: step.ssm_step(
+            pl, jnp.int32(1), *a, interpret=interpret), donate_argnums=0)
+        t0 = time.perf_counter()
+        new, y = jax.block_until_ready(call(plane, slots, decay, dtx, Bm, Cm))
+        secs = round(time.perf_counter() - t0, 2)
+        m_y = float(jnp.max(jnp.abs(y[:-2] - want_y[:-2]))
+                    / jnp.max(jnp.abs(want_y)))
+        m_h = float(jnp.max(jnp.abs(new[1, slots[:-2]] - want_h[:-2])))
+        check(m_y <= 1e-4 and m_h <= 1e-5, "ssm_step strays from the "
+              "gathered update by %g (y) and %g (states)" % (m_y, m_h))
+        check(np.array_equal(np.asarray(new[0, 1]), other),
+              "ssm_step touched another layer's states")
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            new, y = jax.block_until_ready(call(new, slots, decay, dtx, Bm,
+                                                Cm))
+            times.append(time.perf_counter() - t0)
+        best = min(times)
+        self.emit("kernel_ssm_step", rows=R, groups=G, state=N, heads=hpg,
+                  head_dim=P, dtype="float32", interpret=interpret,
+                  rel_err_y=m_y, max_err_state=m_h, compile_and_run_s=secs,
+                  call_ms=round(best * 1e3, 3),
+                  bytes=step.step_bytes(R, G, N, hpg, P),
+                  gb_per_s=round(step.step_bytes(R, G, N, hpg, P)
+                                 / best / 1e9, 1))
 
     def decode_walk(self, interpret):
         """The decode-walk kernel (ops/pallas_decode_walk.py) at the two

@@ -24,3 +24,8 @@ from .latent_moe import (LatentMoEConfig, init_latent_moe_params,
 # window and full attention, grouped-query and gated, over the same expert
 # layer (the third served LM family)
 from .afmoe import AfmoeConfig, init_afmoe_params, afmoe_apply
+
+# attention heads and state-space heads side by side in every layer (the
+# fourth served LM family)
+from .falcon_h1 import (FalconH1Config, init_falcon_h1_params,
+                        falcon_h1_apply)

@@ -32,6 +32,7 @@ from .engine import (Engine, Sequence, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost, pow2_bucket)
 from .latent_lm import LatentMoELM
 from .afmoe_lm import AfmoeLM
+from .falcon_h1_lm import FalconH1LM
 from .scheduler import (Scheduler, Request, QueueFull, RequestTimeout,
                         DeadlineExceeded, DeadlineUnmeetable,
                         BrownoutShed, make_resume)
@@ -51,8 +52,8 @@ from .spec import (DraftLM, self_draft, spec_decode_enabled, spec_k,
 __all__ = [
     "BlockPool", "PagedKVCache", "CacheOverflow",
     "PrefixCache", "prefix_cache_enabled",
-    "Engine", "Sequence", "TransformerLM", "LatentMoELM", "AfmoeLM", "BlockLM",
-    "ExportedLM",
+    "Engine", "Sequence", "TransformerLM", "LatentMoELM", "AfmoeLM",
+    "FalconH1LM", "BlockLM", "ExportedLM",
     "PoolsLost", "pow2_bucket",
     "Scheduler", "Request", "QueueFull", "RequestTimeout",
     "DeadlineExceeded", "DeadlineUnmeetable", "BrownoutShed",

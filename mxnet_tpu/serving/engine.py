@@ -37,10 +37,12 @@ Two model families plug in behind one `Engine`:
   but it makes the whole serving stack (scheduler, batching, HTTP)
   available to every model the framework can express or export.
 
-Two further families bring their own step functions over the same views
+Three further families bring their own step functions over the same views
 and pools and plug in beside `TransformerLM`: `serving/latent_lm.py`
-(a latent pool) and `serving/afmoe_lm.py` (window and full layers over a
-cache of two kinds, `kv_cache.CacheSpec.layer_kinds`).
+(a latent pool), `serving/afmoe_lm.py` (window and full layers over a
+cache of two kinds, `kv_cache.CacheSpec.layer_kinds`) and
+`serving/falcon_h1_lm.py` (keys and values and a recurrent state in every
+layer).
 
 One decode step stays in flight (`Engine.decode_pass`): a pass launches
 step n + 1 from step n's tokens on the device (`carried_tokens`) and only
@@ -83,7 +85,8 @@ from ..models.transformer import OneChip, block, _layer_norm
 from ..ops.pallas_decode_walk import preload as preload_walk
 from . import tp
 from .kv_cache import (POOL_ARGS, CacheSpec, PagedKVCache, PromptView,
-                       LiveGatherView, PagedView, walk_unfit, flat_slots,
+                       LiveGatherView, PagedView, walk_unfit,
+                       state_step_unfit, flat_slots,
                        copy_block, zero_block_scales)
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 
@@ -730,6 +733,11 @@ class Engine:
         # the kernel walks it, and where no gather step does (paged, no
         # cache)
         self.walk_fallback = None
+        # why a decode step updates the recurrent states of a "state"
+        # kind with XLA's gather and scatter and not with the kernel
+        # (ops/pallas_ssm_step.py): `kv_cache.state_step_unfit`, asked
+        # the same way. None where the kernel does, and with no such kind
+        self.state_step_fallback = None
         self.prefill_chunk = 0
         # quantized serving (ISSUE 20): env defaults
         # (MXNET_QUANTIZED_KV / MXNET_QUANTIZED_WEIGHTS), explicit
@@ -817,6 +825,9 @@ class Engine:
                 self.walk_fallback = walk_unfit(self.cache.k, cspec.layout)
                 if self.walk_fallback is None:
                     preload_walk()
+                if "state" in cspec.kinds:
+                    self.state_step_fallback = state_step_unfit(
+                        self.cache.ssm_state)
             model.bind(block_size, paged=self.paged, kv_quant=self.kv_quant,
                        mesh=self.mesh)
             if self.tp == 1 and devices:
@@ -968,7 +979,9 @@ class Engine:
         prices a prefix-cache hit in — a migration hop whose target
         already holds a block skips re-prefilling block_size tokens,
         i.e. this many bytes per token of KV it did not have to
-        rebuild. 0 when the model family keeps no cache."""
+        rebuild. 0 when the model family keeps no cache. A recurrent
+        state is no token's: a sequence holds `CacheSpec.state_bytes()`
+        of it whatever its length, and a hop rebuilds all of it."""
         if self.cache is None:
             return 0
         spec = self.cache.spec
@@ -1431,13 +1444,18 @@ class Engine:
                     pos[i] = n - 1
                     tabs[i] = s.table_row[:w]
                 step_span.attrs["live_max"] = int(pos.max()) + 1
-                if self.cache.spec.window:
+                spec = self.cache.spec
+                if spec.window or spec.state_shape:
                     # tokens the rows hold on a layer that keeps every
                     # one, and on a layer that keeps a window of them
                     held = pos[:len(rows)] + 1
                     step_span.attrs["live_full"] = int(held.sum())
+                if spec.window:
                     step_span.attrs["live_window"] = int(np.minimum(
-                        held, self.cache.spec.window).sum())
+                        held, spec.window).sum())
+                if spec.state_shape:
+                    # rows that read and write a recurrent state
+                    step_span.attrs["state_rows"] = len(rows)
                 toks, pos, tabs = (jnp.asarray(toks), jnp.asarray(pos),
                                    jnp.asarray(tabs))
             # same (batch, width) signature lattice whether the paged

@@ -45,10 +45,21 @@ token, or a window of the last ones) has a pair of planes, a `BlockPool`
 and a group of table columns a KIND of layer; a window kind's columns are
 a ring. The views find a layer's planes and columns by its kind
 (`_place`); with one kind they are the whole of both.
+
+A third kind does not grow with a sequence's length: the "state" of a
+state-space layer (models/falcon_h1.py), the recurrence's matrix a head
+and the convolution's last inputs. Its pool's block is a SLOT, one a
+sequence however long (a ring of one), its planes `ssm_state` and
+`conv_state` are indexed (layer, slot), a sequence's slot is the last
+column of its table row and slot 0 is the null slot of padded rows.
+Prefill overwrites a slot wholly, so a slot given to a new sequence
+carries nothing of the last one. A layer may keep a state BESIDE its
+keys and values (`CacheSpec.layer_kinds`: "full+state").
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import numpy as np
@@ -57,17 +68,23 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..ops import pallas_decode_walk as _walk
+from ..ops import pallas_ssm_step as _ssm
 from ..ops.pallas_attention import default_interpret
 from ..ops.pallas_paged import paged_attention
 from ..models.afmoe import banded_attention
+from ..models.falcon_h1 import mix_prompt, mix_step, state_update
 
 
 #: the names under which every step function takes the pool arrays: the
 #: arguments a step donates (engine `_program`); `kv_pool` is the one
 #: array of the latent layout, `k_ring`/`v_ring` the planes of a second
-#: kind of layer (`CacheSpec.layer_kinds`)
+#: kind of layer (`CacheSpec.layer_kinds`), `ssm_state`/`conv_state` the
+#: planes of the state kind
 POOL_ARGS = ("k_pool", "v_pool", "k_scale", "v_scale", "kv_pool",
-             "k_ring", "v_ring")
+             "k_ring", "v_ring", "ssm_state", "conv_state")
+#: the attributes of `PagedKVCache` that may hold a device array
+_PLANES = ("k", "v", "k_scale", "v_scale", "kv", "k_ring", "v_ring",
+           "ssm_state", "conv_state")
 #: lanes of a TPU tile: the latent pool's rows are whole tiles wide
 LANES = 128
 
@@ -96,7 +113,15 @@ class CacheSpec:
     `window`, so only the last `window` tokens are kept). Each kind has
     its own pair of K/V arrays over its own layers, its own `BlockPool`
     and its own columns of a sequence's table; a window kind's columns
-    are a RING (`ring`). Empty: one kind, every layer full."""
+    are a RING (`ring`). Empty: one kind, every layer full.
+
+    A third kind, "state", is what a state-space layer carries from
+    token to token, of one size however long the sequence: per layer
+    `state_shape` values in `state_dtype` (the recurrence's matrix a
+    head) and `conv_shape` = (taps - 1, channels) values in `dtype` (the
+    causal convolution's last inputs). A layer that keeps it BESIDE its
+    keys and values names both kinds, joined by "+" ("full+state"). Its
+    block is a SLOT: a ring of one."""
     n_layers: int
     dtype: object
     n_heads: int = 0
@@ -105,6 +130,9 @@ class CacheSpec:
     n_q_heads: int = 0
     layer_kinds: tuple = ()
     window: int = 0
+    state_shape: tuple = ()
+    conv_shape: tuple = ()
+    state_dtype: object = None
 
     @property
     def layout(self):
@@ -120,32 +148,59 @@ class CacheSpec:
         return self.n_layers * (self.row_width
                                 or 2 * self.n_heads * self.head_dim)
 
-    @property
+    def _kinds_of(self, layer):
+        return (self.layer_kinds[layer] if self.layer_kinds
+                else "full").split("+")
+
+    @functools.cached_property
     def kinds(self):
-        """The kinds of layer present, "full" first: the order of the
-        pool's arrays, its block pools and a table's groups of columns."""
-        return tuple(k for k in ("full", "window")
-                     if k in (self.layer_kinds or ("full",)))
+        """The kinds of layer present, "full" first and "state" last: the
+        order of the pool's arrays, its block pools and a table's groups
+        of columns."""
+        present = {k for i in range(self.n_layers) for k in self._kinds_of(i)}
+        return tuple(k for k in ("full", "window", "state") if k in present)
 
     def layers_of(self, kind):
-        """The model's layers of one kind, in order: layer `i` of the
-        model is layer `layers_of(kind).index(i)` of its kind's arrays."""
-        if not self.layer_kinds:
-            return tuple(range(self.n_layers))
-        return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
+        """The model's layers that keep one kind, in order: layer `i` of
+        the model is layer `layers_of(kind).index(i)` of its kind's
+        arrays."""
+        return tuple(i for i in range(self.n_layers)
+                     if kind in self._kinds_of(i))
+
+    def attn_kind(self, layer):
+        """The kind layer `layer`'s keys and values are kept as."""
+        return next(k for k in self._kinds_of(layer) if k != "state")
 
     def ring(self, kind, block_size):
         """Blocks a sequence holds at most for a layer of `kind`: 0 (no
         bound) where every token is kept; `window / block_size + 1` for
         a window, which is every block the last `window` positions can
         touch. Position p lies in column `(p // block_size) % ring`: the
-        block it overwrites is wholly behind the window by then."""
+        block it overwrites is wholly behind the window by then. A state
+        is one slot, however long the sequence."""
+        if kind == "state":
+            return 1
         return self.window // block_size + 1 if kind == "window" else 0
+
+    def state_bytes(self):
+        """Bytes one sequence's slots hold over all state layers,
+        whatever its length (0 with no state kind)."""
+        if not self.state_shape:
+            return 0
+        return len(self.layers_of("state")) * (
+            math.prod(self.state_shape) * np.dtype(self.state_dtype).itemsize
+            + math.prod(self.conv_shape) * np.dtype(self.dtype).itemsize)
 
     def paged_unfit(self):
         """Why the paged kernel (and what is built on it: chunked
         prefill, the prefix cache, the int8 pool, speculation, tensor
         parallelism) cannot read this cache, or None."""
+        if "state" in self.kinds:
+            return ("layers keep a recurrent state beside their keys and "
+                    "values: the paged step and the chunked prefill do not "
+                    "carry it from chunk to chunk, a shared prefix block "
+                    "has no snapshot of it, the int8 pool does not hold it "
+                    "and a speculative pass cannot roll it back")
         if self.layout != "kv":
             return ("the pool holds %s rows, not keys and values: the "
                     "paged kernel and the chunked prefill read the K and "
@@ -331,7 +386,11 @@ class PagedKVCache:
     first's). A sequence holds a list of blocks a kind (`try_alloc`,
     `free`) and its table row is the kinds' columns side by side
     (`row`): the first kind's at the engine's width, a window kind's at
-    its ring. One kind is the same code with one entry in each.
+    its ring. One kind is the same code with one entry in each. A
+    "state" kind's planes are ``ssm_state`` (layers, slots, *state_shape)
+    in the spec's `state_dtype` and ``conv_state`` (layers, slots, taps -
+    1 times channels) in the pool's dtype; its `BlockPool` hands out
+    slots, one a sequence, and its column is the row's last.
     """
 
     def __init__(self, n_layers, n_heads, head_dim, block_size=16,
@@ -392,16 +451,27 @@ class PagedKVCache:
         """[(attribute, shape, dtype, is it a scale sidecar)] of the
         device arrays, in the order every step takes and returns them:
         (k, v), and the scale sidecars of an int8 pool; (kv,) in the
-        latent layout; (k, v, k_ring, v_ring) with a window kind."""
+        latent layout; (k, v, k_ring, v_ring) with a window kind; a
+        state kind's (ssm_state, conv_state) after the keys and values,
+        indexed (layer, slot), the convolution's inputs flat."""
         if self.latent_dim:
             return [("kv", (self.n_layers, self.num_blocks, self.block_size,
                             self.spec.row_width), self._dtype, False)]
-        planes = []
-        for kind, blocks, names in zip(self.spec.kinds, self.blocks_of,
-                                       (("k", "v"), ("k_ring", "v_ring"))):
-            shape = (len(self.spec.layers_of(kind)), blocks, self.n_heads,
-                     self.block_size, self.head_dim)
-            planes += [(n, shape, self._dtype, False) for n in names]
+        planes, spec = [], self.spec
+        names = iter((("k", "v"), ("k_ring", "v_ring")))
+        for kind, blocks in zip(spec.kinds, self.blocks_of):
+            layers = len(spec.layers_of(kind))
+            if kind == "state":
+                planes += [
+                    ("ssm_state", (layers, blocks) + tuple(spec.state_shape),
+                     spec.state_dtype, False),
+                    ("conv_state", (layers, blocks,
+                                    math.prod(spec.conv_shape)),
+                     self._dtype, False)]
+                continue
+            shape = (layers, blocks, self.n_heads, self.block_size,
+                     self.head_dim)
+            planes += [(n, shape, self._dtype, False) for n in next(names)]
         if self.quantized:
             planes += [(n, planes[0][1][:3], jnp.float32, True)
                        for n in ("k_scale", "v_scale")]
@@ -420,7 +490,7 @@ class PagedKVCache:
     def drop(self):
         """Let the device arrays go (a server being torn down hands its
         pool's memory back before its successor's is made)."""
-        for n in ("k", "v", "k_scale", "v_scale", "kv", "k_ring", "v_ring"):
+        for n in _PLANES:
             setattr(self, n, None)
 
     def lost(self):
@@ -493,8 +563,8 @@ class PagedKVCache:
     def note_recycled(self, n_tokens, blocks):
         """A sequence that held `blocks` ends after n_tokens: count the
         ring columns it wrote over again."""
-        for ring, ids in zip(self.rings, blocks):
-            if ring:
+        for kind, ring, ids in zip(self.spec.kinds, self.rings, blocks):
+            if ring and kind != "state":
                 self.recycled += max(0, self.blocks_for(n_tokens) - len(ids))
 
     def table_row(self, block_ids, n_entries):
@@ -761,7 +831,7 @@ def _place(spec, layer, pools, tables):
     no window."""
     if spec is None or spec.kinds == ("full",):
         return 0, layer, tables, 0, 0
-    i = spec.kinds.index(spec.layer_kinds[layer])
+    i = spec.kinds.index(spec.attn_kind(layer))
     rings = [spec.ring(k, pools[0].shape[3]) for k in spec.kinds]
     first = tables.shape[1] - sum(rings[1:])
     lo = first + sum(rings[1:i]) if i else 0
@@ -773,6 +843,14 @@ def _place(spec, layer, pools, tables):
 def _put(pools, i, planes):
     """`pools` with the pair at `i` replaced."""
     return pools[:i] + tuple(planes) + pools[i + 2:]
+
+
+def _place_state(spec, layer):
+    """Where layer `layer` keeps its recurrent state: (where the state
+    plane is in the pools, the convolution's after it; the layer's index
+    in them). A sequence's slot is the last column of its table row."""
+    return (2 * spec.kinds.index("state"),
+            spec.layers_of("state").index(layer))
 
 
 #: query rows one pass of a prompt's attention scores at once, against
@@ -803,6 +881,21 @@ class PromptView:
             planes = write_kv_prompt(*planes, j, row[0], k, v)
         self.pools = _put(self.pools, i, planes)
         return banded_attention(q, k, v, window, PROMPT_Q_BLOCK)
+
+    def mix(self, layer, xbc, dt, w, cfg):
+        """A state-space layer's mixer over the prompt as a chunked scan
+        from an empty state (`mix_prompt`); the sequence's slot is
+        overwritten with the state at the prompt's true `length` and the
+        convolution's last real inputs, whatever the bucket's padding."""
+        i, j = _place_state(self.spec, layer)
+        y, state, tail = mix_prompt(xbc, dt, w, cfg, self.length)
+        slot = self.table_row[-1]
+        planes = self.pools[i:i + 2]
+        self.pools = _put(self.pools, i, (
+            planes[0].at[j, slot].set(state.astype(planes[0].dtype)),
+            planes[1].at[j, slot].set(tail.reshape(-1)
+                                      .astype(planes[1].dtype))))
+        return y
 
 
 #: keys one pass of the live-gather view's attention loop folds in: a
@@ -946,6 +1039,45 @@ class LiveGatherView:
                 scale=1.0 / math.sqrt(q.shape[-1]), window=window,
                 ring=ring, rows=self.rows, interpret=default_interpret())
         return _attend_live(q, *planes, j, tab, self.positions, window)
+
+    def mix(self, layer, xbc, dt, w, cfg):
+        """A state-space layer's mixer, one recurrence step a row
+        (`mix_step`): each row's state and last inputs are found through
+        its table's last column (a row's place in the batch changes from
+        step to step). The states are updated where they lie by ONE
+        kernel a layer (ops/pallas_ssm_step.py; the layer's index and
+        the slots as data) where the gate lets it (`state_step_unfit`),
+        else read by one gather over (layer, slot) and written back to
+        the same slots. A padded row's slot is the null slot."""
+        i, j = _place_state(self.spec, layer)
+        slots = self.tables[:, -1]
+        state_p, conv_p = self.pools[i:i + 2]
+        tail = conv_p[j, slots].reshape(xbc.shape[0], -1, xbc.shape[1])
+
+        def update(decay, dtx, Bm, Cm):
+            nonlocal state_p
+            if state_step_unfit(state_p) is None:
+                state_p, y = _ssm.ssm_step(
+                    state_p, jnp.int32(j), slots, decay, dtx, Bm, Cm,
+                    interpret=default_interpret())
+                return y
+            h, y = state_update(state_p[j, slots].astype(jnp.float32),
+                                decay, dtx, Bm, Cm)
+            state_p = state_p.at[j, slots].set(h.astype(state_p.dtype))
+            return y
+
+        y, tail = mix_step(xbc, dt, tail, w, cfg, update)
+        self.pools = _put(self.pools, i, (
+            state_p, conv_p.at[j, slots].set(tail.reshape(xbc.shape[0], -1))))
+        return y
+
+
+def state_step_unfit(state_plane):
+    """Why the decode step updates a state plane with XLA's gather and
+    scatter and not with the kernel (ops/pallas_ssm_step.py), or None:
+    asked of the plane as it lies, by `LiveGatherView.mix` while it
+    traces and by the engine of the plane it will hand that trace."""
+    return _ssm.step_fallback_reason(state_plane)
 
 
 class PagedView:

@@ -1008,17 +1008,21 @@ class ServingMetrics:
                 "prefix_cache": getattr(engine, "prefix_cache",
                                         None) is not None,
             }
-            if getattr(engine, "paged_fallback", None):
-                snap["engine"]["paged_fallback"] = engine.paged_fallback
-            if getattr(engine, "walk_fallback", None):
-                snap["engine"]["walk_fallback"] = engine.walk_fallback
-            if getattr(engine, "prefix_cache_fallback", None):
-                snap["engine"]["prefix_cache_fallback"] = \
-                    engine.prefix_cache_fallback
+            # why an option that was asked for is off, each by its name
+            for name in ("paged_fallback", "walk_fallback",
+                         "state_step_fallback", "prefix_cache_fallback",
+                         "kv_quant_fallback", "weight_quant_fallback",
+                         "tp_fallback", "spec_fallback"):
+                if getattr(engine, name, None):
+                    snap["engine"][name] = getattr(engine, name)
             snap["engine"]["spec_decode"] = bool(
                 getattr(engine, "spec", False))
-            if getattr(engine, "spec_fallback", None):
-                snap["engine"]["spec_fallback"] = engine.spec_fallback
+            spec = getattr(getattr(engine, "cache", None), "spec", None)
+            if getattr(spec, "state_dtype", None) is not None:
+                # a recurrent state beside the keys and values: what it
+                # is kept in between tokens
+                snap["engine"]["state_dtype"] = str(
+                    np.dtype(spec.state_dtype))
             if getattr(engine, "spec", False) or \
                     getattr(engine, "spec_passes", 0):
                 passes = engine.spec_passes

@@ -27,11 +27,12 @@ import time
 from ..base import MXNetError
 from .. import telemetry
 from ..utils import chaos
-from ..models import afmoe, latent_moe
+from ..models import afmoe, falcon_h1, latent_moe
 from .engine import (Engine, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost)
 from .latent_lm import LatentMoELM
 from .afmoe_lm import AfmoeLM
+from .falcon_h1_lm import FalconH1LM
 from .scheduler import (Scheduler, Request, QueueFull, BrownoutShed,
                         DeadlineExceeded, DeadlineUnmeetable, make_resume)
 from .metrics import ServingMetrics
@@ -48,7 +49,8 @@ def _queue_span(req):
 
 
 def _resolve_model(model, vocab=None, max_len=None, time_major=False):
-    if isinstance(model, (TransformerLM, LatentMoELM, BlockLM, ExportedLM)):
+    if isinstance(model, (TransformerLM, LatentMoELM, FalconH1LM, BlockLM,
+                          ExportedLM)):
         return model
     if isinstance(model, str):
         return ExportedLM(model)
@@ -58,6 +60,8 @@ def _resolve_model(model, vocab=None, max_len=None, time_major=False):
             return LatentMoELM(params, cfg)
         if isinstance(cfg, afmoe.AfmoeConfig):
             return AfmoeLM(params, cfg)
+        if isinstance(cfg, falcon_h1.FalconH1Config):
+            return FalconH1LM(params, cfg)
         return TransformerLM(params, cfg)
     if hasattr(model, "collect_params"):          # Gluon Block
         if vocab is None or max_len is None:

@@ -29,6 +29,7 @@ from mxnet_tpu.serving.spec import self_draft
 from mxnet_tpu.telemetry import introspect
 
 from chipbench.families import afmoe_lm as kinds_family
+from chipbench.families import falcon_h1_lm as state_family
 from chipbench.families import latent_moe_lm as latent_family
 from chipbench.generators import serving as bench_serving
 
@@ -63,6 +64,23 @@ KINDS = {
     "sliding_window": 8, "vocab_size": 96, "rms_norm_eps": 1e-5,
     "rope_theta": 10000, "mup_enabled": True, "dtype": "float32"}
 
+#: attention heads and state-space heads side by side in every layer: a
+#: recurrent state a sequence beside its keys and values, found through the
+#: row's table as rows change place (the published multipliers are the
+#: 5,120-wide model's: these keep a 32-wide one's rows of the order of 1)
+STATE = {
+    "hidden_size": 32, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 8, "intermediate_size": 64, "mamba_d_ssm": 32,
+    "mamba_n_heads": 4, "mamba_d_head": 8, "mamba_d_state": 16,
+    "mamba_n_groups": 2, "mamba_d_conv": 4, "mamba_chunk_size": 8,
+    "num_hidden_layers": 2, "vocab_size": 96, "rms_norm_eps": 1e-5,
+    "rope_theta": 1e11, "attention_in_multiplier": 1,
+    "attention_out_multiplier": 8.0, "embedding_multiplier": 50.0,
+    "key_multiplier": 1.0, "lm_head_multiplier": 8.0,
+    "mlp_multipliers": [8.0, 8.0], "ssm_in_multiplier": 1.0,
+    "ssm_out_multiplier": 8.0, "ssm_multipliers": [8.0, 8.0, 8.0, 8.0, 4.0],
+    "dtype": "float32", "state_dtype": "float32"}
+
 #: configuration -> (model family, what `serve` and `Engine` are told)
 CONFIGS = {
     "gather": ("dense", dict()),
@@ -73,6 +91,7 @@ CONFIGS = {
     "tp2": ("dense", dict(paged=True, tp=2)),
     "latent": ("latent", dict()),
     "kinds": ("kinds", dict()),
+    "state": ("state", dict()),
 }
 
 
@@ -82,12 +101,15 @@ def models():
                             d_ff=64, max_len=64)
     weights = latent_family.make_weights(LATENT, 11)
     kinds = kinds_family.make_weights(KINDS, 11)
+    state = state_family.make_weights(STATE, 11)
     return {"dense": (init_transformer_params(jax.random.PRNGKey(0), cfg),
                       cfg),
             "latent": (latent_family.program_params(weights),
                        latent_family.program_config(LATENT, 64)),
             "kinds": (kinds_family.program_params(kinds),
-                      kinds_family.program_config(KINDS, 64))}
+                      kinds_family.program_config(KINDS, 64)),
+            "state": (state_family.program_params(state),
+                      state_family.program_config(STATE, 64))}
 
 
 def prompt(start, n, vocab=48):

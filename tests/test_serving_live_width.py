@@ -318,3 +318,77 @@ def test_the_two_kind_step_compiles_for_the_chip_without_a_copy_of_a_pool(
     planes = 2 * (np.prod(full) + np.prod(ring)) * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= planes
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_the_state_kinds_steps_compile_for_the_chip_without_a_copy_of_a_plane(
+        one_chip, monkeypatch):
+    """At the `falconh1_chat_closed` cell's widths, tables and pools (two
+    layers of its six), for the v5e's compiler, both kernels lowered by
+    Mosaic as on the chip: the decode step holds one `decode_walk` and one
+    `ssm_step` a layer, no loop, no copy of either kind's planes (as XLA's
+    gather the state's 4 MB slices are split by writing the whole plane out
+    as two halves, every layer: PERF.md, PR 37), all four planes aliased and
+    the scratch far under a row's states; the prefill writes one slot and
+    holds no operation of a plane's size either."""
+    from mxnet_tpu.models import falcon_h1
+    from mxnet_tpu.ops import pallas_decode_walk, pallas_ssm_step
+    from mxnet_tpu.serving import falcon_h1_lm, kv_cache
+    ssm_gate, walk_gate = (pallas_ssm_step.step_fallback_reason,
+                           pallas_decode_walk.walk_fallback_reason)
+    monkeypatch.setattr(pallas_ssm_step, "step_fallback_reason",
+                        lambda plane, backend=None: ssm_gate(plane, "tpu"))
+    monkeypatch.setattr(pallas_decode_walk, "walk_fallback_reason",
+                        lambda *a, **k: walk_gate(*a[:3], backend="tpu"))
+    monkeypatch.setattr(kv_cache, "default_interpret", lambda: False)
+    # the benchmark's precision: the suite's "highest" asks the walk's kernel
+    # for a float32 product of bf16 operands, which Mosaic has not
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        _state_steps_compile(one_chip)
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+
+
+def _state_steps_compile(one_chip):
+    from mxnet_tpu.models import falcon_h1
+    from mxnet_tpu.serving import falcon_h1_lm
+    cfg = falcon_h1.FalconH1Config(
+        vocab=261120, d_model=5120, n_heads=20, n_kv_heads=4, head_dim=128,
+        n_layers=2, d_ff=21504, ssm_heads=32, ssm_head_dim=128, ssm_state=256,
+        ssm_groups=2, conv_taps=4, chunk=128, max_len=1024,
+        dtype=jnp.bfloat16, key_multiplier=0.011, lm_head_multiplier=0.0078125,
+        ssm_multipliers=(0.35, 0.25, 0.18, 0.5, 0.35))
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    mats, gains, vectors = falcon_h1.param_shapes(cfg)
+    params = {n: sds(s, jnp.bfloat16) for n, s in {**mats, **gains}.items()}
+    params.update({n: sds(s, jnp.float32) for n, s in vectors.items()})
+    model = falcon_h1_lm.FalconH1LM(params, cfg)
+    model.bind(16)
+    kv, state, conv = ((2, 64 * 64 + 1, 4, 16, 128), (2, 65, 2, 256, 16, 128),
+                       (2, 65, 3 * 5120))
+    assert model.cache_spec().state_shape == state[2:]
+    pools = (sds(kv, jnp.bfloat16), sds(kv, jnp.bfloat16),
+             sds(state, jnp.float32), sds(conv, jnp.bfloat16))
+    planes = 2 * np.prod(kv) * 2 + np.prod(state) * 4 + np.prod(conv) * 2
+    compiled = model._decode_jit.lower(
+        params, *pools, sds((64,), i32), sds((64,), i32), sds((64,), i32),
+        sds((64, 65), i32)).compile()
+    text = compiled.as_text()
+    assert " conditional(" not in text and " while(" not in text
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 4
+    assert not pool_copies(text, kv, "bf16")
+    assert not pool_copies(text, state, "f32")
+    # no half of the state plane either, nor the rows' states gathered
+    assert "f32[2,65,2,256,16,64]" not in text
+    assert "f32[64,2,256,16,128]" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+    compiled = model._prefill_jit.lower(
+        params, *pools, sds((512,), i32), sds((), i32),
+        sds((65,), i32)).compile()
+    text = compiled.as_text()
+    assert not pool_copies(text, kv, "bf16")
+    assert not pool_copies(text, state, "f32")
+    assert compiled.memory_analysis().alias_size_in_bytes >= planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
