@@ -164,12 +164,17 @@ class Sequence:
     bookkeeping the engine and scheduler share. `prefilled` counts prompt
     tokens already written to the cache (chunked prefill advances it one
     chunk per `prefill_step`); `prefill_s` accumulates prefill wall time
-    across chunks for the metrics roll-up."""
+    across chunks for the metrics roll-up. `t_last_token` is when the
+    host held its newest token (`t_begin`, when the engine took it in,
+    until there is one), and `prefills_seen` / `prefill_tokens_seen`
+    are the engine's two prefill counters as they stood then: what a
+    token's record (`Engine.record_tokens`) is made from."""
 
     __slots__ = ("tokens", "prompt_len", "blocks", "table_row",
                  "max_total", "eos_id", "done", "last_logits", "request",
                  "prefilled", "prefill_s", "cache_hit_tokens",
-                 "shared_blocks", "token_logits")
+                 "shared_blocks", "token_logits", "t_begin",
+                 "t_last_token", "prefills_seen", "prefill_tokens_seen")
 
     def __init__(self, prompt, max_total, eos_id=None):
         self.tokens = list(prompt)
@@ -190,6 +195,8 @@ class Sequence:
         self.token_logits = None      # keep_logits engines: one f32 (V,)
                                       # row PER EMITTED token, both decode
                                       # paths — the spec parity oracle
+        self.t_begin = self.t_last_token = time.perf_counter()
+        self.prefills_seen = self.prefill_tokens_seen = 0
 
     @property
     def generated(self):
@@ -213,8 +220,10 @@ class Step:
     launched with nothing in flight (the serving metrics count them by
     reason). `walk`: what walks the cache in the step's program on the
     gather path, the `kernel` (ops/pallas_decode_walk.py) or `xla`'s
-    loop; None elsewhere. A collect fills `advanced`, [(sequence that
-    took tokens, its length before, its length after)], and `t_read`."""
+    loop; None elsewhere. A collect fills `t_read`, when the host held
+    the step's tokens, and `advanced`, [(sequence that took tokens, its
+    length before, its length after, the gap in seconds between each of
+    those tokens and the one before it)]."""
 
     __slots__ = ("seqs", "ahead", "nxt", "stats", "logits", "drains",
                  "advanced", "t_launch", "t_read", "walk")
@@ -925,6 +934,11 @@ class Engine:
         # compiling — kept apart from _compile_counts so the
         # recompile-bound tests stay meaningful with the cache on
         self._warm_counts = {"prefill": 0, "decode": 0}
+        # prefill programs run (whole prompts or chunks) and the rows of
+        # their buckets: a token's record says how many ran between it
+        # and the one before it (`record_tokens`)
+        self.prefills_run = 0
+        self.prefill_tokens_run = 0
         self.pools_lost = 0     # times `_donating` had to remake the pools
         self._constructed = True
         _LIVE.add(self)
@@ -1108,6 +1122,8 @@ class Engine:
         if L < 1:
             raise MXNetError("empty prompt")
         seq = Sequence(prompt, min(self.max_len, L + max_new), eos_id)
+        seq.prefills_seen = self.prefills_run
+        seq.prefill_tokens_seen = self.prefill_tokens_run
         if self.keep_logits:
             seq.token_logits = []
         if self.cache is not None:
@@ -1231,6 +1247,7 @@ class Engine:
                         jnp.int32(qs),
                         jnp.int32(L), jnp.int32(min(L - 1 - qs, C - 1)),
                         jnp.asarray(seq.table_row[:w]))
+                self._ran_prefill(C)
                 seq.prefilled = min(L, qs + C)
                 if seq.prefilled < L:
                     return False
@@ -1252,6 +1269,7 @@ class Engine:
                     logits, *stats = self._step(
                         self.model.prefill, jnp.asarray(toks),
                         jnp.int32(L), jnp.asarray(seq.table_row))
+                self._ran_prefill(s_pad)
                 seq.prefilled = L
                 logits = self._read_back(step_span, logits, stats)
             else:
@@ -1262,13 +1280,21 @@ class Engine:
                     logits = np.asarray(self.model.step_full(
                         jnp.asarray(toks), jnp.asarray([L], np.int32),
                         phase="prefill"))[0]
+                self._ran_prefill(s_pad)
                 seq.prefilled = L
+            # the host holds the prefill's result: a client could read
+            # the first token from here on
+            seq.t_last_token = time.perf_counter()
         if self.keep_logits:
             seq.last_logits = logits
             if seq.token_logits is not None:
                 seq.token_logits.append(logits)
         self._append(seq, int(np.argmax(logits)))
         return True
+
+    def _ran_prefill(self, rows):
+        self.prefills_run += 1
+        self.prefill_tokens_run += rows
 
     def start(self, prompt, max_new, eos_id=None):
         """Admit one request and run its whole prefill: allocate blocks,
@@ -1325,7 +1351,7 @@ class Engine:
         a real draft bug) degrades THIS batch to the verbatim
         non-speculative path."""
         collected, _ = self.decode_pass(seqs, hold=False)
-        return [s for s, _, _ in collected[0].advanced] if collected else []
+        return [row[0] for row in collected[0].advanced] if collected else []
 
     def decode_pass(self, seqs, after=None, hold=True):
         """One pass of the decode pipeline, under one `serving.decode`
@@ -1336,8 +1362,8 @@ class Engine:
         appends and accounts for the old one, admits and builds again.
         Returns (collected, in_flight): the steps whose tokens this pass
         appended, oldest first, each with `advanced` (its sequences that
-        took a token, with their lengths before and after), and the
-        step left in flight for the next pass, or None.
+        took a token, with their lengths before and after and the token's
+        gap), and the step left in flight for the next pass, or None.
 
         The launched step is collected in this pass too where nothing
         can be launched from it: `hold` is false, the engine's next input
@@ -1358,7 +1384,6 @@ class Engine:
             # degradation target (and the parity oracle)
         if not rows and after is None:
             return [], None
-        t0_us = time.perf_counter_ns() // 1000
         collected = []
         with telemetry.span("serving.decode",
                             category="serving") as step_span:
@@ -1368,13 +1393,8 @@ class Engine:
             if step is not None and (step.sealed or not hold):
                 collected.append(self._read(step, step_span))
                 step = None
-        # fan the batch-level decode interval out to every request it
-        # advanced, so each request's trace row stays connected through
-        # its decode steps (ring-only: the batch span above already
-        # covers the interval in the chrome trace)
-        dur_us = time.perf_counter_ns() // 1000 - t0_us
         for done in collected:
-            self._append_step(done, step_span.id, t0_us, dur_us)
+            self._append_step(done, step_span.id)
         return collected, step
 
     def _rows(self, seqs, after):
@@ -1496,7 +1516,7 @@ class Engine:
         step.t_read = time.perf_counter()
         return step
 
-    def _append_step(self, step, parent, t0_us, dur_us):
+    def _append_step(self, step, parent):
         """The other half: every row's token appended to its sequence. A
         row whose sequence has ended since the step was launched is
         dropped: it met its `eos_id` in the step before, which the host
@@ -1512,26 +1532,69 @@ class Engine:
         is sound for the same reason: `release` registers `tokens[:-1]`,
         the stray write is at the position after them, and a reader of a
         partial tail block copies it and writes its own tokens from the
-        registered count on before it reads any."""
-        step.advanced = []
+        registered count on before it reads any. The tokens appended are
+        filed on their requests' timelines (`record_tokens`) under
+        `parent`, the pass's span."""
+        took = []
         with telemetry.span("serving.decode.append", category="serving",
                             to_flight=False, batch=len(step.seqs)):
             for i, s in enumerate(step.seqs):
                 if s.done:
                     continue
-                n = len(s.tokens)
-                step.advanced.append((s, n, n + 1))
+                took.append((s, len(s.tokens)))
                 if step.logits is not None:
                     s.last_logits = step.logits[i]
                     if s.token_logits is not None:
                         s.token_logits.append(step.logits[i])
                 self._append(s, int(step.nxt[i]))
-                if s.request is not None:
-                    telemetry.record_span(
-                        "serving.decode", t0_us, dur_us,
-                        trace=s.request.trace, category="serving",
-                        to_profiler=False, to_flight=False,
-                        parent=parent, position=len(s.tokens) - 1)
+            gaps = self.record_tokens(took, step.t_read, step, parent)
+        step.advanced = [(s, n, n + 1, (gap,))
+                         for (s, n), gap in zip(took, gaps)]
+
+    def record_tokens(self, tokens, at, step=None, parent=None, since=None,
+                      **attrs):
+        """The tokens [(sequence, position)] that a client could read from
+        `at` on, when the host held them: those of `step`, or the one out
+        of a prefill. Returns each one's gap in seconds to its sequence's
+        token before it (`since`: to something earlier) and, where the
+        sequence serves a request, makes ONE `serving.token` span on the
+        request's trace from the one stamp to the other, so a request's
+        row is a gapless chain of its tokens and a span's `dur` is the
+        gap a client saw. It says what the token waited for, by what the
+        engine ran in between and not by what overlapped it: `prefills`
+        programs (whole prompts or chunks, of any request) over
+        `prefill_tokens` rows; the step's `ahead`, and its `drains` where
+        it has them, so that a gap a drain lengthened names it. Ring
+        only: B a step would evict the flight recorder's history, and
+        the chrome trace has the pass."""
+        ahead = 0
+        if step is not None:
+            ahead = int(step.ahead)
+            if step.drains:
+                attrs["drains"] = ",".join(step.drains)
+        at_us = int(at * 1e6)
+        ran, rows = self.prefills_run, self.prefill_tokens_run
+        gaps = []
+        for seq, position in tokens:
+            start = seq.t_last_token if since is None else since
+            req = seq.request
+            if req is not None:
+                ts = int(start * 1e6)
+                telemetry.record_span(
+                    "serving.token", ts, at_us - ts, trace=req.trace,
+                    category="serving", to_profiler=False, to_flight=False,
+                    parent=parent, position=position,
+                    prefills=ran - seq.prefills_seen,
+                    prefill_tokens=rows - seq.prefill_tokens_seen,
+                    ahead=ahead, **attrs)
+                # what a failover's replay starts its first gap from
+                # (`make_resume`): the chain goes on across the hop
+                req.t_last_token = at
+            seq.t_last_token = at
+            seq.prefills_seen = ran
+            seq.prefill_tokens_seen = rows
+            gaps.append(at - start)
+        return gaps
 
     def _draft_propose(self, seqs, bb, k, poison):
         """Draft proposal loop: k greedy autoregressive steps of the
@@ -1593,7 +1656,6 @@ class Engine:
         step = Step(seqs, drains=["spec"])
         before = [len(s.tokens) for s in seqs]
         poison, self.chaos_spec_poison = self.chaos_spec_poison, False
-        t0_us = time.perf_counter_ns() // 1000
         with telemetry.span("serving.spec", category="serving",
                             batch=len(seqs), k=k):
             drafted = self._draft_propose(seqs, bb, k, poison)
@@ -1622,8 +1684,9 @@ class Engine:
                     jnp.asarray(qs), jnp.asarray(counts),
                     jnp.asarray(tabs))
             logits = np.asarray(logits)                    # (bb, C, V)
+            step.t_read = time.perf_counter()
             accepted = proposed = emitted_n = 0
-            dur_us = time.perf_counter_ns() // 1000 - t0_us
+            step.advanced = []
             for i, s in enumerate(seqs):
                 am = np.argmax(logits[i], axis=-1)
                 emitted, acc = greedy_verify(am, draft[i], nbs[i])
@@ -1638,20 +1701,18 @@ class Engine:
                             s.token_logits.append(logits[i, j])
                     self._append(s, int(tok))
                     emitted_n += 1
-                    if s.request is not None:
-                        telemetry.record_span(
-                            "serving.decode", t0_us, dur_us,
-                            trace=s.request.trace, category="serving",
-                            to_profiler=False, to_flight=False,
-                            position=len(s.tokens) - 1)
+                # a burst reaches the client at once: the gaps inside it
+                # are 0
+                n = len(s.tokens)
+                step.advanced.append((s, before[i], n, self.record_tokens(
+                    [(s, p) for p in range(before[i], n)], step.t_read,
+                    step)))
         self.spec_passes += 1
         self.spec_proposed_tokens += proposed
         self.spec_accepted_tokens += accepted
         self.last_spec = {"fallback": False, "batch": B,
                           "proposed": proposed, "accepted": accepted,
                           "emitted": emitted_n}
-        step.advanced = [(s, n, len(s.tokens)) for s, n in zip(seqs, before)]
-        step.t_read = time.perf_counter()
         return step
 
     def _append(self, seq, token):

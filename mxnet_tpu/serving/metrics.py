@@ -587,7 +587,10 @@ class ServingMetrics:
                        reason=type(req.error).__name__
                        if req.error is not None else "timeout")
 
-    def request_prefilled(self, req, prefill_s):
+    def request_prefilled(self, req, prefill_s, t_token):
+        """`t_token`: when the host held the prefill's result, the first
+        token's stamp on the request's timeline (`req.t_last_token` from
+        there on: the engine keeps it, `Engine.record_tokens`)."""
         self._h_queue.observe(req.t_admit - req.t_submit)
         self._h_prefill.observe(prefill_s)
         with self._lock:
@@ -597,10 +600,9 @@ class ServingMetrics:
             # a failover resume carried the victim's last emit time:
             # the replay's first fresh token closes the client's real
             # cross-hop gap — exactly the stall an ITL SLO must see
-            itl = req.t_first_token - req.t_last_token
+            itl = t_token - req.t_last_token
             self._h_itl.observe(itl)
             self._tenant(req.tenant)["itl"].observe(itl)
-        req.t_last_token = req.t_first_token
         if req.t_client_first_token is None:
             # the CLIENT's first token, measured from the CLIENT's
             # submit — for a resume whose victim died mid-prefill this
@@ -626,22 +628,29 @@ class ServingMetrics:
         """One prefill chunk ran for `req` (lifecycle ledger only)."""
         self.log_event("prefill_chunk", req, prefilled=prefilled)
 
-    def token_generated(self, req, now=None, position=None):
-        """One decode token emitted for `req`: observe the per-request
-        inter-token latency (fleet + tenant) — failover stalls land
-        here too, which is exactly what an ITL SLO must see."""
-        now = time.perf_counter() if now is None else now
-        prev = req.t_last_token
-        req.t_last_token = now
-        if prev is None:
-            return
-        itl = now - prev
-        self._h_itl.observe(itl)
-        self._tenant(req.tenant)["itl"].observe(itl)
-        if _slo.request_log().enabled:
-            self.log_event("decode", req,
-                           itl_ms=round(1e3 * itl, 3),
-                           position=position)
+    def step_tokens_generated(self, advanced):
+        """The tokens one collected decode step emitted
+        (`engine.Step.advanced`: each sequence with its gaps, the
+        engine's numbers, the ones on the tokens' `serving.token`
+        records): observe each request's inter-token latency (fleet +
+        tenant), one observation per EMITTED token — failover stalls
+        land here too, which is exactly what an ITL SLO must see, and a
+        speculative burst's interior gaps are 0 (the client receives it
+        at once)."""
+        log = _slo.request_log().enabled
+        fleet = self._h_itl.observe
+        for seq, before, _, gaps in advanced:
+            req = seq.request
+            if req is None:
+                continue
+            tenant = self._tenant(req.tenant)["itl"].observe
+            for position, itl in enumerate(gaps, before):
+                fleet(itl)
+                tenant(itl)
+                if log:
+                    self.log_event("decode", req,
+                                   itl_ms=round(1e3 * itl, 3),
+                                   position=position)
 
     def prefill_chunk(self, queue_depth):
         """One chunked-prefill kernel call ran; `queue_depth` is the

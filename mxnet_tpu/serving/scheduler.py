@@ -159,7 +159,8 @@ class Request:
                                       # replay carried in its prompt
                                       # (the goodput ledger credits the
                                       # CLIENT-visible delivery)
-        self.t_last_token = None      # previous token's emit time (ITL)
+        self.t_last_token = None      # when the host held its newest
+                                      # token (`Engine.record_tokens`)
         self._on_finish = None        # failover stitch callback
         self._event = threading.Event()
         self._finish_lock = threading.Lock()

@@ -820,27 +820,19 @@ class LMServer(_HTTPFrontend):
             if advanced:  # count only sequences that really stepped
                 # a speculative step emits a BURST per sequence, so
                 # tokens = post-len minus pre-len, not 1 per step
-                emitted = sum(after - before for _, before, after in advanced)
+                emitted = sum(len(gaps) for *_, gaps in advanced)
                 met.decode_step(len(advanced), eng.max_batch,
                                 step.t_read - since,
                                 cache_util=eng.cache_utilization(),
                                 paged=eng.paged, tokens=emitted,
-                                live_max=max(n for _, n, _ in advanced))
+                                live_max=max(row[1] for row in advanced))
                 if eng.last_spec is not None:
                     met.spec_pass(**eng.last_spec)
                     eng.last_spec = None
-                # per-request inter-token latency (ISSUE 13): the
-                # ITL SLO and the lifecycle ledger see every gap,
-                # including the one a failover replay opened — a
-                # speculative burst records one observation per
-                # EMITTED token (the burst's interior gaps are ~0:
-                # the client receives those tokens back-to-back)
-                for s, before, after in advanced:
-                    if s.request is not None:
-                        for posn in range(before, after):
-                            met.token_generated(
-                                s.request, now=self._last_step_t,
-                                position=posn)
+                # per-request inter-token latency (ISSUE 13): the ITL
+                # SLO and the lifecycle ledger see every gap, as the
+                # engine put it on the token's record
+                met.step_tokens_generated(advanced)
             if evict:
                 self._evict()
 
@@ -897,7 +889,7 @@ class LMServer(_HTTPFrontend):
             req.state = "running"
             _queue_span(req)
             met.request_admitted(req)
-            met.request_prefilled(req, time.perf_counter() - t0)
+            self._first_token(seq, req, time.perf_counter() - t0)
             # disaggregated serving: same hand-off seam as the chunked
             # path — the dense one-shot prefill just completed and the
             # first token is appended
@@ -906,6 +898,26 @@ class LMServer(_HTTPFrontend):
                     and self._migrate_out(seq, req):
                 continue
             sched.running.append(seq)
+
+    def _first_token(self, seq, req, prefill_s):
+        """A request's prefill has ended with its first token here: the
+        metrics' stamps, and the token's record on the request's
+        timeline. It spans the prefill from where the engine took the
+        sequence in (`first`; the prefills it counts are its own and
+        whatever ran between its chunks) or, for a failover's replay,
+        from the victim's last token, which is the gap the client saw
+        and `serving_itl_seconds` observed; it ends where the host held
+        the prefill's result, and `stamp_lag_us` says how much later
+        `t_first_token` was stamped."""
+        since = req.t_last_token
+        self.metrics.request_prefilled(req, prefill_s, seq.t_last_token)
+        attrs = {"stamp_lag_us": int(
+            (req.t_first_token - seq.t_last_token) * 1e6)}
+        if since is None:
+            since = seq.t_begin
+            attrs["first"] = 1
+        self.engine.record_tokens([(seq, len(seq.tokens) - 1)],
+                                  seq.t_last_token, since=since, **attrs)
 
     def _admit_paged(self, admitted):
         """Paged admission: allocate cache blocks only; the prompt
@@ -1002,7 +1014,7 @@ class LMServer(_HTTPFrontend):
                 sched.prefilling.remove(seq)
                 req = seq.request
                 if req is not None:
-                    met.request_prefilled(req, seq.prefill_s)
+                    self._first_token(seq, req, seq.prefill_s)
                 # disaggregated serving: a prefill-role replica hands
                 # the finished prompt to a decode replica here — after
                 # the first token (TTFT observed on THIS replica, which

@@ -11,13 +11,15 @@ Every closed span is recorded three ways:
   * the flight recorder ring (`telemetry.flight`) — the post-mortem
     record of "what was this process doing right before it died".
 
-Trace ids connect spans: the serving stack uses the request id, so one
-request's submit → queue → prefill chunks → decode steps all share an id
-and render as a single row. Ids propagate implicitly to nested spans via
-a thread-local (set once at the root span, inherited below), or
-explicitly with `span(..., trace=id)` / `record_span(..., trace=id)` for
-regions timed outside a `with` block (e.g. one decode step fanned out to
-every sequence it advanced).
+Trace ids connect spans: the serving stack uses the request's trace id,
+so one request's life shares an id and renders as a single row: submit →
+queue → prefill (chunks) → a gapless chain of `serving.token`, one span
+per token it was served, each from the token before it to the moment the
+host held this one (`Engine.record_tokens`). Ids propagate implicitly to
+nested spans via a thread-local (set once at the root span, inherited
+below), or explicitly with `span(..., trace=id)` /
+`record_span(..., trace=id)` for regions timed outside a `with` block
+(e.g. each token of one decode step, filed on its own request's row).
 
 Parents connect layers: a span takes its id when it opens and every record
 carries `parent`, the id of the span open on the same thread when it
@@ -59,8 +61,8 @@ _appended = 0
 _exported_upto = 0
 
 
-#: cached (counter, gauge) pair — record_span runs once per request per
-#: decode step, so it must not pay a locked registry lookup per span.
+#: cached (counter, gauge) pair — record_span runs once per served token,
+#: so it must not pay a locked registry lookup per span.
 #: Invalidated when the default registry is reset (bench.py's
 #: per-config isolation): the cached counter identity is checked
 #: against the registry's current entry with one plain dict read.
@@ -162,16 +164,16 @@ def record_span(name, start_us, dur_us, trace=None, category="trace",
                 to_profiler=True, to_flight=True, parent=None, alias=None,
                 _id=None, **attrs):
     """Record one already-timed span. The seam for fan-out: a batched
-    decode step is timed once but attributed to every request it
-    advanced, so each request's row stays connected. The per-request
-    copies only matter to the span ring (their Perfetto rows):
+    decode step is read once and each of its tokens filed on its own
+    request's row (`serving.token`), so each row stays connected. Those
+    records only matter to the span ring (their Perfetto rows):
     `to_profiler=False` keeps them out of the chrome trace and
-    `to_flight=False` out of the flight-recorder ring, where B duplicate
-    copies per decode step would evict the history the black box exists
-    to keep (the batch-level span covers the interval in both).
+    `to_flight=False` out of the flight-recorder ring, where B records
+    per decode step would evict the history the black box exists to keep
+    (the batch-level span covers the interval in both).
     `parent` is the span open on this thread unless `parent=` names one
-    (the copies name the batch-level span, closed by then); `alias` is the
-    name the legacy profiler table files the span under."""
+    (a step's tokens name the batch-level span, closed by then); `alias`
+    is the name the legacy profiler table files the span under."""
     if not enabled():
         return
     if trace is None:

@@ -243,13 +243,14 @@ def test_a_row_that_met_its_eos_is_dropped_from_the_step_launched_ahead(
         # ... never given a `token_generated` record (one a decode token:
         # the gap from the token before it) ...
         assert met._h_itl.count == sum(len(g) - 1 for g in want)
-        # ... nor a copy of a step's span on its request's row: one a
-        # decode token, the last at the request's last position
+        # ... nor a `serving.token` record on its request's row: one a
+        # served token, the prefill's first, the last at the request's last
+        # position
         for h, g, (p, _, _) in zip(handles, want, requests):
             assert h.tokens == p + g
-            copies = [s["attrs"]["position"] for s in telemetry.spans(h.trace)
-                      if s["name"] == "serving.decode"]
-            assert sorted(copies) == list(range(len(p) + 1, len(p) + len(g)))
+            served = [s["attrs"]["position"] for s in telemetry.spans(h.trace)
+                      if s["name"] == "serving.token"]
+            assert served == list(range(len(p), len(p) + len(g)))
         # the rows that ran past their end were launched all the same: the
         # steps dispatched hold more rows than the tokens appended
         rows = sum(s["attrs"]["batch"] for s in telemetry.spans()

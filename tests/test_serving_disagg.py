@@ -211,7 +211,7 @@ def test_migration_token_identity_and_single_trace(tiny_lm, tmp_path):
         names = [s["name"] for s in telemetry.spans(trace=req.trace)]
         assert "serving.migration_hop" in names
         assert "serving.prefill" in names
-        assert "serving.decode" in names
+        assert "serving.token" in names
         doc = telemetry.export_perfetto(str(tmp_path / "migr.json"))
         evs = [e for e in doc["traceEvents"]
                if e["ph"] == "X" and e["args"].get("trace") == req.trace]

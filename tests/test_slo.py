@@ -136,7 +136,7 @@ def test_http_fuzzed_traceparent_never_500(tiny_lm):
             out = json.loads(r.read())
         assert out["trace"] == tid
         assert [s for s in telemetry.spans(trace=tid)
-                if s["name"] == "serving.decode"]
+                if s["name"] == "serving.token"]
     finally:
         srv.close()
 
@@ -189,7 +189,7 @@ def test_failover_trace_stitched_single_row(tiny_lm, tmp_path):
         assert names.count("serving.prefill") >= 2, (
             "the replay's prefill must join the original trace: %r"
             % names)
-        assert names.count("serving.decode") >= 3
+        assert names.count("serving.token") >= 3
         hops = [s for s in spans if s["name"] == "serving.failover_hop"]
         assert len(hops) == 1
         attrs = hops[0]["attrs"]

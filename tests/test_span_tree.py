@@ -194,15 +194,21 @@ def test_every_decoding_iteration_records_one_tree(tiny_lm, paged):
     launched, appended = None, 0    # the batch of the step in flight
     for n, under in enumerate(passes):
         first, last = n == 0, n == len(passes) - 1
-        step, = [s for s in under["serving.decode"] if "batch" in s["attrs"]]
-        copies = [s for s in under["serving.decode"] if "batch" not in s["attrs"]]
+        # the name is the batch-level step's alone (ISSUE 40): a request's
+        # tokens are `serving.token` records under it
+        step, = under["serving.decode"]
+        assert "batch" in step["attrs"]
+        tokens = [s for s in under["serving.token"]
+                  if "first" not in s["attrs"]]
         collected = ([] if first else [launched]) \
             + ([step["attrs"]["batch"]] if last else [])
-        # a copy a request for every token this pass appended
-        assert len(copies) == sum(collected)
-        appended += len(copies)
-        assert all(c["parent"] == step["id"]
-                   and abs(c["ts"] - step["ts"]) < 1000 for c in copies)
+        # a record a request for every token this pass appended, ending
+        # where the host held the step's tokens: inside the pass's span
+        assert len(tokens) == sum(collected)
+        appended += len(tokens)
+        assert all(t["parent"] == step["id"] and t["trace"] is not None
+                   and step["ts"] <= t["ts"] + t["dur"]
+                   <= step["ts"] + step["dur"] for t in tokens)
         found = {name: [s for s in under[name] if "batch" in s["attrs"]]
                  for name in TREE}
         for name, parent in TREE.items():
@@ -241,7 +247,7 @@ def test_every_decoding_iteration_records_one_tree(tiny_lm, paged):
     assert {"serving.loop", "serving.admit", "serving.decode"} <= flown
     assert not flown & {"serving.decode.build", "serving.decode.dispatch",
                         "serving.decode.readback", "serving.decode.append",
-                        "serving.account"}
+                        "serving.account", "serving.token"}
 
 
 def test_a_pass_that_only_waits_records_nothing(tiny_lm):

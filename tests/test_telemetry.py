@@ -291,14 +291,15 @@ def test_one_request_single_connected_trace(tmp_path):
     assert "serving.submit" in names
     assert "serving.queue" in names
     assert "serving.prefill" in names
-    assert names.count("serving.decode") >= 2     # one per decode step
+    assert names.count("serving.token") == 4      # one per served token
+    assert "serving.decode" not in names    # the batch-level step's alone
     # the Perfetto export renders them as ONE row (a single tid)
     doc = telemetry.export_perfetto(str(tmp_path / "serving.json"))
     evs = [e for e in doc["traceEvents"]
            if e["ph"] == "X" and e["args"].get("trace") == rid]
     assert len({e["tid"] for e in evs}) == 1
     assert {"serving.submit", "serving.queue", "serving.prefill",
-            "serving.decode"} <= {e["name"] for e in evs}
+            "serving.token"} <= {e["name"] for e in evs}
 
 
 def test_http_metrics_content_negotiation():
