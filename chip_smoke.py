@@ -200,8 +200,10 @@ class Smoke:
                        if k.endswith("_fallback") and v is not None}
                 if self.rehearse:
                     # off the chip the XLA loop is the gather walk's
-                    # own answer (`walk_fallback_reason`)
+                    # own answer (`walk_fallback_reason`), as XLA's
+                    # blocks are a prompt's
                     bad.pop("walk_fallback", None)
+                    bad.pop("prompt_attn_fallback", None)
                     bad.pop("state_step_fallback", None)
                 check(not bad, "fallbacks: %r" % bad)
                 check(eng.tp == (kw.get("tp") or 1), "engine.tp is %r"

@@ -33,9 +33,10 @@ the rows the engine's batch can hold and the batch itself is the grid's
 extent, as data, so the programs of EVERY batch bucket call the same
 function; and that function's body is the kernel traced and lowered once
 a process and kept as text (`_lowered_once`): a program merges the
-module in and calls it (`_splice`). Pallas itself is imported on a thread of its own as the
-engine is built (`preload`): about a second of Python that the first
-decode program's trace would otherwise wait for.
+module in and calls it (ops/pallas_splice.py, which also imports Pallas
+on a thread of its own as the engine is built, `preload`: about a second
+of Python that the first decode program's trace would otherwise wait
+for).
 
 The interpreter runs the same kernel on the CPU for the parity tests
 (tests/test_pallas_decode_walk.py); `chip_smoke.py` compiles it with
@@ -45,18 +46,14 @@ chip.
 from __future__ import annotations
 
 import functools
-import importlib
-import threading
 
 import numpy as np
 import jax
-import jax.extend.core
 import jax.numpy as jnp
-from jax.interpreters import mlir
-from jaxlib.mlir.dialects import func
 
 from .pallas_fused import _cost
 from .pallas_paged import _SUBLANES
+from .pallas_splice import Spliced
 
 #: what a key the row does not see scores, here and in `_attend_live`:
 #: finite, so that the running maximum is, and what a chunk of unseen keys
@@ -88,22 +85,6 @@ def walk_fallback_reason(head_dim, block_size, kv_dtype, backend=None):
         return ("block_size %d is not a multiple of the %s-row tile of a "
                 "%s pool" % (block_size, rows, jnp.dtype(kv_dtype).name))
     return None
-
-
-def preload():
-    """Start importing Pallas on a thread of its own and return at once.
-    The import is a second or two of Python once a process (most of it
-    the GPU half of the package, which nothing here uses), and the first
-    decode step cannot be traced without it: found in the FIRST decode
-    program of a warm process, 1.65 s of the `opt6b7_batch_closed` cell's
-    `setup_s` (PERF.md §6, PR 33). An engine whose gate lets the kernel
-    run calls this as it is built, so the import runs beside what comes
-    before that trace and holds no lock the interpreter needs: the first
-    prefill program's read from the compile cache. A trace that gets
-    there first waits on the module's import lock, as any importer does."""
-    threading.Thread(target=importlib.import_module,
-                     args=("jax.experimental.pallas.tpu",),
-                     name="pallas-preload", daemon=True).start()
 
 
 def chunk_blocks(n_kv_heads, block_size, head_dim, kv_dtype, width,
@@ -298,43 +279,13 @@ def _kernel_call(q, k_pool, v_pool, tables, positions, layer, n_rows, *,
     )(tables, positions, layer, q, k_pool, v_pool)
 
 
-@functools.lru_cache(maxsize=None)
-def _lowered_once(shapes, precision, **static):
-    """The module of `_kernel_call` at `shapes` ((shape, dtype name) an
-    operand), traced and lowered for the TPU ONCE a process, as text:
-    every step program after that parses it and merges it in (`_splice`)
-    and neither traces the kernel nor lowers it to Mosaic again, which on
-    the chip's host is 0.45 s a program (PERF.md §6, PR 33). `precision`
-    (the process's default for a float32 dot, which the kernel's lowering
-    reads) is part of what was lowered, so of the key."""
-    return jax.jit(
-        functools.partial(_kernel_call, interpret=False, **static)).trace(
-            *(jax.ShapeDtypeStruct(s, jnp.dtype(d)) for s, d in shapes)
-        ).lower(lowering_platforms=("tpu",)).as_text()
-
-
-#: the kernel as a step program sees it: one call of the module
-#: `_lowered_once` keeps. (`jax.export` keeps such a module too, but a
-#: program that calls an exported one returns its arrays COMMITTED to
-#: their device, and an unplaced engine's programs would then meet a
-#: second signature after the first decode step: PERF.md §6, PR 33.)
-_spliced_p = jax.extend.core.Primitive("decode_walk")
-_spliced_p.def_abstract_eval(
+#: the kernel as a step program sees it: one call of the module traced
+#: and lowered once a process (ops/pallas_splice.py), 0.45 s a program on
+#: the chip's host otherwise (PERF.md §6, PR 33)
+_spliced = Spliced(
+    "decode_walk", _kernel_call,
     lambda q, *operands, **static: jax.core.ShapedArray(q.shape, jnp.float32))
-
-
-def _splice(ctx, *operands, precision, **static):
-    shapes = tuple((a.shape, a.dtype.name) for a in ctx.avals_in)
-    kernel = mlir.ir.Module.parse(_lowered_once(shapes, precision, **static))
-    results = mlir.ir.SymbolTable(kernel.operation)["main"].type.results
-    name = mlir.merge_mlir_modules(
-        ctx.module_context.module, "decode_walk", kernel,
-        dst_symtab=ctx.module_context.symbol_table)
-    return func.CallOp(results, mlir.ir.FlatSymbolRefAttr.get(name),
-                       operands).results
-
-
-mlir.register_lowering(_spliced_p, _splice, platform="tpu")
+_lowered_once = _spliced.lowered_once
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "ring",
@@ -346,9 +297,7 @@ def _walk_rows(*operands, interpret, **static):
     rows)."""
     if interpret:
         return _kernel_call(*operands, interpret=True, **static)
-    return _spliced_p.bind(
-        *operands, precision=jax.config.jax_default_matmul_precision,
-        **static)
+    return _spliced(*operands, **static)
 
 
 def decode_walk(q, k_pool, v_pool, tables, positions, layer, *, scale,

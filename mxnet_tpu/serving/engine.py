@@ -82,11 +82,11 @@ import jax.numpy as jnp
 from ..base import MXNetError
 from .. import telemetry
 from ..models.transformer import OneChip, block, _layer_norm
-from ..ops.pallas_decode_walk import preload as preload_walk
+from ..ops.pallas_splice import preload as preload_pallas
 from . import tp
 from .kv_cache import (POOL_ARGS, CacheSpec, PagedKVCache, PromptView,
                        LiveGatherView, PagedView, walk_unfit,
-                       state_step_unfit, flat_slots,
+                       prompt_attn_unfit, state_step_unfit, flat_slots,
                        copy_block, zero_block_scales)
 from .prefix_cache import PrefixCache, prefix_cache_enabled
 
@@ -168,13 +168,17 @@ class Sequence:
     host held its newest token (`t_begin`, when the engine took it in,
     until there is one), and `prefills_seen` / `prefill_tokens_seen`
     are the engine's two prefill counters as they stood then: what a
-    token's record (`Engine.record_tokens`) is made from."""
+    token's record (`Engine.record_tokens`) is made from. `attn`: what
+    scored its prompt in a whole-prompt prefill, the `kernel`
+    (ops/pallas_prompt_attention.py) or `xla`, block by block; None
+    elsewhere."""
 
     __slots__ = ("tokens", "prompt_len", "blocks", "table_row",
                  "max_total", "eos_id", "done", "last_logits", "request",
                  "prefilled", "prefill_s", "cache_hit_tokens",
                  "shared_blocks", "token_logits", "t_begin",
-                 "t_last_token", "prefills_seen", "prefill_tokens_seen")
+                 "t_last_token", "prefills_seen", "prefill_tokens_seen",
+                 "attn")
 
     def __init__(self, prompt, max_total, eos_id=None):
         self.tokens = list(prompt)
@@ -197,6 +201,7 @@ class Sequence:
                                       # paths — the spec parity oracle
         self.t_begin = self.t_last_token = time.perf_counter()
         self.prefills_seen = self.prefill_tokens_seen = 0
+        self.attn = None
 
     @property
     def generated(self):
@@ -268,7 +273,7 @@ def prefill(params, pools, tokens, length, table_row, cfg):
     under the causal mask no real position ever attends to them; their
     K/V writes land in not-yet-used or null-block slots and are
     overwritten by decode before they can be read."""
-    view = PromptView(pools, table_row)
+    view = PromptView(pools, table_row, None, length)
     x = params["embed"][tokens] + params["pos_embed"][:tokens.shape[0]]
     x = _layers(params, x, cfg, view, OneChip)                     # (S, D)
     return (*view.pools, _logits(params, x[length - 1]))
@@ -742,6 +747,16 @@ class Engine:
         # the kernel walks it, and where no gather step does (paged, no
         # cache)
         self.walk_fallback = None
+        # why a whole prompt's attention is XLA's, block by block, and
+        # not the kernel (ops/pallas_prompt_attention.py), as far as an
+        # engine knows once (backend, head_dim, dtype):
+        # `kv_cache.prompt_attn_unfit` asked of the pool, as the view
+        # asks it while a prefill program is traced. The bucket is a
+        # program's: `prefill_step` asks again with it and says which of
+        # the two the program holds on its span (`attn`). None where the
+        # kernel may, and where no whole prompt is prefilled (paged, no
+        # cache)
+        self.prompt_attn_fallback = None
         # why a decode step updates the recurrent states of a "state"
         # kind with XLA's gather and scatter and not with the kernel
         # (ops/pallas_ssm_step.py): `kv_cache.state_step_unfit`, asked
@@ -832,8 +847,10 @@ class Engine:
                         if self.kv_quant else None)
             if not self.paged:
                 self.walk_fallback = walk_unfit(self.cache.k, cspec.layout)
-                if self.walk_fallback is None:
-                    preload_walk()
+                self.prompt_attn_fallback = prompt_attn_unfit(
+                    self.cache.k, cspec.q_group, layout=cspec.layout)
+                if None in (self.walk_fallback, self.prompt_attn_fallback):
+                    preload_pallas()
                 if "state" in cspec.kinds:
                     self.state_step_fallback = state_step_unfit(
                         self.cache.ssm_state)
@@ -1265,6 +1282,11 @@ class Engine:
                 toks = np.zeros((s_pad,), np.int32)
                 toks[:L] = prompt
                 step_span.attrs["bucket"] = s_pad
+                spec = self.cache.spec
+                seq.attn = "xla" if prompt_attn_unfit(
+                    self.cache.k, spec.q_group, s_pad, spec.layout) \
+                    else "kernel"
+                step_span.attrs["attn"] = seq.attn
                 with self._count("prefill", s_pad):
                     logits, *stats = self._step(
                         self.model.prefill, jnp.asarray(toks),

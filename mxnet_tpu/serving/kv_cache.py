@@ -68,6 +68,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..ops import pallas_decode_walk as _walk
+from ..ops import pallas_prompt_attention as _prompt
 from ..ops import pallas_ssm_step as _ssm
 from ..ops.pallas_attention import default_interpret
 from ..ops.pallas_paged import paged_attention
@@ -141,6 +142,11 @@ class CacheSpec:
     @property
     def row_width(self):
         return -(-self.latent_dim // LANES) * LANES
+
+    @property
+    def q_group(self):
+        """Query heads one cached head serves."""
+        return (self.n_q_heads or self.n_heads) // max(self.n_heads, 1)
 
     def values_per_token(self):
         """Cached values one token occupies over all layers (a token
@@ -858,15 +864,34 @@ def _place_state(spec, layer):
 PROMPT_Q_BLOCK = 256
 
 
+def prompt_attn_unfit(plane, group, bucket=None, layout="kv"):
+    """Why a whole prompt's attention is XLA's (`banded_attention`) and
+    not the kernel (ops/pallas_prompt_attention.py), or None: asked of a
+    K plane as it lies and the query heads a cached head serves, by
+    `PromptView.attend` while it traces (`bucket`: the program's rows)
+    and by the engine of the plane it will hand that trace (`bucket`
+    None: what it knows once; a prefill's own: which of the two that
+    program holds), so the two cannot disagree. A `layout` other than
+    keys and values never meets the view."""
+    if layout != "kv":
+        return ("the pool holds %s rows, not keys and values: "
+                "`expanded_attention` scores their prompts" % layout)
+    return _prompt.prompt_attention_unfit(bucket, plane.shape[-1], group,
+                                          plane.dtype)
+
+
 class PromptView:
     """Prefill of a whole prompt: the rows are positions 0..S-1 of ONE
     sequence of true `length`. Every layer's K/V go into the blocks of
     its kind's columns of `table_row` (`write_kv_prompt`; a ring's by
     `write_kv_prompt_ring`), and attention is causal over the prompt's
-    own K/V inside the layer's band (`banded_attention`): the cache is
-    written, not read."""
+    own K/V inside the layer's band: the cache is written, not read. ONE
+    kernel a layer where the gate lets it (`prompt_attn_unfit`), which
+    scores the first `length` rows alone, every layer of one window a
+    call site of one lowered function
+    (ops/pallas_prompt_attention.py); `banded_attention` elsewhere."""
 
-    def __init__(self, pools, table_row, spec=None, length=None):
+    def __init__(self, pools, table_row, spec, length):
         self.pools, self.table_row = tuple(pools), table_row
         self.spec, self.length = spec, length
 
@@ -880,6 +905,11 @@ class PromptView:
         else:
             planes = write_kv_prompt(*planes, j, row[0], k, v)
         self.pools = _put(self.pools, i, planes)
+        if prompt_attn_unfit(planes[0], q.shape[1] // k.shape[1],
+                             q.shape[0]) is None:
+            return _prompt.prompt_attention(
+                q, k, v, self.length, window=window,
+                interpret=default_interpret())
         return banded_attention(q, k, v, window, PROMPT_Q_BLOCK)
 
     def mix(self, layer, xbc, dt, w, cfg):

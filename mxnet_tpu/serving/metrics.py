@@ -192,6 +192,11 @@ class ServingMetrics:
             "serving_decode_steps_walk_kernel_total",
             help="gather-path decode steps whose program walks the cache "
                  "with the decode-walk kernel, not with XLA's loop")
+        self._prefills_attn_kernel = c(
+            "serving_prefills_attn_kernel_total",
+            help="whole-prompt prefills whose program scores the prompt "
+                 "with the prompt-attention kernel, not with XLA block "
+                 "by block")
         self._drains = {reason: c(
             "serving_decode_drains_%s_total" % reason,
             help="times the decode pipeline ran empty: %s" % why)
@@ -587,12 +592,15 @@ class ServingMetrics:
                        reason=type(req.error).__name__
                        if req.error is not None else "timeout")
 
-    def request_prefilled(self, req, prefill_s, t_token):
+    def request_prefilled(self, req, prefill_s, t_token, attn=None):
         """`t_token`: when the host held the prefill's result, the first
         token's stamp on the request's timeline (`req.t_last_token` from
-        there on: the engine keeps it, `Engine.record_tokens`)."""
+        there on: the engine keeps it, `Engine.record_tokens`). `attn`:
+        what scored the prompt (`engine.Sequence.attn`)."""
         self._h_queue.observe(req.t_admit - req.t_submit)
         self._h_prefill.observe(prefill_s)
+        if attn == "kernel":
+            self._prefills_attn_kernel.inc()
         with self._lock:
             self._prefill_tokens_obs += len(req.prompt)
         req.t_first_token = time.perf_counter()
@@ -986,6 +994,8 @@ class ServingMetrics:
                 "decode_steps_ahead": int(self._steps_ahead.value),
                 "decode_steps_walk_kernel": int(
                     self._steps_walk_kernel.value),
+                "prefills_attn_kernel": int(
+                    self._prefills_attn_kernel.value),
                 "decode_drains": {reason: int(c.value) for reason, c
                                   in self._drains.items() if c.value},
             },
@@ -1019,7 +1029,8 @@ class ServingMetrics:
             }
             # why an option that was asked for is off, each by its name
             for name in ("paged_fallback", "walk_fallback",
-                         "state_step_fallback", "prefix_cache_fallback",
+                         "prompt_attn_fallback", "state_step_fallback",
+                         "prefix_cache_fallback",
                          "kv_quant_fallback", "weight_quant_fallback",
                          "tp_fallback", "spec_fallback"):
                 if getattr(engine, name, None):

@@ -910,7 +910,8 @@ class LMServer(_HTTPFrontend):
         the prefill's result, and `stamp_lag_us` says how much later
         `t_first_token` was stamped."""
         since = req.t_last_token
-        self.metrics.request_prefilled(req, prefill_s, seq.t_last_token)
+        self.metrics.request_prefilled(req, prefill_s, seq.t_last_token,
+                                       seq.attn)
         attrs = {"stamp_lag_us": int(
             (req.t_first_token - seq.t_last_token) * 1e6)}
         if since is None:

@@ -730,3 +730,68 @@ def test_paged_off_env_restores_gather_path(tiny_lm, monkeypatch):
         assert snap["paths"]["prefill_chunks"] == 0
     finally:
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the whole-prompt prefill's attention: which program holds the kernel
+# (ops/pallas_prompt_attention.py), and who says so (ISSUE 41)
+# ---------------------------------------------------------------------------
+
+
+def _tiny_family(name):
+    """The old family, a Trinity-shaped model (grouped queries, window
+    and full layers) and a Falcon-H1-shaped one (a recurrent state
+    beside its keys and values), each at its own tiny defaults."""
+    from mxnet_tpu.models import afmoe, falcon_h1
+    key = jax.random.PRNGKey(3)
+    if name == "dense":
+        cfg = tiny_cfg()
+        return init_transformer_params(key, cfg), cfg
+    if name == "kinds":
+        cfg = afmoe.AfmoeConfig(max_len=64)
+        return afmoe.init_afmoe_params(key, cfg), cfg
+    cfg = falcon_h1.FalconH1Config(max_len=64)
+    return falcon_h1.init_falcon_h1_params(key, cfg), cfg
+
+
+@pytest.mark.parametrize("name", ["dense", "kinds", "state"])
+def test_a_cpu_engine_scores_prompts_with_xla_and_says_why(name):
+    from mxnet_tpu import telemetry
+    telemetry.tracing.clear()
+    srv = serving.serve(_tiny_family(name), max_batch=2, block_size=8)
+    try:
+        srv.submit(arith_prompt(1, 5, 11), max_new_tokens=3).result(
+            timeout=120)
+        eng, snap = srv.engine, srv.snapshot()
+        assert "the backend is cpu" in eng.prompt_attn_fallback
+        assert snap["engine"]["prompt_attn_fallback"] \
+            == eng.prompt_attn_fallback
+        prefills = [s["attrs"] for s in telemetry.spans()
+                    if s["name"] == "serving.prefill"]
+        assert [(a["bucket"], a["attn"]) for a in prefills] == [(16, "xla")]
+        assert snap["throughput"]["prefills_attn_kernel"] == 0
+        assert "serving_prefills_attn_kernel_total" in srv.prometheus_text()
+    finally:
+        srv.close()
+
+
+def test_the_prompt_attention_gates_reasons():
+    from mxnet_tpu.ops.pallas_prompt_attention import (
+        MIN_BUCKET, prompt_attention_unfit)
+    bf16 = jnp.bfloat16
+    assert prompt_attention_unfit(8192, 128, 6, bf16, "tpu") is None
+    assert prompt_attention_unfit(MIN_BUCKET, 128, 5, bf16, "tpu") is None
+    # what an engine knows once leaves the bucket out
+    assert prompt_attention_unfit(None, 128, 1, bf16, "tpu") is None
+    assert prompt_attention_unfit(8192, 128, 6, bf16, "cpu").startswith(
+        "the backend is cpu: the kernel is compiled for the TPU")
+    assert prompt_attention_unfit(8192, 64, 6, bf16, "tpu") \
+        == "head_dim 64 is not a multiple of the 128-lane tile"
+    assert prompt_attention_unfit(MIN_BUCKET // 2, 128, 6, bf16, "tpu") \
+        == ("a bucket of %d rows is not a power of two of at least %d: "
+            "XLA's own is no slower there" % (MIN_BUCKET // 2, MIN_BUCKET))
+    assert "do not fit the kernel's VMEM" in prompt_attention_unfit(
+        8192, 128, 64, bf16, "tpu")
+    # a latent pool's prompts are another function's
+    assert "`expanded_attention`" in kv_cache.prompt_attn_unfit(
+        jnp.zeros((1, 2, 8, 640), bf16), 1, layout="latent")

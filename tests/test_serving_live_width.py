@@ -320,6 +320,39 @@ def test_the_two_kind_step_compiles_for_the_chip_without_a_copy_of_a_pool(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
+def test_the_prompt_attention_kernel_compiles_for_the_chip_at_the_cells_widths(
+        one_chip):
+    """`ops/pallas_prompt_attention.py` at the `trinity_mixed_closed`
+    cell's widths (48 query heads on 8 of 128, bf16) and its longest
+    bucket, a window layer and the full layer, through Mosaic for the
+    v5e: the blocks of `block_sizes` are whole tiles and the scores of
+    3,072 rows by 1,024 keys fit the VMEM the kernel asks for. Handed q,
+    k and v as the layer has them, the program is the kernel and the
+    reshapes round it: no `while`, no operation of the scores' size."""
+    from mxnet_tpu.ops import pallas_prompt_attention as pa
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    S, H, Hkv, Dh = 8192, 48, 8, 128
+    assert pa.prompt_attention_unfit(S, Dh, H // Hkv, jnp.bfloat16,
+                                     "tpu") is None
+    # the benchmark's precision (the suite's "highest" would ask Mosaic
+    # for a float32 product of bf16 operands)
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        for window in (4096, 0):
+            compiled = jax.jit(
+                lambda q, k, v, n: pa.prompt_attention(
+                    q, k, v, n, window=window)).lower(
+                sds((S, H, Dh), jnp.bfloat16), sds((S, Hkv, Dh), jnp.bfloat16),
+                sds((S, Hkv, Dh), jnp.bfloat16), sds((), i32)).compile()
+            text = compiled.as_text()
+            assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                                  text)) == 1
+            assert " while(" not in text and "f32[8,6," not in text
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+
+
 def test_the_state_kinds_steps_compile_for_the_chip_without_a_copy_of_a_plane(
         one_chip, monkeypatch):
     """At the `falconh1_chat_closed` cell's widths, tables and pools (two
