@@ -538,15 +538,27 @@ class Smoke:
         states gathered by hand: y, the rows' new states, and the slots no
         row names untouched (the two padded rows share the null slot, which
         the second reads while the first writes it: they are not compared). Then the call alone, timed, the plane donated:
-        its bytes over its time is what `ssm_step_hbm_share` reads."""
+        its bytes over its time is what `ssm_step_hbm_share` reads. And at
+        the `nemotron3_reason_closed` cell's (128 rows of 8 groups x 128 x
+        8 heads x 64: `ssm_step_hbm_share.hybrid`)."""
+        for shape in ((64, 65, 2, 256, 16, 128), (128, 129, 8, 128, 8, 64)):
+            self.ssm_step_at(interpret, *(
+                (4, 6, 2, 16, 8, shape[-1]) if self.rehearse else shape))
+
+    def ssm_step_at(self, interpret, R, slots_n, G, N, hpg, P):
+        """`ssm_step` at one shape, the plane laid as the cache lays it:
+        (groups, N, heads, P) for heads the lanes wide, (groups, N, heads x
+        P) for narrower ones side by side (`falcon_h1.state_layout`; the
+        `nemotron3_reason_closed` cell's 8 heads of 64)."""
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.models.falcon_h1 import state_update
+        from mxnet_tpu.models.falcon_h1 import (FalconH1Config, state_layout,
+                                                state_update)
         from mxnet_tpu.ops import pallas_ssm_step as step
-        R, slots_n, G, N, hpg, P = (4, 6, 2, 16, 8, 128) if self.rehearse \
-            else (64, 65, 2, 256, 16, 128)
         keys = jax.random.split(jax.random.PRNGKey(3), 5)
-        plane = jax.random.normal(keys[0], (2, slots_n, G, N, hpg, P))
+        layout = state_layout(FalconH1Config(
+            ssm_groups=G, ssm_state=N, ssm_heads=G * hpg, ssm_head_dim=P))
+        plane = jax.random.normal(keys[0], (2, slots_n) + layout)
         slots = np.random.RandomState(2).permutation(slots_n - 1)[:R] + 1
         slots[-2:] = 0                               # padded rows
         slots = jnp.asarray(slots, jnp.int32)

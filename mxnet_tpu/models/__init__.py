@@ -29,3 +29,9 @@ from .afmoe import AfmoeConfig, init_afmoe_params, afmoe_apply
 # fourth served LM family)
 from .falcon_h1 import (FalconH1Config, init_falcon_h1_params,
                         falcon_h1_apply)
+
+# layers that are each one mixer by a pattern's letter: a state-space
+# mixer, a routed squared-ReLU expert layer or a grouped-query attention
+# (the fifth served LM family)
+from .nemotron_h import (NemotronHConfig, init_nemotron_h_params,
+                         nemotron_h_apply)

@@ -51,6 +51,8 @@ from .afmoe import banded_attention
 from .latent_moe import apply_rope, rms_norm
 
 F32 = jnp.float32
+#: lanes of a TPU tile: what decides how a state lies (`state_layout`)
+LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,19 +146,22 @@ def init_falcon_h1_params(rng, cfg):
     p.update((n, (1.0 + 0.1 * jax.random.normal(next(keys), s))
               .astype(cfg.dtype)) for n, s in sorted(gains.items()))
     for n, s in sorted(vectors.items()):
-        k = next(keys)
-        if n.endswith("A_log"):
-            v = jnp.log(jax.random.uniform(k, s, F32, 1.0, 16.0))
-        elif n.endswith("dt_bias"):
-            dt = jnp.exp(jax.random.uniform(k, s, F32, jnp.log(0.001),
-                                            jnp.log(0.1)))
-            v = dt + jnp.log(-jnp.expm1(-dt))
-        elif n.endswith("D"):
-            v = 1.0 + 0.1 * jax.random.normal(k, s, F32)
-        else:
-            v = jax.random.uniform(k, s, F32, -bound, bound)
-        p[n] = v
+        p[n] = init_mixer_vector(next(keys), n, s, bound)
     return p
+
+
+def init_mixer_vector(key, name, shape, bound):
+    """One float32 vector of a mixer by its name, as the Mamba-2 code
+    draws it: `A_log`, `dt_bias`, `D`, else the convolution's bias."""
+    if name.endswith("A_log"):
+        return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
+    if name.endswith("dt_bias"):
+        dt = jnp.exp(jax.random.uniform(key, shape, F32, jnp.log(0.001),
+                                        jnp.log(0.1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if name.endswith("D"):
+        return 1.0 + 0.1 * jax.random.normal(key, shape, F32)
+    return jax.random.uniform(key, shape, F32, -bound, bound)
 
 
 def rope_cos_sin(positions, cfg):
@@ -280,24 +285,36 @@ def mix_prompt(xbc, dt, w, cfg, length=None):
     tail = jax.lax.dynamic_slice_in_dim(
         padded, S if length is None else length, taps - 1, axis=0)
     state = state.reshape(cfg.ssm_groups, -1, *state.shape[1:])
-    return y.reshape(S, cfg.d_ssm), state.transpose(0, 3, 1, 2), tail
+    return (y.reshape(S, cfg.d_ssm),
+            state.transpose(0, 3, 1, 2).reshape(state_layout(cfg)), tail)
 
 
 def state_update(state, decay, dtx, Bm, Cm):
     """The recurrence's step on states as the cache keeps them
-    (`state_layout`): state (B, G, N, hpg, P); decay (B, G, hpg, 1) and
-    dtx (B, G, hpg, P) a head; Bm, Cm (B, G, N) a group; all float32.
-    Returns the new states and y (B, G, hpg, P), `sum_n C[n] H[n]`."""
-    h = decay[:, :, None] * state + Bm[..., None, None] * dtx[:, :, None]
-    return h, jnp.sum(Cm[..., None, None] * h, axis=2)
+    (`state_layout`): state (B, G, N, hpg, P), or (B, G, N, hpg * P)
+    where a group's heads lie side by side; decay (B, G, hpg, 1) and dtx
+    (B, G, hpg, P) a head; Bm, Cm (B, G, N) a group; all float32. Returns
+    the new states as they came and y (B, G, hpg, P), `sum_n C[n] H[n]`."""
+    B, G, N = Bm.shape
+    row = lambda t: jnp.broadcast_to(t, dtx.shape).reshape(B, G, 1, -1)
+    h = row(decay) * state.reshape(B, G, N, -1) + Bm[..., None] * row(dtx)
+    return (h.reshape(state.shape),
+            jnp.sum(Cm[..., None] * h, axis=2).reshape(dtx.shape))
 
 
 def state_layout(cfg):
-    """One sequence's state in one layer as the cache keeps it: (groups,
-    N, heads of a group, P), so that for one n a group's (heads, P) slab
-    is contiguous and its `B[n]`, `C[n]` are scalars."""
-    return (cfg.ssm_groups, cfg.ssm_state, cfg.ssm_heads // cfg.ssm_groups,
-            cfg.ssm_head_dim)
+    """One sequence's state in one layer as the cache keeps it. Heads as
+    wide as a tile's lanes or wider: (groups, N, heads of a group, P), so
+    that for one n a group's (heads, P) slab is whole tiles and its
+    `B[n]`, `C[n]` are scalars. Narrower heads that fill whole tiles side
+    by side (8 heads of 64): (groups, N, heads of a group x P), the state
+    index on a tile's sublanes, `B` and `C` columns and y a sum over
+    sublanes. A choice of the cache (ops/pallas_ssm_step.py has a form for
+    each), not of the mathematics."""
+    hpg, P = cfg.ssm_heads // cfg.ssm_groups, cfg.ssm_head_dim
+    if P < LANES and hpg * P % LANES == 0:
+        return (cfg.ssm_groups, cfg.ssm_state, hpg * P)
+    return (cfg.ssm_groups, cfg.ssm_state, hpg, P)
 
 
 def mix_step(xbc, dt, tail, w, cfg, update):
@@ -342,6 +359,15 @@ def _dot(x, w):
     return jnp.dot(x, w, preferred_element_type=F32)
 
 
+def gated_group_norm(y, z, gain, cfg):
+    """`RMSNorm(y * silu(z))` over each of the `ssm_groups` groups' values
+    with one gain of d_ssm (the gate before the norm): y (N, d_ssm) in
+    any dtype, z float32. Float32."""
+    y = (y.astype(F32) * jax.nn.silu(z)).reshape(y.shape[0], cfg.ssm_groups, -1)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+    return y.reshape(y.shape[0], cfg.d_ssm) * gain.astype(F32)
+
+
 def ssm_mixer(params, i, h, cfg, view):
     """The state-space mixer of layer i over normed rows h (N, D): its
     output before it joins the residual, float32."""
@@ -352,9 +378,7 @@ def ssm_mixer(params, i, h, cfg, view):
                            axis=-1)
     dt = jax.nn.softplus(dt + params[pre + "dt_bias"].astype(F32))
     y = view.mix(i, xbc.astype(h.dtype), dt, mixer_weights(params, i), cfg)
-    y = (y.astype(F32) * jax.nn.silu(z)).reshape(h.shape[0], cfg.ssm_groups, -1)
-    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
-    y = y.reshape(h.shape[0], cfg.d_ssm) * params[pre + "ssm_norm_g"].astype(F32)
+    y = gated_group_norm(y, z, params[pre + "ssm_norm_g"], cfg)
     return _dot(y.astype(h.dtype), params[pre + "w_out"]) \
         * cfg.ssm_out_multiplier
 

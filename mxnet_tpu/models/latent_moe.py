@@ -163,6 +163,15 @@ def swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def expert_ffn(x, w_gate, w_up, w_down):
+    """One expert (routed or shared) in the form its weights give it:
+    three matrices are a SwiGLU; two (`w_gate` None) a squared ReLU,
+    `relu(x W_up)^2 W_down` (`mlp_hidden_act` "relu2")."""
+    if w_gate is not None:
+        return swiglu(x, w_gate, w_up, w_down)
+    return jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+
+
 def yarn_mscale(factor, mscale):
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
@@ -306,19 +315,21 @@ def route(h, router, bias, cfg):
 def grouped_experts(h, local, w, we_gate, we_up, we_down):
     """The held experts over the pairs that chose them. h (N, D); local
     (N, k) the held expert of each (token, choice) pair, or `n_held`
-    for a pair that is not computed here; w (N, k) float32. Returns
-    `sum_k w E_local(h)` (N, D) float32 and the pairs per held expert
-    (n_held,) int32.
+    for a pair that is not computed here; w (N, k) float32; the experts'
+    matrices stacked by held expert, `we_gate` None where an expert is
+    two matrices and a squared ReLU (`expert_ffn`). Returns `sum_k w
+    E_local(h)` (N, D) float32 and the pairs per held expert (n_held,)
+    int32.
 
     Pairs are sorted by expert and each expert's run is cut into tiles
     of `tile` rows; a loop of as many passes as there are tiles (a
     number only the data knows) gathers a tile's tokens, runs that one
-    expert's SwiGLU on them and lays the rows into a buffer in which
+    expert on them and lays the rows into a buffer in which
     each expert starts on a tile boundary. An expert no pair chose is
     never read. The weighted sum over a token's choices then gathers
     from the buffer."""
     N, k = local.shape
-    n_held, D = we_gate.shape[0], h.shape[1]
+    n_held, D = we_up.shape[0], h.shape[1]
     tile = min(N, EXPERT_TILE)
     flat = local.reshape(N * k)
     hot = flat[:, None] == jnp.arange(n_held)[None, :]        # (N*k, n_held)
@@ -339,7 +350,8 @@ def grouped_experts(h, local, w, we_gate, we_up, we_down):
         within = (t - tile_start[e]) * tile + jnp.arange(tile)
         src = jnp.minimum(pair_start[e] + within, N * k - 1)
         x = h[token_of[src]]                                   # (tile, D)
-        y = swiglu(x, we_gate[e], we_up[e], we_down[e])
+        y = expert_ffn(x, None if we_gate is None else we_gate[e],
+                       we_up[e], we_down[e])
         return jax.lax.dynamic_update_slice(buf, y.astype(buf.dtype),
                                             (t * tile, 0))
 
@@ -357,16 +369,17 @@ def moe_ffn(params, pre, h, real, cfg):
     """(N, D) -> the expert layer's output and the rows of `real` tokens
     sent to each held expert. Rows that are not real (batch and prompt
     padding) are routed nowhere: they cost no expert pass and count
-    nothing."""
+    nothing. The experts' form is their weights': a layer with no
+    `we_gate` / `ws_gate` has experts of two matrices (`expert_ffn`)."""
     idx, w = route(h, params[pre + "router"], params[pre + "router_bias"],
                    cfg)
     lo, hi = cfg.experts_held
     here = (idx >= lo) & (idx < hi) & real[:, None]
     routed, counts = grouped_experts(
-        h, jnp.where(here, idx - lo, hi - lo), w, params[pre + "we_gate"],
+        h, jnp.where(here, idx - lo, hi - lo), w, params.get(pre + "we_gate"),
         params[pre + "we_up"], params[pre + "we_down"])
-    shared = swiglu(h, params[pre + "ws_gate"], params[pre + "ws_up"],
-                    params[pre + "ws_down"])
+    shared = expert_ffn(h, params.get(pre + "ws_gate"), params[pre + "ws_up"],
+                        params[pre + "ws_down"])
     return routed.astype(h.dtype) + shared, counts
 
 

@@ -33,6 +33,7 @@ from .engine import (Engine, Sequence, TransformerLM, BlockLM, ExportedLM,
 from .latent_lm import LatentMoELM
 from .afmoe_lm import AfmoeLM
 from .falcon_h1_lm import FalconH1LM
+from .nemotron_h_lm import NemotronHLM
 from .scheduler import (Scheduler, Request, QueueFull, RequestTimeout,
                         DeadlineExceeded, DeadlineUnmeetable,
                         BrownoutShed, make_resume)
@@ -53,7 +54,7 @@ __all__ = [
     "BlockPool", "PagedKVCache", "CacheOverflow",
     "PrefixCache", "prefix_cache_enabled",
     "Engine", "Sequence", "TransformerLM", "LatentMoELM", "AfmoeLM",
-    "FalconH1LM", "BlockLM", "ExportedLM",
+    "FalconH1LM", "NemotronHLM", "BlockLM", "ExportedLM",
     "PoolsLost", "pow2_bucket",
     "Scheduler", "Request", "QueueFull", "RequestTimeout",
     "DeadlineExceeded", "DeadlineUnmeetable", "BrownoutShed",

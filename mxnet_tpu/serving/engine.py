@@ -37,12 +37,13 @@ Two model families plug in behind one `Engine`:
   but it makes the whole serving stack (scheduler, batching, HTTP)
   available to every model the framework can express or export.
 
-Three further families bring their own step functions over the same views
+Four further families bring their own step functions over the same views
 and pools and plug in beside `TransformerLM`: `serving/latent_lm.py`
 (a latent pool), `serving/afmoe_lm.py` (window and full layers over a
-cache of two kinds, `kv_cache.CacheSpec.layer_kinds`) and
+cache of two kinds, `kv_cache.CacheSpec.layer_kinds`),
 `serving/falcon_h1_lm.py` (keys and values and a recurrent state in every
-layer).
+layer) and `serving/nemotron_h_lm.py` (a state alone, keys and values
+alone, or nothing, layer by layer).
 
 One decode step stays in flight (`Engine.decode_pass`): a pass launches
 step n + 1 from step n's tokens on the device (`carried_tokens`) and only

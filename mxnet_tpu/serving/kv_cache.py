@@ -54,7 +54,11 @@ sequence however long (a ring of one), its planes `ssm_state` and
 column of its table row and slot 0 is the null slot of padded rows.
 Prefill overwrites a slot wholly, so a slot given to a new sequence
 carries nothing of the last one. A layer may keep a state BESIDE its
-keys and values (`CacheSpec.layer_kinds`: "full+state").
+keys and values (`CacheSpec.layer_kinds`: "full+state"), or a state
+ALONE ("state"), or nothing ("none": a layer that mixes no positions, as
+an expert layer of models/nemotron_h.py); every kind's planes, pool and
+columns run over that kind's OWN layers (`CacheSpec.layers_of`), and a
+view is asked nothing by a layer that keeps nothing.
 """
 from __future__ import annotations
 
@@ -65,6 +69,7 @@ import math
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..base import MXNetError
 from ..ops import pallas_decode_walk as _walk
@@ -121,8 +126,9 @@ class CacheSpec:
     `state_shape` values in `state_dtype` (the recurrence's matrix a
     head) and `conv_shape` = (taps - 1, channels) values in `dtype` (the
     causal convolution's last inputs). A layer that keeps it BESIDE its
-    keys and values names both kinds, joined by "+" ("full+state"). Its
-    block is a SLOT: a ring of one."""
+    keys and values names both kinds, joined by "+" ("full+state"); one
+    that keeps it ALONE is "state"; one that keeps nothing at all (it
+    mixes no positions) is "none". Its block is a SLOT: a ring of one."""
     n_layers: int
     dtype: object
     n_heads: int = 0
@@ -149,10 +155,13 @@ class CacheSpec:
         return (self.n_q_heads or self.n_heads) // max(self.n_heads, 1)
 
     def values_per_token(self):
-        """Cached values one token occupies over all layers (a token
-        inside the window, where kinds differ)."""
-        return self.n_layers * (self.row_width
-                                or 2 * self.n_heads * self.head_dim)
+        """Cached values one token occupies over all the layers that
+        keep keys and values (a token inside the window, where kinds
+        differ); a state is no token's (`state_bytes`)."""
+        if self.latent_dim:
+            return self.n_layers * self.row_width
+        return sum(len(self.layers_of(k)) for k in ("full", "window")) \
+            * 2 * self.n_heads * self.head_dim
 
     def _kinds_of(self, layer):
         return (self.layer_kinds[layer] if self.layer_kinds
@@ -174,8 +183,10 @@ class CacheSpec:
                      if kind in self._kinds_of(i))
 
     def attn_kind(self, layer):
-        """The kind layer `layer`'s keys and values are kept as."""
-        return next(k for k in self._kinds_of(layer) if k != "state")
+        """The kind layer `layer`'s keys and values are kept as, or None
+        where it keeps none (a state alone, or nothing)."""
+        return next((k for k in self._kinds_of(layer)
+                     if k in ("full", "window")), None)
 
     def ring(self, kind, block_size):
         """Blocks a sequence holds at most for a layer of `kind`: 0 (no
@@ -202,11 +213,15 @@ class CacheSpec:
         prefill, the prefix cache, the int8 pool, speculation, tensor
         parallelism) cannot read this cache, or None."""
         if "state" in self.kinds:
-            return ("layers keep a recurrent state beside their keys and "
-                    "values: the paged step and the chunked prefill do not "
-                    "carry it from chunk to chunk, a shared prefix block "
-                    "has no snapshot of it, the int8 pool does not hold it "
-                    "and a speculative pass cannot roll it back")
+            beside = any(self.attn_kind(i) for i in self.layers_of("state"))
+            return ("layers keep a recurrent state %s: the paged step and "
+                    "the chunked prefill do not carry it from chunk to "
+                    "chunk, a shared prefix block has no snapshot of it, "
+                    "the int8 pool does not hold it and a speculative pass "
+                    "cannot roll it back"
+                    % ("beside their keys and values" if beside else
+                       "alone, beside layers that keep keys and values or "
+                       "nothing"))
         if self.layout != "kv":
             return ("the pool holds %s rows, not keys and values: the "
                     "paged kernel and the chunked prefill read the K and "
@@ -835,7 +850,7 @@ def _place(spec, layer, pools, tables):
     kind's columns of `tables` (B, W); the window; the ring). `spec`
     None is one kind over every layer: the two planes, the whole table,
     no window."""
-    if spec is None or spec.kinds == ("full",):
+    if spec is None or not spec.layer_kinds:
         return 0, layer, tables, 0, 0
     i = spec.kinds.index(spec.attn_kind(layer))
     rings = [spec.ring(k, pools[0].shape[3]) for k in spec.kinds]
@@ -919,6 +934,12 @@ class PromptView:
         convolution's last real inputs, whatever the bucket's padding."""
         i, j = _place_state(self.spec, layer)
         y, state, tail = mix_prompt(xbc, dt, w, cfg, self.length)
+        # the slot's new state in the plane's own order of axes: left to
+        # choose, the chip's compiler keeps the scan's (N innermost) and
+        # turns the WHOLE plane round to match it, twice a prefill
+        # (PERF.md, PR 42)
+        state = with_layout_constraint(
+            state, Layout(major_to_minor=tuple(range(state.ndim))))
         slot = self.table_row[-1]
         planes = self.pools[i:i + 2]
         self.pools = _put(self.pools, i, (

@@ -1043,6 +1043,11 @@ class ServingMetrics:
                 # is kept in between tokens
                 snap["engine"]["state_dtype"] = str(
                     np.dtype(spec.state_dtype))
+            if getattr(spec, "layer_kinds", None):
+                # which of the model's layers keep each kind (a layer
+                # may keep two, or none)
+                snap["engine"]["cache_layers"] = {
+                    kind: list(spec.layers_of(kind)) for kind in spec.kinds}
             if getattr(engine, "spec", False) or \
                     getattr(engine, "spec_passes", 0):
                 passes = engine.spec_passes

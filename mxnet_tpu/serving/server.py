@@ -27,12 +27,13 @@ import time
 from ..base import MXNetError
 from .. import telemetry
 from ..utils import chaos
-from ..models import afmoe, falcon_h1, latent_moe
+from ..models import afmoe, falcon_h1, latent_moe, nemotron_h
 from .engine import (Engine, TransformerLM, BlockLM, ExportedLM,
                      PoolsLost)
 from .latent_lm import LatentMoELM
 from .afmoe_lm import AfmoeLM
 from .falcon_h1_lm import FalconH1LM
+from .nemotron_h_lm import NemotronHLM
 from .scheduler import (Scheduler, Request, QueueFull, BrownoutShed,
                         DeadlineExceeded, DeadlineUnmeetable, make_resume)
 from .metrics import ServingMetrics
@@ -62,6 +63,8 @@ def _resolve_model(model, vocab=None, max_len=None, time_major=False):
             return AfmoeLM(params, cfg)
         if isinstance(cfg, falcon_h1.FalconH1Config):
             return FalconH1LM(params, cfg)
+        if isinstance(cfg, nemotron_h.NemotronHConfig):
+            return NemotronHLM(params, cfg)
         return TransformerLM(params, cfg)
     if hasattr(model, "collect_params"):          # Gluon Block
         if vocab is None or max_len is None:

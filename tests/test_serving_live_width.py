@@ -425,3 +425,78 @@ def _state_steps_compile(one_chip):
     assert not pool_copies(text, state, "f32")
     assert compiled.memory_analysis().alias_size_in_bytes >= planes
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_the_pattern_familys_steps_compile_for_the_chip_without_a_copy_of_a_plane(
+        one_chip, monkeypatch):
+    """At the `nemotron3_reason_closed` cell's widths, tables and pools
+    (four of its thirteen layers, every letter: a state alone twice,
+    experts and no cache, keys and values alone), for the v5e's compiler,
+    both kernels lowered by Mosaic as on the chip: the decode step of 128
+    rows holds an `ssm_step` a state layer over heads of 64 side by side
+    and ONE `decode_walk` of 16 query heads a cached head, the expert
+    layer's one tile loop, no copy of either kind's planes, all four planes
+    aliased; the prefill writes one slot and one sequence's blocks and
+    holds no operation of a plane's size either (left to choose, the
+    compiler keeps the scan's state with N innermost and turns the WHOLE
+    state plane round to match it, twice a prefill: PERF.md, PR 42)."""
+    from mxnet_tpu.ops import pallas_decode_walk, pallas_ssm_step
+    ssm_gate, walk_gate = (pallas_ssm_step.step_fallback_reason,
+                           pallas_decode_walk.walk_fallback_reason)
+    monkeypatch.setattr(pallas_ssm_step, "step_fallback_reason",
+                        lambda plane, backend=None: ssm_gate(plane, "tpu"))
+    monkeypatch.setattr(pallas_decode_walk, "walk_fallback_reason",
+                        lambda *a, **k: walk_gate(*a[:3], backend="tpu"))
+    monkeypatch.setattr(kv_cache, "default_interpret", lambda: False)
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        _pattern_steps_compile(one_chip)
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+
+
+def _pattern_steps_compile(one_chip):
+    from mxnet_tpu.models import nemotron_h
+    from mxnet_tpu.serving import nemotron_h_lm
+    cfg = nemotron_h.NemotronHConfig(
+        vocab=65536, d_model=2688, pattern="MEM*", n_heads=32, n_kv_heads=2,
+        head_dim=128, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+        ssm_groups=8, conv_taps=4, chunk=128, d_expert=1856, d_shared=3712,
+        n_experts=128, top_k=6, route_scale=2.5, experts_held=(0, 64),
+        max_len=3072, dtype=jnp.bfloat16)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    mats, gains, vectors = nemotron_h.param_shapes(cfg)
+    params = {n: sds(s, jnp.bfloat16) for n, s in {**mats, **gains}.items()}
+    params.update({n: sds(s, jnp.float32) for n, s in vectors.items()})
+    model = nemotron_h_lm.NemotronHLM(params, cfg)
+    model.bind(16)
+    kv, state, conv = ((1, 128 * 192 + 1, 2, 16, 128), (2, 129, 8, 128, 512),
+                       (2, 129, 3 * 6144))
+    spec = model.cache_spec()
+    assert spec.state_shape == state[2:] and spec.q_group == 16
+    assert spec.layer_kinds == ("state", "none", "state", "full")
+    pools = (sds(kv, jnp.bfloat16), sds(kv, jnp.bfloat16),
+             sds(state, jnp.float32), sds(conv, jnp.bfloat16))
+    planes = 2 * np.prod(kv) * 2 + np.prod(state) * 4 + np.prod(conv) * 2
+    compiled = model._decode_jit.lower(
+        params, *pools, sds((128,), i32), sds((128,), i32), sds((128,), i32),
+        sds((128, 193), i32)).compile()
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    assert len(re.findall(r" while\(", text)) == 1      # the experts' tiles
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 3
+    assert not pool_copies(text, kv, "bf16")
+    assert not pool_copies(text, state, "f32")
+    # nor the rows' states gathered
+    assert "f32[128,8,128,512]" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+    compiled = model._prefill_jit.lower(
+        params, *pools, sds((1024,), i32), sds((), i32),
+        sds((193,), i32)).compile()
+    text = compiled.as_text()
+    assert not pool_copies(text, kv, "bf16")
+    assert not pool_copies(text, state, "f32")
+    assert compiled.memory_analysis().alias_size_in_bytes >= planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
