@@ -3,8 +3,9 @@ sparse-expert layer.
 
 The third language-model family. Its expert layer is NOT its own: the
 sigmoid router with a selection bias (one group, so the top-k of all
-experts), the grouped held experts, the shared expert and the share of
-an expert-parallel deployment (`experts_held`) are `route`,
+experts), the grouped held experts (one grouped-product kernel a layer on
+the chip in bf16, a loop of passes elsewhere), the shared expert and the
+share of an expert-parallel deployment (`experts_held`) are `route`,
 `grouped_experts` and `moe_ffn` of `models/latent_moe.py`, as are
 `rms_norm`, `swiglu` and `apply_rope`. What is here is the block around
 it, as the published `afmoe` model (Arcee Trinity) computes it:

@@ -18,7 +18,10 @@ them at the published width, and the layer returns
 absent experts would add is left out, and no code stands in for the
 chips that hold them. Held experts are computed over the (token,
 expert) pairs that chose them, sorted by expert and cut into tiles
-(`grouped_experts`), not densely over every token.
+whose rows follow the shapes (`grouped_experts`: on the chip in bf16 one
+grouped-product kernel a layer, ops/pallas_grouped_experts.py, through
+which the touched experts' matrices stream once, back to back; a loop of
+one pass a tile elsewhere), not densely over every token.
 
 The layer is written ONCE (`block`), over a cache view that says how
 attention reads its keys; the layer knows a view by its `attend` and
@@ -44,10 +47,15 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops import pallas_grouped_experts as _experts
+from ..ops.pallas_attention import default_interpret
+
 #: query rows scored at once by the expanded form: (heads, Q_BLOCK, keys)
 #: float32 is the largest array attention makes
 Q_BLOCK = 256
-#: rows of one grouped-expert tile (a decode batch is one tile)
+#: rows of one tile of the grouped experts' LOOP, the fallback (a decode
+#: batch is one tile); the kernel's tile follows the rows an expert can get
+#: (ops/pallas_grouped_experts.py `tile_rows`)
 EXPERT_TILE = 128
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -312,6 +320,21 @@ def route(h, router, bias, cfg):
     return idx.astype(jnp.int32), w
 
 
+def experts_unfit(h, we_gate, we_up):
+    """Why the held experts are XLA's loop over tiles and not the kernel
+    (ops/pallas_grouped_experts.py), or None: asked of the rows and the
+    experts' matrices as they are, by `grouped_experts` while it traces
+    and by the serving model of the matrices it will hand that trace
+    (`LatentMoELM.moe_unfit`), so the two cannot disagree."""
+    mixed = {jnp.dtype(a.dtype).name for a in (h, we_gate, we_up)
+             if a is not None}
+    if len(mixed) > 1:
+        return "rows and experts differ in dtype: %s" % " and ".join(
+            sorted(mixed))
+    return _experts.experts_unfit(we_up.shape[1], we_up.shape[2],
+                                  2 if we_gate is None else 3, h.dtype)
+
+
 def grouped_experts(h, local, w, we_gate, we_up, we_down):
     """The held experts over the pairs that chose them. h (N, D); local
     (N, k) the held expert of each (token, choice) pair, or `n_held`
@@ -322,15 +345,22 @@ def grouped_experts(h, local, w, we_gate, we_up, we_down):
     int32.
 
     Pairs are sorted by expert and each expert's run is cut into tiles
-    of `tile` rows; a loop of as many passes as there are tiles (a
-    number only the data knows) gathers a tile's tokens, runs that one
-    expert on them and lays the rows into a buffer in which
-    each expert starts on a tile boundary. An expert no pair chose is
-    never read. The weighted sum over a token's choices then gathers
-    from the buffer."""
+    of `tile` rows, laid in a buffer in which each expert starts on a
+    tile boundary; as many tiles as the data has are each multiplied by
+    their one expert's matrices, and an expert no pair chose is never
+    read. Where the gate lets it (`experts_unfit`) the sorted rows are
+    gathered ONCE and ONE kernel a layer walks the tiles
+    (ops/pallas_grouped_experts.py: the next expert's matrices arrive
+    while this tile multiplies; the tile's rows from the shapes,
+    `tile_rows`); elsewhere a loop of one pass a tile gathers the tile's
+    tokens, runs `expert_ffn` on them and lays the rows into the buffer.
+    The weighted sum over a token's choices then gathers from the
+    buffer."""
     N, k = local.shape
     n_held, D = we_up.shape[0], h.shape[1]
-    tile = min(N, EXPERT_TILE)
+    kernel = experts_unfit(h, we_gate, we_up) is None
+    tile = _experts.tile_rows(N * k, n_held, h.dtype) if kernel \
+        else min(N, EXPERT_TILE)
     flat = local.reshape(N * k)
     hot = flat[:, None] == jnp.arange(n_held)[None, :]        # (N*k, n_held)
     counts = hot.sum(0).astype(jnp.int32)
@@ -343,23 +373,48 @@ def grouped_experts(h, local, w, we_gate, we_up, we_down):
     tiles_of = -(-counts // tile)
     tile_end = jnp.cumsum(tiles_of)
     tile_start, pair_start = tile_end - tiles_of, jnp.cumsum(counts) - counts
-    max_tiles = -(-N * k // tile) + n_held
+    # every pair's tile and a part-filled one an expert; with the kernel,
+    # rounded up so that the programs of a few rows each (every bucket of
+    # a decode step) share one lowered shape
+    max_tiles = -(-N * k // tile)
+    if kernel:
+        max_tiles = -(-max_tiles // n_held) * n_held
+    max_tiles += n_held
+
+    def expert_of(t):
+        """The expert of tile(s) t; past the last tile, the last expert."""
+        return jnp.minimum(jnp.sum(t[..., None] >= tile_end, axis=-1),
+                           n_held - 1).astype(jnp.int32)
+
+    def tile_tokens(t, e):
+        """The tokens of tile(s) t of expert(s) e, a row of `tile` each."""
+        within = (t - tile_start[e])[..., None] * tile + jnp.arange(tile)
+        return token_of[jnp.minimum(pair_start[e][..., None] + within,
+                                    N * k - 1)]
 
     def one_tile(t, buf):
-        e = jnp.sum(t >= tile_end).astype(jnp.int32)
-        within = (t - tile_start[e]) * tile + jnp.arange(tile)
-        src = jnp.minimum(pair_start[e] + within, N * k - 1)
-        x = h[token_of[src]]                                   # (tile, D)
-        y = expert_ffn(x, None if we_gate is None else we_gate[e],
+        e = expert_of(t)
+        y = expert_ffn(h[tile_tokens(t, e)],
+                       None if we_gate is None else we_gate[e],
                        we_up[e], we_down[e])
         return jax.lax.dynamic_update_slice(buf, y.astype(buf.dtype),
                                             (t * tile, 0))
 
-    buf = jax.lax.fori_loop(0, tile_end[-1], one_tile,
-                            jnp.zeros((max_tiles * tile, D), h.dtype))
+    if kernel:
+        t = jnp.arange(max_tiles, dtype=jnp.int32)
+        e = expert_of(t)
+        buf = _experts.grouped_experts(
+            h[tile_tokens(t, e).reshape(-1)], e, tile_end[-1], we_gate,
+            we_up, we_down, tile=tile, interpret=default_interpret())
+    else:
+        buf = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                                jnp.zeros((max_tiles * tile, D), h.dtype))
     here = flat < n_held
     row = jnp.where(here, tile_start[jnp.minimum(flat, n_held - 1)] * tile
                     + rank, 0).reshape(N, k)
+    # a pair that is not computed here reads row 0 at weight 0: the first
+    # tile is written whatever the pairs chose (the kernel leaves the tiles
+    # past the last real one as it found them)
     out = jnp.einsum("nk,nkd->nd", jnp.where(here.reshape(N, k), w, 0.0),
                      buf[row].astype(jnp.float32))
     return out, counts

@@ -19,7 +19,9 @@ RMSNorm, then the untied head; the embedding is not scaled:
        selection bias over ONE group, `top_k` winners, their scores
        normalised and scaled; every expert, and the shared one, is TWO
        matrices and a squared ReLU, `relu(h W_up)^2 W_down`; one chip of
-       an expert-parallel deployment holds `experts_held` of them
+       an expert-parallel deployment holds `experts_held` of them, and
+       multiplies the pairs that chose them by ONE grouped-product kernel
+       a layer on the chip in bf16 (ops/pallas_grouped_experts.py)
     *  attention: q (H heads of Dh), k, v (Hkv heads), no biases, causal
        softmax(q k^T / sqrt(Dh)), query head j on cached head j // (H /
        Hkv), o W_o. NO positions are applied: the published code turns

@@ -763,6 +763,17 @@ class Engine:
         # (ops/pallas_ssm_step.py): `kv_cache.state_step_unfit`, asked
         # the same way. None where the kernel does, and with no such kind
         self.state_step_fallback = None
+        # why the held experts of an expert layer are XLA's loop over
+        # tiles and not the grouped-product kernel
+        # (ops/pallas_grouped_experts.py): the family's `moe_unfit`,
+        # asked of the matrices it will hand every trace. None where the
+        # kernel runs, and with no expert layer. `moe`: which of the two
+        # both step programs hold (`kernel` or `xla`: on their spans and
+        # in the serving metrics' counts), None with no expert layer
+        unfit = getattr(model, "moe_unfit", None)
+        self.moe_fallback = unfit() if unfit else None
+        self.moe = None if unfit is None \
+            else "xla" if self.moe_fallback else "kernel"
         self.prefill_chunk = 0
         # quantized serving (ISSUE 20): env defaults
         # (MXNET_QUANTIZED_KV / MXNET_QUANTIZED_WEIGHTS), explicit
@@ -850,7 +861,8 @@ class Engine:
                 self.walk_fallback = walk_unfit(self.cache.k, cspec.layout)
                 self.prompt_attn_fallback = prompt_attn_unfit(
                     self.cache.k, cspec.q_group, layout=cspec.layout)
-                if None in (self.walk_fallback, self.prompt_attn_fallback):
+                if None in (self.walk_fallback, self.prompt_attn_fallback) \
+                        or self.moe == "kernel":
                     preload_pallas()
                 if "state" in cspec.kinds:
                     self.state_step_fallback = state_step_unfit(
@@ -1288,6 +1300,8 @@ class Engine:
                     self.cache.k, spec.q_group, s_pad, spec.layout) \
                     else "kernel"
                 step_span.attrs["attn"] = seq.attn
+                if self.moe:
+                    step_span.attrs["moe"] = self.moe
                 with self._count("prefill", s_pad):
                     logits, *stats = self._step(
                         self.model.prefill, jnp.asarray(toks),
@@ -1449,6 +1463,8 @@ class Engine:
         if self.model.uses_cache and not self.paged:
             step.walk = "xla" if self.walk_fallback else "kernel"
             step_span.attrs["walk"] = step.walk
+        if self.moe:
+            step_span.attrs["moe"] = self.moe
         # the cache path's host work in three child spans (to label the
         # device's idle gaps, PERF.md); ring and profiler only
         part = functools.partial(telemetry.span, category="serving",

@@ -131,6 +131,21 @@ class LatentMoELM:
         return self._decode_jit(self.params, kv, carry, tokens, positions,
                                 tables)
 
+    def moe_unfit(self):
+        """Why both step programs multiply the held experts' tiles with
+        XLA's loop and not with the kernel
+        (ops/pallas_grouped_experts.py), or None: `latent_moe.experts_unfit`
+        asked of the first expert layer's matrices as `grouped_experts`
+        asks it of every layer's while it traces (the layers are alike).
+        None too with no expert layer."""
+        up = next((n for n in sorted(self.params) if n.endswith("we_up")),
+                  None)
+        if up is None:
+            return None
+        return latent_moe.experts_unfit(
+            self.params["embed"], self.params.get(up[:-2] + "gate"),
+            self.params[up])
+
     def note_step(self, counts):
         """One step's rows per (expert layer, held expert), on the host:
         add them up and say what the step's span should carry."""

@@ -197,6 +197,15 @@ class ServingMetrics:
             help="whole-prompt prefills whose program scores the prompt "
                  "with the prompt-attention kernel, not with XLA block "
                  "by block")
+        self._steps_moe_kernel = c(
+            "serving_decode_steps_moe_kernel_total",
+            help="decode steps whose program multiplies the held experts' "
+                 "tiles with the grouped-product kernel, not with XLA's "
+                 "loop of passes")
+        self._prefills_moe_kernel = c(
+            "serving_prefills_moe_kernel_total",
+            help="whole-prompt prefills whose program multiplies the held "
+                 "experts' tiles with the grouped-product kernel")
         self._drains = {reason: c(
             "serving_decode_drains_%s_total" % reason,
             help="times the decode pipeline ran empty: %s" % why)
@@ -592,15 +601,19 @@ class ServingMetrics:
                        reason=type(req.error).__name__
                        if req.error is not None else "timeout")
 
-    def request_prefilled(self, req, prefill_s, t_token, attn=None):
+    def request_prefilled(self, req, prefill_s, t_token, attn=None,
+                          moe=None):
         """`t_token`: when the host held the prefill's result, the first
         token's stamp on the request's timeline (`req.t_last_token` from
         there on: the engine keeps it, `Engine.record_tokens`). `attn`:
-        what scored the prompt (`engine.Sequence.attn`)."""
+        what scored the prompt (`engine.Sequence.attn`); `moe`: what
+        walked its experts' tiles (`Engine.moe`)."""
         self._h_queue.observe(req.t_admit - req.t_submit)
         self._h_prefill.observe(prefill_s)
         if attn == "kernel":
             self._prefills_attn_kernel.inc()
+        if moe == "kernel":
+            self._prefills_moe_kernel.inc()
         with self._lock:
             self._prefill_tokens_obs += len(req.prompt)
         req.t_first_token = time.perf_counter()
@@ -691,15 +704,18 @@ class ServingMetrics:
             self._g_util.set(cache_util)
         self._counter.increment(tokens)
 
-    def decode_collected(self, ahead, drains, walk=None):
+    def decode_collected(self, ahead, drains, walk=None, moe=None):
         """One decode step collected: was it launched ahead, why (if
         so) it was launched with nothing in flight or collected in the
         pass that launched it (`engine.Step.drains`), and what walks the
-        cache in its program (`engine.Step.walk`)."""
+        cache (`engine.Step.walk`) and the held experts' tiles
+        (`Engine.moe`) in its program."""
         if ahead:
             self._steps_ahead.inc()
         if walk == "kernel":
             self._steps_walk_kernel.inc()
+        if moe == "kernel":
+            self._steps_moe_kernel.inc()
         for reason in drains:
             self._drains[reason].inc()
 
@@ -996,6 +1012,10 @@ class ServingMetrics:
                     self._steps_walk_kernel.value),
                 "prefills_attn_kernel": int(
                     self._prefills_attn_kernel.value),
+                "decode_steps_moe_kernel": int(
+                    self._steps_moe_kernel.value),
+                "prefills_moe_kernel": int(
+                    self._prefills_moe_kernel.value),
                 "decode_drains": {reason: int(c.value) for reason, c
                                   in self._drains.items() if c.value},
             },
@@ -1030,7 +1050,7 @@ class ServingMetrics:
             # why an option that was asked for is off, each by its name
             for name in ("paged_fallback", "walk_fallback",
                          "prompt_attn_fallback", "state_step_fallback",
-                         "prefix_cache_fallback",
+                         "moe_fallback", "prefix_cache_fallback",
                          "kv_quant_fallback", "weight_quant_fallback",
                          "tp_fallback", "spec_fallback"):
                 if getattr(engine, name, None):

@@ -819,7 +819,8 @@ class LMServer(_HTTPFrontend):
                             to_flight=False, batch=len(advanced)):
             since = max(step.t_launch, self._last_step_t or 0.0)
             self._last_step_t = step.t_read
-            met.decode_collected(step.ahead, step.drains, step.walk)
+            met.decode_collected(step.ahead, step.drains, step.walk,
+                                 eng.moe)
             if advanced:  # count only sequences that really stepped
                 # a speculative step emits a BURST per sequence, so
                 # tokens = post-len minus pre-len, not 1 per step
@@ -914,7 +915,7 @@ class LMServer(_HTTPFrontend):
         `t_first_token` was stamped."""
         since = req.t_last_token
         self.metrics.request_prefilled(req, prefill_s, seq.t_last_token,
-                                       seq.attn)
+                                       seq.attn, self.engine.moe)
         attrs = {"stamp_lag_us": int(
             (req.t_first_token - seq.t_last_token) * 1e6)}
         if since is None:

@@ -456,7 +456,39 @@ def test_the_pattern_familys_steps_compile_for_the_chip_without_a_copy_of_a_plan
         jax.config.update("jax_default_matmul_precision", was)
 
 
-def _pattern_steps_compile(one_chip):
+def test_the_pattern_familys_steps_hold_one_grouped_product_a_layer_and_no_copy_of_the_experts(
+        one_chip, monkeypatch):
+    """The same steps with the experts' gate open as on the chip (ISSUE
+    43): the decode step's one tile loop is gone and a fourth kernel stands
+    in its place, and neither step copies a layer's stacked experts. The
+    chip keeps `we_up` (64, 2688, 1856), whose last axis is not whole
+    lanes, with the MODEL width innermost: handed to the kernel as it is
+    named it would be copied whole, 660 MB a layer a step; it goes in by
+    its transpose, which is the array as it lies (PERF.md §6, PR 43)."""
+    from mxnet_tpu.models import latent_moe
+    from mxnet_tpu.ops import (pallas_decode_walk, pallas_grouped_experts,
+                               pallas_ssm_step)
+    ssm_gate, walk_gate, moe_gate = (
+        pallas_ssm_step.step_fallback_reason,
+        pallas_decode_walk.walk_fallback_reason,
+        pallas_grouped_experts.experts_unfit)
+    monkeypatch.setattr(pallas_ssm_step, "step_fallback_reason",
+                        lambda plane, backend=None: ssm_gate(plane, "tpu"))
+    monkeypatch.setattr(pallas_decode_walk, "walk_fallback_reason",
+                        lambda *a, **k: walk_gate(*a[:3], backend="tpu"))
+    monkeypatch.setattr(pallas_grouped_experts, "experts_unfit",
+                        lambda *a, **k: moe_gate(*a[:4], backend="tpu"))
+    monkeypatch.setattr(kv_cache, "default_interpret", lambda: False)
+    monkeypatch.setattr(latent_moe, "default_interpret", lambda: False)
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        _pattern_steps_compile(one_chip, experts_kernel=True)
+    finally:
+        jax.config.update("jax_default_matmul_precision", was)
+
+
+def _pattern_steps_compile(one_chip, experts_kernel=False):
     from mxnet_tpu.models import nemotron_h
     from mxnet_tpu.serving import nemotron_h_lm
     cfg = nemotron_h.NemotronHConfig(
@@ -484,10 +516,14 @@ def _pattern_steps_compile(one_chip):
         sds((128, 193), i32)).compile()
     text = compiled.as_text()
     assert " conditional(" not in text
-    assert len(re.findall(r" while\(", text)) == 1      # the experts' tiles
-    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 3
+    # the experts' tiles: one loop of passes, or one kernel
+    assert len(re.findall(r" while\(", text)) == (not experts_kernel)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) \
+        == 3 + experts_kernel
+    experts = (64, 2688, 1856), (64, 1856, 2688)
     assert not pool_copies(text, kv, "bf16")
     assert not pool_copies(text, state, "f32")
+    assert not any(pool_copies(text, shape, "bf16") for shape in experts)
     # nor the rows' states gathered
     assert "f32[128,8,128,512]" not in text
     assert compiled.memory_analysis().alias_size_in_bytes >= planes
@@ -498,5 +534,8 @@ def _pattern_steps_compile(one_chip):
     text = compiled.as_text()
     assert not pool_copies(text, kv, "bf16")
     assert not pool_copies(text, state, "f32")
+    assert not any(pool_copies(text, shape, "bf16") for shape in experts)
     assert compiled.memory_analysis().alias_size_in_bytes >= planes
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    # with the kernel, the sorted rows there and back: 2 x 88 MB
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.2e9 + 0.05e9 * experts_kernel
