@@ -52,7 +52,11 @@ admits and builds. The tokens are carried at one shape (max_batch), so a
 step launched with none in flight and a step launched ahead are one
 program per batch bucket. Engines whose next input exists on the host
 alone (speculative, cache-free, `keep_logits`: `Engine.sync_reason`)
-collect every step in the pass that launched it.
+collect every step in the pass that launched it. A whole-prompt prefill's
+first token takes the same road (`First`): it is chosen on the device
+(`first_token`), the next step takes it from a free place of its carry
+(`carry_first`), and the host reads it behind that step's launch
+(`Engine.collect_firsts`).
 
 jit stability: the engine never hands XLA a novel shape per request.
 Prompt lengths pad to power-of-two buckets (gather path) or one fixed
@@ -172,14 +176,16 @@ class Sequence:
     token's record (`Engine.record_tokens`) is made from. `attn`: what
     scored its prompt in a whole-prompt prefill, the `kernel`
     (ops/pallas_prompt_attention.py) or `xla`, block by block; None
-    elsewhere."""
+    elsewhere. `first`: its whole-prompt prefill while that is launched and
+    its token not read (`First`): until then it is one token longer on
+    the device than its `tokens` say."""
 
     __slots__ = ("tokens", "prompt_len", "blocks", "table_row",
                  "max_total", "eos_id", "done", "last_logits", "request",
                  "prefilled", "prefill_s", "cache_hit_tokens",
                  "shared_blocks", "token_logits", "t_begin",
                  "t_last_token", "prefills_seen", "prefill_tokens_seen",
-                 "attn")
+                 "attn", "first")
 
     def __init__(self, prompt, max_total, eos_id=None):
         self.tokens = list(prompt)
@@ -202,7 +208,7 @@ class Sequence:
                                       # paths — the spec parity oracle
         self.t_begin = self.t_last_token = time.perf_counter()
         self.prefills_seen = self.prefill_tokens_seen = 0
-        self.attn = None
+        self.attn = self.first = None
 
     @property
     def generated(self):
@@ -251,6 +257,42 @@ class Step:
         return any(d != "first_step" for d in self.drains)
 
 
+class First:
+    """One whole-prompt prefill of the gather path from its dispatch to
+    the read of its first token (`Engine.collect_firsts`), and its
+    `serving.prefill` span, which covers just that. In between, `token`
+    (chosen on the device, `first_token`), `stats` (what the family
+    returns beside the logits) and `logits` (`keep_logits` engines) are
+    on the device. `ahead`: the token is read behind the launch of the
+    decode step that takes it there (`carry_first`), and not before
+    anything else is done. `attrs` are the span's (`bucket`, `length`,
+    `attn`, `moe`, `ahead`, what `note_step` adds at the read): the span
+    is recorded when it closes, by hand, since it outlives the pass's
+    `serving.admit` and overlaps its `serving.decode`."""
+
+    __slots__ = ("seq", "ahead", "token", "stats", "logits", "trace",
+                 "t0_us", "attrs")
+
+    name = "serving.prefill"
+
+    def __init__(self, seq, ahead, **attrs):
+        self.seq = seq
+        self.ahead = ahead
+        self.token = self.logits = None
+        self.stats = []
+        self.trace = seq.request.trace if seq.request is not None \
+            else telemetry.current_trace()
+        self.attrs = dict(attrs, ahead=int(ahead))
+        self.t0_us = time.perf_counter_ns() // 1000
+
+    def close(self, at_us=None, **attrs):
+        if at_us is None:
+            at_us = time.perf_counter_ns() // 1000
+        telemetry.record_span(self.name, self.t0_us, at_us - self.t0_us,
+                              trace=self.trace, category="serving",
+                              **self.attrs, **attrs)
+
+
 # ---------------------------------------------------------------------------
 # paged-cache transformer adapter
 # ---------------------------------------------------------------------------
@@ -295,6 +337,31 @@ def carry_of(nxt, carry):
     carries them in (`carry`'s: max_batch): whatever bucket the next
     step has, it takes them under one signature."""
     return jnp.zeros_like(carry).at[:nxt.shape[0]].set(nxt)
+
+
+@jax.jit
+def first_token(logits):
+    """A prefill's greedy first token, chosen on the device from the
+    float32 logits (V,) its program returns: among equals the lowest
+    index, as `np.argmax` gives on the host."""
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+@jax.jit
+def carry_first(carry, token, place):
+    """`carry` (the step before's tokens, `carry_of`) with a prefill's
+    `first_token` at `place`, a row of it that no row of the next step
+    takes: that step then takes the sequence's first token there as it
+    takes every other row's (`carried_tokens`), under the one signature
+    a batch bucket has, the place being data."""
+    return carry.at[place].set(token)
+
+
+# `first_token` and `carry_first` are the two small programs of a first
+# token: a device trace names them `jit_first_token` and `jit_carry_first`,
+# neither a `jit_serving_prefill*` nor a `jit_serving_decode*`, whose device
+# time readers add up by name. Plain jits of one shape each, as the
+# copy-on-write and the scale reset are: the first admission compiles both.
 
 
 def decode(params, pools, carry, tokens, positions, tables, cfg, block_size,
@@ -969,7 +1036,10 @@ class Engine:
         # and the one before it (`record_tokens`)
         self.prefills_run = 0
         self.prefill_tokens_run = 0
-        self.pools_lost = 0     # times `_donating` had to remake the pools
+        # whole-prompt prefills launched whose first token the host has
+        # not read, oldest first (`collect_firsts`)
+        self._firsts = []
+        self.pools_lost = 0     # times the pools had to be made anew
         self._constructed = True
         _LIVE.add(self)
 
@@ -1109,14 +1179,19 @@ class Engine:
         except Exception as e:
             if not self.cache.lost():
                 raise
-            self.cache.remake()
-            if self.prefix_cache is not None:
-                self.prefix_cache.clear()
-            self.pools_lost += 1
-            raise PoolsLost(
-                "a step failed after it consumed the KV pools (%s: %s); "
-                "the pools were made anew, empty: replay every running "
-                "and prefilling sequence" % (type(e).__name__, e)) from e
+            raise self._pools_lost(e) from e
+
+    def _pools_lost(self, e):
+        """The pools made anew after `e`, a fault of a program that had
+        consumed them, and the `PoolsLost` to raise for it."""
+        self.cache.remake()
+        if self.prefix_cache is not None:
+            self.prefix_cache.clear()
+        self.pools_lost += 1
+        return PoolsLost(
+            "a step failed after it consumed the KV pools (%s: %s); "
+            "the pools were made anew, empty: replay every running "
+            "and prefilling sequence" % (type(e).__name__, e))
 
     def _read_back(self, step_span, result, stats):
         """A step's result on the host. What a family's step returns
@@ -1253,18 +1328,42 @@ class Engine:
             return self.prefill_chunk
         return pow2_bucket(prompt_len, lo=1, hi=self.max_len)
 
-    def prefill_step(self, seq):
+    @property
+    def first_sync_reason(self):
+        """Why a prefill's first token is read before anything else is
+        done, or None where the next decode step can take it on the
+        device (`First`): a whole-prompt prefill of the gather path,
+        under an engine whose steps stay in flight. `sync_reason` says
+        why they cannot; the paged path's prompt comes in chunks, and its
+        steps take a token from the host or from the step before."""
+        if self.sync_reason is None and self.paged:
+            return "paged"
+        return self.sync_reason
+
+    def prefill_step(self, seq, hold=False):
         """Advance one sequence's prefill. Paged path: run ONE
         fixed-shape chunk (appending its K/V to the pool); other paths:
         run the whole prompt. Returns True when the prompt is fully
-        prefilled and the first token has been sampled."""
+        prefilled: its first token has been chosen and appended or, with
+        `hold` where the engine can carry it (`first_sync_reason`), is
+        left in flight as `seq.first` for `decode_pass` to launch from
+        and `collect_firsts` to read."""
+        if self.model.uses_cache and not self.paged:
+            first = self._launch_first(
+                seq, hold and self.first_sync_reason is None)
+            if first.ahead:
+                seq.first = first
+                self._firsts.append(first)
+            else:
+                self._collect_first(first)
+            return True
         L = seq.prompt_len
         prompt = seq.tokens[:L]
         rid = seq.request.trace if seq.request is not None else None
         with telemetry.span("serving.prefill", trace=rid,
                             category="serving", prompt_len=L, length=L,
-                            chunk_start=seq.prefilled) as step_span:
-            if self.model.uses_cache and self.paged:
+                            chunk_start=seq.prefilled, ahead=0):
+            if self.model.uses_cache:
                 C = self.prefill_chunk
                 qs = seq.prefilled
                 toks = np.zeros((C,), np.int32)
@@ -1289,26 +1388,6 @@ class Engine:
                     # request is still decoding. The partial tail stays
                     # private until release — decode keeps writing it.
                     self.prefix_cache.insert(prompt, seq.block_ids, L)
-            elif self.model.uses_cache:
-                s_pad = pow2_bucket(L, lo=min(8, self.max_len),
-                                    hi=self.max_len)
-                toks = np.zeros((s_pad,), np.int32)
-                toks[:L] = prompt
-                step_span.attrs["bucket"] = s_pad
-                spec = self.cache.spec
-                seq.attn = "xla" if prompt_attn_unfit(
-                    self.cache.k, spec.q_group, s_pad, spec.layout) \
-                    else "kernel"
-                step_span.attrs["attn"] = seq.attn
-                if self.moe:
-                    step_span.attrs["moe"] = self.moe
-                with self._count("prefill", s_pad):
-                    logits, *stats = self._step(
-                        self.model.prefill, jnp.asarray(toks),
-                        jnp.int32(L), jnp.asarray(seq.table_row))
-                self._ran_prefill(s_pad)
-                seq.prefilled = L
-                logits = self._read_back(step_span, logits, stats)
             else:
                 s_pad = pow2_bucket(L, lo=1, hi=self.max_len)
                 toks = np.zeros((1, s_pad), np.int32)
@@ -1322,30 +1401,110 @@ class Engine:
             # the host holds the prefill's result: a client could read
             # the first token from here on
             seq.t_last_token = time.perf_counter()
+        self._keep_logits(seq, logits)
+        self._append(seq, int(np.argmax(logits)))
+        return True
+
+    def _keep_logits(self, seq, logits):
         if self.keep_logits:
             seq.last_logits = logits
             if seq.token_logits is not None:
                 seq.token_logits.append(logits)
-        self._append(seq, int(np.argmax(logits)))
-        return True
+
+    def _launch_first(self, seq, ahead):
+        """Dispatch the whole-prompt prefill of the gather path and the
+        choice of its first token; nothing of either is read. What
+        raises here (tracing, shapes, a chaos seam; `PoolsLost` from a
+        program that had the pools) is this request's fault alone."""
+        L = seq.prompt_len
+        s_pad = pow2_bucket(L, lo=min(8, self.max_len), hi=self.max_len)
+        toks = np.zeros((s_pad,), np.int32)
+        toks[:L] = seq.tokens[:L]
+        spec = self.cache.spec
+        seq.attn = "xla" if prompt_attn_unfit(
+            self.cache.k, spec.q_group, s_pad, spec.layout) else "kernel"
+        first = First(seq, ahead, prompt_len=L, length=L,
+                      chunk_start=seq.prefilled, bucket=s_pad, attn=seq.attn,
+                      **({"moe": self.moe} if self.moe else {}))
+        try:
+            with self._count("prefill", s_pad):
+                logits, *first.stats = self._step(
+                    self.model.prefill, jnp.asarray(toks), jnp.int32(L),
+                    jnp.asarray(seq.table_row))
+            first.token = first_token(logits)
+        except Exception as e:
+            first.close(error=type(e).__name__)
+            raise
+        seq.prefilled = L
+        if self.keep_logits:
+            first.logits = logits
+        # the copy back starts as soon as the prefill has run
+        for result in (first.token, *first.stats):
+            result.copy_to_host_async()
+        return first
+
+    def _collect_first(self, first):
+        """The blocking half: the first token on the host (the read
+        returns when the prefill has run, whatever was launched behind
+        it), the family's `note_step`, the span closed, the token
+        appended. The prefill counts as run from here on: the tokens of
+        a step collected before this read did not wait for it, those of
+        the steps behind it did (`record_tokens`). A fault here is one of
+        a program that had consumed the pools, as has every program
+        queued behind it since: `PoolsLost`."""
+        seq = first.seq
+        seq.first = None
+        try:
+            token = int(self._read_back(first, first.token, first.stats))
+            if first.logits is not None:
+                self._keep_logits(seq, np.asarray(first.logits))
+        except Exception as e:
+            first.close(error=type(e).__name__)
+            raise self._pools_lost(e) from e
+        self._ran_prefill(first.attrs["bucket"])
+        # the host holds the prefill's result: a client could read the
+        # first token from here on
+        seq.t_last_token = time.perf_counter()
+        first.close(int(seq.t_last_token * 1e6))
+        self._append(seq, token)
+
+    def collect_firsts(self):
+        """Read the first tokens in flight, oldest first, which is the
+        device's order: yields each sequence as its token is appended.
+        The serving loop does so once the pass's step is launched and the
+        step before it collected and accounted for."""
+        while self._firsts:
+            first = self._firsts.pop(0)
+            self._collect_first(first)
+            yield first.seq
+
+    def drop_firsts(self):
+        """Forget the first tokens in flight, unread (a fault, a replay,
+        the loop's end): none was appended, so every sequence's tokens
+        are still exactly those the host has read, and a replay from
+        them chooses the dropped ones again."""
+        for first in self._firsts:
+            first.seq.first = None
+        self._firsts = []
 
     def _ran_prefill(self, rows):
         self.prefills_run += 1
         self.prefill_tokens_run += rows
 
-    def start(self, prompt, max_new, eos_id=None):
+    def start(self, prompt, max_new, eos_id=None, hold=False):
         """Admit one request and run its whole prefill: allocate blocks,
         prefill (chunk-by-chunk on the paged path), sample the first
         token. Returns the live Sequence (caller keeps it in the running
         set), or None if blocks ran out (transient). The serving loop
-        uses begin/prefill_step instead so chunks interleave with decode
-        steps; `start` is the synchronous convenience for direct Engine
-        users (bench.py, tests)."""
+        uses begin/prefill_step on the paged path, so chunks interleave
+        with decode steps, and `hold` on the other (`prefill_step`);
+        without it `start` is the synchronous convenience for direct
+        Engine users (bench.py, tests)."""
         seq = self.begin(prompt, max_new, eos_id=eos_id)
         if seq is None:
             return None
         try:
-            while not self.prefill_step(seq):
+            while not self.prefill_step(seq, hold):
                 pass
         except Exception:
             self.release(seq, reusable=False)   # nobody else holds it
@@ -1408,7 +1567,17 @@ class Engine:
         rows ends with it by length (`Step.drains` says which). A fault
         leaves both steps uncollected: the caller drops them, and the
         tokens appended so far are exactly those of collected steps,
-        which is all a replay needs."""
+        which is all a replay needs.
+
+        A sequence whose first token is in flight (`First`) is launched
+        like a row of `after`: its token is set in a free place of the
+        step's carry, on the device. The caller reads it once this pass
+        has returned (`collect_firsts`): behind the launch, and after
+        `after`'s tokens, which did not wait for that prefill. Without
+        `hold` nothing is left in flight, so it is read first."""
+        if not hold:
+            for _ in self.collect_firsts():
+                pass
         rows = self._rows(seqs, after)
         if len(rows) > self.max_batch:
             raise MXNetError("decode batch %d exceeds max_batch %d"
@@ -1438,14 +1607,15 @@ class Engine:
         """The rows of the next step, [(sequence, its length when the
         step runs, its row in `after` or None)], given `after`, the step
         launched and not yet collected: a row of it will be one token
-        longer by then, and one that `after` brings to its `max_total`
-        ends there, which is known before `after` has run, so it is left
-        out. A row that may end with `after` by its `eos_id` is launched
-        all the same (`_append_step`)."""
+        longer by then, as will a sequence whose first token is in
+        flight, and one that this brings to its `max_total` ends there,
+        which is known before either has run, so it is left out. A row
+        that may end with `after`, or with its first token, by its
+        `eos_id` is launched all the same (`_append_step`)."""
         row_of = {} if after is None else {
             id(s): r for r, s in enumerate(after.seqs)}
-        rows = [(s, len(s.tokens) + (id(s) in row_of), row_of.get(id(s)))
-                for s in seqs if not s.done]
+        rows = [(s, len(s.tokens) + (id(s) in row_of or s.first is not None),
+                 row_of.get(id(s))) for s in seqs if not s.done]
         return [row for row in rows if row[1] < row[0].max_total]
 
     def _launch(self, rows, after, step_span):
@@ -1454,9 +1624,12 @@ class Engine:
         A row that continues from `after` takes its token from
         `after`'s result there (`carried_tokens`), at the position and
         table width the host knows it will have; a row that joined since
-        (a sequence just prefilled) brings its token from the host. So a
-        step costs three uploads, as a step that reads first did, and
-        one program per batch bucket serves both."""
+        (a sequence just prefilled) takes its first token from a place of
+        the carry that no such row refers to, where `carry_first` sets it
+        on the device (there is one for every row: the step has at most
+        max_batch), or brings it from the host where it was read there.
+        So a step costs three uploads, as a step that reads first did,
+        and one program per batch bucket serves all three."""
         bb = pow2_bucket(len(rows), lo=1, hi=self.max_batch)
         step = Step([row[0] for row in rows], ahead=after is not None)
         step_span.attrs["batch"] = len(rows)
@@ -1494,11 +1667,18 @@ class Engine:
                 w = pow2_bucket(max(self.cache.blocks_for(n)
                                     for _, n, _ in rows),
                                 lo=1, hi=self._nblk)
+            carry = self._no_carry if after is None else after.nxt
+            taken = {r for _, _, r in rows if r is not None}
+            free = (r for r in range(self.max_batch) if r not in taken)
             with part("serving.decode.build"):
                 toks = np.zeros((bb,), np.int32)
                 pos = np.zeros((bb,), np.int32)
                 tabs = np.zeros((bb, w), np.int32)
                 for i, (s, n, r) in enumerate(rows):
+                    if s.first is not None:
+                        r = next(free)
+                        carry = carry_first(carry, s.first.token,
+                                            jnp.int32(r))
                     toks[i] = s.tokens[-1] if r is None else -1 - r
                     pos[i] = n - 1
                     tabs[i] = s.table_row[:w]
@@ -1523,9 +1703,7 @@ class Engine:
             with part("serving.decode.dispatch", ahead=int(step.ahead)), \
                     self._count("decode", sig):
                 step.logits, step.nxt, *step.stats = self._step(
-                    self.model.decode,
-                    self._no_carry if after is None else after.nxt,
-                    toks, pos, tabs)
+                    self.model.decode, carry, toks, pos, tabs)
                 if not self.keep_logits:
                     step.logits = None
             # the copy back starts as soon as the step has run, not when
@@ -1537,7 +1715,9 @@ class Engine:
             return step
         if after is None:
             step.drains.append("first_step")
-        if all(n + 1 >= s.max_total for s, n, _ in rows):
+        # a step that carries a first token is collected behind the read
+        # of that token, so not in this pass, whatever ends with it
+        if all(n + 1 >= s.max_total and s.first is None for s, n, _ in rows):
             step.drains.append("last_step")
         return step
 
@@ -1558,9 +1738,10 @@ class Engine:
     def _append_step(self, step, parent):
         """The other half: every row's token appended to its sequence. A
         row whose sequence has ended since the step was launched is
-        dropped: it met its `eos_id` in the step before, which the host
-        learned only after this one was launched (or a failover detached
-        it meanwhile). Its token is not the sequence's: never appended,
+        dropped: it met its `eos_id` in the step before, or with the first
+        token it was launched from (`First`), which the host learned only
+        after this one was launched (or a failover detached it
+        meanwhile). Its token is not the sequence's: never appended,
         counted or recorded. The one cache write the row made lies past
         the sequence's end in blocks that were still its own when the
         step was queued (`blocks_needed` reserves up to `max_total`, and

@@ -53,6 +53,23 @@ DECODE_DRAINS = {
     "close": "a step in flight dropped as the loop closed",
 }
 
+#: why a prefill's first token was read inside the pass that ran the
+#: prefill, before anything else was done with the sequence, and not behind
+#: the launch of the decode step that takes it on the device
+#: (`LMServer._first_sync_reason`): the snapshot's `prefill_syncs`, which
+#: with `prefills_ahead` add up to the prefills that gave a first token
+PREFILL_SYNCS = {
+    "spec": DECODE_DRAINS["spec"],
+    "no_cache": DECODE_DRAINS["no_cache"],
+    "keep_logits": DECODE_DRAINS["keep_logits"],
+    "paged": "the paged path's prompt comes in chunks, and its steps take "
+             "a token from the host or from the step before",
+    "hand_off": "the sequence is handed to another replica with its first "
+                "token",
+    "more_admitted": "another prompt was admitted behind it in the same "
+                     "pass, and one first token is in flight at a time",
+}
+
 #: per-tenant instrument-name templates (ISSUE 13; docs/OBSERVABILITY.md
 #: names these with a `<tenant>` placeholder). Token counters share the
 #: terminal-classification ledger documented in telemetry/slo.py:
@@ -210,6 +227,15 @@ class ServingMetrics:
             "serving_decode_drains_%s_total" % reason,
             help="times the decode pipeline ran empty: %s" % why)
             for reason, why in DECODE_DRAINS.items()}
+        self._prefills_ahead = c(
+            "serving_prefills_ahead_total",
+            help="prefills whose first token the next decode step took on "
+                 "the device, read on the host behind that step's launch")
+        self._prefill_syncs = {reason: c(
+            "serving_prefill_syncs_%s_total" % reason,
+            help="prefills whose first token was read before anything "
+                 "else was done: %s" % why)
+            for reason, why in PREFILL_SYNCS.items()}
         self._chunks = c("serving_prefill_chunks_total",
                          help="chunked-prefill kernel calls")
         # prefix-cache observables (ISSUE 10): counters synced from the
@@ -602,14 +628,18 @@ class ServingMetrics:
                        if req.error is not None else "timeout")
 
     def request_prefilled(self, req, prefill_s, t_token, attn=None,
-                          moe=None):
+                          moe=None, sync=None):
         """`t_token`: when the host held the prefill's result, the first
         token's stamp on the request's timeline (`req.t_last_token` from
         there on: the engine keeps it, `Engine.record_tokens`). `attn`:
         what scored the prompt (`engine.Sequence.attn`); `moe`: what
-        walked its experts' tiles (`Engine.moe`)."""
+        walked its experts' tiles (`Engine.moe`); `sync`: why the token
+        was read before anything else was done (`PREFILL_SYNCS`), None
+        where the next decode step took it on the device."""
         self._h_queue.observe(req.t_admit - req.t_submit)
         self._h_prefill.observe(prefill_s)
+        (self._prefills_ahead if sync is None
+         else self._prefill_syncs[sync]).inc()
         if attn == "kernel":
             self._prefills_attn_kernel.inc()
         if moe == "kernel":
@@ -1018,6 +1048,10 @@ class ServingMetrics:
                     self._prefills_moe_kernel.value),
                 "decode_drains": {reason: int(c.value) for reason, c
                                   in self._drains.items() if c.value},
+                "prefills_ahead": int(self._prefills_ahead.value),
+                "prefill_syncs": {reason: int(c.value) for reason, c
+                                  in self._prefill_syncs.items()
+                                  if c.value},
             },
             "batch": {
                 "mean_active": (self._h_batch.sum / steps

@@ -714,7 +714,9 @@ class LMServer(_HTTPFrontend):
         decode step, collect the one the last pass launched, that step's
         bookkeeping (`Engine.decode_pass`: one step stays in flight from
         pass to pass wherever the engine can launch from a result it has
-        not read); or, with nothing to do, a wait (and then no span is
+        not read), then the first tokens of the prefills this pass
+        launched (`Engine.collect_firsts`: the launched step took them on
+        the device); or, with nothing to do, a wait (and then no span is
         recorded)."""
         eng, sched, met = self.engine, self.scheduler, self.metrics
         # chaos seams (no-ops unless armed; utils/chaos.py): a kill
@@ -769,32 +771,27 @@ class LMServer(_HTTPFrontend):
                 collected, self._flight = eng.decode_pass(
                     sched.running, after=self._flight)
             except Exception as e:
-                # a decode fault poisons the STEPS in flight, not the
-                # history (unless a step had consumed the KV pools:
-                # PoolsLost, below): every token already appended came
-                # from a step that was collected, and what was launched
-                # and not collected is dropped with the fault (the
-                # greedy replay chooses those tokens again). Re-home the
-                # batch onto this server's own
-                # queue as failover replays (prompt + generated so
-                # far re-prefills, decode continues token-identically)
-                # instead of failing user-visible work; a request
-                # that keeps hitting faults exhausts max_failovers
-                # and surfaces the error
-                met.engine_failure()
-                err = MXNetError("engine decode failed: %s: %s"
-                                 % (type(e).__name__, e))
-                if isinstance(e, PoolsLost):
-                    self._replay_all(err)     # the prefilling ones too
-                    return
-                self._drop_flight("fault")
-                self._resume_locally(sched.running, err)
-                sched.running = []
+                self._decode_fault(e)
                 return
             for step in collected:
                 self._account(step, evict=step is collected[-1])
-            if not collected:       # nothing read, but a sequence may have
-                self._evict()       # ended in its prefill or been detached
+            # the first tokens of this pass's prefills, behind the step
+            # that was launched from them on the device: each read blocks
+            # until its prefill has run, with that step queued behind it
+            firsts = []
+            try:
+                for seq in eng.collect_firsts():
+                    firsts.append(seq)
+                    if seq.request is not None:
+                        self._first_token(seq, seq.request,
+                                          seq.t_last_token - seq.t_begin)
+            except Exception as e:
+                self._decode_fault(e)
+                return
+            # nothing read, but a sequence may have ended in its prefill
+            # or been detached; or one ended with its first token
+            if not collected or any(seq.done for seq in firsts):
+                self._evict()
         elif sched.prefilling:
             pass      # chunk work ran this iteration; no decode to
                       # pace against, so loop straight into the next
@@ -805,6 +802,28 @@ class LMServer(_HTTPFrontend):
             self._work.wait(self._idle_wait * 20)
         else:
             time.sleep(self._idle_wait)
+
+    def _decode_fault(self, e):
+        """A decode fault poisons the STEPS in flight, not the history
+        (unless a program had consumed the KV pools: `PoolsLost`, which
+        a fault at the read of a first token in flight always is): every
+        token already appended came from a step, or a prefill, that was
+        collected, and what was launched and not collected is dropped
+        with the fault (the greedy replay chooses those tokens again).
+        Re-home the batch onto this server's own queue as failover
+        replays (prompt + generated so far re-prefills, decode continues
+        token-identically) instead of failing user-visible work; a
+        request that keeps hitting faults exhausts max_failovers and
+        surfaces the error."""
+        self.metrics.engine_failure()
+        err = MXNetError("engine decode failed: %s: %s"
+                         % (type(e).__name__, e))
+        if isinstance(e, PoolsLost):
+            self._replay_all(err)     # the prefilling ones too
+            return
+        self._drop_flight("fault")
+        self._resume_locally(self.scheduler.running, err)
+        self.scheduler.running = []
 
     def _account(self, step, evict):
         """A collected decode step's bookkeeping: the metrics, each
@@ -847,20 +866,45 @@ class LMServer(_HTTPFrontend):
 
     def _drop_flight(self, reason):
         """Forget the step in flight, uncollected (a fault, a replay, the
-        loop's end): its tokens were never appended, so every sequence's
-        tokens are still exactly those of collected steps, and a replay
-        from them chooses the dropped ones again. What the step wrote
-        lies in its own sequences' blocks; whoever gets them next is
-        queued behind it on the device."""
+        loop's end), and with it the first tokens in flight that it was
+        launched from: none of their tokens was appended, so every
+        sequence's tokens are still exactly those of collected steps and
+        prefills, and a replay from them chooses the dropped ones again.
+        What the step wrote lies in its own sequences' blocks; whoever
+        gets them next is queued behind it on the device."""
+        self.engine.drop_firsts()
         if self._flight is not None:
             self._flight = None
             self.metrics.decode_drained(reason)
 
+    def _first_sync_reason(self, more=False):
+        """Why a prefill's first token is read inside the pass, before
+        anything else is done with the sequence, or None where it stays
+        in flight (`Engine.first_sync_reason`): the engine's reason; that
+        the sequence is handed to another replica with that token
+        (`on_prefill_done`); or that `more` prompts are admitted behind
+        it in this pass. One first token is in flight at a time, so that
+        a `serving.prefill` span holds its own program on the device and
+        no other prompt's (what reads a prefill's device time finds it by
+        that span); the pass's last prompt is the one carried. The metrics
+        count prefills by it."""
+        if self.on_prefill_done is not None:
+            return "hand_off"
+        return self.engine.first_sync_reason or ("more_admitted" if more
+                                                 else None)
+
     def _admit_dense(self, admitted):
-        """PR 1 admission: each admitted request runs its WHOLE prefill
-        before the decode step — the gather path's one-shot prefill."""
+        """PR 1 admission: each admitted request's WHOLE prefill is
+        launched before the decode step (the gather path's one-shot
+        prefill), and its first token read there and then only where
+        something needs it at once (`_first_sync_reason`: every prompt of
+        the pass but the last, too): otherwise the sequence joins the
+        running set with that token in flight, the pass's decode step
+        takes it on the device, and `_iterate` reads it behind that
+        step's launch."""
         eng, sched, met = self.engine, self.scheduler, self.metrics
         for i, req in enumerate(admitted):
+            sync = self._first_sync_reason(more=i + 1 < len(admitted))
             t0 = time.perf_counter()
             try:
                 # the engine's prefill span inherits the request's trace
@@ -869,7 +913,7 @@ class LMServer(_HTTPFrontend):
                 prev = telemetry.set_trace(req.trace)
                 try:
                     seq = eng.start(req.prompt, req.max_new_tokens,
-                                    eos_id=req.eos_id)
+                                    eos_id=req.eos_id, hold=sync is None)
                 finally:
                     telemetry.set_trace(prev)
             except Exception as e:  # engine fault: fail THIS request,
@@ -893,29 +937,33 @@ class LMServer(_HTTPFrontend):
             req.state = "running"
             _queue_span(req)
             met.request_admitted(req)
-            self._first_token(seq, req, time.perf_counter() - t0)
-            # disaggregated serving: same hand-off seam as the chunked
-            # path — the dense one-shot prefill just completed and the
-            # first token is appended
-            if not seq.done and self.on_prefill_done is not None \
-                    and not req._event.is_set() \
-                    and self._migrate_out(seq, req):
-                continue
+            if seq.first is None:
+                self._first_token(seq, req, time.perf_counter() - t0, sync)
+                # disaggregated serving: same hand-off seam as the
+                # chunked path — the dense one-shot prefill just
+                # completed and the first token is appended
+                if not seq.done and self.on_prefill_done is not None \
+                        and not req._event.is_set() \
+                        and self._migrate_out(seq, req):
+                    continue
             sched.running.append(seq)
 
-    def _first_token(self, seq, req, prefill_s):
-        """A request's prefill has ended with its first token here: the
-        metrics' stamps, and the token's record on the request's
-        timeline. It spans the prefill from where the engine took the
-        sequence in (`first`; the prefills it counts are its own and
-        whatever ran between its chunks) or, for a failover's replay,
-        from the victim's last token, which is the gap the client saw
-        and `serving_itl_seconds` observed; it ends where the host held
-        the prefill's result, and `stamp_lag_us` says how much later
-        `t_first_token` was stamped."""
+    def _first_token(self, seq, req, prefill_s, sync=None):
+        """A request's prefill has ended with its first token here (read
+        behind the launch of the step that took it on the device, or
+        inside the pass for the reason `sync`, which the metrics count):
+        the metrics' stamps, and the token's record on the request's
+        timeline. It spans the
+        prefill from where the engine took the sequence in (`first`; the
+        prefills it counts are its own and whatever ran between its
+        chunks) or, for a failover's replay, from the victim's last
+        token, which is the gap the client saw and `serving_itl_seconds`
+        observed; it ends where the host held the prefill's result, and
+        `stamp_lag_us` says how much later `t_first_token` was stamped."""
         since = req.t_last_token
-        self.metrics.request_prefilled(req, prefill_s, seq.t_last_token,
-                                       seq.attn, self.engine.moe)
+        self.metrics.request_prefilled(
+            req, prefill_s, seq.t_last_token, seq.attn, self.engine.moe,
+            sync)
         attrs = {"stamp_lag_us": int(
             (req.t_first_token - seq.t_last_token) * 1e6)}
         if since is None:
@@ -1019,7 +1067,8 @@ class LMServer(_HTTPFrontend):
                 sched.prefilling.remove(seq)
                 req = seq.request
                 if req is not None:
-                    self._first_token(seq, req, seq.prefill_s)
+                    self._first_token(seq, req, seq.prefill_s,
+                                      self._first_sync_reason())
                 # disaggregated serving: a prefill-role replica hands
                 # the finished prompt to a decode replica here — after
                 # the first token (TTFT observed on THIS replica, which

@@ -12,14 +12,24 @@ end is appended, counted or recorded; (c) an engine whose next input
 exists on the host alone never launches ahead and says why; (d) the
 window costs no program: one decode compilation a signature after the
 benchmark's warm-up, and none, by the watchdog and by jax's own compile
-log, in a churned window after it.
+log, in a churned window after it; (e) a whole-prompt prefill's first token
+takes the same road (ISSUE 46): chosen on the device, taken there by the
+step launched behind the prefill, read on the host after that launch; one
+that ends its request (its `eos_id`, `max_new_tokens` 1) ends it with that
+one token, and the row launched from the former is dropped; where something
+needs the token at once it is read inside the pass, and counted by why.
 """
 import logging
+import re
+import threading
 import time
 
 import pytest
 
+import numpy as np
+
 import jax
+import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu import serving, telemetry
@@ -31,7 +41,10 @@ from mxnet_tpu.telemetry import introspect
 from chipbench.families import afmoe_lm as kinds_family
 from chipbench.families import falcon_h1_lm as state_family
 from chipbench.families import latent_moe_lm as latent_family
+from chipbench.families import nemotron_h_lm as pattern_family
 from chipbench.generators import serving as bench_serving
+
+from test_nemotron_h import TOY as PATTERN
 
 BS, MAX_BATCH = 8, 4
 
@@ -92,7 +105,13 @@ CONFIGS = {
     "latent": ("latent", dict()),
     "kinds": ("kinds", dict()),
     "state": ("state", dict()),
+    # a layer is ONE mixer by a pattern's letter: a state alone, keys and
+    # values alone, or experts and no cache
+    "pattern": ("pattern", dict()),
 }
+#: those that prefill a whole prompt in one program, whose first token the
+#: next decode step takes on the device
+WHOLE_PROMPT = sorted(c for c, (_, o) in CONFIGS.items() if not o.get("paged"))
 
 
 @pytest.fixture(scope="module")
@@ -102,7 +121,10 @@ def models():
     weights = latent_family.make_weights(LATENT, 11)
     kinds = kinds_family.make_weights(KINDS, 11)
     state = state_family.make_weights(STATE, 11)
-    return {"dense": (init_transformer_params(jax.random.PRNGKey(0), cfg),
+    pattern = pattern_family.make_weights(PATTERN, 11)
+    return {"pattern": (pattern_family.program_params(pattern),
+                        pattern_family.program_config(PATTERN, 64)),
+            "dense": (init_transformer_params(jax.random.PRNGKey(0), cfg),
                       cfg),
             "latent": (latent_family.program_params(weights),
                        latent_family.program_config(LATENT, 64)),
@@ -132,6 +154,14 @@ def oracle(model, options, requests):
     finally:
         eng.close()
     return out
+
+
+def carried(snap):
+    """Whole-prompt prefills a server launched to carry their first token:
+    those it did, and those with another prompt admitted behind them in the
+    same pass (one first token is in flight at a time)."""
+    return snap["prefills_ahead"] + snap["prefill_syncs"].get(
+        "more_admitted", 0)
 
 
 def serve_churned(srv, first, later):
@@ -180,6 +210,16 @@ def test_served_tokens_are_the_synchronous_steps_tokens_under_churn(
     assert snap["tokens_generated"] == sum(len(g) - 1 for g in want)
     assert snap["decode_steps_ahead"] > 0.8 * snap["decode_steps"]
     assert set(snap["decode_drains"]) <= {"first_step", "last_step"}
+    # every whole-prompt prefill's first token was carried, the one with
+    # nothing to launch too, but for the prompts with another admitted
+    # behind them in one pass; a prompt that came in chunks says why not
+    n = len(FIRST + LATER)
+    if options.get("paged"):
+        assert (snap["prefills_ahead"], snap["prefill_syncs"]) == (
+            0, {"paged": n})
+    else:
+        assert set(snap["prefill_syncs"]) <= {"more_admitted"}
+        assert carried(snap) == n and snap["prefills_ahead"] >= 4
     # a full batch, and smaller ones down through the buckets as it drained
     batches = {s["attrs"]["batch"] for s in telemetry.spans()
                if s["name"] == "serving.decode.dispatch"}
@@ -293,6 +333,9 @@ def test_an_engine_whose_next_input_is_on_the_host_never_launches_ahead(
         assert snap["decode_steps_ahead"] == 0
         assert snap["decode_drains"] == {reason: snap["decode_steps"]}
         assert srv._flight is None
+        # nor is a first token carried: each is read inside its pass
+        assert (snap["prefills_ahead"], snap["prefill_syncs"]) == (
+            0, {reason: 3})
     finally:
         srv.close()
 
@@ -359,6 +402,11 @@ def test_the_window_compiles_nothing_after_the_benchmarks_warm_up(
         # a wave is two steps, the second launched from the first's tokens
         ahead = srv.snapshot()["throughput"]["decode_steps_ahead"]
         assert ahead >= MAX_BATCH
+        # ... and every whole-prompt prefill's first token was carried: the
+        # two small programs of that are compiled too
+        firsts = srv.snapshot()["throughput"]["prefills_ahead"]
+        assert (firsts > 0) == (config in WHOLE_PROMPT)
+        launched = carried(srv.snapshot()["throughput"])
         mark = introspect.watchdog().mark()
         compiled = eng.decode_compilations, eng.prefill_compilations
         with CompileLog() as log:
@@ -370,5 +418,224 @@ def test_the_window_compiles_nothing_after_the_benchmarks_warm_up(
         assert log.compiled == []
         snap = srv.snapshot()["throughput"]
         assert snap["decode_steps_ahead"] > ahead
+        assert snap["prefills_ahead"] > firsts or config not in WHOLE_PROMPT
+        assert carried(snap) == (
+            launched + len(window) if config in WHOLE_PROMPT else 0)
+    finally:
+        srv.close()
+
+
+# -- (e) -----------------------------------------------------------------------
+
+def admitted_in_flight(srv, requests):
+    """`requests` one by one, each submitted once steps are under way and
+    the one before it has its first token: every admission lands with a
+    step in flight and rows that go on."""
+    keep = [srv.submit(prompt(1, 9), max_new_tokens=40),
+            srv.submit(prompt(3, 12), max_new_tokens=40)]
+    deadline = time.perf_counter() + 120
+    while srv.metrics.tokens_generated < 3:
+        assert time.perf_counter() < deadline
+        time.sleep(0.002)
+    handles = []
+    for p, n, e in requests:
+        handles.append(srv.submit(p, max_new_tokens=n, eos_id=e))
+        while handles[-1].t_first_token is None:
+            assert time.perf_counter() < deadline
+            time.sleep(0.002)
+    got = [list(h.result(timeout=300)) for h in handles]
+    for h in keep:
+        h.result(timeout=300)
+    return handles, got
+
+
+@pytest.mark.parametrize("config", WHOLE_PROMPT)
+def test_a_first_token_that_ends_its_request_ends_it_with_that_one_token(
+        models, config):
+    family, options = CONFIGS[config]
+    model = models[family]
+    plain = [(prompt(2, 20), 6, None), (prompt(4, 12), 5, None)]
+    free = oracle(model, options, plain)
+    # the first ends by its eos in its prefill, the second by its length
+    # there; the third goes on, and its first token is nobody's eos
+    requests = [(plain[0][0], 6, free[0][0]), (plain[1][0], 1, None),
+                (prompt(6, 10), 4, None)]
+    want = oracle(model, options, requests)
+    assert [len(g) for g in want] == [1, 1, 4]
+    assert want[0] == free[0][:1] and want[1] == free[1][:1]
+    telemetry.tracing.clear()
+    srv = serving.serve(model, max_batch=MAX_BATCH, block_size=BS, **options)
+    try:
+        handles, got = admitted_in_flight(srv, requests)
+        assert got == want
+        snap = srv.snapshot()["throughput"]
+        assert snap["prefills_ahead"] >= len(requests)
+        assert carried(snap) == len(requests) + 2
+        spans = telemetry.spans()
+        # the row of the first was launched from its token all the same (the
+        # host had not read it), and dropped at that step's collect: never
+        # appended, counted or recorded. The second was never launched.
+        rows = sum(s["attrs"]["batch"] for s in spans
+                   if s["name"] == "serving.decode.dispatch")
+        assert rows == snap["tokens_generated"] + 1
+        assert snap["tokens_generated"] == 2 * 39 + 3
+        for h, g, (p, _, _) in zip(handles, want, requests):
+            assert h.tokens == p + g
+            served = [s["attrs"]["position"] for s in telemetry.spans(h.trace)
+                      if s["name"] == "serving.token"]
+            assert served == list(range(len(p), len(p) + len(g)))
+    finally:
+        srv.close()         # the pool's audit: no block leaked
+    assert srv.engine.cache.pool.in_use == 0
+
+
+@pytest.mark.parametrize("config", WHOLE_PROMPT)
+def test_two_first_tokens_are_carried_into_one_step_beside_the_rows_that_go_on(
+        models, config):
+    """The engine's half alone, pass by pass: two prompts admitted in one
+    pass while a step is in flight; the step launched behind them takes both
+    tokens on the device, each in a place of the carry no other row has."""
+    family, options = CONFIGS[config]
+    model = models[family]
+    requests = [(prompt(1, 9), 8, None), (prompt(2, 20), 8, None),
+                (prompt(3, 5), 8, None), (prompt(4, 12), 8, None)]
+    want = oracle(model, options, requests)
+    eng = serving.Engine(serving.server._resolve_model(model),
+                         max_batch=MAX_BATCH, block_size=BS, **options)
+    try:
+        assert eng.first_sync_reason is None
+        seqs = [eng.start(p, n, eos_id=e) for p, n, e in requests[:2]]
+        collected, flight = eng.decode_pass(seqs)
+        assert collected == [] and flight.drains == ["first_step"]
+        late = [eng.start(p, n, eos_id=e, hold=True)
+                for p, n, e in requests[2:]]
+        # in flight: nothing of either prefill has been read
+        assert [len(s.tokens) for s in late] == [5, 12]
+        assert all(s.first is not None and s.first.ahead for s in late)
+        ran = eng.prefills_run
+        seqs += late
+        collected, flight = eng.decode_pass(seqs, after=flight)
+        assert len(flight.seqs) == 4 and flight.ahead
+        assert [len(s.tokens) for s in late] == [5, 12]
+        # the step collected before the read did not wait for them
+        assert eng.prefills_run == ran
+        assert list(eng.collect_firsts()) == late
+        assert eng.prefills_run == ran + 2
+        assert [len(s.tokens) for s in late] == [6, 13]
+        assert all(s.first is None for s in late)
+        while flight is not None:
+            collected, flight = eng.decode_pass(seqs, after=flight)
+        assert [list(s.generated) for s in seqs] == want
+    finally:
+        for s in seqs:
+            eng.release(s, reusable=False)
+        eng.close()
+
+
+def test_of_the_prompts_admitted_in_one_pass_the_last_is_the_one_carried(
+        models):
+    """One first token is in flight at a time: a `serving.prefill` span
+    then holds its own program on the device and no other prompt's."""
+    requests = FIRST[:3]
+    want = oracle(models["dense"], {}, requests)
+    telemetry.tracing.clear()
+    srv = serving.serve(models["dense"], max_batch=MAX_BATCH, block_size=BS)
+    try:
+        queued, real = threading.Event(), srv.scheduler.admit
+
+        def admit(*args, **kw):         # the pass waits for all three
+            queued.wait(60)
+            return real(*args, **kw)
+
+        srv.scheduler.admit = admit
+        handles = [srv.submit(p, max_new_tokens=n) for p, n, _ in requests]
+        queued.set()
+        assert [list(h.result(timeout=300)) for h in handles] == want
+        snap = srv.snapshot()["throughput"]
+        assert (snap["prefills_ahead"], snap["prefill_syncs"]) == (
+            1, {"more_admitted": 2})
+        prefills = sorted((s for s in telemetry.spans()
+                           if s["name"] == "serving.prefill"),
+                          key=lambda s: s["ts"])
+        assert [s["attrs"]["ahead"] for s in prefills] == [0, 0, 1]
+        for before, after in zip(prefills, prefills[1:]):   # one at a time
+            assert before["ts"] + before["dur"] <= after["ts"]
+    finally:
+        srv.close()
+
+
+def test_the_synchronous_step_reads_a_first_token_in_flight_before_it_launches(
+        models):
+    """`decode_step` leaves nothing in flight: not a first token either."""
+    request = (prompt(2, 9), 5, None)
+    want, = oracle(models["dense"], {}, [request])
+    eng = serving.Engine(serving.server._resolve_model(models["dense"]),
+                         max_batch=2, block_size=BS)
+    try:
+        seq = eng.start(*request[:2], hold=True)
+        assert seq.first is not None and len(seq.tokens) == 9
+        while not seq.done:
+            assert eng.decode_step([seq]) == [seq]
+            assert seq.first is None
+        assert list(seq.generated) == want
+        eng.release(seq, reusable=False)
+    finally:
+        eng.close()
+
+
+def test_the_device_chooses_the_token_numpy_would(models):
+    """Among equal logits the lowest index, as `np.argmax` on the host."""
+    from mxnet_tpu.serving.engine import carry_first, first_token
+    logits = np.zeros((48,), np.float32)
+    logits[[7, 19, 30]] = 3.5
+    assert int(first_token(jnp.asarray(logits))) == np.argmax(logits) == 7
+    carry = carry_first(jnp.arange(4, dtype=jnp.int32), jnp.int32(9),
+                        jnp.int32(2))
+    assert carry.tolist() == [0, 1, 9, 3]
+    # and through an engine that keeps its logits: the token served is the
+    # best of the logits the prefill's program returned
+    eng = serving.Engine(serving.server._resolve_model(models["dense"]),
+                         max_batch=2, block_size=BS, keep_logits=True)
+    try:
+        seq = eng.start(prompt(2, 9), 2)
+        assert seq.tokens[9] == int(np.argmax(seq.token_logits[0]))
+        eng.release(seq, reusable=False)
+    finally:
+        eng.close()
+
+
+READ_INSIDE = {
+    "paged": lambda srv: None,
+    # a prefill replica's hook, here one that finds no decode replica: the
+    # sequence stays, and its first token was read before the hook was asked
+    "hand_off": lambda srv: setattr(srv, "on_prefill_done",
+                                    lambda srv, req, tokens: False),
+}
+
+
+@pytest.mark.parametrize("reason", sorted(READ_INSIDE))
+def test_a_first_token_needed_at_once_is_read_inside_the_pass(models, reason):
+    options = dict(paged=True, prefill_chunk=8) if reason == "paged" else {}
+    want = oracle(models["dense"], options, FIRST[:4])
+    telemetry.tracing.clear()
+    srv = serving.serve(models["dense"], max_batch=2, block_size=BS,
+                        **options)
+    try:
+        READ_INSIDE[reason](srv)
+        assert srv.engine.sync_reason is None
+        assert srv._first_sync_reason() == reason
+        handles = [srv.submit(p, max_new_tokens=n) for p, n, _ in FIRST[:4]]
+        assert [list(h.result(timeout=300)) for h in handles] == want
+        snap = srv.snapshot()["throughput"]
+        assert (snap["prefills_ahead"], snap["prefill_syncs"]) == (
+            0, {reason: 4})
+        assert snap["decode_steps_ahead"] > 0       # the steps still are
+        assert re.search(r"^serving_prefill_syncs_%s_total\S* 4$" % reason,
+                         srv.prometheus_text(), re.M)
+        spans = {s["id"]: s for s in telemetry.spans()}
+        prefills = [s for s in spans.values() if s["name"] == "serving.prefill"]
+        assert prefills and all(s["attrs"]["ahead"] == 0 for s in prefills)
+        assert all(spans[s["parent"]]["name"] == "serving.admit"
+                   for s in prefills)
     finally:
         srv.close()

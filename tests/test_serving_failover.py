@@ -384,6 +384,11 @@ FAULTS = {
     # the read of the step in flight fails (what an error on the device
     # surfaces as): it and the step launched behind it are both dropped
     "collect_fails": (1, lambda srv: fail_read(srv.engine, 3), "fault"),
+    # the read of a first token in flight fails (ISSUE 46): its prefill had
+    # the pools, and so has the step launched behind it: everything replays
+    "first_read_fails": (1, lambda srv: fail_read(srv.engine, 3,
+                                                  "serving.prefill"),
+                         "fault"),
     "killed_loop_rescued": (2, lambda srv: kill_at_evict(srv.replicas[0]),
                             "fault"),
     "killed_loop_alone": (1, kill_at_evict, "fault"),
@@ -391,11 +396,13 @@ FAULTS = {
 }
 
 
-def fail_read(eng, at):
+def fail_read(eng, at, of="serving.decode"):
+    """The `at`-th read of a step's tokens (or, `of` = "serving.prefill",
+    of a whole-prompt prefill's first token) raises."""
     real, calls = eng._read_back, {"n": 0}
 
     def read_back(span, result, stats):
-        if span.name == "serving.decode":
+        if span.name == of:
             calls["n"] += 1
             if calls["n"] == at:
                 raise RuntimeError("injected fault in the read")
@@ -442,6 +449,123 @@ def test_a_fault_with_a_step_in_flight_loses_and_doubles_no_token(tiny_lm,
             time.sleep(0.01)
         assert engine.cache.pool.in_use == 0
         engine.audit_quiescent()
+    finally:
+        srv.close()
+
+
+# -- a fault with a first token in flight (ISSUE 46) ---------------------------
+#
+# A whole-prompt prefill is launched and its first token read only behind
+# the launch of the step that takes it on the device. What fails at that
+# read had consumed the pools; what fails before it drops the token unread.
+
+#: two that decode for long enough to be running still when the third comes
+LATE = [(arith_prompt(3, 2, 6), 40), (arith_prompt(4, 1, 9), 44),
+        (arith_prompt(5, 3, 7), 12)]
+
+
+def running_and_late(srv, arm):
+    """Two requests decoding, then `arm()`, then a third: its prefill is
+    launched with a step in flight and rows that go on."""
+    reqs = [srv.submit(p, max_new_tokens=n) for p, n in LATE[:2]]
+    deadline = time.time() + 60
+    while srv.metrics.tokens_generated < 4:
+        assert time.time() < deadline
+        time.sleep(0.002)
+    arm()
+    return reqs + [srv.submit(LATE[2][0], max_new_tokens=LATE[2][1])]
+
+
+def test_a_fault_at_the_read_of_a_first_token_in_flight_replays_everything(
+        tiny_lm):
+    want = [oracle_tokens(tiny_lm, p, n) for p, n in LATE]
+    srv = serving.serve(tiny_lm, max_batch=4, block_size=8)
+    eng = srv.engine
+    try:
+        seen = {}
+        real_replay = srv._replay_all
+
+        def replay_all(err, req=None):
+            seen.update(held=len(srv.scheduler.running), req=req,
+                        firsts=len(eng._firsts), err=str(err))
+            return real_replay(err, req)
+
+        srv._replay_all = replay_all
+        reqs = running_and_late(
+            srv, lambda: fail_read(eng, 1, "serving.prefill"))
+        calls = [count_finishes(r) for r in reqs]
+        assert [r.result(timeout=120) for r in reqs] == want
+        assert [c["n"] for c in calls] == [1, 1, 1]
+        # the pools were made anew and all three sequences replayed, the
+        # late one from its prompt alone: it was running, its token unread
+        assert eng.pools_lost == 1 and not eng.cache.lost()
+        assert (seen["held"], seen["req"], seen["firsts"]) == (3, None, 0)
+        assert "PoolsLost" in seen["err"]
+        snap = srv.snapshot()
+        assert snap["requests"]["engine_failures"] == 1
+        assert snap["requests"]["failovers"] == 3
+        assert snap["requests"]["failed"] == 0
+        assert snap["throughput"]["decode_drains"]["fault"] == 1
+        # the failed read made no first token; the replays' three did
+        # (one in flight at a time: of two admitted at once the last)
+        th = snap["throughput"]
+        assert th["prefills_ahead"] + th["prefill_syncs"].get(
+            "more_admitted", 0) == 5
+        deadline = time.time() + 60
+        while eng.cache.pool.in_use and time.time() < deadline:
+            time.sleep(0.01)
+        eng.audit_quiescent()
+    finally:
+        srv.close()
+
+
+def test_a_dropped_step_in_flight_drops_the_first_token_launched_into_it(
+        tiny_lm):
+    """The pass that admitted the late request fails before anything is
+    read: `_drop_flight` forgets the step AND the first token in flight, so
+    every sequence's tokens are exactly those of collected steps and
+    prefills, and the replay chooses both dropped tokens again."""
+    want = [oracle_tokens(tiny_lm, p, n) for p, n in LATE]
+    srv = serving.serve(tiny_lm, max_batch=4, block_size=8)
+    eng = srv.engine
+    try:
+        seen = {"replayed": {}}
+        real_pass, real_replay = eng.decode_pass, srv._replay
+
+        def decode_pass(seqs, after=None, **kw):
+            if any(s.first is not None for s in seqs) and after is not None \
+                    and "dropped" not in seen:
+                seen["dropped"] = {id(s.request): list(s.tokens)
+                                   for s in seqs}
+                seen["in_flight"] = len(eng._firsts)
+                raise RuntimeError("injected fault in the pass")
+            return real_pass(seqs, after=after, **kw)
+
+        def replay(req, tokens, err):
+            seen["replayed"][id(req)] = list(tokens)
+            seen["left"] = len(eng._firsts)
+            return real_replay(req, tokens, err)
+
+        def arm():
+            eng.decode_pass, srv._replay = decode_pass, replay
+
+        reqs = running_and_late(srv, arm)
+        assert [r.result(timeout=120) for r in reqs] == want
+        assert seen["in_flight"] == 1 and seen["left"] == 0
+        assert eng.pools_lost == 0
+        # what each was replayed from is what it had when the pass began:
+        # the late one its prompt, the others every collected token
+        assert seen["replayed"] == seen["dropped"]
+        late = reqs[2]
+        assert seen["replayed"][id(late)] == list(late.prompt)
+        for r in reqs[:2]:
+            assert len(seen["replayed"][id(r)]) > len(r.prompt)
+        snap = srv.snapshot()["throughput"]
+        assert snap["decode_drains"]["fault"] == 1
+        deadline = time.time() + 60
+        while eng.cache.pool.in_use and time.time() < deadline:
+            time.sleep(0.01)
+        eng.audit_quiescent()
     finally:
         srv.close()
 

@@ -239,9 +239,17 @@ def test_every_decoding_iteration_records_one_tree(tiny_lm, paged):
     assert appended == 4 + 5 + 6
     admits = [s for s in spans if s["name"] == "serving.admit"]
     assert sum(s["attrs"]["admitted"] for s in admits) == 3
-    for s in spans:             # prefills and queue waits hang under admit
+    for s in spans:
+        # queue waits hang under admit, and so does a prefill read there (a
+        # chunk); a whole prompt's outlives the admission, its first token
+        # read behind the pass's launch (ISSUE 46): it hangs under the pass
         if s["name"] in ("serving.prefill", "serving.queue"):
-            assert ids[s["parent"]]["name"] == "serving.admit"
+            carried = s["name"] == "serving.prefill" and s["attrs"]["ahead"]
+            assert ids[s["parent"]]["name"] == (
+                "serving.loop" if carried else "serving.admit")
+    # the last prompt of every pass that admitted whole prompts is carried
+    assert any(s["name"] == "serving.prefill" and s["attrs"]["ahead"]
+               for s in spans) == (not paged)
     # the fine-grained spans stay out of the flight ring
     flown = {e["name"] for e in telemetry.flight().events()}
     assert {"serving.loop", "serving.admit", "serving.decode"} <= flown

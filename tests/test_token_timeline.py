@@ -11,8 +11,12 @@ between two steps marks the NEXT token of exactly the rows then running;
 is the record the program already made, under another name: as many a step
 as rows advanced, none with telemetry off, and `serving.decode` names the
 batch-level step alone; (f) the stamps `tpot_p90_ms` reads are taken where
-they were.
+they were; (g) a whole-prompt prefill whose first token the next step takes
+on the device (ISSUE 46) keeps a `serving.prefill` span from its dispatch to
+the read of that token, behind the pass's launch, and counts as run from
+that read on.
 """
+import re
 import time
 
 import pytest
@@ -109,7 +113,13 @@ def test_a_requests_tokens_are_a_gapless_chain_from_the_first(tiny_lm,
             h.t_first_token * 1e6 - end_us(first), abs=2)
         assert first["attrs"]["stamp_lag_us"] >= 0
         assert first["ts"] >= h.t_admit * 1e6 - 1
-        assert spans[first["parent"]]["name"] == "serving.admit"
+        # read inside the admission, or (a whole prompt's, carried on the
+        # device) behind the launch of the pass's step
+        prefill = [s for s in telemetry.spans(h.trace)
+                   if s["name"] == "serving.prefill"][-1]
+        assert spans[first["parent"]]["name"] == (
+            "serving.loop" if prefill["attrs"]["ahead"] else "serving.admit")
+        assert not prefill["attrs"]["ahead"] or config == "gather"
         for s in rest:
             assert "first" not in s["attrs"] and "ahead" in s["attrs"]
             assert spans[s["parent"]]["name"] == (
@@ -218,16 +228,19 @@ def test_an_admission_between_two_steps_marks_the_next_token_of_the_rows_running
         line = assert_chain(h, n)
         since = [s for s in line if end_us(s) > late.t_submit * 1e6]
         marked = [s for s in since if s["attrs"]["prefills"]]
-        # exactly one, the next: the first to end after that prefill did
+        # exactly one, the next: the first to end after that prefill did,
+        # the token of the step that ran BEHIND the prefill on the device
         assert len(marked) == 1 and len(since) < len(line) - 1
         assert (marked[0]["attrs"]["prefills"],
                 marked[0]["attrs"]["prefill_tokens"]) == (1, bucket)
-        assert marked[0]["ts"] <= first["ts"] \
-            and end_us(marked[0]) >= end_us(first)
+        assert marked[0]["ts"] <= end_us(first) <= end_us(marked[0])
         assert marked[0] is min(
             (s for s in line if end_us(s) >= end_us(first)), key=end_us)
-        # the gap holds the prefill: it is no shorter than it
-        assert marked[0]["dur"] >= first["dur"]
+        # the token collected before the prefill's in the same pass (the
+        # step in flight when the prompt was admitted) did not wait for it
+        before = line[line.index(marked[0]) - 1]
+        assert first["ts"] <= end_us(before) <= end_us(first)
+        assert before["attrs"]["prefills"] == 0
 
 
 def test_a_failovers_replay_keeps_the_chain(tiny_lm):
@@ -306,8 +319,9 @@ def test_with_telemetry_off_no_record_is_made_and_the_tokens_are_the_same(
 
 def test_the_stamps_tpot_reads_are_taken_where_they_were(tiny_lm):
     """`t_first_token` (and the client's, the same for a fresh request) in
-    the admitting pass after the prefill's span has closed; `t_done` in the
-    account of the pass that read the last token, after it."""
+    the admitting pass after the prefill's span has closed, which is after
+    the pass's step was launched; `t_done` in the account of the pass that
+    read the last token, after it."""
     telemetry.tracing.clear()
     srv = serving.serve(tiny_lm, max_batch=2, block_size=BS)
     try:
@@ -320,11 +334,90 @@ def test_the_stamps_tpot_reads_are_taken_where_they_were(tiny_lm):
     prefill, = [s for s in spans if s["name"] == "serving.prefill"]
     admit, = [s for s in spans if s["name"] == "serving.admit"
               and s["ts"] <= prefill["ts"] < end_us(s)]
+    loop, = [s for s in spans if s["id"] == admit["parent"]]
+    sent, = [s for s in spans if s["name"] == "serving.decode.dispatch"
+             and loop["ts"] <= s["ts"] < end_us(loop)]
     assert h.t_client_first_token == h.t_first_token
-    assert end_us(prefill) <= h.t_first_token * 1e6 <= end_us(admit)
+    assert end_us(admit) <= end_us(sent) <= end_us(prefill)
+    assert end_us(prefill) <= h.t_first_token * 1e6 <= end_us(loop)
     # the first token could be read before the prefill's span closed
     assert prefill["ts"] < end_us(line[0]) <= end_us(prefill)
     account = max((s for s in spans if s["name"] == "serving.account"),
                   key=end_us)
     assert account["ts"] <= h.t_done * 1e6 <= end_us(account) + 1
     assert end_us(line[-1]) <= account["ts"]
+
+
+# -- (g) -----------------------------------------------------------------------
+
+def carried(config, tiny_lm):
+    """A family that prefills whole prompts: the dense one, or one with
+    experts, whose prefill counts the pairs it routed."""
+    if config == "gather":
+        return tiny_lm
+    import test_serving_ahead as ahead
+    weights = ahead.latent_family.make_weights(ahead.LATENT, 11)
+    return (ahead.latent_family.program_params(weights),
+            ahead.latent_family.program_config(ahead.LATENT, 64))
+
+
+@pytest.mark.parametrize("config", ["gather", "experts"])
+def test_a_carried_prefills_span_runs_from_its_dispatch_to_the_read_of_its_token(
+        tiny_lm, config):
+    model, requests = carried(config, tiny_lm), REQUESTS
+    telemetry.tracing.clear()
+    srv = serving.serve(model, max_batch=4, block_size=BS)
+    try:
+        keep = srv.submit(prompt(1, 7), max_new_tokens=40)
+        deadline = time.monotonic() + 120
+        while srv.metrics.tokens_generated < 3:     # a step is in flight
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        handles = []
+        for p, n in requests:           # one a pass: each is its pass's last
+            handles.append(srv.submit(p, max_new_tokens=n))
+            while handles[-1].t_first_token is None:
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+        for h in handles + [keep]:
+            h.result(timeout=300)
+        snap = srv.snapshot()["throughput"]
+        ran = srv.engine.prefills_run
+        assert re.search(r"^serving_prefills_ahead_total\S* %d$" % ran,
+                         srv.prometheus_text(), re.M)
+    finally:
+        srv.close()
+    spans = telemetry.spans()
+    by_id = {s["id"]: s for s in spans}
+    for h, (p, _) in zip(handles, requests):
+        prefill, = [s for s in telemetry.spans(h.trace)
+                    if s["name"] == "serving.prefill"]
+        attrs = prefill["attrs"]
+        assert (attrs["ahead"], attrs["bucket"], attrs["length"]) == (
+            1, pow2_bucket(len(p), lo=8), len(p))
+        assert (attrs.get("moe_pairs", 0) > 0) == (config == "experts")
+        # under the pass, not under its admission, which it outlives: it
+        # ends after the pass's step was launched, where the host read its
+        # token, which is where the first token's record ends
+        loop = by_id[prefill["parent"]]
+        assert loop["name"] == "serving.loop"
+        admit, = [s for s in spans if s["name"] == "serving.admit"
+                  and s["parent"] == loop["id"]]
+        sent, = [s for s in spans if s["name"] == "serving.decode.dispatch"
+                 and loop["ts"] <= s["ts"] < end_us(loop)]
+        assert admit["ts"] <= prefill["ts"] < end_us(admit) <= sent["ts"]
+        assert end_us(sent) <= end_us(prefill) <= end_us(loop)
+        first = timeline(h)[0]
+        assert first["attrs"]["first"] == 1
+        assert end_us(first) == end_us(prefill)
+        assert first["attrs"]["prefills"] >= 1
+        # the pass's own step span closed with the read of the step before:
+        # the prefill's wait is not under it
+        step, = [s for s in spans if s["name"] == "serving.decode"
+                 and s["parent"] == loop["id"]]
+        assert end_us(step) <= end_us(prefill)
+    # the counters say how often it engaged: every prefill run gave a first
+    # token, each carried or read inside its pass for a reason
+    assert snap["prefills_ahead"] + sum(snap["prefill_syncs"].values()) \
+        == ran == len(requests) + 1
+    assert snap["prefill_syncs"] == {}
